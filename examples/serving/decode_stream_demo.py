@@ -16,7 +16,6 @@ import sys
 import threading
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", ".."))
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
 from mxtpu.serving import ServingHTTPServer  # noqa: E402
 from mxtpu.serving.decode import (DecodeSession,  # noqa: E402
@@ -43,9 +42,11 @@ def main():
     server = ServingHTTPServer(None, decode=sess, port=args.port)
     t = threading.Thread(target=server.serve_forever, daemon=True)
     t.start()
-    print("decode serving on %s (slots %d, %d KV blocks of %d tokens)"
-          % (server.endpoint, sess.slot_capacity,
-             sess.arena.blocks_total, sess.block_size))
+    ctx = sess.pool.replicas[0].ctx
+    print("decode serving on %s (%s=%s, slots %d, %d KV blocks of %d "
+          "tokens)"
+          % (server.endpoint, ctx, ctx.jax_device.platform,
+             sess.slot_capacity, sess.arena.blocks_total, sess.block_size))
 
     if args.serve:
         print("POST %s/v1/generate?stream=1 | GET /debug/state | "

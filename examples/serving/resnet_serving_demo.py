@@ -15,7 +15,6 @@ import urllib.request
 import numpy as np
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", ".."))
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
 from mxtpu.models.serving_fixtures import get_fixture  # noqa: E402
 from mxtpu.serving import ServingHTTPServer, ServingSession  # noqa: E402
@@ -38,8 +37,10 @@ def main():
     server = ServingHTTPServer(session, port=args.port)
     t = threading.Thread(target=server.serve_forever, daemon=True)
     t.start()
-    print("serving on %s (buckets %s, %d replica(s))"
-          % (server.endpoint, list(session.buckets), len(session.pool)))
+    print("serving on %s (buckets %s, %d replica(s) on %s)"
+          % (server.endpoint, list(session.buckets), len(session.pool),
+             ", ".join("%s=%s" % (r.ctx, r.ctx.jax_device.platform)
+                       for r in session.pool.replicas)))
 
     if args.serve:
         print("POST %s/v1/predict | GET /v1/metrics | GET /healthz"

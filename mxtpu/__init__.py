@@ -15,9 +15,8 @@ from .libinfo import __version__  # single source of truth
 
 from . import base
 
-# multi-process CPU collectives (2-process kvstore tests, CPU pod runs)
-# need gloo selected before the CPU backend initializes
-base.select_cpu_collectives()
+# the persistent compile cache must be placed before the first compile
+base.configure_compile_cache()
 from .base import MXNetError, MXTPUError
 from . import attribute
 from .attribute import AttrScope

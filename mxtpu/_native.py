@@ -6,24 +6,24 @@ host-side runtime (storage pool, recordio, dependency engine, threaded
 prefetch — see src/core/); everything device-side is JAX/XLA.
 
 If the library is missing, we try a one-shot build via ``make -C src``
-(toolchain is assumed present in dev images); failing that, every
-consumer falls back to a pure-Python path, so the framework stays fully
-functional — just without the native fast paths.
+(toolchain is assumed present in dev images); failing that, the failure
+is logged once at WARNING with make's stderr and every consumer takes
+its pure-Python path — functional, without the native fast paths.
 """
 from __future__ import annotations
 
 import ctypes
+import logging
 import os
 import subprocess
 import threading
 
-from .base import NativeError
+from .base import _REPO_ROOT, NativeError
 
 _LIB = None
 # mxtpu: allow-raw-lock(library-loader bootstrap: taken once before
 # any subsystem exists; leaf by construction)
 _LIB_LOCK = threading.Lock()
-_REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 _LIB_PATH = os.path.join(os.path.dirname(os.path.abspath(__file__)),
                          "native", "libmxtpu.so")
 
@@ -34,16 +34,23 @@ ASYNC_FN = ctypes.CFUNCTYPE(None, ctypes.c_void_p)
 
 
 def _try_build():
+    """One-shot ``make -C src``; runs at most once a process (get_lib
+    latches the outcome), so a failure is reported exactly once."""
     src = os.path.join(_REPO_ROOT, "src")
     if not os.path.isfile(os.path.join(src, "Makefile")):
         return False
     try:
         subprocess.run(["make", "-C", src], check=True,
-                       stdout=subprocess.DEVNULL, stderr=subprocess.DEVNULL,
+                       stdout=subprocess.DEVNULL, stderr=subprocess.PIPE,
                        timeout=120)
-        return os.path.isfile(_LIB_PATH)
-    except Exception:
+    except (OSError, subprocess.SubprocessError) as exc:
+        stderr = getattr(exc, "stderr", None) or b""
+        logging.getLogger(__name__).warning(
+            "native runtime build failed (%s); continuing on the "
+            "pure-Python paths. make stderr:\n%s", exc,
+            stderr.decode("utf-8", "replace")[-2000:])
         return False
+    return os.path.isfile(_LIB_PATH)
 
 
 def _declare(lib):
@@ -104,7 +111,10 @@ def get_lib():
             lib = ctypes.CDLL(_LIB_PATH)
             _declare(lib)
             _LIB = lib
-        except OSError:
+        except OSError as exc:
+            logging.getLogger(__name__).warning(
+                "native runtime %s failed to load (%s); continuing on "
+                "the pure-Python paths", _LIB_PATH, exc)
             _LIB = False
             return None
     return _LIB

@@ -164,19 +164,38 @@ class PrefixOpNamespace:
                 if k.startswith(self._prefix)]
 
 
-def select_cpu_collectives():
-    """Select the gloo CPU-collectives implementation when this process is
-    part of a jax.distributed cluster. Must run BEFORE the CPU backend
-    initializes; the default 'none' makes any cross-process psum/allgather
-    fail with "Multiprocess computations aren't implemented on the CPU
-    backend". No-op when not distributed or on jax versions without the
-    flag. Called from package import AND from the dist kvstore constructor
-    so both `initialize → import mxtpu` and `import mxtpu → initialize`
-    orders are covered."""
-    try:
+_REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+def cpu_forced():
+    """True when JAX was held to the CPU platform (``JAX_PLATFORMS=cpu``
+    or the ``jax_platforms`` config — what tests/conftest.py and the
+    tier-1 command set). The one condition under which ``tpu()``/``gpu()``
+    contexts may alias CPU devices."""
+    import jax
+    return (jax.config.jax_platforms or "").strip().lower() == "cpu"
+
+
+def compile_cache_dir(environ=None):
+    """Where this checkout keeps JAX's persistent compilation cache, or
+    None when ``JAX_COMPILATION_CACHE_DIR`` places it from outside (JAX
+    reads that variable itself; nothing here may override it). The
+    directory is fixed — never derived from a pid, the clock, a tempdir
+    or the cwd — because a cache that moves never hits."""
+    environ = os.environ if environ is None else environ
+    if environ.get("JAX_COMPILATION_CACHE_DIR"):
+        return None
+    return os.path.join(_REPO_ROOT, ".jax_cache")
+
+
+def configure_compile_cache():
+    """Point JAX at :func:`compile_cache_dir` — the one place in the tree
+    that sets a cache directory. Called once at package import. A process
+    held to the CPU keeps JAX's default (no persistent cache): every
+    XLA:CPU cache hit logs two multi-KB "machine type doesn't match"
+    errors about tuning pseudo-features on the very machine that wrote
+    the entry, and the programs worth keeping are the chip's."""
+    path = compile_cache_dir()
+    if path is not None and not cpu_forced():
         import jax
-        from jax._src import distributed as _jd
-        if getattr(_jd.global_state, "client", None) is not None:
-            jax.config.update("jax_cpu_collectives_implementation", "gloo")
-    except Exception:  # flag renamed/absent on other jax versions
-        pass
+        jax.config.update("jax_compilation_cache_dir", path)

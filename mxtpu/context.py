@@ -12,7 +12,7 @@ import threading
 
 import jax
 
-from .base import MXNetError
+from .base import MXNetError, cpu_forced
 
 __all__ = ["Context", "cpu", "gpu", "tpu", "current_context", "num_gpus", "num_devices"]
 
@@ -26,10 +26,24 @@ def _cpu_devices():
         return jax.local_devices()
 
 
+def _resolve_accel(devices, forced):
+    """The devices ``tpu(i)``/``gpu(i)`` index into: the non-CPU ones of
+    ``devices``. With none present the CPU devices stand in ONLY when the
+    CPU platform was forced (``JAX_PLATFORMS=cpu``, the test mesh);
+    otherwise a missing accelerator is an error, never a silent host run."""
+    accel = [d for d in devices if d.platform != "cpu"]
+    if accel:
+        return accel
+    if forced:
+        return list(devices)
+    raise MXNetError(
+        "no accelerator: jax found only platform(s) %s. tpu()/gpu() "
+        "contexts alias the CPU only under JAX_PLATFORMS=cpu"
+        % sorted({d.platform for d in devices}))
+
+
 def _accel_devices():
-    """Non-CPU local JAX devices, else CPU (covers the forced-CPU test mesh)."""
-    devs = [d for d in jax.local_devices() if d.platform != "cpu"]
-    return devs if devs else _cpu_devices()
+    return _resolve_accel(jax.local_devices(), cpu_forced())
 
 
 class Context:

@@ -94,10 +94,6 @@ class KVStore:
             "kvstore.pull", max_attempts=attempts, backoff_s=backoff,
             backoff_cap_s=1.0)
         if kind.startswith("dist"):
-            # covers the mxtpu-first import order (the import-time call in
-            # mxtpu/__init__.py only sees clusters initialized earlier)
-            from .base import select_cpu_collectives
-            select_cpu_collectives()
             from . import kvstore_server as kvs
 
             env = kvs.cluster_env()

@@ -243,13 +243,8 @@ class BaseModule:
         if device_prefetch:
             from .. import io as _io
             if not isinstance(train_data, _io.DevicePrefetchIter):
-                device = None
                 ctxs = getattr(self, "_context", None)
-                if ctxs:
-                    try:
-                        device = ctxs[0].jax_device
-                    except Exception:
-                        device = None
+                device = ctxs[0].jax_device if ctxs else None
                 train_data = owned_iter = _io.DevicePrefetchIter(
                     train_data, device=device)
 

@@ -325,11 +325,9 @@ class FusedTrainStep:
         self._idx2name = idx2name
         self._name_idx = [name2idx[n] for n in self.trainable]
         # Selective rematerialization (MXTPU_REMAT):
-        #   none/0 — keep every residual XLA wants. DEFAULT: measured
-        #            fastest on v5e for ResNet-50 (docs/perf.md r3 table —
-        #            the step is bandwidth-bound and recompute re-streams
-        #            the same bytes, so remat LOSES throughput here; it
-        #            remains the memory-capacity lever, not a speed lever)
+        #   none/0 — keep every residual XLA wants. DEFAULT: remat is
+        #            the memory-capacity lever; its cost in step time on
+        #            the chip is not measured (PERF.md)
         #   block  — save ONLY block-boundary activations (dataflow cut
         #            vertices, executor._block_boundaries); backward
         #            recomputes each block's interior. Largest memory
@@ -916,11 +914,9 @@ class FusedTrainStep:
         The arrays stay ON DEVICE: a single jitted tree-copy snapshots
         every parameter (so the next step's donation can't invalidate the
         returned buffers), and the NDArrays wrap the copies zero-transfer.
-        On a remote/tunneled runtime a host export costs a full round trip
-        PER ARRAY (~40 s per epoch for ResNet-50's ~270 params), which
-        turned Module.fit's epoch-end get_params into the dominant cost;
-        host bytes are only materialized when something actually reads them
-        (asnumpy / nd.save's packed bulk fetch)."""
+        Host bytes are only materialized when something actually reads
+        them (asnumpy / nd.save's packed bulk fetch), so Module.fit's
+        epoch-end get_params moves nothing to the host by itself."""
         from .. import ndarray as nd
         snap_p, snap_a = _snapshot((self.params, self.aux))
         args = {n: nd.NDArray(v) for n, v in snap_p.items()}

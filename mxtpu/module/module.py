@@ -426,10 +426,7 @@ class Module(BaseModule):
             n = len(self._context)
             if n > 1 and self._exec_group.batch_size % n != 0:
                 return
-            try:
-                devices = [c.jax_device for c in self._context]
-            except Exception:
-                return
+            devices = [c.jax_device for c in self._context]
         shapes, types = self._pipeline_hints()
         self._fused = _fused.FusedTrainStep(
             self._symbol, devices, self._param_names, self._data_names,

@@ -584,12 +584,13 @@ def _pack_flat(xs):
 def _bulk_to_numpy(arrays):
     """Fetch many (possibly device-resident) arrays to host numpy.
 
-    On a remote/tunneled runtime every device->host read is a full round
-    trip (~70-150 ms) and PJRT does not pipeline them, so fetching a model
-    checkpoint array-by-array costs minutes. Instead: group the on-device
-    arrays by dtype, concatenate each group into ONE flat buffer in a
-    single jitted program, fetch the few packed buffers, and split on the
-    host. Host-resident inputs pass straight through."""
+    Group the on-device arrays by dtype, concatenate each group into ONE
+    flat buffer in a single jitted program, fetch the few packed buffers,
+    and split on the host: one transfer per dtype instead of one per
+    array. Host-resident inputs pass straight through. Measured on one
+    TPU v5e (PERF.md, PR 21) for ResNet-50's 255 parameter and aux arrays
+    (102 MB): 0.049 s packed against 0.12-0.17 s array by array, after a
+    first call that compiles the 255-operand concatenate for 15.6 s."""
     out = [None] * len(arrays)
     dev_idx = []
     for i, a in enumerate(arrays):

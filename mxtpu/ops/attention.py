@@ -15,8 +15,11 @@ Backward runs through a jax.custom_vjp whose residual-free bwd recomputes
 with the pure-jnp reference (identical math) — the standard
 recompute-in-bwd tradeoff flash attention makes anyway.
 
-On non-TPU backends the kernel runs in interpret mode for small shapes
-(tests) and falls back to the jnp reference otherwise.
+Which forward runs is decided per compiled program, from the platform the
+program is lowered for (``lax.platform_dependent``): on ``tpu`` always the
+Mosaic kernel; on ``cpu`` the same kernel in interpret mode for small
+shapes (tests) and the jnp reference otherwise. No other platform has a
+branch, so lowering for one is an error rather than a quiet substitute.
 """
 from __future__ import annotations
 
@@ -24,15 +27,10 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from .registry import register
-
-try:  # pallas import kept soft so CPU-only installs still import this module
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-    _HAVE_PALLAS = True
-except ImportError:  # pragma: no cover
-    _HAVE_PALLAS = False
 
 NEG_INF = -1e30
 
@@ -176,20 +174,29 @@ def _flash_call(q, k, v, scale, causal, block_q, block_k, interpret):
     )(q, k, v)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
-def _flash3(q, k, v, scale, causal, block_q, block_k):
-    if not _HAVE_PALLAS:
-        return _reference(q, k, v, scale, causal)
-    on_tpu = jax.default_backend() == "tpu"
-    if not on_tpu:
+@functools.partial(jax.jit, static_argnums=(3, 4, 5, 6))
+def _forward(q, k, v, scale, causal, block_q, block_k):
+    """The forward for the platform this program is compiled for. Jitted
+    so the choice follows the operands' device even when called eagerly
+    (a CPU-committed operand on a chip host must not reach Mosaic)."""
+    def on_tpu(q, k, v):
+        return _flash_call(q, k, v, scale, causal, block_q, block_k,
+                           interpret=False)
+
+    def on_cpu(q, k, v):
         # interpret mode exercises the kernel logic on CPU for small
         # problems; big CPU shapes take the reference path
         if q.shape[0] * q.shape[1] * k.shape[1] <= 1 << 22:
             return _flash_call(q, k, v, scale, causal, block_q, block_k,
                                interpret=True)
         return _reference(q, k, v, scale, causal)
-    return _flash_call(q, k, v, scale, causal, block_q, block_k,
-                       interpret=False)
+
+    return jax.lax.platform_dependent(q, k, v, tpu=on_tpu, cpu=on_cpu)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
+def _flash3(q, k, v, scale, causal, block_q, block_k):
+    return _forward(q, k, v, scale, causal, block_q, block_k)
 
 
 def _flash3_fwd(q, k, v, scale, causal, block_q, block_k):

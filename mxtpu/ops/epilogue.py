@@ -8,7 +8,7 @@ folded from batch stats, gamma, beta — batch_norm-inl.h's normalize step);
 fusing it with the activation and the block-join add means the conv
 output is read ONCE and the block input written ONCE. XLA usually builds
 the same fusion by itself — `tools/bench_epilogue.py` measures whether
-there is anything left on the table (the answer feeds docs/perf.md).
+there is anything left on the table (not measured yet; see PERF.md).
 
 Layout: channel-minor (M, C) tiles, the TPU-native layout (C is the
 128-lane axis). NCHW callers reshape/transpose outside; the microbench
@@ -18,13 +18,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
-
-try:
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu  # noqa: F401
-    _HAVE_PALLAS = True
-except Exception:  # pragma: no cover - pallas always present in this env
-    _HAVE_PALLAS = False
+from jax.experimental import pallas as pl
 
 
 def _kernel(x_ref, s_ref, b_ref, r_ref, o_ref):
@@ -36,11 +30,14 @@ def _kernel(x_ref, s_ref, b_ref, r_ref, o_ref):
     o_ref[...] = y.astype(o_ref.dtype)
 
 
-def bn_apply_relu_add(x, scale, shift, residual=None, block_m=1024,
-                      interpret=False):
+@functools.partial(jax.jit, static_argnames=("block_m",))
+def bn_apply_relu_add(x, scale, shift, residual=None, block_m=1024):
     """y = relu(x * scale + shift) [+ residual], one HBM pass.
 
     x (M, C) bf16/f32; scale/shift (C,) f32; residual optional (M, C).
+    Mosaic-compiled when the program is lowered for ``tpu``; interpret
+    mode only when it is lowered for ``cpu`` (the tests). Jitted so the
+    choice follows the operands' device, not the process default.
     """
     m, c = x.shape
     block_m = min(block_m, m)
@@ -62,14 +59,18 @@ def bn_apply_relu_add(x, scale, shift, residual=None, block_m=1024,
         def kern(x_ref, s_ref, b_ref, o_ref):
             return _kernel(x_ref, s_ref, b_ref, None, o_ref)
 
-    return pl.pallas_call(
-        kern,
-        out_shape=jax.ShapeDtypeStruct((m, c), x.dtype),
-        grid=grid,
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((block_m, c), lambda i: (i, 0)),
-        interpret=interpret,
-    )(*args)
+    def call(interpret):
+        return pl.pallas_call(
+            kern,
+            out_shape=jax.ShapeDtypeStruct((m, c), x.dtype),
+            grid=grid,
+            in_specs=in_specs,
+            out_specs=pl.BlockSpec((block_m, c), lambda i: (i, 0)),
+            interpret=interpret,
+        )
+
+    return jax.lax.platform_dependent(
+        *args, tpu=call(False), cpu=call(True))
 
 
 def bn_apply_relu_add_reference(x, scale, shift, residual=None):

@@ -17,7 +17,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as P
-from ._compat import shard_map
 
 __all__ = ["moe_apply", "moe_apply_topk", "load_balancing_loss"]
 
@@ -90,10 +89,10 @@ def moe_apply(expert_fn, expert_params, gate_logits, x, mesh=None,
     # cannot prove replication through all_to_all/all_gather — so the VMA
     # check is disabled for this map; test_moe_expert_parallel asserts the
     # exact values instead.
-    return shard_map(local_fn, mesh=mesh,
-                     in_specs=(pspec, P(), P()),
-                     out_specs=P(), check_vma=False)(expert_params,
-                                                     gate_logits, x)
+    return jax.shard_map(local_fn, mesh=mesh,
+                         in_specs=(pspec, P(), P()),
+                         out_specs=P(), check_vma=False)(expert_params,
+                                                         gate_logits, x)
 
 
 def load_balancing_loss(gate_logits, choice_onehot):
@@ -176,7 +175,7 @@ def moe_apply_topk(expert_fn, expert_params, gate_logits, x, k=2, mesh=None,
         return routed, aux
 
     pspec = jax.tree.map(lambda _: P(axis_name), expert_params)
-    return shard_map(local_fn, mesh=mesh,
-                     in_specs=(pspec, P(), P()),
-                     out_specs=(P(), P()), check_vma=False)(
-                         expert_params, gate_logits, x)
+    return jax.shard_map(local_fn, mesh=mesh,
+                         in_specs=(pspec, P(), P()),
+                         out_specs=(P(), P()), check_vma=False)(
+                             expert_params, gate_logits, x)

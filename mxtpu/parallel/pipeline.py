@@ -19,9 +19,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.sharding import NamedSharding, PartitionSpec as P
-from ._compat import shard_map
-
-from ._compat import _to_varying
 
 __all__ = ["pipeline_apply", "stack_stage_params"]
 
@@ -104,8 +101,8 @@ def pipeline_apply(stage_fn, stacked_params, x, mesh=None,
         # with a composed data axis the activations vary over BOTH axes
         # (each data row pipelines its own shard)
         vary = (axis_name, batch_axis) if batch_axis else axis_name
-        acts0 = _to_varying(acts0, vary)
-        outputs0 = _to_varying(outputs0, vary)
+        acts0 = lax.pcast(acts0, vary, to="varying")
+        outputs0 = lax.pcast(outputs0, vary, to="varying")
         (acts, outputs), _ = lax.scan(tick, (acts0, outputs0),
                                       jnp.arange(n_ticks))
         # only the last stage holds real outputs; share them with everyone
@@ -113,7 +110,7 @@ def pipeline_apply(stage_fn, stacked_params, x, mesh=None,
             jnp.where(sidx == n_stages - 1, outputs, 0.0), axis_name)
         return outputs.reshape(xl.shape[0], *out_shape.shape[1:])
 
-    return shard_map(local_fn, mesh=mesh,
-                     in_specs=(jax.tree.map(lambda _: pspec, stacked_params),
-                               xspec),
-                     out_specs=xspec)(stacked_params, x)
+    return jax.shard_map(local_fn, mesh=mesh,
+                         in_specs=(jax.tree.map(lambda _: pspec,
+                                                stacked_params), xspec),
+                         out_specs=xspec)(stacked_params, x)

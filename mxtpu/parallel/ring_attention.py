@@ -19,9 +19,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from ._compat import shard_map
-
-from ._compat import _to_varying
 
 NEG_INF = -1e30
 
@@ -80,8 +77,8 @@ def blockwise_attention(q, k, v, block_size=512, causal=False,
     l0 = jnp.zeros((B, H, Tq), q.dtype)
     o0 = jnp.zeros_like(q)
     if axis_name is not None:  # inside shard_map: carries must be varying
-        m0 = _to_varying(m0, axis_name)
-        l0 = _to_varying(l0, axis_name)
+        m0 = lax.pcast(m0, axis_name, to="varying")
+        l0 = lax.pcast(l0, axis_name, to="varying")
     (m, l, o), _ = lax.scan(body, (m0, l0, o0),
                             (kb, vb, jnp.arange(nblk)))
     return o / jnp.maximum(l, 1e-20).transpose(0, 2, 1)[..., None]
@@ -120,14 +117,16 @@ def ring_attention(q, k, v, mesh=None, axis_name="seq", causal=False):
             vc = lax.ppermute(vc, axis_name, perm)
             return (m, l, o, kc, vc)
 
-        m0 = _to_varying(jnp.full((B, H, Tl), NEG_INF, ql.dtype), axis_name)
-        l0 = _to_varying(jnp.zeros((B, H, Tl), ql.dtype), axis_name)
+        m0 = lax.pcast(jnp.full((B, H, Tl), NEG_INF, ql.dtype), axis_name,
+                       to="varying")
+        l0 = lax.pcast(jnp.zeros((B, H, Tl), ql.dtype), axis_name,
+                       to="varying")
         o0 = jnp.zeros_like(ql)
         m, l, o, _, _ = lax.fori_loop(0, axis_size, body, (m0, l0, o0, kl, vl))
         return o / jnp.maximum(l, 1e-20).transpose(0, 2, 1)[..., None]
 
-    return shard_map(local_fn, mesh=mesh, in_specs=(spec, spec, spec),
-                     out_specs=spec)(q, k, v)
+    return jax.shard_map(local_fn, mesh=mesh, in_specs=(spec, spec, spec),
+                         out_specs=spec)(q, k, v)
 
 
 def ulysses_attention(q, k, v, mesh=None, axis_name="seq", causal=False):
@@ -182,5 +181,5 @@ def ulysses_attention(q, k, v, mesh=None, axis_name="seq", causal=False):
                                   causal=causal, axis_name=axis_name)
         return to_seq(out)
 
-    return shard_map(local_fn, mesh=mesh, in_specs=(spec, spec, spec),
-                     out_specs=spec)(q, k, v)
+    return jax.shard_map(local_fn, mesh=mesh, in_specs=(spec, spec, spec),
+                         out_specs=spec)(q, k, v)

@@ -287,8 +287,8 @@ declare("fit.batch_size", "int_or_none", None, env="MXTPU_FIT_BATCH_SIZE",
              "caller's iterator")
 declare("fit.remat", "str", "none", env="MXTPU_REMAT",
         help="selective rematerialization policy of the fused step: "
-             "none/auto/block/conv/all (memory-capacity lever; "
-             "docs/perf.md). Unset or auto honor the remat_reuse "
+             "none/auto/block/conv/all (memory-capacity lever). "
+             "Unset or auto honor the remat_reuse "
              "pass's per-node annotations; an env-SET none/0 pins no-"
              "remat and suppresses them, like block/conv/all pin "
              "their explicit policy")

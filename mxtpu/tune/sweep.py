@@ -9,6 +9,11 @@ parse its JSON line", shared by the XLA flag sweep (previously a
 standalone script) and available to future env-vector searches —
 including re-benching a ``TunedConfig`` artifact on the real chip via
 ``bench.py --tuned``.
+
+One process per chip: each child bench needs the chip, so the calling
+process must not have initialised JAX on it. ``tools/flag_sweep.py``
+loads this file by path for exactly that reason; this module imports
+neither ``jax`` nor ``mxtpu``.
 """
 from __future__ import annotations
 
@@ -22,9 +27,7 @@ __all__ = ["XLA_FLAG_COMBOS", "probe_bench", "run_flag_sweep"]
 _REPO = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 
-#: the XLA TPU flag combos the historical sweep measured: the step is
-#: HBM-bandwidth-bound (docs/perf.md) with reads ~5x writes, and these
-#: steer XLA's fusion/memory decisions
+#: XLA TPU flag combos that steer the step's fusion/memory decisions
 XLA_FLAG_COMBOS = [
     ("baseline", ""),
     ("vmem64", "--xla_tpu_scoped_vmem_limit_kib=65536"),
@@ -46,11 +49,9 @@ def probe_bench(env_overrides=None, xla_flags="", tuned=None,
     """Run ``bench.py`` once in a child process with the given env
     vector; returns its parsed JSON result dict (``{"error": ...}`` on
     failure). ``tuned`` passes a TunedConfig artifact path through
-    ``--tuned``. ``BENCH_NO_LASTGOOD`` is always set: probe combos
-    (some deliberately degraded) must never overwrite the headline
-    last-good record bench.py falls back on."""
+    ``--tuned``."""
     repo = repo or _REPO
-    env = dict(os.environ, BENCH_NO_LASTGOOD="1", BENCH_RECORDIO="0")
+    env = dict(os.environ, BENCH_RECORDIO="0")
     env.update({k: str(v) for k, v in (env_overrides or {}).items()})
     if xla_flags:
         env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "") + " "
@@ -80,9 +81,8 @@ def run_flag_sweep(iters=40, combos=None, tuned=None, stream=None):
     out = stream or sys.stdout
     results = []
     for name, flags in (combos or XLA_FLAG_COMBOS):
-        d = probe_bench(env_overrides={"BENCH_ITERS": iters,
-                                       "BENCH_TIMEOUT": "900"},
-                        xla_flags=flags, tuned=tuned)
+        d = probe_bench(env_overrides={"BENCH_ITERS": iters},
+                        xla_flags=flags, tuned=tuned, timeout=900)
         if d.get("error") or not d.get("value"):
             print("%-16s FAILED: %s" % (name, d.get("error", "no value")),
                   file=out)

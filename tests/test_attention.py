@@ -132,12 +132,11 @@ def test_pallas_epilogue_matches_reference():
     mean = jnp.asarray(rng.randn(128), jnp.float32)
     var = jnp.asarray(rng.rand(128) + 0.1, jnp.float32)
     scale, shift = fold_bn(gamma, beta, mean, var)
-    got = bn_apply_relu_add(x, scale, shift, r, block_m=32, interpret=True)
+    got = bn_apply_relu_add(x, scale, shift, r, block_m=32)
     want = bn_apply_relu_add_reference(x, scale, shift, r)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=1e-5, atol=1e-5)
-    got2 = bn_apply_relu_add(x, scale, shift, None, block_m=32,
-                             interpret=True)
+    got2 = bn_apply_relu_add(x, scale, shift, None, block_m=32)
     want2 = bn_apply_relu_add_reference(x, scale, shift, None)
     np.testing.assert_allclose(np.asarray(got2), np.asarray(want2),
                                rtol=1e-5, atol=1e-5)

@@ -135,6 +135,41 @@ def test_wait_and_context():
     assert b.shape == (4,)
 
 
+def test_accelerator_contexts_alias_cpu_only_when_forced():
+    """tpu()/gpu() resolve to CPU devices on the forced-CPU test mesh and
+    nowhere else: with no accelerator and no forcing, the resolver raises
+    instead of running on the host under a device's name."""
+    import collections
+    from mxtpu import context
+    assert mx.tpu(0).jax_device.platform == "cpu"      # JAX_PLATFORMS=cpu
+    assert mx.gpu(0).jax_device == mx.tpu(0).jax_device
+    Dev = collections.namedtuple("Dev", "platform id")
+    cpus = [Dev("cpu", 0), Dev("cpu", 1)]
+    mixed = cpus + [Dev("tpu", 0)]
+    assert context._resolve_accel(cpus, forced=True) == cpus
+    assert context._resolve_accel(mixed, forced=False) == [mixed[2]]
+    assert context._resolve_accel(mixed, forced=True) == [mixed[2]]
+    with pytest.raises(mx.MXNetError, match="no accelerator.*cpu"):
+        context._resolve_accel(cpus, forced=False)
+
+
+def test_compile_cache_dir_is_placed_from_outside_or_fixed(
+        tmp_path, monkeypatch):
+    """JAX_COMPILATION_CACHE_DIR set -> the package configures nothing;
+    unset -> one fixed path inside the checkout, whatever the pid, clock
+    or cwd."""
+    import os
+    from mxtpu import base
+    assert base.compile_cache_dir(
+        {"JAX_COMPILATION_CACHE_DIR": "/some/dir"}) is None
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(base.__file__)))
+    want = os.path.join(repo, ".jax_cache")
+    assert base.compile_cache_dir({}) == want
+    monkeypatch.chdir(tmp_path)
+    monkeypatch.setattr(os, "getpid", lambda: 4242)
+    assert base.compile_cache_dir({"JAX_COMPILATION_CACHE_DIR": ""}) == want
+
+
 def test_astype_copy():
     a = nd.ones((2, 2))
     b = a.astype("int32")

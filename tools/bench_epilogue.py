@@ -2,7 +2,7 @@
 """A/B the Pallas BN-apply+ReLU+add epilogue against XLA's own fusion on
 the real chip (VERDICT r3 next #2). Prints achieved GB/s for both
 formulations on ResNet-50 stage shapes at the bench batch size; the
-verdict (who wins, by how much) goes to docs/perf.md.
+verdict (who wins, by how much) goes to PERF.md.
 
 Usage: python tools/bench_epilogue.py [batch]   # needs the accelerator
 """

@@ -4,9 +4,8 @@
 The verdict basis is DETERMINISTIC (PR-2 convention): the cost registry's
 XLA ``cost_analysis``/``memory_analysis`` numbers for the SAME program
 built f32 versus under ``MXTPU_PIPELINE=bf16`` — flops and, above all,
-bytes-accessed (the fused train step is bandwidth-bound on TPU, so the
-bytes delta is the throughput lever; BENCH_r04's 34.7% MFU headline is
-the number this is aimed at). Wall-clock steps/sec is recorded as a
+bytes-accessed (whether the bytes delta moves throughput on the chip is
+not measured; see PERF.md). Wall-clock steps/sec is recorded as a
 CAVEAT only: on the 2-core CPU host XLA:CPU emulates bf16 by widening,
 so CPU wall-clock says nothing about TPU behavior (noise floor recorded
 per the PR-2 convention).

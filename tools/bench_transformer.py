@@ -4,11 +4,12 @@
 matmul-dominated workload — conv-train's roofline caps near ~55-60% on
 v5e, so the MFU north star is demonstrated on the LM).
 
-Same harness discipline as bench.py: subprocess backend probe, fused
-one-program Module step, bf16, host-read completion barrier. FLOPs model
-is the standard dense-LM count 6*P*tokens (P = non-embedding-output
-matmul params) plus the causal-attention term 12*L*B*T^2*D/2; peak
-BENCH_PEAK_TFLOPS (197 bf16 v5e).
+Same harness as bench.py (imported: one process, no child that needs
+the chip): fused one-program Module step, bf16, timed windows that end on
+block_until_ready, no accelerator -> non-zero exit. FLOPs model is the
+standard dense-LM count 6*P*tokens (P = non-embedding-output matmul
+params) plus the causal-attention term 12*L*B*T^2*D/2, over the device's
+peak from bench.PEAK_TFLOPS.
 
 Prints ONE JSON line {"metric": "transformer_lm_mfu", ...}.
 """
@@ -21,21 +22,13 @@ import numpy as np
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-PEAK_TFLOPS = float(os.environ.get("BENCH_PEAK_TFLOPS", 197.0))
-
 
 def main():
-    sys.path.insert(0, os.path.join(
-        os.path.dirname(os.path.dirname(os.path.abspath(__file__)))))
     import bench as _bench
-
-    status = _bench._wait_for_backend()
-    if status in ("unreachable", "broken"):
-        print(json.dumps({"metric": "transformer_lm_mfu", "value": 0.0,
-                          "unit": "mfu", "error": "backend " + status}))
-        sys.exit(1)
     import jax
     import jax.numpy as jnp
+
+    device, peak = _bench.require_chip()
 
     import mxtpu as mx
     from mxtpu.models import transformer
@@ -49,18 +42,10 @@ def main():
     vocab = int(os.environ.get("TBENCH_VOCAB", 16384))
     iters = int(os.environ.get("TBENCH_ITERS", 20))
 
-    has_accel = any(d.platform != "cpu" for d in jax.local_devices())
-    if not has_accel and not os.environ.get("BENCH_ALLOW_CPU"):
-        print(json.dumps({"metric": "transformer_lm_mfu", "value": 0.0,
-                          "unit": "mfu",
-                          "error": "no accelerator attached"}))
-        sys.exit(1)
-
     sym = transformer.get_symbol(vocab, seq, num_layers=layers,
                                  num_heads=heads, d_model=d_model,
                                  dtype="bfloat16")
-    ctx = mx.tpu(0) if has_accel else mx.cpu(0)
-    mod = mx.mod.Module(sym, context=ctx)
+    mod = mx.mod.Module(sym, context=mx.tpu(0))
     pdata = [mx.io.DataDesc("data", (batch, seq), dtype="float32")]
     plabel = [mx.io.DataDesc("softmax_label", (batch * seq,),
                              dtype="float32")]
@@ -88,12 +73,12 @@ def main():
                                     "rescale_grad": 1.0 / batch},
                   force_init=False, begin_epoch=0)
     mod.fit(warm, num_epoch=1, **fit_kw)
-    np.asarray(jax.tree_util.tree_leaves(mod._fused.params)[0])[:1]
+    _bench._finish(mod)
 
     timed = _bench._DeviceBatchIter(batch_obj, iters, pdata, plabel)
     t0 = time.perf_counter()
     mod.fit(timed, num_epoch=1, **fit_kw)
-    np.asarray(jax.tree_util.tree_leaves(mod._fused.params)[0])[:1]
+    _bench._finish(mod)
     dt = time.perf_counter() - t0
 
     # 6*P*tokens: P = every matmul param incl. embedding-as-output head
@@ -107,7 +92,7 @@ def main():
     flops_step = flops_dense + flops_attn
     step_t = dt / iters
     tflops = flops_step / step_t / 1e12
-    mfu = tflops / PEAK_TFLOPS
+    mfu = tflops / peak
     out = {
         "metric": "transformer_lm_mfu",
         "value": round(mfu, 4),
@@ -117,7 +102,8 @@ def main():
         "config": {"batch": batch, "seq": seq, "d_model": d_model,
                    "layers": layers, "heads": heads, "vocab": vocab},
         "flops_model": "6*P_matmul*tokens + causal attn 6*L*B*T^2*D/2, "
-                       "peak=%.0fTF bf16" % PEAK_TFLOPS,
+                       "peak=%.0fTF bf16" % peak,
+        "device": device,
         "path": "Module.fit (fused one-program step, bf16, "
                 "flash attention)"}
     print(json.dumps(out))

@@ -13,8 +13,8 @@ anywhere in the same file.
 
 Two shapes are exempt:
 
-  * raw run logs (``BENCH_r0N.json``) — transcripts of a command
-    (``cmd`` + ``rc`` keys), not verdicts; they assert nothing;
+  * raw run logs — transcripts of a command (``cmd`` + ``rc`` keys),
+    not verdicts; they assert nothing;
   * files with no verdict marker at all (pure measurement dumps).
 
 Usage: python tools/check_bench_basis.py [--root DIR]

@@ -7,6 +7,9 @@ DMLC_PS_ROOT_* the same way; both spellings are honored by
 mxtpu.kvstore_server.cluster_env). This is how multi-node is exercised
 without a cluster — the reference's own trick (tests/nightly/test_all.sh).
 
+CPU-only: every process it starts runs with JAX_PLATFORMS=cpu. A chip
+belongs to one process, so N workers on one host cannot share it.
+
 Usage:
   python tools/launch.py -n 4 python train.py --kv-store dist_sync
 """
@@ -43,6 +46,7 @@ def main():
     port = _free_port()
     base_env = dict(os.environ)
     base_env.update({
+        "JAX_PLATFORMS": "cpu",
         "MXTPU_ROOT_URI": "127.0.0.1",
         "MXTPU_ROOT_PORT": str(port),
         "MXTPU_NUM_WORKERS": str(args.num_workers),

@@ -26,7 +26,6 @@ def run(sizes_mb, iters):
     import jax
     import jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
-    from mxtpu.parallel._compat import shard_map as _shard_map
 
     from mxtpu.parallel import make_mesh
 
@@ -53,18 +52,18 @@ def run(sizes_mb, iters):
         # DP-gradient model: every device holds a FULL replica (the
         # gradient) and the collective runs over it — in_specs=P() so the
         # per-device buffer size matches the formulas below
-        @functools.partial(_shard_map, mesh=mesh, in_specs=P(),
+        @functools.partial(jax.shard_map, mesh=mesh, in_specs=P(),
                            out_specs=P(), check_vma=False)
         def allreduce(v):
             return jax.lax.psum(v, "data") / n
 
-        @functools.partial(_shard_map, mesh=mesh, in_specs=P(),
+        @functools.partial(jax.shard_map, mesh=mesh, in_specs=P(),
                            out_specs=P("data"), check_vma=False)
         def reducescatter(v):
             return jax.lax.psum_scatter(v, "data", tiled=True) / n
 
         # gather back from shards: per-device input is elems/n
-        @functools.partial(_shard_map, mesh=mesh, in_specs=P("data"),
+        @functools.partial(jax.shard_map, mesh=mesh, in_specs=P("data"),
                            out_specs=P(), check_vma=False)
         def allgather(v):
             return jax.lax.all_gather(v, "data", tiled=True)
