@@ -24,8 +24,8 @@ fused step / metric accumulators route through the identical sequence.
 from __future__ import annotations
 
 from .pipeline import (PipelineReport, add_build_listener, configure,
-                       configured, instrument_program, notify_build,
-                       pipeline_scope, program_build_count,
+                       configured, instrument_program, named_jit,
+                       notify_build, pipeline_scope, program_build_count,
                        record_program_build, remove_build_listener,
                        set_calib_observer, set_output_sanitizer,
                        transform_graph)
@@ -36,5 +36,6 @@ __all__ = [
     "pipeline_scope",
     "add_build_listener", "remove_build_listener", "notify_build",
     "program_build_count", "record_program_build", "instrument_program",
+    "named_jit",
     "set_output_sanitizer", "set_calib_observer", "quant",
 ]

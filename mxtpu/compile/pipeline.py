@@ -196,6 +196,28 @@ def notify_build(kind, owner):
             pass
 
 
+PROGRAM_PREFIX = "mxtpu_"
+
+
+def named_jit(name, fn, **jit_kw):
+    """``jax.jit(fn, **jit_kw)`` under a stable name: the XLA module, and
+    so the profiler trace's ``XLA Modules`` line and the compile cache's
+    entry, reads ``jit_mxtpu_<name>`` whatever closure the caller wrote
+    and however it is refactored. Every program built through the seam
+    below is jitted here; the name lands in its ``ProgramRecord``, so
+    ``diagnostics.program_table()`` and a trace agree."""
+    import jax
+    fn.__name__ = fn.__qualname__ = PROGRAM_PREFIX + name
+    return jax.jit(fn, **jit_kw)
+
+
+def program_name(fn):
+    """The XLA module name of a function jitted by ``named_jit`` (``""``
+    for any other)."""
+    name = getattr(fn, "__name__", "")
+    return "jit_" + name if name.startswith(PROGRAM_PREFIX) else ""
+
+
 def record_program_build(kind, owner, fn, precision=None, transforms=None,
                          cert=None):
     """Public build-seam entry for program tables outside the Executor
@@ -291,7 +313,8 @@ def instrument_program(kind, fn, owner=None, matmul_env=False,
                 exe = fn.lower(*args, **kwargs).compile()
                 state["rec"] = _diag.record_program(
                     kind, owner, exe, (_time.perf_counter() - t0) * 1e3,
-                    transforms=transforms, cert=cert)
+                    transforms=transforms, cert=cert,
+                    name=program_name(fn))
                 # SPMD shape of the program: devices spanned + how many
                 # arg leaves are mesh-split vs replicated (read off the
                 # live args — the one place both are in hand)

@@ -54,13 +54,21 @@ class FlightRecorder:
         if limit:
             entries = entries[-int(limit):]
         return [{"seq": e[0], "time": round(e[1], 6), "thread": e[2],
-                 "kind": e[3], "name": e[4],
-                 "detail": e[5] if isinstance(
-                     e[5], (str, int, float, type(None))) else str(e[5])}
+                 "kind": e[3], "name": e[4], "detail": _detail(e[3], e[5])}
                 for e in entries]
 
     def clear(self):
         self._ring = [None] * self.capacity
+
+
+def _detail(kind, detail):
+    """JSON-ready detail. A span's end arrives as (span id, ns) and is
+    formatted here, when the ring is read, not on every span exit."""
+    if isinstance(detail, (str, int, float, type(None))):
+        return detail
+    if kind == "span_end":
+        return "%d %.3fms" % (detail[0], detail[1] / 1e6)
+    return str(detail)
 
 
 _RECORDER = FlightRecorder() \

@@ -65,7 +65,8 @@ def owner_name(owner):
 class ProgramRecord:
     """One compiled program's captured cost/memory metadata."""
 
-    __slots__ = ("id", "kind", "owner", "created", "compile_ms", "flops",
+    __slots__ = ("id", "kind", "name", "owner", "created", "compile_ms",
+                 "flops",
                  "bytes_accessed", "argument_bytes", "output_bytes",
                  "temp_bytes", "generated_code_bytes", "calls",
                  "n_devices", "sharded_args", "replicated_args",
@@ -74,6 +75,9 @@ class ProgramRecord:
     def __init__(self, kind, owner, compile_ms):
         self.id = next(_ids)
         self.kind = kind
+        # the XLA module's name (``jit_mxtpu_<name>``, compile.named_jit):
+        # what a profiler trace calls this program
+        self.name = ""
         self.owner = owner_name(owner)
         self.created = time.time()
         self.compile_ms = compile_ms
@@ -114,7 +118,8 @@ class ProgramRecord:
 
     def to_dict(self):
         return {
-            "id": self.id, "kind": self.kind, "owner": self.owner,
+            "id": self.id, "kind": self.kind, "name": self.name,
+            "owner": self.owner,
             "created": round(self.created, 3),
             "compile_ms": round(self.compile_ms, 3),
             "flops": self.flops, "bytes_accessed": self.bytes_accessed,
@@ -198,13 +203,14 @@ def summarize_precision(rec, args, tag=None):
 
 
 def record_program(kind, owner, compiled, compile_ms, transforms=None,
-                   cert=None):
+                   cert=None, name=""):
     """Capture a freshly compiled executable's analyses into the registry
     (and the telemetry counters). Never raises — introspection must not
     take down the program it is describing. ``transforms`` stamps the
     applied compile-pipeline pass names on the record; ``cert`` the
     pipeline's equivalence-certification tag for those rewrites."""
     rec = ProgramRecord(kind, owner, compile_ms)
+    rec.name = name
     if transforms:
         rec.transforms = tuple(transforms)
         rec.cert = cert or "off"
@@ -281,19 +287,20 @@ def latest_record(kind=None):
 def program_table(kind=None):
     """Human-readable cost report, one row per captured program."""
     rows = programs(kind)
-    header = ("id", "kind", "owner", "calls", "compile_ms", "mflops",
-              "mb_accessed", "arg_kb", "out_kb", "temp_kb", "devs",
+    header = ("id", "kind", "program", "owner", "calls", "compile_ms",
+              "mflops", "mb_accessed", "arg_kb", "out_kb", "temp_kb", "devs",
               "prec", "cert", "xforms")
-    lines = ["%4s %-12s %-16s %6s %10s %10s %11s %8s %8s %8s %9s %-10s "
-             "%-4s %s" % header]
+    lines = ["%4s %-12s %-26s %-16s %6s %10s %10s %11s %8s %8s %8s %9s "
+             "%-10s %-4s %s" % header]
     for r in rows:
         devs = "%d" % r.get("n_devices", 1)
         if r.get("sharded_args"):
             devs += " (%ds)" % r["sharded_args"]
-        lines.append("%4d %-12s %-16s %6d %10.1f %10.2f %11.2f %8d %8d "
-                     "%8d %9s %-10s %-4s %s"
-                     % (r["id"], r["kind"][:12], r["owner"][:16], r["calls"],
-                        r["compile_ms"], r["flops"] / 1e6,
+        lines.append("%4d %-12s %-26s %-16s %6d %10.1f %10.2f %11.2f %8d "
+                     "%8d %8d %9s %-10s %-4s %s"
+                     % (r["id"], r["kind"][:12],
+                        (r.get("name") or "-")[:26], r["owner"][:16],
+                        r["calls"], r["compile_ms"], r["flops"] / 1e6,
                         r["bytes_accessed"] / 1e6,
                         r["argument_bytes"] // 1024,
                         r["output_bytes"] // 1024,

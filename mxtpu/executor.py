@@ -38,11 +38,13 @@ from .compile.pipeline import (_AOT_MISS, _DEMOTE_MISS_TOTAL,  # noqa: F401
                                as _instrument_program,
                                notify_build as _notify_build,
                                prewarm_build_count, prewarm_scope,
-                               program_build_count, record_program_build,
+                               named_jit, program_build_count,
+                               record_program_build,
                                remove_build_listener, set_output_sanitizer)
 
 __all__ = ["Executor", "add_build_listener", "remove_build_listener",
-           "program_build_count", "record_program_build", "device_wait",
+           "program_build_count", "record_program_build", "named_jit",
+           "device_wait",
            "set_output_sanitizer", "prewarm_scope", "in_prewarm",
            "prewarm_build_count"]
 
@@ -51,14 +53,12 @@ def device_wait(x):
     """Block until ``x`` — a device array / NDArray, or a list of them —
     has finished computing: the explicit engine-sync point of the
     pipelined ``Module.fit`` loop (the WaitToRead analogue the bounded
-    in-flight window uses to pace dispatch). Returns the wall-clock
-    milliseconds spent blocked, so callers can report pacing honestly.
+    in-flight window uses to pace dispatch). It is not timed here: the
+    caller's span (``fit.pace``) is the region's one clock pair.
 
     The wait registers itself with the diagnostics watchdog: a thread
     stuck here past the deadline is the classic wedged-device signature
     and triggers a postmortem dump."""
-    import time as _time
-    t0 = _time.perf_counter()
     if isinstance(x, (list, tuple)):
         x = [getattr(a, "_data", a) for a in x]
     else:
@@ -72,7 +72,6 @@ def device_wait(x):
         jax.block_until_ready(x)
     finally:
         _diag.wait_end()
-    return (_time.perf_counter() - t0) * 1e3
 
 # standing series: registry-direct so they exist for /metrics even when
 # MXTPU_TELEMETRY=0 was set at import (the flag silences the helper-
@@ -455,11 +454,11 @@ class Executor:
         if kind == "fwd_eval":
             run = _trace_graph(symbol, is_train=False,
                                placements=self._placements)
-            fn = jax.jit(lambda a, x, r: run(a, x, r))
+            fn = run
         elif kind == "fwd_train":
             run = _trace_graph(symbol, is_train=True,
                                placements=self._placements)
-            fn = jax.jit(lambda a, x, r: run(a, x, r))
+            fn = run
         elif kind == "fwd_bwd":
             run = _trace_graph(symbol, is_train=True,
                                placements=self._placements)
@@ -481,7 +480,7 @@ class Executor:
                                       for k, v in auxu.items()}))
                 return outs, auxu, grads
 
-            fn = jax.jit(fb)
+            fn = fb
         elif kind == "fwd_bwd_heads":
             run = _trace_graph(symbol, is_train=True,
                                placements=self._placements)
@@ -502,7 +501,7 @@ class Executor:
                                 {k: jnp.zeros_like(v) for k, v in auxu.items()}))
                 return outs, auxu, grads
 
-            fn = jax.jit(fbh)
+            fn = fbh
         elif kind == "fwd_vjp":
             # Forward that also returns the vjp closure. jax.vjp's result
             # is a registered pytree (its leaves are the saved residuals),
@@ -524,7 +523,7 @@ class Executor:
                 (outs, auxu), vjp = jax.vjp(f, gvals)
                 return outs, auxu, vjp
 
-            fn = jax.jit(fv)
+            fn = fv
         elif kind == "vjp_apply":
             def va(vjp, head_grads, auxu):
                 (grads,) = vjp((list(head_grads),
@@ -532,10 +531,11 @@ class Executor:
                                  for k, v in auxu.items()}))
                 return grads
 
-            fn = jax.jit(va)
+            fn = va
         else:
             raise MXNetError("unknown program kind %s" % kind)
-        fn = _instrument_program(kind, fn, owner=self, matmul_env=True,
+        fn = _instrument_program(kind, named_jit("exec_" + kind, fn),
+                                 owner=self, matmul_env=True,
                                  precision=self._precision_tag(),
                                  transforms=self._transform_tags(),
                                  calib_heads=calib_heads,

@@ -596,8 +596,9 @@ class DeviceMetricAccum:
         # route through the executor's build seam so program_build_count,
         # the build listeners and executor_compile_ms{kind=metric_accum}
         # stay consistent with every other traced program in the process
-        from .executor import record_program_build
-        fn = record_program_build("metric_accum", self, jax.jit(accumulate))
+        from .executor import named_jit, record_program_build
+        fn = record_program_build(
+            "metric_accum", self, named_jit("metric_accum", accumulate))
         if cacheable:
             _ACCUM_FN_CACHE[cache_key] = fn
         return fn

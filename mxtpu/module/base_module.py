@@ -206,96 +206,99 @@ class BaseModule:
           elastic supervisor (docs/elastic.md). Needs the fused train
           step; disarmed (with a log line) otherwise.
         """
-        from ..initializer import Uniform
-        from .. import tune as _tune
-        assert num_epoch is not None, "please specify number of epochs"
-        initializer = initializer or Uniform(0.01)
+        # the outer span of the call: everything fit does, the resolution
+        # of its knobs included, is inside it on the trace
+        with _tracing.span("fit", category="module"):
+            from ..initializer import Uniform
+            from .. import tune as _tune
+            assert num_epoch is not None, "please specify number of epochs"
+            initializer = initializer or Uniform(0.01)
 
-        # one resolution point for every pipeline knob (the hand-picked
-        # constants moved into the registry catalog; resolution order is
-        # default < artifact < env < this call's explicit arguments)
-        tuned = _tune.artifact(tuned)
-        max_in_flight = _tune.resolve_int(
-            "fit.max_in_flight", explicit=max_in_flight, artifact=tuned,
-            floor=1)
-        # metric_sync is special: an explicit arg or env wins outright,
-        # but an ARTIFACT cadence cannot simply preempt the auto-derive
-        # — the search could not see this fit's callbacks, and every
-        # Speedometer window boundary must stay a sync batch. The
-        # artifact value rides along as a preference the derivation
-        # reconciles (gcd) with the callback contract below.
-        metric_sync = _tune.resolve(
-            "fit.metric_sync", explicit=metric_sync, artifact=False)
-        tuned_metric_sync = _tune.resolve("fit.metric_sync",
-                                          artifact=tuned) \
-            if metric_sync is None else None
-        device_metrics = _tune.resolve(
-            "fit.device_metrics", explicit=device_metrics, artifact=tuned)
-        device_prefetch = _tune.resolve(
-            "fit.device_prefetch", explicit=device_prefetch,
-            artifact=tuned)
-        self._fit_knobs = {"fit.max_in_flight": max_in_flight,
-                           "fit.metric_sync": metric_sync,
-                           "fit.device_metrics": device_metrics,
-                           "fit.device_prefetch": device_prefetch}
+            # one resolution point for every pipeline knob (the hand-picked
+            # constants moved into the registry catalog; resolution order is
+            # default < artifact < env < this call's explicit arguments)
+            tuned = _tune.artifact(tuned)
+            max_in_flight = _tune.resolve_int(
+                "fit.max_in_flight", explicit=max_in_flight, artifact=tuned,
+                floor=1)
+            # metric_sync is special: an explicit arg or env wins outright,
+            # but an ARTIFACT cadence cannot simply preempt the auto-derive
+            # — the search could not see this fit's callbacks, and every
+            # Speedometer window boundary must stay a sync batch. The
+            # artifact value rides along as a preference the derivation
+            # reconciles (gcd) with the callback contract below.
+            metric_sync = _tune.resolve(
+                "fit.metric_sync", explicit=metric_sync, artifact=False)
+            tuned_metric_sync = _tune.resolve("fit.metric_sync",
+                                              artifact=tuned) \
+                if metric_sync is None else None
+            device_metrics = _tune.resolve(
+                "fit.device_metrics", explicit=device_metrics, artifact=tuned)
+            device_prefetch = _tune.resolve(
+                "fit.device_prefetch", explicit=device_prefetch,
+                artifact=tuned)
+            self._fit_knobs = {"fit.max_in_flight": max_in_flight,
+                               "fit.metric_sync": metric_sync,
+                               "fit.device_metrics": device_metrics,
+                               "fit.device_prefetch": device_prefetch}
 
-        owned_iter = None
-        if device_prefetch:
-            from .. import io as _io
-            if not isinstance(train_data, _io.DevicePrefetchIter):
-                ctxs = getattr(self, "_context", None)
-                device = ctxs[0].jax_device if ctxs else None
-                train_data = owned_iter = _io.DevicePrefetchIter(
-                    train_data, device=device)
+            owned_iter = None
+            if device_prefetch:
+                from .. import io as _io
+                if not isinstance(train_data, _io.DevicePrefetchIter):
+                    ctxs = getattr(self, "_context", None)
+                    device = ctxs[0].jax_device if ctxs else None
+                    train_data = owned_iter = _io.DevicePrefetchIter(
+                        train_data, device=device)
 
-        from .. import sharding as _sharding
-        mesh_ctx = _sharding.resolve(mesh)
+            from .. import sharding as _sharding
+            mesh_ctx = _sharding.resolve(mesh)
 
-        from .. import elastic as _elastic
-        el_cfg = _elastic.ElasticConfig.resolve(elastic)
-        resume_state = None
-        if resume:
-            spec = resume
-            if resume is True:
-                if el_cfg is None:
-                    raise MXNetError(
-                        "fit(resume=True) needs elastic= (or MXTPU_ELASTIC)"
-                        " to name the checkpoint prefix")
-                spec = el_cfg.prefix
-            resume_state = _elastic.load_resume(spec)
-            if resume_state is None:
-                self.logger.info(
-                    "fit(resume): no durable generation at %r — starting "
-                    "fresh", spec)
+            from .. import elastic as _elastic
+            el_cfg = _elastic.ElasticConfig.resolve(elastic)
+            resume_state = None
+            if resume:
+                spec = resume
+                if resume is True:
+                    if el_cfg is None:
+                        raise MXNetError(
+                            "fit(resume=True) needs elastic= (or MXTPU_ELASTIC)"
+                            " to name the checkpoint prefix")
+                    spec = el_cfg.prefix
+                resume_state = _elastic.load_resume(spec)
+                if resume_state is None:
+                    self.logger.info(
+                        "fit(resume): no durable generation at %r — starting "
+                        "fresh", spec)
 
-        # arm the hang watchdog (MXTPU_WATCHDOG=0 opts out) + the SIGUSR2
-        # postmortem handler (only over SIG_DFL — a user's own USR2
-        # handler is never replaced; MXTPU_DIAG_SIGNAL=0 opts out)
-        _diag.on_session_start()
-        try:
-            with _sharding.use(mesh_ctx):
-                self._fit_impl(
-                    train_data, eval_data, eval_metric, epoch_end_callback,
-                    batch_end_callback, kvstore, optimizer, optimizer_params,
-                    eval_end_callback, eval_batch_end_callback, initializer,
-                    arg_params, aux_params, allow_missing, force_rebind,
-                    force_init, begin_epoch, num_epoch, validation_metric,
-                    monitor, max_in_flight, metric_sync, device_metrics,
-                    el_cfg, resume_state, tuned_metric_sync, health)
-        except Exception as exc:
-            # fatal training exception: capture the flight ring / ledger /
-            # engine state BEFORE the stack unwinds and the evidence GCs.
-            # Plain MXNetError is a usage error (bad shape/name at bind),
-            # not a backend failure — no forensics, match serving's
-            # filter. NativeError (nonzero native-engine return) IS a
-            # backend failure despite being an MXNetError subclass.
-            if not isinstance(exc, MXNetError) or isinstance(exc,
-                                                             NativeError):
-                _diag.postmortem("fit_exception", exc=exc, source="fit")
-            raise
-        finally:
-            if owned_iter is not None:
-                owned_iter.close()
+            # arm the hang watchdog (MXTPU_WATCHDOG=0 opts out) + the SIGUSR2
+            # postmortem handler (only over SIG_DFL — a user's own USR2
+            # handler is never replaced; MXTPU_DIAG_SIGNAL=0 opts out)
+            _diag.on_session_start()
+            try:
+                with _sharding.use(mesh_ctx):
+                    self._fit_impl(
+                        train_data, eval_data, eval_metric, epoch_end_callback,
+                        batch_end_callback, kvstore, optimizer, optimizer_params,
+                        eval_end_callback, eval_batch_end_callback, initializer,
+                        arg_params, aux_params, allow_missing, force_rebind,
+                        force_init, begin_epoch, num_epoch, validation_metric,
+                        monitor, max_in_flight, metric_sync, device_metrics,
+                        el_cfg, resume_state, tuned_metric_sync, health)
+            except Exception as exc:
+                # fatal training exception: capture the flight ring / ledger /
+                # engine state BEFORE the stack unwinds and the evidence GCs.
+                # Plain MXNetError is a usage error (bad shape/name at bind),
+                # not a backend failure — no forensics, match serving's
+                # filter. NativeError (nonzero native-engine return) IS a
+                # backend failure despite being an MXNetError subclass.
+                if not isinstance(exc, MXNetError) or isinstance(exc,
+                                                                 NativeError):
+                    _diag.postmortem("fit_exception", exc=exc, source="fit")
+                raise
+            finally:
+                if owned_iter is not None:
+                    owned_iter.close()
 
     def _fit_impl(self, train_data, eval_data, eval_metric,
                   epoch_end_callback, batch_end_callback, kvstore, optimizer,
@@ -424,6 +427,10 @@ class BaseModule:
             "fit_dispatch_ms",
             help="host time to issue one step (async dispatch, no device "
                  "wait) — fit_step_ms minus this is pacing/back-pressure")
+        input_wait_ms = _tel.histogram(
+            "fit_input_wait_ms",
+            help="wall time fit waited for the next batch: the "
+                 "iterator's next() and prepare() (the fit.input span)")
         sync_wait_ms = _tel.histogram(
             "fit_sync_wait_ms",
             help="pacing: wall time blocked on the oldest in-flight step")
@@ -440,184 +447,208 @@ class BaseModule:
 
         try:
             for epoch in range(begin_epoch, num_epoch):
-                tic = time.time()
-                # a mid-epoch resume continues THIS epoch: the restored
-                # metric sums and iterator cursor must survive, so skip
-                # the epoch-top reset exactly once
-                resumed_here = (resume_state is not None
-                                and not resume_state.epoch_boundary
-                                and epoch == resume_state.epoch)
-                if not resumed_here:
-                    eval_metric.reset()
-                    if accum is not None:
-                        accum.reset()
-                nbatch = 0
-                skip_batches = 0
-                if resumed_here:
-                    nbatch = resume_state.start_nbatch
-                    if not restored_iter:
-                        # iterator without a native cursor: replay the
-                        # epoch head and discard (deterministic order,
-                        # no training, no RNG draws)
-                        skip_batches = nbatch
-                epoch_samples = 0
-                data_iter = iter(train_data)
-                for _ in range(skip_batches):
-                    try:
-                        next(data_iter)
-                    except StopIteration:
-                        break
-                end_of_batch = False
-                try:
-                    next_data_batch = next(data_iter)
-                except StopIteration:
-                    # resumed exactly at the epoch's last batch
-                    next_data_batch = None
-                    end_of_batch = True
-                inflight = deque()
-                while not end_of_batch:
-                    data_batch = next_data_batch
-                    if monitor is not None:
-                        monitor.tic()
-                    # fit.step is the correlation root for everything one
-                    # batch triggers (executor.forward -> engine dispatches,
-                    # kvstore push/pull inside update)
-                    with _tracing.span("fit.step", category="module") as sp:
-                        self.forward_backward(data_batch)
-                        self.update()
-                    dispatch_ms.observe(sp.duration_ms)
-                    if health_session is not None:
-                        # fold the step's device stat rows (async, no
-                        # transfer) before anything can overwrite them
-                        health_session.on_step()
+                with _tracing.span("fit.epoch", category="module",
+                                   tags={"epoch": epoch}):
+                    tic = time.time()
+                    # a mid-epoch resume continues THIS epoch: the restored
+                    # metric sums and iterator cursor must survive, so skip
+                    # the epoch-top reset exactly once
+                    resumed_here = (resume_state is not None
+                                    and not resume_state.epoch_boundary
+                                    and epoch == resume_state.epoch)
+                    if not resumed_here:
+                        eval_metric.reset()
+                        if accum is not None:
+                            accum.reset()
+                    nbatch = 0
+                    skip_batches = 0
+                    if resumed_here:
+                        nbatch = resume_state.start_nbatch
+                        if not restored_iter:
+                            # iterator without a native cursor: replay the
+                            # epoch head and discard (deterministic order,
+                            # no training, no RNG draws)
+                            skip_batches = nbatch
+                    epoch_samples = 0
+                    data_iter = iter(train_data)
+                    for _ in range(skip_batches):
+                        try:
+                            next(data_iter)
+                        except StopIteration:
+                            break
+                    end_of_batch = False
+                    with _tracing.span("fit.input", category="module") as sp_in:
+                        try:
+                            next_data_batch = next(data_iter)
+                        except StopIteration:
+                            # resumed exactly at the epoch's last batch
+                            next_data_batch = None
+                            end_of_batch = True
+                    input_wait_ms.observe(sp_in.duration_ms)
+                    inflight = deque()
+                    while not end_of_batch:
+                        data_batch = next_data_batch
+                        if monitor is not None:
+                            monitor.tic()
+                        # fit.step is the correlation root for everything one
+                        # batch triggers (executor.forward -> engine dispatches,
+                        # kvstore push/pull inside update)
+                        with _tracing.span("fit.step", category="module",
+                                           tags={"epoch": epoch,
+                                                 "nbatch": nbatch},
+                                           step_num=nbatch) as sp:
+                            self.forward_backward(data_batch)
+                            self.update()
+                        dispatch_ms.observe(sp.duration_ms)
+                        if health_session is not None:
+                            # fold the step's device stat rows (async, no
+                            # transfer) before anything can overwrite them
+                            health_session.on_step()
+                        if el_session is not None:
+                            # BEFORE the lookahead fetch below: the only
+                            # point where the iterator cursor still reads
+                            # "batches 0..nbatch consumed"
+                            el_session.pre_lookahead(train_data, epoch, nbatch)
+                        view = self._device_step_view(data_batch) \
+                            if accum is not None else None
+                        if data_batch.data:
+                            epoch_samples += data_batch.data[0].shape[0] - \
+                                (data_batch.pad or 0)
+                        # fetch batch N+1 FIRST: its host assembly overlaps step
+                        # N's device execution (and, with DevicePrefetchIter, its
+                        # transfer is already in flight on the producer thread)
+                        with _tracing.span("fit.input",
+                                           category="module") as sp_in:
+                            try:
+                                next_data_batch = next(data_iter)
+                                self.prepare(next_data_batch)
+                            except StopIteration:
+                                end_of_batch = True
+                        input_wait_ms.observe(sp_in.duration_ms)
+                        pacing = 0.0
+                        if view is not None:
+                            labels, outs, token = view
+                            accum.update(labels, outs)
+                            if token is not None:
+                                inflight.append(token)
+                                # bounded in-flight window: block ONLY when more
+                                # than K steps are outstanding, and only on the
+                                # oldest — the device never idles waiting for the
+                                # host between steps
+                                while len(inflight) > \
+                                        max(1, int(inflight_limit["v"])):
+                                    with _tracing.span(
+                                            "fit.pace",
+                                            category="module") as sp_w:
+                                        _device_wait(inflight.popleft())
+                                    sync_wait_ms.observe(sp_w.duration_ms)
+                                    pacing += sp_w.duration_ms
+                        else:
+                            self.update_metric(eval_metric, data_batch.label)
+                        step_ms.observe(sp.duration_ms + pacing)
+                        if _obs_corpus.enabled():
+                            # measurement-corpus service row: the same
+                            # per-step wall time the histogram sees, keyed
+                            # by batch rows for the cost-model fit
+                            _obs_corpus.record_service(
+                                "fit_step", sp.duration_ms + pacing,
+                                rows=data_batch.data[0].shape[0]
+                                if data_batch.data else None)
+                        cadence_now = (end_of_batch or metric_sync == 1 or
+                                       (metric_sync and nbatch and
+                                        nbatch % metric_sync == 0))
+                        if health_session is not None and monitor is not None \
+                                and monitor.activated:
+                            # a sampled (monitored) batch forces a cadence so
+                            # its device taps land before toc_print below
+                            cadence_now = True
+                        if accum is not None and cadence_now:
+                            if end_of_batch:
+                                inflight.clear()  # metric sync covers every step
+                            with _tracing.span("fit.metric_sync",
+                                               category="module") as sp_m:
+                                accum.sync()
+                            msync_ms.observe(sp_m.duration_ms)
+                        elif health_session is not None and cadence_now:
+                            with _tracing.span("fit.metric_sync",
+                                               category="module"):
+                                health_session.sync_direct()
+                        if health_session is not None and cadence_now:
+                            # detectors run on the freshly landed window —
+                            # BEFORE el_session.on_step below, so a rollback
+                            # wedge aborts before the corrupted snapshot
+                            health_session.on_cadence(eval_metric)
+                        if monitor is not None or el_session is not None \
+                                or callbacks:
+                            # the per-batch hooks, in their contract order
+                            with _tracing.span("fit.callbacks",
+                                               category="module"):
+                                if monitor is not None:
+                                    monitor.toc_print()
+                                if el_session is not None:
+                                    # after the step's metrics accumulated,
+                                    # before the callbacks: the cadence
+                                    # snapshot point, and where supervisor
+                                    # interrupts (wedge/SIGTERM) surface as
+                                    # exceptions
+                                    el_session.on_step(eval_metric, accum,
+                                                       train_data)
+                                if callbacks:
+                                    batch_end_params = BatchEndParam(
+                                        epoch=epoch, nbatch=nbatch,
+                                        eval_metric=eval_metric,
+                                        locals=locals())
+                                    for callback in callbacks:
+                                        callback(batch_end_params)
+                        nbatch += 1
+
+                    for name, val in eval_metric.get_name_value():
+                        self.logger.info("Epoch[%d] Train-%s=%f", epoch, name, val)
+                    toc = time.time()
+                    self.logger.info("Epoch[%d] Time cost=%.3f", epoch, (toc - tic))
+                    samples_total.inc(epoch_samples)
+                    epochs_done.inc()
+                    if toc > tic:
+                        sps_gauge.set(epoch_samples / (toc - tic))
+
+                    # the reference round-trips every parameter through the host
+                    # here each epoch; with device-resident weights (fused step)
+                    # that transfer is pure waste unless a callback wants them —
+                    # elastic-aware checkpoint callbacks (_needs_host_params
+                    # False: they snapshot the device state directly through
+                    # the async writer) don't, so the round trip is skipped
+                    # and _params_device_resident stays true through a
+                    # checkpointing fit
+                    epoch_cbs = _as_list(epoch_end_callback)
+                    need_host = any(getattr(cb, "_needs_host_params", True)
+                                    for cb in epoch_cbs)
+                    arg_params_out = aux_params_out = None
+                    if (epoch_cbs and need_host) or \
+                            not self._params_device_resident():
+                        arg_params_out, aux_params_out = self.get_params()
+                        self.set_params(arg_params_out, aux_params_out)
+                    for callback in epoch_cbs:
+                        callback(epoch, self.symbol, arg_params_out,
+                                 aux_params_out)
+
+                    if eval_data:
+                        if accum is not None:
+                            # validation updates the metric live (score() runs the
+                            # numpy path) — drop the training snapshot so an eval
+                            # Speedometer reads real values, not the stale cadence
+                            accum.last_snapshot = None
+                        with _tracing.span("fit.eval", category="module") as sp:
+                            res = self.score(eval_data, validation_metric,
+                                             score_end_callback=eval_end_callback,
+                                             batch_end_callback=eval_batch_end_callback,
+                                             epoch=epoch)
+                        eval_ms.observe(sp.duration_ms)
+                        for name, val in res:
+                            self.logger.info("Epoch[%d] Validation-%s=%f", epoch, name,
+                                             val)
+                    train_data.reset()
                     if el_session is not None:
-                        # BEFORE the lookahead fetch below: the only
-                        # point where the iterator cursor still reads
-                        # "batches 0..nbatch consumed"
-                        el_session.pre_lookahead(train_data, epoch, nbatch)
-                    view = self._device_step_view(data_batch) \
-                        if accum is not None else None
-                    if data_batch.data:
-                        epoch_samples += data_batch.data[0].shape[0] - \
-                            (data_batch.pad or 0)
-                    # fetch batch N+1 FIRST: its host assembly overlaps step
-                    # N's device execution (and, with DevicePrefetchIter, its
-                    # transfer is already in flight on the producer thread)
-                    try:
-                        next_data_batch = next(data_iter)
-                        self.prepare(next_data_batch)
-                    except StopIteration:
-                        end_of_batch = True
-                    pacing = 0.0
-                    if view is not None:
-                        labels, outs, token = view
-                        accum.update(labels, outs)
-                        if token is not None:
-                            inflight.append(token)
-                            # bounded in-flight window: block ONLY when more
-                            # than K steps are outstanding, and only on the
-                            # oldest — the device never idles waiting for the
-                            # host between steps
-                            while len(inflight) > \
-                                    max(1, int(inflight_limit["v"])):
-                                w = _device_wait(inflight.popleft())
-                                sync_wait_ms.observe(w)
-                                pacing += w
-                    else:
-                        self.update_metric(eval_metric, data_batch.label)
-                    step_ms.observe(sp.duration_ms + pacing)
-                    if _obs_corpus.enabled():
-                        # measurement-corpus service row: the same
-                        # per-step wall time the histogram sees, keyed
-                        # by batch rows for the cost-model fit
-                        _obs_corpus.record_service(
-                            "fit_step", sp.duration_ms + pacing,
-                            rows=data_batch.data[0].shape[0]
-                            if data_batch.data else None)
-                    cadence_now = (end_of_batch or metric_sync == 1 or
-                                   (metric_sync and nbatch and
-                                    nbatch % metric_sync == 0))
-                    if health_session is not None and monitor is not None \
-                            and monitor.activated:
-                        # a sampled (monitored) batch forces a cadence so
-                        # its device taps land before toc_print below
-                        cadence_now = True
-                    if accum is not None and cadence_now:
-                        if end_of_batch:
-                            inflight.clear()  # metric sync covers every step
-                        t0 = time.perf_counter()
-                        accum.sync()
-                        msync_ms.observe((time.perf_counter() - t0) * 1e3)
-                    elif health_session is not None and cadence_now:
-                        health_session.sync_direct()
-                    if health_session is not None and cadence_now:
-                        # detectors run on the freshly landed window —
-                        # BEFORE el_session.on_step below, so a rollback
-                        # wedge aborts before the corrupted snapshot
-                        health_session.on_cadence(eval_metric)
-                    if monitor is not None:
-                        monitor.toc_print()
-                    if el_session is not None:
-                        # after the step's metrics accumulated, before
-                        # the callbacks: the cadence snapshot point, and
-                        # where supervisor interrupts (wedge/SIGTERM)
-                        # surface as exceptions
-                        el_session.on_step(eval_metric, accum, train_data)
-                    if batch_end_callback is not None:
-                        batch_end_params = BatchEndParam(epoch=epoch, nbatch=nbatch,
-                                                         eval_metric=eval_metric,
-                                                         locals=locals())
-                        for callback in callbacks:
-                            callback(batch_end_params)
-                    nbatch += 1
-
-                for name, val in eval_metric.get_name_value():
-                    self.logger.info("Epoch[%d] Train-%s=%f", epoch, name, val)
-                toc = time.time()
-                self.logger.info("Epoch[%d] Time cost=%.3f", epoch, (toc - tic))
-                samples_total.inc(epoch_samples)
-                epochs_done.inc()
-                if toc > tic:
-                    sps_gauge.set(epoch_samples / (toc - tic))
-
-                # the reference round-trips every parameter through the host
-                # here each epoch; with device-resident weights (fused step)
-                # that transfer is pure waste unless a callback wants them —
-                # elastic-aware checkpoint callbacks (_needs_host_params
-                # False: they snapshot the device state directly through
-                # the async writer) don't, so the round trip is skipped
-                # and _params_device_resident stays true through a
-                # checkpointing fit
-                epoch_cbs = _as_list(epoch_end_callback)
-                need_host = any(getattr(cb, "_needs_host_params", True)
-                                for cb in epoch_cbs)
-                arg_params_out = aux_params_out = None
-                if (epoch_cbs and need_host) or \
-                        not self._params_device_resident():
-                    arg_params_out, aux_params_out = self.get_params()
-                    self.set_params(arg_params_out, aux_params_out)
-                for callback in epoch_cbs:
-                    callback(epoch, self.symbol, arg_params_out,
-                             aux_params_out)
-
-                if eval_data:
-                    if accum is not None:
-                        # validation updates the metric live (score() runs the
-                        # numpy path) — drop the training snapshot so an eval
-                        # Speedometer reads real values, not the stale cadence
-                        accum.last_snapshot = None
-                    with _tracing.span("fit.eval", category="module") as sp:
-                        res = self.score(eval_data, validation_metric,
-                                         score_end_callback=eval_end_callback,
-                                         batch_end_callback=eval_batch_end_callback,
-                                         epoch=epoch)
-                    eval_ms.observe(sp.duration_ms)
-                    for name, val in res:
-                        self.logger.info("Epoch[%d] Validation-%s=%f", epoch, name,
-                                         val)
-                train_data.reset()
-                if el_session is not None:
-                    el_session.on_epoch(epoch, eval_metric, train_data)
+                        el_session.on_epoch(epoch, eval_metric, train_data)
             if el_session is not None:
                 # fit returning implies its checkpoints are durable
                 _elastic.writer().flush()

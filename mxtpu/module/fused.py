@@ -769,8 +769,8 @@ class FusedTrainStep:
             out_sh = (p_sh, a_sh, o_sh, None)
             if health_classes:
                 out_sh += (None,)   # health rows: propagated (replicated)
-            self._step_fn = jax.jit(
-                step, in_shardings=(p_sh, a_sh, o_sh, b_sh, repl, repl,
+            self._step_fn = _pipeline.named_jit(
+                "fused_step", step, in_shardings=(p_sh, a_sh, o_sh, b_sh, repl, repl,
                                     repl),
                 out_shardings=out_sh,
                 donate_argnums=(0, 1, 2))
@@ -781,11 +781,12 @@ class FusedTrainStep:
             a_sh = {n: repl for n in self.aux}
             o_sh = jax.tree.map(lambda _: repl, self.opt_state)
             b_sh = {n: bshard for n in self.data_names + self.label_names}
-            self._step_fn = jax.jit(
-                step, in_shardings=(p_sh, a_sh, o_sh, b_sh, repl, repl, repl),
+            self._step_fn = _pipeline.named_jit(
+                "fused_step", step, in_shardings=(p_sh, a_sh, o_sh, b_sh, repl, repl, repl),
                 donate_argnums=(0, 1, 2))
         else:
-            self._step_fn = jax.jit(step, donate_argnums=(0, 1, 2))
+            self._step_fn = _pipeline.named_jit(
+                "fused_step", step, donate_argnums=(0, 1, 2))
         return self._step_fn
 
     # ------------------------------------------------ per-step driver

@@ -187,6 +187,41 @@ class Module(BaseModule):
             self._sync_params_from_devices()
         return (self._arg_params, self._aux_params)
 
+    def device_state(self):
+        """The training state where it lives, on the device, with no host
+        copy (``get_params`` makes one). Returns a dict:
+
+        * ``params``, ``aux`` — name -> device array (``jax.Array``);
+        * ``opt_state`` — name -> the optimizer's state for that
+          trainable parameter (a pytree of device arrays: momentum, or
+          Adam's ``(mean, var)``), or ``None`` while the fused train step
+          is not armed (the classic path keeps it in the Updater — see
+          ``save_optimizer_states``);
+        * ``dtypes`` — name -> the dtype (as a string) the Module BOUND
+          each parameter and auxiliary state in, which is what
+          ``init_params(arg_params=)`` of another dtype silently replaces.
+
+        With the fused step armed (any ``fit`` with a supported optimizer)
+        the trees are its live state: the next step donates their buffers,
+        so read or copy what you need before training goes on. The dict
+        itself is a fresh shallow copy. Needs ``bind``; before
+        ``init_params`` the arrays are the bound zeros."""
+        assert self.binded, "call bind before device_state"
+        grp = self._exec_group
+        bound = dict(zip(grp._param_names_out, grp.param_arrays))
+        bound.update(zip(grp.aux_names, grp.aux_arrays))
+        dtypes = {n: str(blocks[0].dtype) for n, blocks in bound.items()}
+        if self._fused is not None:
+            f = self._fused
+            return {"params": dict(f.params), "aux": dict(f.aux),
+                    "opt_state": dict(f.opt_state), "dtypes": dtypes}
+        aux_names = set(grp.aux_names)
+        return {"params": {n: b[0]._data for n, b in bound.items()
+                           if n not in aux_names},
+                "aux": {n: b[0]._data for n, b in bound.items()
+                        if n in aux_names},
+                "opt_state": None, "dtypes": dtypes}
+
     def init_params(self, initializer=None, arg_params=None, aux_params=None,
                     allow_missing=False, force_init=False, allow_extra=False):
         if self.params_initialized and not force_init:

@@ -122,8 +122,9 @@ class HealthAccum:
         def fold(sums, mx, batch_sums, batch_max):
             return sums + batch_sums, jnp.maximum(mx, batch_max)
 
-        from ..executor import record_program_build
-        return record_program_build("health_accum", self, jax.jit(fold))
+        from ..executor import named_jit, record_program_build
+        return record_program_build(
+            "health_accum", self, named_jit("health_accum", fold))
 
     def update(self, hstats):
         """Fold one fused step's stat rows in (device-only)."""

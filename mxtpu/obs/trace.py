@@ -49,7 +49,7 @@ class SpanRing:
         prices per step."""
         i = next(self._idx)
         self._slots[i % self.capacity] = (
-            i, span.name, span.category, span.t0_us, span.t1_us,
+            i, span.name, span.category, span.t0_ns, span.t1_ns,
             span.span_id, span.parent_id, span.trace_id,
             threading.get_ident(), span.tags or None)
 
@@ -57,14 +57,15 @@ class SpanRing:
         return sum(1 for r in self._slots if r is not None)
 
     def snapshot(self, limit=None):
-        """Oldest-first list of span dicts (the exporter's input)."""
+        """Oldest-first list of span dicts (the exporter's input);
+        ``t0_ns``/``t1_ns`` are wall-clock integer nanoseconds."""
         rows = [r for r in self._slots if r is not None]
         rows.sort(key=lambda r: r[0])
         if limit is not None:
             rows = rows[-int(limit):]
         return [
-            {"seq": r[0], "name": r[1], "category": r[2], "t0_us": r[3],
-             "t1_us": r[4], "span_id": r[5], "parent_id": r[6],
+            {"seq": r[0], "name": r[1], "category": r[2], "t0_ns": r[3],
+             "t1_ns": r[4], "span_id": r[5], "parent_id": r[6],
              "trace_id": r[7], "thread": r[8], "tags": r[9]}
             for r in rows]
 

@@ -21,7 +21,7 @@ records onto named per-thread tracks:
     ``threading.enumerate()`` table (dead threads fall back to
     ``tid-<ident>``).
 
-Timebase: wall-clock microseconds (``Span.t0_us`` convention), shared
+Timebase: wall-clock microseconds (the ring's ``t0_ns`` over 1,000), shared
 with ``mxtpu.profiler``'s op spans, so an exported timeline and a
 profiler dump line up. Serving exposes this body at ``GET
 /debug/trace``; ``mxtpu_top --trace-out FILE`` fetches it once.
@@ -66,8 +66,8 @@ def trace_events(flight_limit=1024):
                 args[str(k)] = _jsonable(v)
         events.append({
             "name": s["name"], "cat": s["category"] or "default",
-            "ph": "X", "ts": s["t0_us"],
-            "dur": max(0.0, s["t1_us"] - s["t0_us"]),
+            "ph": "X", "ts": s["t0_ns"] / 1e3,
+            "dur": max(0, s["t1_ns"] - s["t0_ns"]) / 1e3,
             "pid": 0, "tid": s["thread"], "args": args})
         parent = by_id.get(s["parent_id"])
         if parent is not None and parent["thread"] != s["thread"]:
@@ -76,11 +76,11 @@ def trace_events(flight_limit=1024):
             events.append({
                 "name": "flow", "cat": "flow", "ph": "s",
                 "id": s["span_id"], "pid": 0, "tid": parent["thread"],
-                "ts": min(parent["t0_us"], s["t0_us"])})
+                "ts": min(parent["t0_ns"], s["t0_ns"]) / 1e3})
             events.append({
                 "name": "flow", "cat": "flow", "ph": "f", "bp": "e",
                 "id": s["span_id"], "pid": 0, "tid": s["thread"],
-                "ts": s["t0_us"]})
+                "ts": s["t0_ns"] / 1e3})
 
     # flight ring -> thread-scoped instants (late import: diagnostics
     # imports obs.trace to arm the sink; this direction must stay lazy)

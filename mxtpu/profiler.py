@@ -9,12 +9,15 @@ TPU-native three-tier design:
      node-at-a-time execution with a device sync per node, recording true
      per-layer wall times as chrome://tracing spans (DumpProfile parity,
      profiler.cc:152 EmitPid/EmitEvent);
-  3. ``profiler_set_state('run')`` also starts a jax xplane trace for
-     TensorBoard's profile plugin when available.
+  3. ``profiler_set_state('run')`` also starts a jax xplane trace
+     (``xplane_dir()``, beside the configured filename) for xprof /
+     Perfetto; every telemetry span is a ``TraceAnnotation`` in it, so
+     the host's phases stand above the device's operations.
 """
 from __future__ import annotations
 
 import json
+import os
 import threading
 import time
 
@@ -54,7 +57,7 @@ def profiler_set_state(state="stop"):
     if state == "run":
         _state["running"] = True
         try:
-            jax.profiler.start_trace("/tmp/mxtpu_xplane")
+            jax.profiler.start_trace(xplane_dir())
             _state["jax_trace"] = True
         except Exception:
             _state["jax_trace"] = False
@@ -66,6 +69,14 @@ def profiler_set_state(state="stop"):
                 pass
             _state["jax_trace"] = False
         _state["running"] = False
+
+
+def xplane_dir():
+    """Where ``set_state('run')`` writes the JAX profiler's trace: beside
+    the configured ``filename``, ``<filename without extension>_xplane``.
+    It holds the device's operations and, on the host threads' lines,
+    every telemetry span (docs/observability.md, "One timeline")."""
+    return os.path.splitext(_state["filename"])[0] + "_xplane"
 
 
 # aliases matching python/mxnet/profiler.py's public names

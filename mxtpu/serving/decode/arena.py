@@ -160,9 +160,11 @@ class SequenceSlotArena:
 
         owner = "decode_arena[b=%d]" % bucket
         gfn = _pipeline.record_program_build(
-            "decode_state", owner, jax.jit(_gather))
+            "decode_state", owner,
+            _pipeline.named_jit("arena_gather", _gather))
         sfn = _pipeline.record_program_build(
-            "decode_state", owner, jax.jit(_scatter, donate_argnums=0))
+            "decode_state", owner,
+            _pipeline.named_jit("arena_scatter", _scatter, donate_argnums=0))
         self._gather_fns[bucket] = gfn
         self._scatter_fns[bucket] = sfn
         return gfn, sfn
@@ -464,7 +466,7 @@ class PagedArena:
 
         return _pipeline.record_program_build(
             "decode_paged", "decode_paged_view[b=%d]" % bucket,
-            jax.jit(_view))
+            _pipeline.named_jit("arena_view", _view))
 
     def _build_rows(self, bucket):
         def _rows(arrays, idx, fresh):
@@ -481,7 +483,7 @@ class PagedArena:
 
         return _pipeline.record_program_build(
             "decode_paged", "decode_paged_rows[b=%d]" % bucket,
-            jax.jit(_rows))
+            _pipeline.named_jit("arena_rows", _rows))
 
     def _build_scatter(self, bucket):
         def _scatter(arrays, idx, rows):
@@ -492,7 +494,7 @@ class PagedArena:
 
         return _pipeline.record_program_build(
             "decode_paged", "decode_paged_scatter[b=%d]" % bucket,
-            jax.jit(_scatter, donate_argnums=0))
+            _pipeline.named_jit("arena_scatter", _scatter, donate_argnums=0))
 
     def gather_view(self, slots):
         """Assemble the bucketed ``(B, max_blocks, block, …)`` KV view

@@ -50,6 +50,7 @@ Three arena layouts share this loop (``arena=`` / ``paged=``):
 """
 from __future__ import annotations
 
+import contextlib
 import logging
 import threading
 import time
@@ -169,10 +170,15 @@ class _Sequence:
         self.t_last_tok = None    # session clock at the previous emit
         self.trace = None         # exemplar event list when sampled
 
-    def mark(self, event, t, **detail):
-        """Append one exemplar timeline event (no-op unless sampled)."""
+    def mark(self, event, t, span=0, **detail):
+        """Append one exemplar timeline event (no-op unless sampled).
+        ``span`` is the id of the ``decode.step`` / ``decode.prefill_chunk``
+        span the event happened in: a sampled request can be followed
+        through the steps that served it on the trace timeline."""
         if self.trace is not None:
             row = {"event": event, "t": round(float(t), 6)}
+            if span:
+                row["span"] = span
             if detail:
                 row.update(detail)
             self.trace.append(row)
@@ -492,7 +498,15 @@ class DecodeSession:
             self.metrics.histogram("decode_phase_ms",
                                    labels={"phase": _phase})
         self.metrics.counter("decode_trace_sampled")
+        # sums beside the gauges: mean occupancy over any scrape interval
+        # is a ratio of rates (slot_steps / (steps_total x capacity)), no
+        # sampling thread needed
+        self.metrics.counter("decode_slot_steps")
+        # id of the decode.step / decode.prefill_chunk span the worker is
+        # in (0 outside): stamped on exemplar events
+        self._unit_span = 0
         if self._kind != "slots":
+            self.metrics.counter("decode_kv_block_steps")
             self.metrics.gauge("decode_kv_blocks_live",
                                fn=lambda: self.arena.blocks_live)
             self.metrics.gauge("decode_kv_blocks_free",
@@ -912,7 +926,9 @@ class DecodeSession:
             with self._lock:
                 if self._abort:
                     return
-                self._admit_queued_locked()
+                if self._queue:
+                    with self._span("decode.admit"):
+                        self._admit_queued_locked()
                 active = list(self._active)
                 if not active:
                     if self._closed and not self._queue:
@@ -970,6 +986,51 @@ class DecodeSession:
                         self._fail_chunk(chunk, DecodeWorkerCrash(
                             "decode worker died mid-step"))
                         raise
+
+    def _span(self, name, tags=None):
+        """One phase of the worker's loop. ``always``: the loop's own
+        histograms (and through ``decode_step_ms`` the admission model)
+        read these spans' durations, the regions' one timing."""
+        return self.metrics.span(name, category="decode", tags=tags,
+                                 always=True)
+
+    def _step_tags(self, n, bucket):
+        """A step's tags: live sequences, the bucket they ran in and, for
+        a paged arena, the live KV blocks."""
+        tags = {"n": n, "bucket": bucket}
+        if self._kind != "slots":
+            tags["kv_live"] = self.arena.blocks_live
+        return tags
+
+    @contextlib.contextmanager
+    def _unit(self, name, tags=None):
+        """A ``decode.step`` / ``decode.prefill_chunk`` span: the unit of
+        device work whose id is stamped on the exemplar events inside."""
+        with self._span(name, tags) as sp:
+            self._unit_span = sp.span_id
+            try:
+                yield sp
+            finally:
+                self._unit_span = 0
+
+    def _count_step(self, n, tags, phases):
+        """Book one finished device step: the counters, and the step's
+        latency as the sum of its four device-facing phases' spans
+        (gather, dispatch, scatter, logits wait)."""
+        self._steps += 1
+        self.metrics.counter("decode_steps_total").inc()
+        self.metrics.counter("decode_slot_steps").inc(n)
+        if "kv_live" in tags:
+            self.metrics.counter("decode_kv_block_steps").inc(
+                tags["kv_live"])
+        step_ms = sum(p.duration_ms for p in phases)
+        self.metrics.histogram("decode_step_ms").observe(step_ms)
+        self.metrics.histogram(
+            "decode_phase_ms", labels={"phase": "step"}).observe(step_ms)
+        _diag.record("decode", "step",
+                     "n=%d step=%d %.3fms" % (n, self._steps, step_ms))
+        if _obs_corpus.enabled():
+            _obs_corpus.record_service("decode_step", step_ms, rows=n)
 
     def _fail_chunk(self, chunk, exc):
         """A step program failure kills the CHUNK's sequences (their
@@ -1061,55 +1122,57 @@ class DecodeSession:
                 else s.slot
             fresh[i] = 1.0 if s.fresh else 0.0
         _faults.point("serving.decode.step")
-        t0 = time.perf_counter()
-        states = self.arena.gather_rows(idx, fresh) if rows_mode \
-            else self.arena.gather(idx, fresh)
-        rep = pool.replicas[0]
-        shapes = pool.bucket_shapes(bucket)
-        with rep.lock:
-            pred = rep.predictor_for(shapes)
-            ex = pred._executor
-            feed = {"data": tokens}
-            for name, st in zip(self._state_names, states):
-                feed[name] = st
-            # async dispatch: arg _data assignment keeps device arrays
-            # on device (never Predictor.set_input's host staging path)
-            ex.forward(is_train=False, **feed)
-            outs = [o._data for o in ex.outputs]
-        logits_dev, new_states = outs[0], outs[1:]
-        if rows_mode:
-            self.arena.scatter_rows(idx, new_states)
-        else:
-            self.arena.scatter(idx, new_states)
-        for s in seqs:
-            s.fresh = False
-        # the per-step host sync: ONE bulk logits transfer, off every
-        # lock; the registered wait doubles as the witness's blocking
-        # seam and shows up in watchdog postmortems by name
-        _diag.wait_begin("decode_logits")
-        try:
-            # mxtpu: allow-sync(per-step logits materialization — the
-            # single deliberate host transfer of the decode loop;
-            # sampling and EOS checks are host decisions by nature)
-            logits = jax.device_get(logits_dev)
-        finally:
-            _diag.wait_end()
-        self._steps += 1
-        self.metrics.counter("decode_steps_total").inc()
-        step_ms = (time.perf_counter() - t0) * 1e3
-        self.metrics.histogram("decode_step_ms").observe(step_ms)
-        self.metrics.histogram(
-            "decode_phase_ms", labels={"phase": "step"}).observe(step_ms)
-        _diag.record("decode", "step",
-                     "n=%d step=%d %.3fms" % (len(seqs), self._steps,
-                                              step_ms))
-        if _obs_corpus.enabled():
-            _obs_corpus.record_service("decode_step", step_ms,
-                                      rows=len(seqs))
-        now = self._clock()
-        for s in seqs:
-            s.mark("step", now, step=self._steps)
-        self._advance(seqs, logits)
+        tags = self._step_tags(len(seqs), bucket)
+        with self._unit("decode.step", tags):
+            with self._span("decode.gather") as sp_g:
+                states = self.arena.gather_rows(idx, fresh) if rows_mode \
+                    else self.arena.gather(idx, fresh)
+            rep = pool.replicas[0]
+            shapes = pool.bucket_shapes(bucket)
+            with self._span("decode.dispatch") as sp_d:
+                with rep.lock:
+                    pred = rep.predictor_for(shapes)
+                    ex = pred._executor
+                    feed = {"data": tokens}
+                    for name, st in zip(self._state_names, states):
+                        feed[name] = st
+                    # async dispatch: arg _data assignment keeps device
+                    # arrays on device (never Predictor.set_input's host
+                    # staging path)
+                    ex.forward(is_train=False, **feed)
+                    outs = [o._data for o in ex.outputs]
+            logits_dev, new_states = outs[0], outs[1:]
+            with self._span("decode.scatter") as sp_s:
+                if rows_mode:
+                    self.arena.scatter_rows(idx, new_states)
+                else:
+                    self.arena.scatter(idx, new_states)
+            for s in seqs:
+                s.fresh = False
+            logits, sp_w = self._logits_wait(logits_dev)
+            self._count_step(len(seqs), tags, (sp_g, sp_d, sp_s, sp_w))
+            with self._span("decode.sample"):
+                now = self._clock()
+                for s in seqs:
+                    s.mark("step", now, span=self._unit_span,
+                           step=self._steps)
+                self._advance(seqs, logits)
+
+    def _logits_wait(self, logits_dev, desc="decode_logits"):
+        """The per-step host sync: ONE bulk logits transfer, off every
+        lock — where the host waits for the step's device programs. The
+        registered wait doubles as the witness's blocking seam and shows
+        up in watchdog postmortems by name. Returns (logits, the span)."""
+        with self._span("decode.logits_wait") as sp:
+            _diag.wait_begin(desc)
+            try:
+                # mxtpu: allow-sync(per-step logits materialization — the
+                # single deliberate host transfer of the decode loop;
+                # sampling and EOS checks are host decisions by nature)
+                logits = jax.device_get(logits_dev)
+            finally:
+                _diag.wait_end()
+        return logits, sp
 
     def _ensure_blocks(self, s, n_tokens):
         """Grow ``s``'s KV block table to cover ``n_tokens`` positions.
@@ -1122,7 +1185,8 @@ class DecodeSession:
         if grew:
             _diag.record("decode", "block_alloc",
                          "slot=%d +%d blocks" % (s.slot, grew))
-            s.mark("block_alloc", self._clock(), blocks=grew)
+            s.mark("block_alloc", self._clock(), span=self._unit_span,
+                   blocks=grew)
 
     def _emit_token(self, s, token):
         """The single token-retirement seam: every emitted token —
@@ -1146,8 +1210,8 @@ class DecodeSession:
             self.metrics.histogram("decode_tbt_ms").observe(
                 (now - s.t_last_tok) * 1e3)
         s.t_last_tok = now
-        s.mark("token", now, index=len(s.out_tokens) - 1,
-               token=int(token))
+        s.mark("token", now, span=self._unit_span,
+               index=len(s.out_tokens) - 1, token=int(token))
         _diag.record("decode", "token",
                      "ord=%d idx=%d" % (s.req_ord,
                                         len(s.out_tokens) - 1))
@@ -1163,7 +1227,24 @@ class DecodeSession:
         TTFT emit site). Non-final chunks never transfer logits to the
         host: the decode loop's one-sync-per-step discipline holds."""
         _faults.point("serving.decode.prefill")
-        t0 = time.perf_counter()
+        slot, p0 = s.slot, s.pos
+        with self._unit("decode.prefill_chunk",
+                        {"slot": slot, "pos": p0}) as sp:
+            self._run_prefill_chunk(s, decoding_active)
+        # the whole chunk: host masks, gather, dispatch, scatter and, on
+        # the final chunk, the wait for its logits and the first token
+        prefill_ms = sp.duration_ms
+        self.metrics.histogram(
+            "decode_phase_ms",
+            labels={"phase": "prefill"}).observe(prefill_ms)
+        _diag.record("decode", "prefill_chunk",
+                     "slot=%d pos=%d/%d %.3fms"
+                     % (slot, s.pos, len(s.prompt), prefill_ms))
+        if _obs_corpus.enabled():
+            _obs_corpus.record_service("decode_prefill", prefill_ms,
+                                       rows=s.pos - p0)
+
+    def _run_prefill_chunk(self, s, decoding_active):
         p0 = s.pos
         rem = len(s.prompt) - p0
         cv = min(rem, self._prefill_quantum, self.prefill_buckets[-1])
@@ -1188,27 +1269,30 @@ class DecodeSession:
         kv_valid[0, :p0] = 1.0
         chunk_valid = _np.zeros((bucket, 1), dtype=_np.float32)
         chunk_valid[:cv, 0] = 1.0
-        views = self.arena.gather_view([s.slot])
+        with self._span("decode.gather"):
+            views = self.arena.gather_view([s.slot])
         pool = s.prefill_pool
         rep = pool.replicas[0]
         shapes = pool.bucket_shapes(bucket)
-        with rep.lock:
-            pred = rep.predictor_for(shapes)
-            ex = pred._executor
-            feed = {"data": data, "attn_mask_cache": mask_cache,
-                    "attn_mask_chunk": mask_chunk,
-                    "kv_valid_cache": kv_valid,
-                    "chunk_valid": chunk_valid}
-            for name, view in zip(self._kv_names, views):
-                feed[name] = view
-            ex.forward(is_train=False, **feed)
-            outs = [o._data for o in ex.outputs]
+        with self._span("decode.dispatch"):
+            with rep.lock:
+                pred = rep.predictor_for(shapes)
+                ex = pred._executor
+                feed = {"data": data, "attn_mask_cache": mask_cache,
+                        "attn_mask_chunk": mask_chunk,
+                        "kv_valid_cache": kv_valid,
+                        "chunk_valid": chunk_valid}
+                for name, view in zip(self._kv_names, views):
+                    feed[name] = view
+                ex.forward(is_train=False, **feed)
+                outs = [o._data for o in ex.outputs]
         logits_dev, kv_rows = outs[0], outs[1:]
         flat = _np.full((bucket,), self.arena.pad_flat_index,
                         dtype=_np.int32)
         for c in range(cv):
             flat[c] = self.arena.flat_index(s.slot, p0 + c)
-        self.arena.scatter_rows(flat, kv_rows)
+        with self._span("decode.scatter"):
+            self.arena.scatter_rows(flat, kv_rows)
         s.pos = p0 + cv
         self.metrics.counter("decode_prefill_chunks").inc()
         self.metrics.counter("decode_prefill_tokens").inc(cv)
@@ -1217,42 +1301,26 @@ class DecodeSession:
             # processed more prompt tokens than the declared latency
             # quantum while a generating sequence sat out the iteration
             self.metrics.counter("decode_prefill_stalls").inc()
-        prefill_ms = (time.perf_counter() - t0) * 1e3
-        self.metrics.histogram("decode_prefill_chunk_ms").observe(
-            prefill_ms)
-        self.metrics.histogram(
-            "decode_phase_ms",
-            labels={"phase": "prefill"}).observe(prefill_ms)
-        _diag.record("decode", "prefill_chunk",
-                     "slot=%d pos=%d/%d %.3fms"
-                     % (s.slot, s.pos, len(s.prompt), prefill_ms))
-        if _obs_corpus.enabled():
-            _obs_corpus.record_service("decode_prefill", prefill_ms,
-                                       rows=cv)
-        s.mark("prefill_chunk", self._clock(), pos=s.pos,
-               prompt_len=len(s.prompt), tokens=cv)
+        s.mark("prefill_chunk", self._clock(), span=self._unit_span,
+               pos=s.pos, prompt_len=len(s.prompt), tokens=cv)
         if s.pos < len(s.prompt):
             return     # mid-prompt: logits stay on device, no sync
-        _diag.wait_begin("decode_prefill_logits")
-        try:
-            # mxtpu: allow-sync(final-chunk logits materialization — the
-            # first-token sample is a host decision, same discipline as
-            # the decode step's one transfer)
-            logits = jax.device_get(logits_dev)
-        finally:
-            _diag.wait_end()
+        # the final chunk's logits: the first-token sample is a host
+        # decision, same discipline as the decode step's one transfer
+        logits, _ = self._logits_wait(logits_dev, "decode_prefill_logits")
         if s.expire_at is not None and self._clock() > s.expire_at:
             self._retire(s, error=TimeoutError(
                 "generate exceeded its deadline mid-prefill"),
                 reason="deadline")
             return
-        # mxtpu: allow-sync(logits already host-materialized above)
-        token = self._sample(_np.asarray(logits)[cv - 1], s)
-        self._emit_token(s, token)
-        if s.eos_id is not None and token == s.eos_id:
-            self._retire(s, reason="eos")
-        elif len(s.out_tokens) >= s.max_new:
-            self._retire(s, reason="length")
+        with self._span("decode.sample"):
+            # mxtpu: allow-sync(logits already host-materialized above)
+            token = self._sample(_np.asarray(logits)[cv - 1], s)
+            self._emit_token(s, token)
+            if s.eos_id is not None and token == s.eos_id:
+                self._retire(s, reason="eos")
+            elif len(s.out_tokens) >= s.max_new:
+                self._retire(s, reason="length")
 
     def _step_chunk_kv(self, pool, seqs):
         """One attention decode step for up to largest-bucket GENERATING
@@ -1282,66 +1350,55 @@ class DecodeSession:
         bucket = pick_bucket(len(seqs), self.buckets)
         T = self.max_blocks_per_seq * self.block_size
         _faults.point("serving.decode.step")
-        t0 = time.perf_counter()
-        tokens = _np.zeros((bucket, 1), dtype=_np.float32)
-        mask = _np.zeros((bucket, T), dtype=_np.float32)
-        slots = [None] * bucket
-        flat = _np.full((bucket,), self.arena.pad_flat_index,
-                        dtype=_np.int32)
-        for i, s in enumerate(seqs):
-            tokens[i, 0] = s.next_input_token()
-            mask[i, :s.pos] = 1.0
-            slots[i] = s.slot
-            flat[i] = self.arena.flat_index(s.slot, s.pos)
-        views = self.arena.gather_view(slots)
-        rep = pool.replicas[0]
-        shapes = pool.bucket_shapes(bucket)
-        with rep.lock:
-            pred = rep.predictor_for(shapes)
-            ex = pred._executor
-            feed = {"data": tokens, "attn_mask": mask}
-            for name, view in zip(self._kv_names, views):
-                feed[name] = view
-            ex.forward(is_train=False, **feed)
-            outs = [o._data for o in ex.outputs]
-        logits_dev, kv_rows = outs[0], outs[1:]
-        self.arena.scatter_rows(flat, kv_rows)
-        for s in seqs:
-            s.pos += 1
-        _diag.wait_begin("decode_logits")
-        try:
-            # mxtpu: allow-sync(per-step logits materialization — the
-            # single deliberate host transfer of the decode loop;
-            # sampling and EOS checks are host decisions by nature)
-            logits = jax.device_get(logits_dev)
-        finally:
-            _diag.wait_end()
-        self._steps += 1
-        self.metrics.counter("decode_steps_total").inc()
-        step_ms = (time.perf_counter() - t0) * 1e3
-        self.metrics.histogram("decode_step_ms").observe(step_ms)
-        self.metrics.histogram(
-            "decode_phase_ms", labels={"phase": "step"}).observe(step_ms)
-        _diag.record("decode", "step",
-                     "n=%d step=%d %.3fms" % (len(seqs), self._steps,
-                                              step_ms))
-        if _obs_corpus.enabled():
-            _obs_corpus.record_service("decode_step", step_ms,
-                                       rows=len(seqs))
-        now = self._clock()
-        for i, s in enumerate(seqs):
-            s.mark("step", now, step=self._steps)
-            if s.expire_at is not None and now > s.expire_at:
-                self._retire(s, error=TimeoutError(
-                    "generate exceeded its deadline mid-decode"),
-                    reason="deadline")
-                continue
-            token = self._sample(logits[i], s)
-            self._emit_token(s, token)
-            if s.eos_id is not None and token == s.eos_id:
-                self._retire(s, reason="eos")
-            elif len(s.out_tokens) >= s.max_new:
-                self._retire(s, reason="length")
+        tags = self._step_tags(len(seqs), bucket)
+        with self._unit("decode.step", tags):
+            # the host's inputs are the step span's own time
+            tokens = _np.zeros((bucket, 1), dtype=_np.float32)
+            mask = _np.zeros((bucket, T), dtype=_np.float32)
+            slots = [None] * bucket
+            flat = _np.full((bucket,), self.arena.pad_flat_index,
+                            dtype=_np.int32)
+            for i, s in enumerate(seqs):
+                tokens[i, 0] = s.next_input_token()
+                mask[i, :s.pos] = 1.0
+                slots[i] = s.slot
+                flat[i] = self.arena.flat_index(s.slot, s.pos)
+            with self._span("decode.gather") as sp_g:
+                views = self.arena.gather_view(slots)
+            rep = pool.replicas[0]
+            shapes = pool.bucket_shapes(bucket)
+            with self._span("decode.dispatch") as sp_d:
+                with rep.lock:
+                    pred = rep.predictor_for(shapes)
+                    ex = pred._executor
+                    feed = {"data": tokens, "attn_mask": mask}
+                    for name, view in zip(self._kv_names, views):
+                        feed[name] = view
+                    ex.forward(is_train=False, **feed)
+                    outs = [o._data for o in ex.outputs]
+            logits_dev, kv_rows = outs[0], outs[1:]
+            with self._span("decode.scatter") as sp_s:
+                self.arena.scatter_rows(flat, kv_rows)
+            for s in seqs:
+                s.pos += 1
+            logits, sp_w = self._logits_wait(logits_dev)
+            self._count_step(len(seqs), tags, (sp_g, sp_d, sp_s, sp_w))
+            with self._span("decode.sample"):
+                now = self._clock()
+                for i, s in enumerate(seqs):
+                    s.mark("step", now, span=self._unit_span,
+                           step=self._steps)
+                    if s.expire_at is not None and now > s.expire_at:
+                        self._retire(s, error=TimeoutError(
+                            "generate exceeded its deadline mid-decode"),
+                            reason="deadline")
+                        continue
+                    token = self._sample(logits[i], s)
+                    self._emit_token(s, token)
+                    if s.eos_id is not None and token == s.eos_id:
+                        self._retire(s, reason="eos")
+                    elif len(s.out_tokens) >= s.max_new:
+                        self._retire(s, reason="length")
 
     def _sample(self, row, seq):
         """Next token from one logits row: greedy argmax at
@@ -1381,14 +1438,27 @@ class DecodeSession:
                 self._retire(s, reason="length")
 
     def _retire(self, s, reason, error=None):
-        t0 = time.perf_counter()
+        with self._span("decode.retire") as sp:
+            result = self._retire_body(s, reason, error)
+        self.metrics.histogram(
+            "decode_phase_ms", labels={"phase": "retire"}).observe(
+            sp.duration_ms)
+        # the waiter is answered after the phase is booked, as before
+        if error is not None:
+            s.item.fail(error)
+        else:
+            s.item.finish(result)
+
+    def _retire_body(self, s, reason, error):
+        """Eviction and result assembly (the ``decode.retire`` span's
+        body); returns the result dict, None for a failed request."""
         s.finish_step = self._steps
         with self._lock:
             if s in self._active:
                 self._active.remove(s)
         self._evict(s, reason)
         now = self._clock()
-        s.mark("retire", now, reason=reason,
+        s.mark("retire", now, span=self._unit_span, reason=reason,
                tokens=len(s.out_tokens), error=error is not None)
         if s.trace is not None:
             # sampled request: count it, hold the finished exemplar for
@@ -1400,11 +1470,7 @@ class DecodeSession:
                  "events": list(s.trace)})
         if error is not None:
             self.metrics.counter("requests_timed_out").inc()
-            self.metrics.histogram(
-                "decode_phase_ms", labels={"phase": "retire"}).observe(
-                (time.perf_counter() - t0) * 1e3)
-            s.item.fail(error)
-            return
+            return None
         self.metrics.counter("requests_completed").inc()
         request_ms = (now - s.item.t_enqueue) * 1e3
         self.metrics.histogram("request_latency_ms").observe(request_ms)
@@ -1424,10 +1490,7 @@ class DecodeSession:
         if self.id2word is not None:
             result["text"] = " ".join(
                 str(self.id2word.get(t, t)) for t in s.out_tokens)
-        self.metrics.histogram(
-            "decode_phase_ms", labels={"phase": "retire"}).observe(
-            (time.perf_counter() - t0) * 1e3)
-        s.item.finish(result)
+        return result
 
     def _evict(self, s, reason, swallow=False):
         """Return a sequence's slot to the arena. The injection point

@@ -44,13 +44,15 @@ class MetricsRegistry(_BaseRegistry):
     def __init__(self, namespace="mxtpu_serving"):
         super().__init__(namespace=namespace)
 
-    def span(self, name, category="serving"):
+    def span(self, name, category="serving", tags=None, always=False):
         """Correlated trace-span context manager: nests under the ambient
         span (cross-thread parents via ``telemetry.current_span()``), is
-        mirrored into the chrome://tracing dump while the profiler runs
+        an event of any JAX profiler trace being recorded, is mirrored
+        into the chrome://tracing dump while the profiler runs
         (``profiler.set_state('run')``), and lands in the process-wide
-        ``span_ms{span=...}`` histogram."""
-        return _tel.span(name, category=category)
+        ``span_ms{span=...}`` histogram. ``always``: see
+        ``telemetry.span``."""
+        return _tel.span(name, category=category, tags=tags, always=always)
 
     # ---------------------------------------------------------- derived
     def _sum_counters(self, name):

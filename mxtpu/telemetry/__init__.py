@@ -110,6 +110,13 @@ class _NullMetric:
     def percentile(self, p):
         return 0.0
 
+    # the Histogram's read side too: a reader that takes deltas of
+    # snapshots (the benchmark's fit generator) sees nothing observed
+    bounds = ()
+
+    def snapshot(self):
+        return (0, 0.0, 0.0, 0.0, [])
+
 
 _NULL_METRIC = _NullMetric()
 
@@ -166,7 +173,7 @@ def _emit_span(s):
             return
     if _prof_mod._state["running"]:
         _prof_mod.record_span(
-            s.name, s.t0_us, s.t1_us, category=s.category,
+            s.name, s.t0_ns / 1e3, s.t1_ns / 1e3, category=s.category,
             args={"trace_id": s.trace_id, "span_id": s.span_id,
                   "parent_id": s.parent_id, **s.tags})
     if _ENABLED:

@@ -116,7 +116,7 @@ def test_span_ring_records_finished_spans():
     inner, out = rows
     assert inner["parent_id"] == out["span_id"]
     assert inner["trace_id"] == out["trace_id"] == outer.trace_id
-    assert out["t1_us"] >= out["t0_us"] > 0
+    assert out["t1_ns"] >= out["t0_ns"] > 0 and isinstance(out["t0_ns"], int)
     assert out["tags"] == {"k": 1}
     assert inner["thread"] == threading.get_ident()
 

@@ -54,10 +54,12 @@ DERIVED_OK = {
 }
 
 #: literal first-string-arg of span(...) calls (telemetry.span,
-#: tracing.span, metrics.span — the name is always the first string).
+#: tracing.span, metrics.span, the decode session's _span / _unit — the
+#: name is always the first string).
 #: Dotted names allowed; a name with format placeholders ("batch[%d]")
 #: deliberately fails the closing-quote match and is declared below.
-_SPAN_RE = re.compile(r"\bspan\(\s*(?:name=)?\"([a-z][a-z0-9_.]+)\"")
+_SPAN_RE = re.compile(
+    r"(?:\b|_)(?:span|unit)\(\s*(?:name=)?\"([a-z][a-z0-9_.]+)\"")
 
 #: dynamic span names, spelled the way the docs' span inventory does
 EXTRA_SPANS = [
