@@ -3,9 +3,13 @@
 The reference era (MXNet 0.11) predates transformers — its sequence
 baseline is the LSTM bucketing LM (example/rnn/lstm_bucketing.py). This
 family is the long-context flagship this framework treats as first-class:
-attention runs through the streaming/flash kernel
-(ops/attention.py `_contrib_FlashAttention`, O(T) residuals — no T^2
-score materialization), and the same graph trains sequence-parallel via
+attention runs through the flash kernels (ops/attention.py
+`_contrib_FlashAttention`): the forward saves its output and each row's
+log-sum-exp, O(T) numbers a head, and the backward kernel recomputes the
+scores block by block in VMEM — no T^2 tensor reaches HBM in either
+direction at sequence lengths the backward tiles (multiples of 128 up to
+4096; others take the reference's VJP, which materializes the scores).
+The same graph trains sequence-parallel via
 `mxtpu.parallel.ring_attention`/`ulysses_attention` over a 'seq' mesh
 axis (tests/test_parallel.py, __graft_entry__.dryrun_multichip).
 

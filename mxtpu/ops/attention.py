@@ -1,25 +1,41 @@
-"""Flash attention as a Pallas TPU kernel.
+"""Flash attention as Pallas TPU kernels, forward and backward.
 
 Beyond reference parity (the reference has no attention operator —
 SURVEY.md §5 'Long-context'), but the hot op of any long-context model, so
-it gets the full TPU treatment per /opt/skills/guides/pallas_guide.md:
+it gets the full TPU treatment per /opt/skills/guides/pallas_guide.md.
+
+Forward:
 
 - grid (batch*heads, q_blocks, kv_blocks), iterated sequentially on-core
   so VMEM scratch (running max / normalizer / accumulator) carries the
   online-softmax state across the kv dimension;
 - q@k^T and p@v on the MXU with f32 accumulation (preferred_element_type);
 - causal masking per block via broadcasted iotas;
-- output written once, on the last kv block, normalized by the running sum.
+- output written once, on the last kv block, normalized by the running
+  sum; under differentiation the same step writes each row's log-sum-exp
+  (float32), the one number the backward needs to rebuild the softmax.
 
-Backward runs through a jax.custom_vjp whose residual-free bwd recomputes
-with the pure-jnp reference (identical math) — the standard
-recompute-in-bwd tradeoff flash attention makes anyway.
+Backward (``jax.custom_vjp``): the residuals are ``(q, k, v, o, lse)``,
+O(T) numbers a head. Nothing of size T x S is saved or written to HBM. One
+kernel per head recomputes the scores block by block as
+``p = exp(s - lse)``, with ``delta = rowsum(dO * O)`` taken from ``o``,
+and accumulates dQ, dK and dV in VMEM in float32 (scores, ``lse``,
+``delta`` and every accumulator are float32; ``p`` and ``dS`` are cast to
+the operands' dtype for the MXU, as the forward casts ``p``). Under a
+causal mask the blocks above the diagonal are never visited and only the
+blocks on it are masked. Its block sizes follow T, S, D and the dtype
+(``_bwd_blocks``); the forward's ``block_q`` / ``block_k`` are not
+consulted. Shapes it does not tile (sequence lengths that are no multiple
+of 128, a causal mask with T != S, a head too long for VMEM) save
+``(q, k, v)`` and take the VJP of the jnp reference, which materializes
+the scores.
 
-Which forward runs is decided per compiled program, from the platform the
-program is lowered for (``lax.platform_dependent``): on ``tpu`` always the
-Mosaic kernel; on ``cpu`` the same kernel in interpret mode for small
-shapes (tests) and the jnp reference otherwise. No other platform has a
-branch, so lowering for one is an error rather than a quiet substitute.
+Which program runs is decided per compiled program, from the shapes and
+from the platform the program is lowered for (``lax.platform_dependent``):
+on ``tpu`` always the Mosaic kernels; on ``cpu`` the same kernels in
+interpret mode for small shapes (tests) and the jnp reference otherwise.
+No other platform has a branch, so lowering for one is an error rather
+than a quiet substitute.
 """
 from __future__ import annotations
 
@@ -30,65 +46,49 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .. import telemetry
 from .registry import register
 
 NEG_INF = -1e30
 
+# scoped VMEM asked for the backward kernel, which keeps a whole head
+# resident: under a third of a v5e core's 128 MiB
+_BWD_VMEM_BYTES = 40 << 20
 
-def _reference(q, k, v, scale, causal):
-    """Pure-jnp oracle. (BH, T, D) layout. Materializes the T^2 score
-    matrix — tests and small shapes only."""
+
+def _scores(q, k, scale, causal):
     s = jnp.einsum("btd,bsd->bts", q, k).astype(jnp.float32) * scale
     if causal:
         t = s.shape[1]
         srng = s.shape[2]
         mask = jnp.arange(srng)[None, :] <= jnp.arange(t)[:, None]
         s = jnp.where(mask[None], s, NEG_INF)
-    p = jax.nn.softmax(s, axis=-1)
+    return s
+
+
+def _reference(q, k, v, scale, causal):
+    """Pure-jnp oracle. (BH, T, D) layout. Materializes the T^2 score
+    matrix — tests and small shapes only."""
+    p = jax.nn.softmax(_scores(q, k, scale, causal), axis=-1)
     return jnp.einsum("bts,bsd->btd", p.astype(v.dtype), v)
 
 
-def _streaming(q, k, v, scale, causal, block=512):
-    """lax.scan flash-style attention, (BH, T, D) layout: O(T) residuals,
-    so its VJP is the memory-efficient backward recompute path."""
-    bh, t, d = q.shape
-    s_len = k.shape[1]
-    nblk = -(-s_len // block)
-    pad = nblk * block - s_len
-    kp = jnp.pad(k, ((0, 0), (0, pad), (0, 0))) if pad else k
-    vp = jnp.pad(v, ((0, 0), (0, pad), (0, 0))) if pad else v
-    kb = kp.reshape(bh, nblk, block, d).transpose(1, 0, 2, 3)
-    vb = vp.reshape(bh, nblk, block, d).transpose(1, 0, 2, 3)
-    q_idx = jnp.arange(t)
-
-    def body(carry, blk):
-        m_prev, l_prev, o_prev = carry
-        kc, vc, bi = blk
-        s = jnp.einsum("btd,bsd->bts", q, kc).astype(jnp.float32) * scale
-        k_idx = bi * block + jnp.arange(block)
-        valid = k_idx[None, :] < s_len
-        if causal:
-            valid = valid & (k_idx[None, :] <= q_idx[:, None])
-        s = jnp.where(valid[None], s, NEG_INF)
-        m_cur = jnp.max(s, axis=-1)
-        m_new = jnp.maximum(m_prev, m_cur)
-        p = jnp.exp(s - m_new[..., None])
-        alpha = jnp.exp(m_prev - m_new)
-        l_new = alpha * l_prev + jnp.sum(p, axis=-1)
-        o_new = o_prev * alpha[..., None] + jnp.einsum(
-            "bts,bsd->btd", p, vc.astype(jnp.float32))
-        return (m_new, l_new, o_new), None
-
-    m0 = jnp.full((bh, t), NEG_INF, jnp.float32)
-    l0 = jnp.zeros((bh, t), jnp.float32)
-    o0 = jnp.zeros((bh, t, d), jnp.float32)
-    (m, l, o), _ = jax.lax.scan(body, (m0, l0, o0),
-                                (kb, vb, jnp.arange(nblk)))
-    return (o / jnp.maximum(l, 1e-20)[..., None]).astype(q.dtype)
+def _reference_vjp(q, k, v, g, scale, causal):
+    _, vjp = jax.vjp(lambda a, b, c: _reference(a, b, c, scale, causal),
+                     q, k, v)
+    return vjp(g)
 
 
-def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *,
-                scale, causal, block_q, block_k, kv_len):
+def _interpretable(q, k):
+    """Small enough for Pallas' interpret mode on the CPU."""
+    return q.shape[0] * q.shape[1] * k.shape[1] <= 1 << 22
+
+
+def _fwd_kernel(q_ref, k_ref, v_ref, *refs, scale, causal, block_q, block_k,
+                kv_len):
+    # outputs (o and, under differentiation, lse), then the scratch
+    o_ref, lse_ref = refs[0], (refs[1] if len(refs) == 5 else None)
+    acc_ref, m_ref, l_ref = refs[-3:]
     j = pl.program_id(2)
     nj = pl.num_programs(2)
 
@@ -144,27 +144,46 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *,
         l = l_ref[:, :1]
         o_ref[0] = (acc_ref[...] /
                     jnp.where(l == 0, 1.0, l)).astype(o_ref.dtype)
+        if lse_ref is not None:
+            # m and l are columns broadcast over 128 lanes; the backward
+            # reads lse as a row over the queries, so turn it here
+            l_all = l_ref[...]
+            lse = m_ref[...] + jnp.log(jnp.where(l_all == 0, 1.0, l_all))
+            lse_ref[0, 0] = lse.T[:1]
 
 
-def _flash_call(q, k, v, scale, causal, block_q, block_k, interpret):
+def _flash_call(q, k, v, scale, causal, block_q, block_k, interpret,
+                with_lse=False):
+    """The forward kernel: o, and with `with_lse` also the rows'
+    log-sum-exp, float32 (BH, T)."""
     bh, t, d = q.shape
     s_len = k.shape[1]
     block_q = min(block_q, t)
     block_k = min(block_k, s_len)
-    grid = (bh, pl.cdiv(t, block_q), pl.cdiv(s_len, block_k))
+    nq = pl.cdiv(t, block_q)
+    grid = (bh, nq, pl.cdiv(s_len, block_k))
     kernel = functools.partial(_fwd_kernel, scale=scale, causal=causal,
                                block_q=block_q, block_k=block_k,
                                kv_len=s_len)
-    return pl.pallas_call(
+    out_shape = [jax.ShapeDtypeStruct((bh, t, d), q.dtype)]
+    out_specs = [pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0))]
+    if with_lse:
+        # one (1, block_q) row a q block: its last two dims are the
+        # array's own, so any block_q is a legal block
+        out_shape.append(jax.ShapeDtypeStruct((bh, nq, 1, block_q),
+                                              jnp.float32))
+        out_specs.append(pl.BlockSpec((1, 1, 1, block_q),
+                                      lambda b, i, j: (b, i, 0, 0)))
+    out = pl.pallas_call(
         kernel,
-        out_shape=jax.ShapeDtypeStruct((bh, t, d), q.dtype),
+        out_shape=out_shape,
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
             pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, j, 0)),
             pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, j, 0)),
         ],
-        out_specs=pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
+        out_specs=out_specs,
         scratch_shapes=[
             pltpu.VMEM((block_q, d), jnp.float32),
             pltpu.VMEM((block_q, 128), jnp.float32),
@@ -172,45 +191,191 @@ def _flash_call(q, k, v, scale, causal, block_q, block_k, interpret):
         ],
         interpret=interpret,
     )(q, k, v)
+    if not with_lse:
+        return out[0]
+    return out[0], out[1].reshape(bh, nq * block_q)[:, :t]
 
 
-@functools.partial(jax.jit, static_argnums=(3, 4, 5, 6))
-def _forward(q, k, v, scale, causal, block_q, block_k):
+@functools.partial(jax.jit, static_argnums=(3, 4, 5, 6, 7))
+def _forward(q, k, v, scale, causal, block_q, block_k, with_lse):
     """The forward for the platform this program is compiled for. Jitted
     so the choice follows the operands' device even when called eagerly
     (a CPU-committed operand on a chip host must not reach Mosaic)."""
     def on_tpu(q, k, v):
         return _flash_call(q, k, v, scale, causal, block_q, block_k,
-                           interpret=False)
+                           interpret=False, with_lse=with_lse)
 
     def on_cpu(q, k, v):
         # interpret mode exercises the kernel logic on CPU for small
         # problems; big CPU shapes take the reference path
-        if q.shape[0] * q.shape[1] * k.shape[1] <= 1 << 22:
+        if _interpretable(q, k):
             return _flash_call(q, k, v, scale, causal, block_q, block_k,
-                               interpret=True)
-        return _reference(q, k, v, scale, causal)
+                               interpret=True, with_lse=with_lse)
+        out = _reference(q, k, v, scale, causal)
+        if not with_lse:
+            return out
+        return out, jax.nn.logsumexp(_scores(q, k, scale, causal), axis=-1)
 
     return jax.lax.platform_dependent(q, k, v, tpu=on_tpu, cpu=on_cpu)
 
 
+def _bwd_blocks(t, s_len, d, itemsize, causal):
+    """(block_q, block_k) of the backward kernel, or None where it does not
+    tile. The largest of 512, 256, 128 that divides: fewer, longer loop
+    iterations won on a v5e (PERF.md section 6, PR 27), and at T=1024 a
+    causal mask still skips one block pair of four."""
+    if t % 128 or s_len % 128 or (causal and t != s_len):
+        return None
+    block_q = next(b for b in (512, 256, 128) if t % b == 0)
+    block_k = block_q if causal else next(
+        b for b in (512, 256, 128) if s_len % b == 0)
+    # a whole head is resident: q, dO, dQ and k, v, dK, dV double-buffered
+    # (lanes padded to 128), dQ's float32 accumulator, and eight
+    # block-pair temporaries (scores, p, dP, dS and their casts)
+    lanes = -(-d // 128) * 128
+    resident = (2 * (3 * t + 4 * s_len) * lanes * itemsize
+                + t * lanes * 4 + 8 * block_q * block_k * 4)
+    if resident > _BWD_VMEM_BYTES * 3 // 4:
+        return None
+    return block_q, block_k
+
+
+def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+                dq_ref, dk_ref, dv_ref, dq_acc, dk_acc, dv_acc, *,
+                scale, causal, block_q, block_k):
+    """One head. Scores are held transposed, (block_k, block_q): lse and
+    delta then broadcast as rows, and dV = P^T dO and dK = dS^T Q are plain
+    matmuls. lse_ref and delta_ref are (1, T / block_q, block_q)."""
+    t = q_ref.shape[1]
+    nq, nk = t // block_q, k_ref.shape[1] // block_k
+    a_bt = (((1,), (1,)), ((), ()))   # a @ b.T
+    at_b = (((0,), (0,)), ((), ()))   # a.T @ b
+    dq_acc[...] = jnp.zeros_like(dq_acc)
+
+    def kv_block(j, _):
+        cols = pl.ds(pl.multiple_of(j * block_k, block_k), block_k)
+        k_j, v_j = k_ref[0, cols, :], v_ref[0, cols, :]
+        dk_acc[...] = jnp.zeros_like(dk_acc)
+        dv_acc[...] = jnp.zeros_like(dv_acc)
+
+        def pair(i, on_diagonal):
+            rows = pl.ds(pl.multiple_of(i * block_q, block_q), block_q)
+            q_i, do_i = q_ref[0, rows, :], do_ref[0, rows, :]
+            s_t = jax.lax.dot_general(
+                k_j, q_i, a_bt, preferred_element_type=jnp.float32) * scale
+            if on_diagonal:
+                # block_q == block_k and i == j: the offsets cancel
+                key = jax.lax.broadcasted_iota(jnp.int32, s_t.shape, 0)
+                query = jax.lax.broadcasted_iota(jnp.int32, s_t.shape, 1)
+                s_t = jnp.where(key <= query, s_t, NEG_INF)
+            p_t = jnp.exp(s_t - lse_ref[0, pl.ds(i, 1), :])
+            dv_acc[...] += jnp.dot(p_t.astype(do_i.dtype), do_i,
+                                   preferred_element_type=jnp.float32)
+            dp_t = jax.lax.dot_general(v_j, do_i, a_bt,
+                                       preferred_element_type=jnp.float32)
+            # dS less the softmax scale, which dQ and dK take once a block
+            ds_t = (p_t * (dp_t - delta_ref[0, pl.ds(i, 1), :])
+                    ).astype(q_i.dtype)
+            dk_acc[...] += jnp.dot(ds_t, q_i,
+                                   preferred_element_type=jnp.float32)
+            dq_acc[rows, :] += jax.lax.dot_general(
+                ds_t, k_j, at_b, preferred_element_type=jnp.float32)
+
+        def below(i, _):
+            pair(i, False)
+            return 0
+
+        if causal:
+            # queries before this kv block see none of it: start on the
+            # diagonal, the one block pair that needs the mask
+            pair(j, True)
+            jax.lax.fori_loop(j + 1, nq, below, 0)
+        else:
+            jax.lax.fori_loop(0, nq, below, 0)
+        dk_ref[0, cols, :] = (dk_acc[...] * scale).astype(dk_ref.dtype)
+        dv_ref[0, cols, :] = dv_acc[...].astype(dv_ref.dtype)
+        return 0
+
+    jax.lax.fori_loop(0, nk, kv_block, 0)
+    dq_ref[0] = (dq_acc[...] * scale).astype(dq_ref.dtype)
+
+
+def _bwd_call(q, k, v, do, lse, delta, scale, causal, block_q, block_k,
+              interpret):
+    bh, t, d = q.shape
+    s_len = k.shape[1]
+    kernel = functools.partial(_bwd_kernel, scale=scale, causal=causal,
+                               block_q=block_q, block_k=block_k)
+    q_spec = pl.BlockSpec((1, t, d), lambda b: (b, 0, 0))
+    kv_spec = pl.BlockSpec((1, s_len, d), lambda b: (b, 0, 0))
+    row_spec = pl.BlockSpec((1, t // block_q, block_q), lambda b: (b, 0, 0))
+    rows = (bh, t // block_q, block_q)
+    return pl.pallas_call(
+        kernel,
+        out_shape=[jax.ShapeDtypeStruct(q.shape, q.dtype),
+                   jax.ShapeDtypeStruct(k.shape, k.dtype),
+                   jax.ShapeDtypeStruct(v.shape, v.dtype)],
+        grid=(bh,),
+        in_specs=[q_spec, kv_spec, kv_spec, q_spec, row_spec, row_spec],
+        out_specs=[q_spec, kv_spec, kv_spec],
+        scratch_shapes=[
+            pltpu.VMEM((t, d), jnp.float32),
+            pltpu.VMEM((block_k, d), jnp.float32),
+            pltpu.VMEM((block_k, d), jnp.float32),
+        ],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",),
+            vmem_limit_bytes=_BWD_VMEM_BYTES),
+        interpret=interpret,
+    )(q, k, v, do, lse.reshape(rows), delta.reshape(rows))
+
+
+@functools.partial(jax.jit, static_argnums=(6, 7, 8))
+def _backward(q, k, v, o, lse, g, scale, causal, blocks):
+    """(dq, dk, dv) by the backward kernel, for the platform this program
+    is compiled for (as `_forward`)."""
+    delta = jnp.sum(o.astype(jnp.float32) * g.astype(jnp.float32), axis=-1)
+
+    def on_tpu(q, k, v, g, lse, delta):
+        return tuple(_bwd_call(q, k, v, g, lse, delta, scale, causal,
+                               *blocks, interpret=False))
+
+    def on_cpu(q, k, v, g, lse, delta):
+        if _interpretable(q, k):
+            return tuple(_bwd_call(q, k, v, g, lse, delta, scale, causal,
+                                   *blocks, interpret=True))
+        return _reference_vjp(q, k, v, g, scale, causal)
+
+    return jax.lax.platform_dependent(q, k, v, g, lse, delta,
+                                      tpu=on_tpu, cpu=on_cpu)
+
+
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
 def _flash3(q, k, v, scale, causal, block_q, block_k):
-    return _forward(q, k, v, scale, causal, block_q, block_k)
+    return _forward(q, k, v, scale, causal, block_q, block_k, False)
 
 
 def _flash3_fwd(q, k, v, scale, causal, block_q, block_k):
-    return _flash3(q, k, v, scale, causal, block_q, block_k), (q, k, v)
+    if _bwd_blocks(q.shape[1], k.shape[1], q.shape[2], q.dtype.itemsize,
+                   causal) is None:
+        out = _forward(q, k, v, scale, causal, block_q, block_k, False)
+        return out, (q, k, v)
+    out, lse = _forward(q, k, v, scale, causal, block_q, block_k, True)
+    return out, (q, k, v, out, lse)
 
 
 def _flash3_bwd(scale, causal, block_q, block_k, res, g):
-    q, k, v = res
-    # recompute through the streaming implementation: its scan keeps O(T)
-    # residuals, so long-context training never materializes T^2 scores
-    _, vjp = jax.vjp(lambda a, b, c: _streaming(a, b, c, scale, causal,
-                                                block=block_k),
-                     q, k, v)
-    return vjp(g)
+    q, k, v = res[:3]
+    blocks = _bwd_blocks(q.shape[1], k.shape[1], q.shape[2],
+                         q.dtype.itemsize, causal)
+    telemetry.counter(
+        "attention_bwd_builds",
+        labels={"path": "reference" if blocks is None else "kernel"},
+        help="attention backward passes traced, by the path their shape "
+             "takes").inc()
+    if blocks is None:
+        return _reference_vjp(q, k, v, g, scale, causal)
+    return _backward(*res, g, scale, causal, blocks)
 
 
 _flash3.defvjp(_flash3_fwd, _flash3_bwd)

@@ -1,6 +1,7 @@
-"""Flash-attention Pallas kernel tests: interpret-mode kernel vs the jnp
-reference oracle, causal masking, gradients, op registration, and the
-ring-attention cross-check."""
+"""Flash-attention Pallas kernel tests: interpret-mode kernels, forward and
+backward, vs the jnp reference oracle, causal masking, gradients, which
+backward a shape takes, op registration, and the ring-attention
+cross-check."""
 import numpy as np
 import pytest
 
@@ -101,20 +102,59 @@ def test_flash_ragged_kv_tail():
                                rtol=2e-3, atol=2e-3)
 
 
-def test_streaming_matches_reference():
-    bh, t, s, d = 2, 96, 160, 32
-    q = _rand((bh, t, d), 0)
-    k = _rand((bh, s, d), 1)
-    v = _rand((bh, s, d), 2)
-    for causal in (False, True):
-        if causal and t != s:
-            ref = att._reference(q, k, v, 0.2, False)
-            stream = att._streaming(q, k, v, 0.2, False, block=64)
-        else:
-            ref = att._reference(q, k, v, 0.2, causal)
-            stream = att._streaming(q, k, v, 0.2, causal, block=64)
-        np.testing.assert_allclose(np.asarray(stream), np.asarray(ref),
-                                   rtol=2e-3, atol=2e-3)
+def _grads(fn, q, k, v, w):
+    return jax.grad(lambda *a: (fn(*a).astype(jnp.float32) * w).sum(),
+                    argnums=(0, 1, 2))(q, k, v)
+
+
+# (causal, T, S, the backward's blocks): multi-block T, so that skipping
+# above the diagonal and accumulation across block pairs run in interpret
+# mode; None where the kernel does not tile and the reference's VJP runs
+_BWD_CASES = [
+    (True, 384, 384, (128, 128)),     # 6 live pairs of 9, 3 on the diagonal
+    (True, 1024, 1024, (512, 512)),   # the LM cell's blocks: 3 pairs of 4
+    (False, 384, 384, (128, 128)),
+    (False, 256, 384, (256, 128)),    # t != s
+    (False, 64, 96, None),            # ragged kv tail
+]
+
+
+@pytest.mark.parametrize("dtype,tol", [("float32", 1e-4), ("bfloat16", 3e-2)])
+@pytest.mark.parametrize("causal,t,s,blocks", _BWD_CASES)
+def test_flash_backward_matches_reference_vjp(causal, t, s, blocks, dtype,
+                                              tol):
+    bh, d = 2, 64
+    q, k, v = (_rand((bh, n, d), i).astype(dtype)
+               for i, n in enumerate((t, s, s)))
+    w = _rand((bh, t, d), 3)
+    scale = 1.0 / d ** 0.5
+    assert att._bwd_blocks(t, s, d, q.dtype.itemsize, causal) == blocks
+    got = _grads(lambda *a: att._flash3(*a, scale, causal, 512, 1024),
+                 q, k, v, w)
+    want = _grads(lambda *a: att._reference(*a, scale, causal), q, k, v, w)
+    for g, r in zip(got, want):
+        assert g.dtype == r.dtype and g.shape == r.shape
+        np.testing.assert_allclose(np.asarray(g, "f4"), np.asarray(r, "f4"),
+                                   rtol=tol, atol=tol)
+
+
+def test_attention_bwd_builds_counts_the_path():
+    from mxtpu import telemetry
+
+    def built(path):
+        return telemetry.counter("attention_bwd_builds",
+                                 labels={"path": path}).value
+
+    def grad(shape):
+        q = _rand(shape)
+        jax.grad(lambda a: att.flash_attention(a, q, q, causal=True).sum())(q)
+
+    before = built("kernel"), built("reference")
+    grad((1, 2, 128, 32))
+    assert (built("kernel"), built("reference")) == (before[0] + 1, before[1])
+    grad((1, 4, 2, 4))   # the gradient sweep's shape: nothing to tile
+    assert (built("kernel"), built("reference")) == (before[0] + 1,
+                                                     before[1] + 1)
 
 
 def test_pallas_epilogue_matches_reference():

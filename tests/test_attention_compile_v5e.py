@@ -1,0 +1,58 @@
+"""Compile-only check, for a described (not attached) TPU v5e, of the
+attention gradient at the LM cell's size. Nothing runs: a compile that
+passes here is not a chip run. The fixture skips, as
+tests/bench_yardstick/test_compile_v5e.py does, where no topology can be
+described (another process of the run may hold the TPU's compiler)."""
+import os
+
+import pytest
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 - whatever stops the description
+        pytest.skip("no v5e:2x2 topology can be described here: %s" % e)
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture()
+def no_cache():
+    """A compile for a described chip is written to the persistent cache but
+    cannot be read back without one: keep the cache off around these."""
+    import jax
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", was)
+    cc.reset_cache()
+
+
+def test_attention_gradient_keeps_no_scores_at_the_lm_cells_size(one_chip,
+                                                                 no_cache):
+    """opt-1.3b-fit-s1024: batch 4, 32 heads of 64, 1024 tokens, bf16. The
+    gradient is the forward kernel and the backward kernel, and nothing of
+    (B*H, T, T) is written to HBM."""
+    import jax
+    import jax.numpy as jnp
+    from mxtpu.ops import attention
+    q = jax.ShapeDtypeStruct((4, 32, 1024, 64), jnp.bfloat16,
+                             sharding=one_chip)
+
+    def loss(q, k, v):
+        return jnp.sum(attention.flash_attention(q, k, v, causal=True)
+                       .astype(jnp.float32))
+
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        q, q, q).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") >= 2
+    assert "[128,1024,1024]" not in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.35e9

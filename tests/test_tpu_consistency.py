@@ -163,14 +163,18 @@ _FLASH_CASES = [
     ("bfloat16", 2, 4, 1024, 128, 512, 1024, 2e-2),
     # bf16 with a tail block
     ("bfloat16", 1, 4, 640, 128, 256, 256, 2e-2),
+    # opt-1.3b-fit-s1024's own shape: head 64, the op's default blocks
+    ("bfloat16", 4, 32, 1024, 64, 512, 1024, 2e-2),
 ]
 
 
 @pytest.mark.parametrize("dtype,B,H,T,D,bq,bk,tol", _FLASH_CASES)
 def test_pallas_flash_kernel_on_chip(dtype, B, H, T, D, bq, bk, tol):
-    """The Mosaic-compiled flash kernel against float32 reference math on
-    the chip — values and gradients. CPU runs reach the same kernel only
-    in interpret mode, so this validates the lowered kernel itself."""
+    """The Mosaic-compiled flash kernels, forward and backward, against
+    float32 reference math on the chip — values and gradients. CPU runs
+    reach the same kernels only in interpret mode, so this validates the
+    lowered kernels themselves. T=320 does not tile for the backward and
+    takes the reference's VJP."""
     import jax
     import jax.numpy as jnp
     from mxtpu.ops import attention as att
@@ -188,6 +192,11 @@ def test_pallas_flash_kernel_on_chip(dtype, B, H, T, D, bq, bk, tol):
 
     hlo = jax.jit(flash).lower(q, k, v).as_text()
     assert "tpu_custom_call" in hlo, "flash forward is not a Mosaic call"
+    if att._bwd_blocks(T, T, D, q.dtype.itemsize, True) is not None:
+        hlo = jax.jit(jax.grad(flash_loss, argnums=(0, 1, 2))).lower(
+            q, k, v).as_text()
+        assert hlo.count("tpu_custom_call") >= 2, \
+            "the gradient holds no Mosaic backward beside the forward"
     with jax.default_matmul_precision("highest"):
         expect = _attention_ref(q, k, v, True)
         g_ref = jax.grad(lambda a, b, c: _attention_ref(

@@ -3,8 +3,8 @@ causality, convergence, and data-parallel training over a mesh.
 
 The reference era has no transformer (its sequence baseline is
 example/rnn/lstm_bucketing.py); this family is the long-context flagship —
-attention is the streaming/flash kernel and the same blocks drive the
-ring/ulysses sequence-parallel paths (tests/test_parallel.py)."""
+attention is the flash kernels (forward and backward) and the same blocks
+drive the ring/ulysses sequence-parallel paths (tests/test_parallel.py)."""
 import math
 
 import numpy as np
