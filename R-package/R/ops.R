@@ -1,5 +1,5 @@
 # GENERATED by R-package/gen_r_ops.py -- do not edit.
-# One mx.symbol.<op> function per registered op (290 ops); tensor inputs
+# One mx.symbol.<op> function per registered op (295 ops); tensor inputs
 # are mx.symbol objects passed by name, everything else becomes a string
 # param (the reference R package generates its op surface the same way).
 
@@ -249,6 +249,12 @@
   mx.symbol.create("Proposal", a[is.sym], a[!is.sym], name)
 }
 
+`mx.symbol.RMSNorm` <- function(..., name = "") {
+  a <- list(...)
+  is.sym <- vapply(a, function(v) inherits(v, "mx.symbol"), TRUE)
+  mx.symbol.create("RMSNorm", a[is.sym], a[!is.sym], name)
+}
+
 `mx.symbol.RNN` <- function(..., name = "") {
   a <- list(...)
   is.sym <- vapply(a, function(v) inherits(v, "mx.symbol"), TRUE)
@@ -363,6 +369,12 @@
   mx.symbol.create("_contrib_CTCLoss", a[is.sym], a[!is.sym], name)
 }
 
+`mx.symbol._contrib_CausalConv1D` <- function(..., name = "") {
+  a <- list(...)
+  is.sym <- vapply(a, function(v) inherits(v, "mx.symbol"), TRUE)
+  mx.symbol.create("_contrib_CausalConv1D", a[is.sym], a[!is.sym], name)
+}
+
 `mx.symbol._contrib_DeformableConvolution` <- function(..., name = "") {
   a <- list(...)
   is.sym <- vapply(a, function(v) inherits(v, "mx.symbol"), TRUE)
@@ -379,6 +391,12 @@
   a <- list(...)
   is.sym <- vapply(a, function(v) inherits(v, "mx.symbol"), TRUE)
   mx.symbol.create("_contrib_FlashAttention", a[is.sym], a[!is.sym], name)
+}
+
+`mx.symbol._contrib_GatedDeltaRule` <- function(..., name = "") {
+  a <- list(...)
+  is.sym <- vapply(a, function(v) inherits(v, "mx.symbol"), TRUE)
+  mx.symbol.create("_contrib_GatedDeltaRule", a[is.sym], a[!is.sym], name)
 }
 
 `mx.symbol._contrib_MultiBoxDetection` <- function(..., name = "") {
@@ -1065,6 +1083,12 @@
   mx.symbol.create("cast_storage", a[is.sym], a[!is.sym], name)
 }
 
+`mx.symbol.causal_conv1d` <- function(..., name = "") {
+  a <- list(...)
+  is.sym <- vapply(a, function(v) inherits(v, "mx.symbol"), TRUE)
+  mx.symbol.create("causal_conv1d", a[is.sym], a[!is.sym], name)
+}
+
 `mx.symbol.cbrt` <- function(..., name = "") {
   a <- list(...)
   is.sym <- vapply(a, function(v) inherits(v, "mx.symbol"), TRUE)
@@ -1225,6 +1249,12 @@
   a <- list(...)
   is.sym <- vapply(a, function(v) inherits(v, "mx.symbol"), TRUE)
   mx.symbol.create("gammaln", a[is.sym], a[!is.sym], name)
+}
+
+`mx.symbol.gated_delta_rule` <- function(..., name = "") {
+  a <- list(...)
+  is.sym <- vapply(a, function(v) inherits(v, "mx.symbol"), TRUE)
+  mx.symbol.create("gated_delta_rule", a[is.sym], a[!is.sym], name)
 }
 
 `mx.symbol.gather_nd` <- function(..., name = "") {

@@ -677,6 +677,21 @@ inline std::vector<NDArray> Proposal(const NDArray &cls_prob, const NDArray &bbo
   return op_.Invoke();
 }
 
+inline Symbol RMSNorm(const std::string &symbol_name, const Symbol &data, const Symbol &gamma, const std::map<std::string, std::string> &kwargs = {}) {
+  Operator op_("RMSNorm");
+  for (const auto &kv : kwargs) op_.SetParam(kv.first, kv.second);
+  op_.SetInput("data", data);
+  op_.SetInput("gamma", gamma);
+  return op_.CreateSymbol(symbol_name);
+}
+inline std::vector<NDArray> RMSNorm(const NDArray &data, const NDArray &gamma, const std::map<std::string, std::string> &kwargs = {}) {
+  Operator op_("RMSNorm");
+  for (const auto &kv : kwargs) op_.SetParam(kv.first, kv.second);
+  op_.AddInput(data);
+  op_.AddInput(gamma);
+  return op_.Invoke();
+}
+
 inline Symbol RNN(const std::string &symbol_name, const Symbol &data, const Symbol &parameters, const Symbol &state, int state_size, int num_layers, const std::string & mode, const std::map<std::string, std::string> &kwargs = {}) {
   Operator op_("RNN");
   op_.SetParam("state_size", state_size);
@@ -971,6 +986,23 @@ inline std::vector<NDArray> _contrib_CTCLoss(const NDArray &data, const NDArray 
   return op_.Invoke();
 }
 
+inline Symbol _contrib_CausalConv1D(const std::string &symbol_name, const Symbol &data, const Symbol &weight, int kernel, const std::map<std::string, std::string> &kwargs = {}) {
+  Operator op_("_contrib_CausalConv1D");
+  op_.SetParam("kernel", kernel);
+  for (const auto &kv : kwargs) op_.SetParam(kv.first, kv.second);
+  op_.SetInput("data", data);
+  op_.SetInput("weight", weight);
+  return op_.CreateSymbol(symbol_name);
+}
+inline std::vector<NDArray> _contrib_CausalConv1D(const NDArray &data, const NDArray &weight, int kernel, const std::map<std::string, std::string> &kwargs = {}) {
+  Operator op_("_contrib_CausalConv1D");
+  op_.SetParam("kernel", kernel);
+  for (const auto &kv : kwargs) op_.SetParam(kv.first, kv.second);
+  op_.AddInput(data);
+  op_.AddInput(weight);
+  return op_.Invoke();
+}
+
 inline Symbol _contrib_DeformableConvolution(const std::string &symbol_name, const Symbol &data, const Symbol &offset, const Symbol &weight, const Shape & kernel, int num_filter, const std::map<std::string, std::string> &kwargs = {}) {
   Operator op_("_contrib_DeformableConvolution");
   op_.SetParam("kernel", kernel);
@@ -1031,6 +1063,27 @@ inline std::vector<NDArray> _contrib_FlashAttention(const NDArray &query, const 
   op_.AddInput(query);
   op_.AddInput(key);
   op_.AddInput(value);
+  return op_.Invoke();
+}
+
+inline Symbol _contrib_GatedDeltaRule(const std::string &symbol_name, const Symbol &query, const Symbol &key, const Symbol &value, const Symbol &g, const Symbol &beta, const std::map<std::string, std::string> &kwargs = {}) {
+  Operator op_("_contrib_GatedDeltaRule");
+  for (const auto &kv : kwargs) op_.SetParam(kv.first, kv.second);
+  op_.SetInput("query", query);
+  op_.SetInput("key", key);
+  op_.SetInput("value", value);
+  op_.SetInput("g", g);
+  op_.SetInput("beta", beta);
+  return op_.CreateSymbol(symbol_name);
+}
+inline std::vector<NDArray> _contrib_GatedDeltaRule(const NDArray &query, const NDArray &key, const NDArray &value, const NDArray &g, const NDArray &beta, const std::map<std::string, std::string> &kwargs = {}) {
+  Operator op_("_contrib_GatedDeltaRule");
+  for (const auto &kv : kwargs) op_.SetParam(kv.first, kv.second);
+  op_.AddInput(query);
+  op_.AddInput(key);
+  op_.AddInput(value);
+  op_.AddInput(g);
+  op_.AddInput(beta);
   return op_.Invoke();
 }
 
@@ -2791,6 +2844,23 @@ inline std::vector<NDArray> cast_storage(const NDArray &data, const std::string 
   return op_.Invoke();
 }
 
+inline Symbol causal_conv1d(const std::string &symbol_name, const Symbol &data, const Symbol &weight, int kernel, const std::map<std::string, std::string> &kwargs = {}) {
+  Operator op_("causal_conv1d");
+  op_.SetParam("kernel", kernel);
+  for (const auto &kv : kwargs) op_.SetParam(kv.first, kv.second);
+  op_.SetInput("data", data);
+  op_.SetInput("weight", weight);
+  return op_.CreateSymbol(symbol_name);
+}
+inline std::vector<NDArray> causal_conv1d(const NDArray &data, const NDArray &weight, int kernel, const std::map<std::string, std::string> &kwargs = {}) {
+  Operator op_("causal_conv1d");
+  op_.SetParam("kernel", kernel);
+  for (const auto &kv : kwargs) op_.SetParam(kv.first, kv.second);
+  op_.AddInput(data);
+  op_.AddInput(weight);
+  return op_.Invoke();
+}
+
 inline Symbol cbrt(const std::string &symbol_name, const Symbol &data, const std::map<std::string, std::string> &kwargs = {}) {
   Operator op_("cbrt");
   for (const auto &kv : kwargs) op_.SetParam(kv.first, kv.second);
@@ -3177,6 +3247,27 @@ inline std::vector<NDArray> gammaln(const NDArray &data, const std::map<std::str
   Operator op_("gammaln");
   for (const auto &kv : kwargs) op_.SetParam(kv.first, kv.second);
   op_.AddInput(data);
+  return op_.Invoke();
+}
+
+inline Symbol gated_delta_rule(const std::string &symbol_name, const Symbol &query, const Symbol &key, const Symbol &value, const Symbol &g, const Symbol &beta, const std::map<std::string, std::string> &kwargs = {}) {
+  Operator op_("gated_delta_rule");
+  for (const auto &kv : kwargs) op_.SetParam(kv.first, kv.second);
+  op_.SetInput("query", query);
+  op_.SetInput("key", key);
+  op_.SetInput("value", value);
+  op_.SetInput("g", g);
+  op_.SetInput("beta", beta);
+  return op_.CreateSymbol(symbol_name);
+}
+inline std::vector<NDArray> gated_delta_rule(const NDArray &query, const NDArray &key, const NDArray &value, const NDArray &g, const NDArray &beta, const std::map<std::string, std::string> &kwargs = {}) {
+  Operator op_("gated_delta_rule");
+  for (const auto &kv : kwargs) op_.SetParam(kv.first, kv.second);
+  op_.AddInput(query);
+  op_.AddInput(key);
+  op_.AddInput(value);
+  op_.AddInput(g);
+  op_.AddInput(beta);
   return op_.Invoke();
 }
 

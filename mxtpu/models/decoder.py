@@ -1,0 +1,230 @@
+"""The decoder-only language-model family (symbol factory).
+
+One builder for every causal LM of the zoo: a stack of residual blocks, each
+a token mixer and a feed-forward sublayer, between an embedding and an
+output head. What differs between members is a few choices:
+
+- ``layer_types``: one entry a layer, ``"full_attention"`` (causal softmax
+  attention through ``_contrib_FlashAttention``) or ``"linear_attention"``
+  (the gated delta rule through ``_contrib_GatedDeltaRule``, behind causal
+  short convolutions, with a gated RMSNorm on its output);
+- ``norm``: ``"layer_pre"`` (LayerNorm before each sublayer, OPT's block)
+  or ``"rms_post"`` (RMSNorm on each sublayer's output before the residual
+  add, and on q and k of full attention: the Olmo 2/3 block);
+- ``ffn``: ``"relu"`` (two biased matrices) or ``"silu_gated"`` (three,
+  (silu(x W_gate) * x W_up) W_down, no bias);
+- ``positions``: ``"learned"`` (a ``pos_emb`` table) or ``"none"``.
+
+``mxtpu.models.transformer.get_symbol`` is the ``layer_pre`` / ``relu`` /
+``learned`` member and keeps its graph and parameter names;
+``get_symbol`` here builds the hybrid ``rms_post`` / ``silu_gated`` /
+``none`` member (Olmo-Hybrid: three linear-attention layers, then one of
+full attention).
+
+Parameter names of the hybrid member (shapes as FullyConnected keeps them,
+(out, in); H heads, d = ``d_model``, f = ``d_ff``):
+
+- ``tok_emb_weight`` (V, d), ``norm_f_gamma`` (d), ``lm_head_weight`` (V, d);
+- every layer ``l<i>_``: ``mix_norm_gamma``, ``ffn_norm_gamma`` (d);
+  ``ff_gate_weight``, ``ff_up_weight`` (f, d), ``ff_down_weight`` (d, f);
+- a full-attention layer: ``q_weight``, ``k_weight``, ``v_weight``,
+  ``proj_weight`` (d, d); ``q_norm_gamma``, ``k_norm_gamma`` (d);
+- a linear-attention layer: ``q_weight``, ``k_weight`` (H d_k, d),
+  ``v_weight``, ``g_weight`` (H d_v, d); ``q_conv_weight``,
+  ``k_conv_weight`` (H d_k, K), ``v_conv_weight`` (H d_v, K);
+  ``a_weight``, ``b_weight`` (H, d); ``A_log``, ``dt_bias`` (H,), float32
+  whatever ``dtype`` is; ``o_norm_gamma`` (d_v); ``proj_weight`` (d, H d_v).
+
+Layout discipline as transformer.py's: tokens (B, T) -> (B, T, D); both
+mixers in (B, H, T, dh); every matmul a FullyConnected(flatten=False).
+"""
+from .. import symbol as sym
+
+FULL, LINEAR = "full_attention", "linear_attention"
+
+
+def _fc(x, num_hidden, name, no_bias=False, flatten=False):
+    """FullyConnected along the last axis; `no_bias` is only written into
+    the graph where it is set, so the biased members' JSON stays as it was."""
+    kw = {"no_bias": True} if no_bias else {}
+    if not flatten:
+        kw["flatten"] = False
+    return sym.FullyConnected(x, num_hidden=num_hidden, name=name, **kw)
+
+
+def _heads(x, seq_len, num_heads, dh):
+    """(B, T, H dh) -> (B, H, T, dh)."""
+    x = sym.reshape(x, shape=(-1, seq_len, num_heads, dh))
+    return sym.transpose(x, axes=(0, 2, 1, 3))
+
+
+def attention_mix(x, seq_len, num_heads, d_model, prefix, no_bias=False,
+                  qk_norm_eps=None):
+    """Proj(Attn(x)): causal softmax attention over `num_heads` heads of
+    d_model / num_heads. With `qk_norm_eps`, q and k pass an RMSNorm over
+    all d_model channels first (Olmo 2/3 QK-norm)."""
+    dh = d_model // num_heads
+
+    def heads(tag):
+        p = _fc(x, d_model, "%s_%s" % (prefix, tag), no_bias)
+        if qk_norm_eps is not None and tag != "v":
+            p = sym.RMSNorm(p, eps=qk_norm_eps,
+                            name="%s_%s_norm" % (prefix, tag))
+        return _heads(p, seq_len, num_heads, dh)
+
+    q, k, v = heads("q"), heads("k"), heads("v")
+    att = sym.contrib.FlashAttention(q, k, v, causal=True,
+                                     name="%s_attn" % prefix)
+    att = sym.transpose(att, axes=(0, 2, 1, 3))
+    att = sym.reshape(att, shape=(-1, seq_len, d_model))
+    return _fc(att, d_model, "%s_proj" % prefix, no_bias)
+
+
+def delta_rule_mix(x, seq_len, num_heads, d_model, prefix, key_dim, value_dim,
+                   conv_kernel=4, neg_eigval=True, norm_eps=1e-6):
+    """The Gated DeltaNet mixer (arXiv:2412.06464): q, k, v through a causal
+    short convolution and SiLU, q and k L2-normalised per head, the gated
+    delta rule, a per-head RMSNorm gated by silu(x W_g), then W_o. The decay
+    (A_log, dt_bias, softplus, exp) is float32 whatever x is."""
+    h = num_heads
+
+    def short(tag, dh):
+        p = _fc(x, h * dh, "%s_%s" % (prefix, tag), True)
+        p = sym.contrib.CausalConv1D(p, kernel=conv_kernel,
+                                     name="%s_%s_conv" % (prefix, tag))
+        p = sym.Activation(p, act_type="silu")
+        p = sym.reshape(p, shape=(-1, seq_len, h, dh))
+        if tag != "v":
+            p = sym.L2Normalization(p, mode="last", eps=1e-6)
+            if tag == "q":
+                p = p * (key_dim ** -0.5)
+        return sym.transpose(p, axes=(0, 2, 1, 3))
+
+    q, k, v = short("q", key_dim), short("k", key_dim), short("v", value_dim)
+
+    def per_head(tag):
+        return _fc(x, h, "%s_%s" % (prefix, tag), True)
+
+    beta = sym.Activation(per_head("b"), act_type="sigmoid")
+    if neg_eigval:
+        beta = beta * 2.0
+    beta = sym.transpose(beta, axes=(0, 2, 1))
+    a_log = sym.Variable("%s_A_log" % prefix, shape=(h,), dtype="float32")
+    dt_bias = sym.Variable("%s_dt_bias" % prefix, shape=(h,), dtype="float32")
+    dt = sym.broadcast_add(sym.Cast(per_head("a"), dtype="float32"),
+                           sym.reshape(dt_bias, shape=(1, 1, h)))
+    g = sym.broadcast_mul(sym.Activation(dt, act_type="softrelu"),
+                          sym.reshape(sym.negative(sym.exp(a_log)),
+                                      shape=(1, 1, h)))
+    g = sym.transpose(g, axes=(0, 2, 1))
+    o = sym.contrib.GatedDeltaRule(q, k, v, g, beta, name="%s_delta" % prefix)
+    o = sym.transpose(o, axes=(0, 2, 1, 3))
+    gate = _fc(x, h * value_dim, "%s_g" % prefix, True)
+    gate = sym.reshape(gate, shape=(-1, seq_len, h, value_dim))
+    o = sym.RMSNorm(o, gate=gate, gated=True, eps=norm_eps,
+                    name="%s_o_norm" % prefix)
+    o = sym.reshape(o, shape=(-1, seq_len, h * value_dim))
+    return _fc(o, d_model, "%s_proj" % prefix, True)
+
+
+def relu_ffn(x, d_model, d_ff, prefix):
+    f = _fc(x, d_ff, "%s_ff1" % prefix)
+    f = sym.Activation(f, act_type="relu")
+    return _fc(f, d_model, "%s_ff2" % prefix)
+
+
+def silu_gated_ffn(x, d_model, d_ff, prefix):
+    gate = _fc(x, d_ff, "%s_ff_gate" % prefix, True)
+    up = _fc(x, d_ff, "%s_ff_up" % prefix, True)
+    f = sym.Activation(gate, act_type="silu") * up
+    return _fc(f, d_model, "%s_ff_down" % prefix, True)
+
+
+def _sublayer(h, fn, norm, name, eps, dropout):
+    """h + fn(LN(h)) (`layer_pre`) or h + RMSNorm(fn(h)) (`rms_post`)."""
+    if norm == "layer_pre":
+        y = fn(sym.LayerNorm(h, name=name))
+    else:
+        y = sym.RMSNorm(fn(h), eps=eps, name=name)
+    if dropout > 0:
+        y = sym.Dropout(y, p=dropout)
+    return h + y
+
+
+def build(vocab_size, seq_len, layer_types, num_heads, d_model, d_ff,
+          norm="rms_post", ffn="silu_gated", positions="none", dropout=0.0,
+          max_len=None, dtype=None, norm_eps=1e-6, linear=None):
+    """Causal LM of the family: data (B, T) int tokens -> SoftmaxOutput over
+    (B*T, vocab). `linear` holds the linear-attention layers' sizes
+    (``key_dim``, ``value_dim``, ``conv_kernel``, ``neg_eigval``, and
+    ``num_heads`` if it differs)."""
+    assert d_model % num_heads == 0, "d_model must divide into heads"
+    assert norm in ("layer_pre", "rms_post") and ffn in ("relu", "silu_gated")
+    pre = norm == "layer_pre"
+    data = sym.Variable("data")
+    h = sym.Embedding(data, input_dim=vocab_size, output_dim=d_model,
+                      name="tok_emb")
+    if dtype is not None:
+        h = sym.Cast(h, dtype=dtype)
+    if positions == "learned":
+        max_len = max_len or seq_len
+        assert max_len >= seq_len, "max_len must cover seq_len"
+        pos = sym.Variable("pos_emb", shape=(1, max_len, d_model))
+        if dtype is not None:
+            pos = sym.Cast(pos, dtype=dtype)
+        if max_len != seq_len:
+            pos = sym.slice_axis(pos, axis=1, begin=0, end=seq_len)
+        h = sym.broadcast_add(h, pos)
+    lin = dict(linear or {})
+    lin_heads = lin.pop("num_heads", num_heads)
+    for i, kind in enumerate(layer_types):
+        p = "l%d" % i
+        if kind == FULL:
+            def mix(x, p=p):
+                return attention_mix(
+                    x, seq_len, num_heads, d_model, p, no_bias=not pre,
+                    qk_norm_eps=None if pre else norm_eps)
+        elif kind == LINEAR:
+            def mix(x, p=p):
+                return delta_rule_mix(x, seq_len, lin_heads, d_model, p,
+                                      norm_eps=norm_eps, **lin)
+        else:
+            raise ValueError("layer %d: unknown layer type %r" % (i, kind))
+        h = _sublayer(h, mix, norm, p + ("_ln1" if pre else "_mix_norm"),
+                      norm_eps, dropout)
+        if ffn == "relu":
+            def feed(x, p=p):
+                return relu_ffn(x, d_model, d_ff, p)
+        else:
+            def feed(x, p=p):
+                return silu_gated_ffn(x, d_model, d_ff, p)
+        h = _sublayer(h, feed, norm, p + ("_ln2" if pre else "_ffn_norm"),
+                      norm_eps, dropout)
+    if pre:
+        h = sym.LayerNorm(h, name="ln_f")
+    else:
+        h = sym.RMSNorm(h, eps=norm_eps, name="norm_f")
+    h = sym.reshape(h, shape=(-1, d_model))
+    logits = _fc(h, vocab_size, "lm_head", no_bias=not pre, flatten=True)
+    if dtype is not None:
+        logits = sym.Cast(logits, dtype="float32")
+    return sym.SoftmaxOutput(logits, name="softmax")
+
+
+def get_symbol(vocab_size, seq_len, layer_types, num_heads, d_model, d_ff,
+               linear_key_dim, linear_value_dim, linear_num_heads=None,
+               linear_conv_kernel=4, linear_allow_neg_eigval=True,
+               norm_eps=1e-6, dtype=None):
+    """The hybrid member: RMSNorm after each sublayer, a SiLU-gated FFN, no
+    biases, no position table (position comes from the recurrent layers),
+    an untied head. Train with label = data shifted left by one, flattened
+    to (B*T,). `seq_len` must be a multiple of the delta rule's chunk (64)
+    when `layer_types` has a linear-attention layer."""
+    return build(vocab_size, seq_len, list(layer_types), num_heads, d_model,
+                 d_ff, norm="rms_post", ffn="silu_gated", positions="none",
+                 dtype=dtype, norm_eps=norm_eps,
+                 linear={"num_heads": linear_num_heads or num_heads,
+                         "key_dim": linear_key_dim,
+                         "value_dim": linear_value_dim,
+                         "conv_kernel": linear_conv_kernel,
+                         "neg_eigval": linear_allow_neg_eigval})

@@ -71,6 +71,7 @@ class Module(BaseModule):
 
         self._fused = None             # FusedTrainStep when armed
         self._last_step_fused = False
+        self._execs_parked = False
         self._monitor_installed = False
         self._monitor_adapter = None   # default-stat Monitor riding the
         # fused step's device tap kernels (obs/health.py) instead of
@@ -313,6 +314,7 @@ class Module(BaseModule):
             self._aux_params.update(aux)
             self._fused_host_stale_ = False
         else:
+            self._sync_fused_to_execs()   # a parked executor holds nothing
             self._exec_group.get_params(self._arg_params, self._aux_params)
         self._params_dirty = False
 
@@ -557,25 +559,52 @@ class Module(BaseModule):
             # adapter install deferred
             self._fused.arm_health(
                 taps=self._monitor_adapter.re_prog.pattern)
+        if not self._execs_parked:
+            self._park_execs()
         self._fused.step(data_batch.data, labels)
         self._last_step_fused = True
         self._fused_host_stale_ = True
         self._fused_exec_stale_ = True
         self._params_dirty = True
 
+    def _park_execs(self):
+        """While the fused step trains, the per-node executors are dormant:
+        their parameter arrays go stale with its first step and nobody
+        writes their gradient arrays; `_sync_fused_to_execs` re-fills them
+        the moment the classic path is driven. Until then they would hold a
+        second copy of every weight and a whole gradient beside the step's
+        own (3.9 GB at 928 M parameters in bfloat16, which kept the step
+        program from loading: PERF.md, PR 28). Hand those buffers back to
+        the device; a placeholder of the same shape and dtype stands in,
+        and any use of it before the re-fill raises."""
+        import jax as _jax
+        names = set(self._fused.params)
+        for exe in self._exec_group.execs:
+            for table in (exe.arg_dict, exe.grad_dict):
+                for name, arr in table.items():
+                    if name in names and arr is not None:
+                        arr._data = _jax.ShapeDtypeStruct(arr.shape, arr.dtype)
+        self._fused_exec_stale_ = True
+        self._execs_parked = True
+
     def _sync_fused_to_execs(self):
         if self._fused is None or not self._fused_exec_stale_:
             return
         import jax as _jax
+        import jax.numpy as _jnp
         for i, exe in enumerate(self._exec_group.execs):
             dev = self._context[i].jax_device
             for name, v in self._fused.params.items():
                 if name in exe.arg_dict:
                     exe.arg_dict[name]._data = _jax.device_put(v, dev)
+                g = exe.grad_dict.get(name) if self._execs_parked else None
+                if g is not None:
+                    g._data = _jnp.zeros(g.shape, g.dtype, device=dev)
             for name, v in self._fused.aux.items():
                 if name in exe.aux_dict:
                     exe.aux_dict[name]._data = _jax.device_put(v, dev)
         self._fused_exec_stale_ = False
+        self._execs_parked = False
 
     # ------------------------------------------------ compute
     def forward(self, data_batch, is_train=None):

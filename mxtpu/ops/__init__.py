@@ -12,4 +12,5 @@ from . import contrib  # noqa: F401
 from . import spatial  # noqa: F401
 from . import custom  # noqa: F401
 from . import attention  # noqa: F401
+from . import delta_rule  # noqa: F401
 from .registry import OpDef, get_op, list_ops, op_exists, register  # noqa: F401

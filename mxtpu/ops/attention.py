@@ -51,6 +51,11 @@ from .registry import register
 
 NEG_INF = -1e30
 
+# the kernels' names: a Mosaic call's HLO instruction is named after its
+# kernel, which is how a trace reader tells the forward from the backward
+FWD_KERNEL_NAME = "mxtpu_flash_fwd"
+BWD_KERNEL_NAME = "mxtpu_flash_bwd"
+
 # scoped VMEM asked for the backward kernel, which keeps a whole head
 # resident: under a third of a v5e core's 128 MiB
 _BWD_VMEM_BYTES = 40 << 20
@@ -189,7 +194,7 @@ def _flash_call(q, k, v, scale, causal, block_q, block_k, interpret,
             pltpu.VMEM((block_q, 128), jnp.float32),
             pltpu.VMEM((block_q, 128), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=interpret, name=FWD_KERNEL_NAME,
     )(q, k, v)
     if not with_lse:
         return out[0]
@@ -326,7 +331,7 @@ def _bwd_call(q, k, v, do, lse, delta, scale, causal, block_q, block_k,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",),
             vmem_limit_bytes=_BWD_VMEM_BYTES),
-        interpret=interpret,
+        interpret=interpret, name=BWD_KERNEL_NAME,
     )(q, k, v, do, lse.reshape(rows), delta.reshape(rows))
 
 

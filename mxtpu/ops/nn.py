@@ -90,6 +90,27 @@ register("Convolution", _convolution,
          aliases=("Convolution_v1",))
 
 
+def _causal_conv1d(a, data, weight):
+    """Causal depthwise convolution over time, channels last: data
+    (B, T, C), weight (C, K), y[t] = sum_i weight[:, i] * x[t - (K-1) + i],
+    nothing before the row's start. The short convolution of the
+    linear-attention mixers (K = 4): K shifted multiply-adds that XLA fuses
+    into one pass, where a grouped Convolution with one channel a group
+    would go through the convolution emitter. Accumulates in float32."""
+    k = int(a.kernel)
+    t = data.shape[1]
+    x = jnp.pad(data, ((0, 0), (k - 1, 0), (0, 0)))
+    w = weight.astype(jnp.float32)
+    out = sum(x[:, i:i + t, :].astype(jnp.float32) * w[:, i]
+              for i in range(k))
+    return out.astype(data.dtype)
+
+
+register("_contrib_CausalConv1D", _causal_conv1d,
+         arg_names=["data", "weight"], attrs={"kernel": Required(int)},
+         aliases=("causal_conv1d",))
+
+
 def _deconvolution(a, data, weight, bias=None):
     """Transposed convolution as the explicit gradient-of-conv form:
     lhs_dilation=stride + spatially-flipped weight. Weight layout is the
@@ -276,6 +297,28 @@ register("LayerNorm", _layer_norm,
          attrs={"eps": 1e-5, "axis": -1, "output_mean_var": False},
          num_outputs=lambda a: 3 if a.output_mean_var else 1)
 
+
+def _rms_norm(a, data, gamma, gate=None):
+    """gamma * x / sqrt(mean(x^2) + eps) over one axis (Zhang and Sennrich,
+    arXiv:1910.07467), no mean and no shift. The reduction and the scaling
+    run in float32 whatever the input's dtype; one fused pass, as
+    _layer_norm. With ``gated`` a third input scales the result by
+    silu(gate), the output gate of the linear-attention mixers."""
+    ax = int(a.axis) % data.ndim
+    x = data.astype(jnp.float32)
+    ms = jnp.mean(jnp.square(x), axis=ax, keepdims=True)
+    bshape = tuple(data.shape[ax] if i == ax else 1 for i in range(data.ndim))
+    out = x * lax.rsqrt(ms + a.eps) * gamma.astype(jnp.float32).reshape(bshape)
+    if gate is not None:
+        out = out * jax.nn.silu(gate.astype(jnp.float32))
+    return out.astype(data.dtype)
+
+
+register("RMSNorm", _rms_norm,
+         arg_names=lambda a: ["data", "gamma", "gate"] if a.get("gated") else
+         ["data", "gamma"],
+         attrs={"eps": 1e-6, "axis": -1, "gated": False})
+
 # ---------------------------------------------------------------- activations
 
 
@@ -289,6 +332,8 @@ def _activation(a, x):
         return jnp.tanh(x)
     if t == "softrelu":
         return jax.nn.softplus(x)
+    if t == "silu":
+        return jax.nn.silu(x)
     raise ValueError("unknown act_type %s" % t)
 
 
@@ -525,6 +570,11 @@ register("InstanceNorm", _instance_norm, arg_names=["data", "gamma", "beta"],
 
 
 def _l2_normalization(a, x):
+    if a.mode == "last":
+        # each vector along the last axis (a head's query or key), float32
+        x32 = x.astype(jnp.float32)
+        ss = jnp.sum(jnp.square(x32), axis=-1, keepdims=True)
+        return (x32 * lax.rsqrt(ss + a.eps)).astype(x.dtype)
     if a.mode == "channel":
         norm = jnp.sqrt(jnp.sum(jnp.square(x), axis=1, keepdims=True) + a.eps)
     elif a.mode == "spatial":
@@ -740,6 +790,24 @@ def _ln_infer(a, shapes):
 
 
 _get_op("LayerNorm").infer_args = _ln_infer
+
+
+def _rms_infer(a, shapes):
+    data = shapes[0]
+    out = [data, (data[int(a.get("axis", -1)) % len(data)],)]
+    if a.get("gated"):
+        out.append(shapes[2] if shapes[2] is not None else data)
+    return out
+
+
+_get_op("RMSNorm").infer_args = _rms_infer
+
+
+def _causal_conv_infer(a, shapes):
+    return [shapes[0], (shapes[0][-1], int(a.kernel))]
+
+
+_get_op("_contrib_CausalConv1D").infer_args = _causal_conv_infer
 
 
 def _in_infer(a, shapes):

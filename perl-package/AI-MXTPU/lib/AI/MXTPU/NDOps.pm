@@ -1,5 +1,5 @@
 # GENERATED by perl-package/gen_perl_ops.py — do not edit.
-# One sub per registered op (290 ops). Regenerate after registry changes.
+# One sub per registered op (295 ops). Regenerate after registry changes.
 package AI::MXTPU::NDOps;
 use strict;
 use warnings;
@@ -246,6 +246,12 @@ sub Proposal {
     return AI::MXTPU::invoke('Proposal', \@ins, %a);
 }
 
+sub RMSNorm {
+    my @ins = splice(@_, 0, 2);
+    my %a = @_;
+    return AI::MXTPU::invoke('RMSNorm', \@ins, %a);
+}
+
 sub RNN {
     my @ins = splice(@_, 0, 3);
     my %a = @_;
@@ -358,6 +364,12 @@ sub _contrib_CTCLoss {
     return AI::MXTPU::invoke('_contrib_CTCLoss', \@ins, %a);
 }
 
+sub _contrib_CausalConv1D {
+    my @ins = splice(@_, 0, 2);
+    my %a = @_;
+    return AI::MXTPU::invoke('_contrib_CausalConv1D', \@ins, %a);
+}
+
 sub _contrib_DeformableConvolution {
     my @ins = splice(@_, 0, 3);
     my %a = @_;
@@ -374,6 +386,12 @@ sub _contrib_FlashAttention {
     my @ins = splice(@_, 0, 3);
     my %a = @_;
     return AI::MXTPU::invoke('_contrib_FlashAttention', \@ins, %a);
+}
+
+sub _contrib_GatedDeltaRule {
+    my @ins = splice(@_, 0, 5);
+    my %a = @_;
+    return AI::MXTPU::invoke('_contrib_GatedDeltaRule', \@ins, %a);
 }
 
 sub _contrib_MultiBoxDetection {
@@ -1056,6 +1074,12 @@ sub cast_storage {
     return AI::MXTPU::invoke('cast_storage', \@ins, %a);
 }
 
+sub causal_conv1d {
+    my @ins = splice(@_, 0, 2);
+    my %a = @_;
+    return AI::MXTPU::invoke('causal_conv1d', \@ins, %a);
+}
+
 sub cbrt {
     my @ins = splice(@_, 0, 1);
     my %a = @_;
@@ -1215,6 +1239,12 @@ sub gammaln {
     my @ins = splice(@_, 0, 1);
     my %a = @_;
     return AI::MXTPU::invoke('gammaln', \@ins, %a);
+}
+
+sub gated_delta_rule {
+    my @ins = splice(@_, 0, 5);
+    my %a = @_;
+    return AI::MXTPU::invoke('gated_delta_rule', \@ins, %a);
 }
 
 sub gather_nd {

@@ -1,5 +1,5 @@
 # GENERATED by perl-package/gen_perl_ops.py — do not edit.
-# One sub per registered op (290 ops). Regenerate after registry changes.
+# One sub per registered op (295 ops). Regenerate after registry changes.
 package AI::MXTPU::Ops;
 use strict;
 use warnings;
@@ -330,6 +330,14 @@ sub Proposal {
     return AI::MXTPU::Symbol->create('Proposal', \%in, %a);
 }
 
+sub RMSNorm {
+    my %a = @_;
+    my %in;
+    $in{'data'} = delete $a{'data'} if exists $a{'data'};
+    $in{'gamma'} = delete $a{'gamma'} if exists $a{'gamma'};
+    return AI::MXTPU::Symbol->create('RMSNorm', \%in, %a);
+}
+
 sub RNN {
     my %a = @_;
     my %in;
@@ -470,6 +478,14 @@ sub _contrib_CTCLoss {
     return AI::MXTPU::Symbol->create('_contrib_CTCLoss', \%in, %a);
 }
 
+sub _contrib_CausalConv1D {
+    my %a = @_;
+    my %in;
+    $in{'data'} = delete $a{'data'} if exists $a{'data'};
+    $in{'weight'} = delete $a{'weight'} if exists $a{'weight'};
+    return AI::MXTPU::Symbol->create('_contrib_CausalConv1D', \%in, %a);
+}
+
 sub _contrib_DeformableConvolution {
     my %a = @_;
     my %in;
@@ -495,6 +511,17 @@ sub _contrib_FlashAttention {
     $in{'key'} = delete $a{'key'} if exists $a{'key'};
     $in{'value'} = delete $a{'value'} if exists $a{'value'};
     return AI::MXTPU::Symbol->create('_contrib_FlashAttention', \%in, %a);
+}
+
+sub _contrib_GatedDeltaRule {
+    my %a = @_;
+    my %in;
+    $in{'query'} = delete $a{'query'} if exists $a{'query'};
+    $in{'key'} = delete $a{'key'} if exists $a{'key'};
+    $in{'value'} = delete $a{'value'} if exists $a{'value'};
+    $in{'g'} = delete $a{'g'} if exists $a{'g'};
+    $in{'beta'} = delete $a{'beta'} if exists $a{'beta'};
+    return AI::MXTPU::Symbol->create('_contrib_GatedDeltaRule', \%in, %a);
 }
 
 sub _contrib_MultiBoxDetection {
@@ -1348,6 +1375,14 @@ sub cast_storage {
     return AI::MXTPU::Symbol->create('cast_storage', \%in, %a);
 }
 
+sub causal_conv1d {
+    my %a = @_;
+    my %in;
+    $in{'data'} = delete $a{'data'} if exists $a{'data'};
+    $in{'weight'} = delete $a{'weight'} if exists $a{'weight'};
+    return AI::MXTPU::Symbol->create('causal_conv1d', \%in, %a);
+}
+
 sub cbrt {
     my %a = @_;
     my %in;
@@ -1546,6 +1581,17 @@ sub gammaln {
     my %in;
     $in{'data'} = delete $a{'data'} if exists $a{'data'};
     return AI::MXTPU::Symbol->create('gammaln', \%in, %a);
+}
+
+sub gated_delta_rule {
+    my %a = @_;
+    my %in;
+    $in{'query'} = delete $a{'query'} if exists $a{'query'};
+    $in{'key'} = delete $a{'key'} if exists $a{'key'};
+    $in{'value'} = delete $a{'value'} if exists $a{'value'};
+    $in{'g'} = delete $a{'g'} if exists $a{'g'};
+    $in{'beta'} = delete $a{'beta'} if exists $a{'beta'};
+    return AI::MXTPU::Symbol->create('gated_delta_rule', \%in, %a);
 }
 
 sub gather_nd {

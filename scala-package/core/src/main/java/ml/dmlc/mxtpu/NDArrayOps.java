@@ -1,7 +1,7 @@
 package ml.dmlc.mxtpu;
 
 // GENERATED by scala-package/gen_jvm_ops.py - do not edit.
-// One static method per registered op (290 ops). Regenerate after
+// One static method per registered op (295 ops). Regenerate after
 // registry changes. Parity: the reference scala-package's generated op
 // surface (scala-package/macros SymbolMacro/NDArrayMacro over
 // MXListAllOpNames).
@@ -272,6 +272,12 @@ public final class NDArrayOps {
     return invoke("Proposal", attrs, inputs);
   }
 
+  /** RMSNorm(data, gamma) */
+  public static NDArray[] RMSNorm(
+      java.util.Map<String, String> attrs, NDArray... inputs) {
+    return invoke("RMSNorm", attrs, inputs);
+  }
+
   /** RNN(data, parameters, state) */
   public static NDArray[] RNN(
       java.util.Map<String, String> attrs, NDArray... inputs) {
@@ -386,6 +392,12 @@ public final class NDArrayOps {
     return invoke("_contrib_CTCLoss", attrs, inputs);
   }
 
+  /** _contrib_CausalConv1D(data, weight) */
+  public static NDArray[] _contrib_CausalConv1D(
+      java.util.Map<String, String> attrs, NDArray... inputs) {
+    return invoke("_contrib_CausalConv1D", attrs, inputs);
+  }
+
   /** _contrib_DeformableConvolution(data, offset, weight) */
   public static NDArray[] _contrib_DeformableConvolution(
       java.util.Map<String, String> attrs, NDArray... inputs) {
@@ -402,6 +414,12 @@ public final class NDArrayOps {
   public static NDArray[] _contrib_FlashAttention(
       java.util.Map<String, String> attrs, NDArray... inputs) {
     return invoke("_contrib_FlashAttention", attrs, inputs);
+  }
+
+  /** _contrib_GatedDeltaRule(query, key, value, g, beta) */
+  public static NDArray[] _contrib_GatedDeltaRule(
+      java.util.Map<String, String> attrs, NDArray... inputs) {
+    return invoke("_contrib_GatedDeltaRule", attrs, inputs);
   }
 
   /** _contrib_MultiBoxDetection(cls_prob, loc_pred, anchor) */
@@ -1088,6 +1106,12 @@ public final class NDArrayOps {
     return invoke("cast_storage", attrs, inputs);
   }
 
+  /** causal_conv1d(data, weight) */
+  public static NDArray[] causal_conv1d(
+      java.util.Map<String, String> attrs, NDArray... inputs) {
+    return invoke("causal_conv1d", attrs, inputs);
+  }
+
   /** cbrt(data) */
   public static NDArray[] cbrt(
       java.util.Map<String, String> attrs, NDArray... inputs) {
@@ -1248,6 +1272,12 @@ public final class NDArrayOps {
   public static NDArray[] gammaln(
       java.util.Map<String, String> attrs, NDArray... inputs) {
     return invoke("gammaln", attrs, inputs);
+  }
+
+  /** gated_delta_rule(query, key, value, g, beta) */
+  public static NDArray[] gated_delta_rule(
+      java.util.Map<String, String> attrs, NDArray... inputs) {
+    return invoke("gated_delta_rule", attrs, inputs);
   }
 
   /** gather_nd(data, indices) */

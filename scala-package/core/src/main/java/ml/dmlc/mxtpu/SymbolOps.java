@@ -1,7 +1,7 @@
 package ml.dmlc.mxtpu;
 
 // GENERATED by scala-package/gen_jvm_ops.py - do not edit.
-// One static method per registered op (290 ops). Regenerate after
+// One static method per registered op (295 ops). Regenerate after
 // registry changes. Parity: the reference scala-package's generated op
 // surface (scala-package/macros SymbolMacro/NDArrayMacro over
 // MXListAllOpNames).
@@ -255,6 +255,12 @@ public final class SymbolOps {
     return Symbol.create("Proposal", name, attrs, new String[] {"cls_prob", "bbox_pred", "im_info"}, inputs);
   }
 
+  /** RMSNorm(data, gamma) */
+  public static Symbol RMSNorm(String name,
+      java.util.Map<String, String> attrs, Symbol... inputs) {
+    return Symbol.create("RMSNorm", name, attrs, new String[] {"data", "gamma"}, inputs);
+  }
+
   /** RNN(data, parameters, state) */
   public static Symbol RNN(String name,
       java.util.Map<String, String> attrs, Symbol... inputs) {
@@ -369,6 +375,12 @@ public final class SymbolOps {
     return Symbol.create("_contrib_CTCLoss", name, attrs, new String[] {"data", "label"}, inputs);
   }
 
+  /** _contrib_CausalConv1D(data, weight) */
+  public static Symbol _contrib_CausalConv1D(String name,
+      java.util.Map<String, String> attrs, Symbol... inputs) {
+    return Symbol.create("_contrib_CausalConv1D", name, attrs, new String[] {"data", "weight"}, inputs);
+  }
+
   /** _contrib_DeformableConvolution(data, offset, weight) */
   public static Symbol _contrib_DeformableConvolution(String name,
       java.util.Map<String, String> attrs, Symbol... inputs) {
@@ -385,6 +397,12 @@ public final class SymbolOps {
   public static Symbol _contrib_FlashAttention(String name,
       java.util.Map<String, String> attrs, Symbol... inputs) {
     return Symbol.create("_contrib_FlashAttention", name, attrs, new String[] {"query", "key", "value"}, inputs);
+  }
+
+  /** _contrib_GatedDeltaRule(query, key, value, g, beta) */
+  public static Symbol _contrib_GatedDeltaRule(String name,
+      java.util.Map<String, String> attrs, Symbol... inputs) {
+    return Symbol.create("_contrib_GatedDeltaRule", name, attrs, new String[] {"query", "key", "value", "g", "beta"}, inputs);
   }
 
   /** _contrib_MultiBoxDetection(cls_prob, loc_pred, anchor) */
@@ -1071,6 +1089,12 @@ public final class SymbolOps {
     return Symbol.create("cast_storage", name, attrs, new String[] {"data"}, inputs);
   }
 
+  /** causal_conv1d(data, weight) */
+  public static Symbol causal_conv1d(String name,
+      java.util.Map<String, String> attrs, Symbol... inputs) {
+    return Symbol.create("causal_conv1d", name, attrs, new String[] {"data", "weight"}, inputs);
+  }
+
   /** cbrt(data) */
   public static Symbol cbrt(String name,
       java.util.Map<String, String> attrs, Symbol... inputs) {
@@ -1231,6 +1255,12 @@ public final class SymbolOps {
   public static Symbol gammaln(String name,
       java.util.Map<String, String> attrs, Symbol... inputs) {
     return Symbol.create("gammaln", name, attrs, new String[] {"data"}, inputs);
+  }
+
+  /** gated_delta_rule(query, key, value, g, beta) */
+  public static Symbol gated_delta_rule(String name,
+      java.util.Map<String, String> attrs, Symbol... inputs) {
+    return Symbol.create("gated_delta_rule", name, attrs, new String[] {"query", "key", "value", "g", "beta"}, inputs);
   }
 
   /** gather_nd(data, indices) */

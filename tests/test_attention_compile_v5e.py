@@ -56,3 +56,53 @@ def test_attention_gradient_keeps_no_scores_at_the_lm_cells_size(one_chip,
     assert text.count("tpu_custom_call") >= 2
     assert "[128,1024,1024]" not in text
     assert compiled.memory_analysis().temp_size_in_bytes < 0.35e9
+
+
+def test_attention_gradient_compiles_at_the_hybrid_cells_size(one_chip,
+                                                              no_cache):
+    """olmo-hybrid-7b-fit-s2048's full-attention layer: batch 4, 30 heads of
+    128, 2048 tokens, bf16: both kernels, by their names, and no scores."""
+    import jax
+    import jax.numpy as jnp
+    from mxtpu.ops import attention
+    q = jax.ShapeDtypeStruct((4, 30, 2048, 128), jnp.bfloat16,
+                             sharding=one_chip)
+
+    def loss(q, k, v):
+        return jnp.sum(attention.flash_attention(q, k, v, causal=True)
+                       .astype(jnp.float32))
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        q, q, q).compile().as_text()
+    assert "%" + attention.FWD_KERNEL_NAME in text
+    assert "%" + attention.BWD_KERNEL_NAME in text
+    assert "[120,2048,2048]" not in text
+
+
+def test_delta_rule_gradient_compiles_at_the_hybrid_cells_size(one_chip,
+                                                               no_cache):
+    """olmo-hybrid-7b-fit-s2048's linear-attention layers: batch 4, 30 heads,
+    d_k 96, d_v 192, 2048 tokens, bf16. Two Mosaic calls found by name, the
+    chunk-boundary states the only residual beyond the inputs, and no
+    per-token state (2048 states of 96 x 192 a head) anywhere."""
+    import jax
+    import jax.numpy as jnp
+    from mxtpu.ops import delta_rule
+
+    def s(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    args = (s((4, 30, 2048, 96)), s((4, 30, 2048, 96)), s((4, 30, 2048, 192)),
+            s((4, 30, 2048), jnp.float32), s((4, 30, 2048)))
+
+    def loss(*a):
+        return jnp.sum(delta_rule.gated_delta_rule(*a).astype(jnp.float32))
+
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4))).lower(
+        *args).compile()
+    text = compiled.as_text()
+    assert "%" + delta_rule.FWD_KERNEL_NAME in text
+    assert "%" + delta_rule.BWD_KERNEL_NAME in text
+    assert "f32[120,32,96,192]" in text          # one state a chunk
+    assert "2048,96,192]" not in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 1.2e9

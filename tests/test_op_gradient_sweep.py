@@ -204,6 +204,15 @@ SPECS = {
     "_contrib_FlashAttention": dict(
         primary={"query": (1, 4, 2, 4), "key": (1, 4, 2, 4),
                  "value": (1, 4, 2, 4)}, tol=dict(rtol=3e-2, atol=3e-3)),
+    "_contrib_GatedDeltaRule": dict(
+        primary={"query": (1, 2, 8, 2), "key": (1, 2, 8, 2),
+                 "value": (1, 2, 8, 3), "g": (1, 2, 8), "beta": (1, 2, 8)},
+        attrs={"chunk": 4}, tol=dict(rtol=3e-2, atol=3e-3)),
+    "_contrib_CausalConv1D": dict(primary={"data": (2, 5, 3)},
+                                  attrs={"kernel": 3}),
+    "RMSNorm": dict(primary={"data": S}),
+    "RMSNorm_gated": dict(op="RMSNorm", primary={"data": S},
+                          attrs={"gated": True}),
     "_slice_assign": dict(primary={"lhs": S, "rhs": (2, 2)},
                           attrs={"begin": (0, 0), "end": (2, 2)}),
     "_slice_assign_scalar": dict(primary={"data": S},
