@@ -27,12 +27,19 @@ whatever the operands' dtype, and every exponent taken is <= 0. Matrix
 operands are cast to the dtype of q for the MXU, as the flash kernels do.
 
 Forward: grid (batch*heads, T / (C * chunks a step)); the state is carried
-in VMEM scratch along the second axis. Under differentiation each chunk's
-first state is also written out, float32: (B*H, T/C, d_k, d_v) numbers, the
-only residual besides the inputs. Backward (``jax.custom_vjp``): the same
-grid walked from the last chunk to the first with dS carried in scratch;
-each chunk is recomputed from its saved first state and differentiated by
-hand (`_chunk_bwd`). No per-token state reaches HBM in either direction.
+in VMEM scratch along the second axis. Under differentiation two residuals
+are written out besides the inputs: each chunk's first state, float32,
+(B*H, T/C, d_k, d_v) numbers, and each chunk's T = (I + A)^{-1} in the dtype
+of q, C x C numbers a chunk, those of a grid step side by side (whole
+128-lane tiles in HBM). Backward (``jax.custom_vjp``): the same grid walked
+from the last chunk to the first with dS carried in scratch; each chunk is
+recomputed from its saved first state and its saved T and differentiated by
+hand (`_chunk_bwd`). Handing T over is exact: T depends on k, g and beta
+alone, the backward differentiates through U = T X by dX = T^T dU and
+dA = -dX U^T and never through the inverse's own products, and it reads T
+only in the dtype the forward had already cast it to for its own product.
+So `_inverse`, four fifths of a forward chunk's instructions, runs once a
+chunk a step, not twice. No per-token state reaches HBM in either direction.
 
 Which program runs follows the platform the program is lowered for, as in
 ops/attention.py: the Mosaic kernels on ``tpu``; on ``cpu`` the same chunk
@@ -118,9 +125,9 @@ def _inverse(a):
 
 
 def _chunk_prep(q, k, g_row, b_row):
-    """What a chunk needs that does not depend on its first state: the
-    decays, (I + A)^{-1} and the masked, decayed Q K^T. Rows (1, C) in,
-    columns (C, 1) out."""
+    """What a chunk needs that depends neither on its first state nor on
+    (I + A)^{-1}: the decays, A and the masked, decayed Q K^T. Rows (1, C)
+    in, columns (C, 1) out."""
     c = k.shape[0]
     incl, strict, eye = _masks(c)
     g_row = g_row.astype(_F32)
@@ -137,23 +144,22 @@ def _chunk_prep(q, k, g_row, b_row):
                        0.0)
     kk = _dot(k, k, _ABT)
     msp = jnp.where(strict, m_full * kk, 0.0)
-    a = b_col * msp
-    t_inv = _inverse(a)
     att = m_full * _dot(q, k, _ABT)
     return {"b": b_col, "eg": jnp.exp(gam_col), "ed": jnp.exp(total - gam_col),
-            "a_end": jnp.exp(total), "m": m_full, "msp": msp, "A": a,
-            "T": t_inv, "att": att}
+            "a_end": jnp.exp(total), "m": m_full, "msp": msp,
+            "A": b_col * msp, "att": att}
 
 
-def _chunk_state(p, s, q, k, v):
-    """The part that needs the chunk's first state `s` (float32): U, the
-    chunk's output and its last state."""
+def _chunk_state(p, t_inv, s, q, k, v):
+    """The part that needs the chunk's first state `s` (float32) and
+    `t_inv`, (I + A)^{-1} in the dtype of q: U, the chunk's output and its
+    last state."""
     cd = q.dtype
     s_c = s.astype(cd)
     ks = _dot(k, s_c)
     y = v.astype(_F32) - p["eg"] * ks
     x = p["b"] * y
-    u = _dot(p["T"].astype(cd), x.astype(cd))
+    u = _dot(t_inv, x.astype(cd))
     qs = _dot(q, s_c)
     o = p["eg"] * qs + _dot(p["att"].astype(cd), u.astype(cd))
     edk = (p["ed"] * k.astype(_F32)).astype(cd)
@@ -162,20 +168,23 @@ def _chunk_state(p, s, q, k, v):
 
 
 def _chunk_fwd(s, q, k, v, g_row, b_row):
+    """(o, the chunk's last state, (I + A)^{-1} as the products use it)."""
     p = _chunk_prep(q, k, g_row, b_row)
-    o, s_next, _ = _chunk_state(p, s, q, k, v)
-    return o, s_next
+    t_inv = _inverse(p["A"]).astype(q.dtype)
+    o, s_next, _ = _chunk_state(p, t_inv, s, q, k, v)
+    return o, s_next, t_inv
 
 
-def _chunk_bwd(s, q, k, v, g_row, b_row, do, ds_next):
-    """Gradients of one chunk, recomputed from its first state `s`:
-    (dq, dk, dv, dg (1, C), dbeta (1, C), ds). `do` is the gradient of the
-    chunk's output, `ds_next` of its last state (float32)."""
+def _chunk_bwd(s, t_inv, q, k, v, g_row, b_row, do, ds_next):
+    """Gradients of one chunk, recomputed from its first state `s` and the
+    forward's `t_inv`: (dq, dk, dv, dg (1, C), dbeta (1, C), ds). `do` is
+    the gradient of the chunk's output, `ds_next` of its last state
+    (float32)."""
     cd = q.dtype
     c = k.shape[0]
     incl, strict, eye = _masks(c)
     p = _chunk_prep(q, k, g_row, b_row)
-    _, _, r = _chunk_state(p, s, q, k, v)
+    _, _, r = _chunk_state(p, t_inv, s, q, k, v)
     s_c = s.astype(cd)
     u_c = r["u"].astype(cd)
     do32 = do.astype(_F32)
@@ -195,7 +204,7 @@ def _chunk_bwd(s, q, k, v, g_row, b_row, do, ds_next):
     ded = jnp.sum(dedk * kf, axis=1, keepdims=True)
 
     # U = T X with T = (I + A)^{-1}: dX = T^T dU, dA = -dX U^T
-    dx = _dot(p["T"].astype(cd), du.astype(cd), _ATB)
+    dx = _dot(t_inv, du.astype(cd), _ATB)
     da = jnp.where(strict, -_dot(dx.astype(cd), u_c, _ABT), 0.0)
 
     # X = beta * Y, Y = V - eg * (K S)
@@ -235,9 +244,10 @@ def _chunk_bwd(s, q, k, v, g_row, b_row, do, ds_next):
 # ------------------------------------------------------------------ kernels
 def _fwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, *refs, chunk, per_step):
     """`per_step` chunks of one head: outputs o and, under differentiation,
-    each chunk's first state; the state is carried in the scratch."""
-    o_ref, s_out = refs[0], (refs[1] if len(refs) == 3 else None)
-    s_ref = refs[-1]
+    each chunk's first state and its (I + A)^{-1}; the state is carried in
+    the scratch."""
+    o_ref, s_ref = refs[0], refs[-1]
+    s_out, t_out = refs[1:-1] or (None, None)
 
     @pl.when(pl.program_id(1) == 0)
     def _init():
@@ -248,17 +258,19 @@ def _fwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, *refs, chunk, per_step):
     rows = [pl.ds(i * chunk, chunk) for i in range(per_step)]
     preps = [_chunk_prep(q_ref[0, r, :], k_ref[0, r, :], g_ref[0, i],
                          b_ref[0, i]) for i, r in enumerate(rows)]
+    t_invs = [_inverse(p["A"]).astype(q_ref.dtype) for p in preps]
     s = s_ref[...]
     for i, r in enumerate(rows):
         if s_out is not None:
             s_out[0, i] = s
-        o, s, _ = _chunk_state(preps[i], s, q_ref[0, r, :], k_ref[0, r, :],
-                               v_ref[0, r, :])
+            t_out[0, 0, :, r] = t_invs[i]    # chunk i's C lanes of the step's
+        o, s, _ = _chunk_state(preps[i], t_invs[i], s, q_ref[0, r, :],
+                               k_ref[0, r, :], v_ref[0, r, :])
         o_ref[0, r, :] = o.astype(o_ref.dtype)
     s_ref[...] = s
 
 
-def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, s_in, do_ref,
+def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, s_in, t_in, do_ref,
                 dq_ref, dk_ref, dv_ref, dg_ref, db_ref, ds_ref, *, chunk,
                 per_step):
     """The same chunks, last to first, with dS carried in the scratch."""
@@ -270,8 +282,8 @@ def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, s_in, do_ref,
     for i in reversed(range(per_step)):
         r = pl.ds(i * chunk, chunk)
         dq, dk, dv, dg, db, ds = _chunk_bwd(
-            s_in[0, i], q_ref[0, r, :], k_ref[0, r, :], v_ref[0, r, :],
-            g_ref[0, i], b_ref[0, i], do_ref[0, r, :], ds)
+            s_in[0, i], t_in[0, 0, :, r], q_ref[0, r, :], k_ref[0, r, :],
+            v_ref[0, r, :], g_ref[0, i], b_ref[0, i], do_ref[0, r, :], ds)
         dq_ref[0, r, :] = dq.astype(dq_ref.dtype)
         dk_ref[0, r, :] = dk.astype(dk_ref.dtype)
         dv_ref[0, r, :] = dv.astype(dv_ref.dtype)
@@ -288,7 +300,8 @@ def _per_step(n_chunks):
 
 
 def _specs(bh, t, dk, dv, chunk, per_step, reverse):
-    """Block specs of (q or k, v, a row of g or beta, the states)."""
+    """Block specs of (q or k, v, a row of g or beta, the states, the
+    inverses)."""
     steps = t // (chunk * per_step)
     at = (lambda n: steps - 1 - n) if reverse else (lambda n: n)
     qk = pl.BlockSpec((1, chunk * per_step, dk), lambda b, n: (b, at(n), 0))
@@ -297,7 +310,11 @@ def _specs(bh, t, dk, dv, chunk, per_step, reverse):
     # array's own, so any C is a legal block
     row = pl.BlockSpec((1, per_step, 1, chunk), lambda b, n: (b, at(n), 0, 0))
     st = pl.BlockSpec((1, per_step, dk, dv), lambda b, n: (b, at(n), 0, 0))
-    return steps, qk, vv, row, st
+    # a grid step's inverses side by side, (C, per_step * C): whole
+    # 128-lane tiles in HBM where a (C, C) array of its own is padded to them
+    inv = pl.BlockSpec((1, 1, chunk, chunk * per_step),
+                       lambda b, n: (b, at(n), 0, 0))
+    return steps, qk, vv, row, st, inv
 
 
 def _fwd_call(q, k, v, g, beta, chunk, with_states, interpret):
@@ -305,12 +322,15 @@ def _fwd_call(q, k, v, g, beta, chunk, with_states, interpret):
     dv = v.shape[2]
     n = t // chunk
     per_step = _per_step(n)
-    steps, qk, vv, row, st = _specs(bh, t, dk, dv, chunk, per_step, False)
+    steps, qk, vv, row, st, inv = _specs(bh, t, dk, dv, chunk, per_step,
+                                         False)
     out_shape = [jax.ShapeDtypeStruct((bh, t, dv), v.dtype)]
     out_specs = [vv]
     if with_states:
-        out_shape.append(jax.ShapeDtypeStruct((bh, n, dk, dv), _F32))
-        out_specs.append(st)
+        out_shape += [jax.ShapeDtypeStruct((bh, n, dk, dv), _F32),
+                      jax.ShapeDtypeStruct(
+                          (bh, steps, chunk, chunk * per_step), q.dtype)]
+        out_specs += [st, inv]
     out = pl.pallas_call(
         functools.partial(_fwd_kernel, chunk=chunk, per_step=per_step),
         out_shape=out_shape, grid=(bh, steps),
@@ -320,15 +340,15 @@ def _fwd_call(q, k, v, g, beta, chunk, with_states, interpret):
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret, name=FWD_KERNEL_NAME,
     )(q, k, v, g.reshape(bh, n, 1, chunk), beta.reshape(bh, n, 1, chunk))
-    return (out[0], out[1]) if with_states else out[0]
+    return tuple(out) if with_states else out[0]
 
 
-def _bwd_call(q, k, v, g, beta, states, do, chunk, interpret):
+def _bwd_call(q, k, v, g, beta, states, t_invs, do, chunk, interpret):
     bh, t, dk = q.shape
     dv = v.shape[2]
     n = t // chunk
     per_step = _per_step(n)
-    steps, qk, vv, row, st = _specs(bh, t, dk, dv, chunk, per_step, True)
+    steps, qk, vv, row, st, inv = _specs(bh, t, dk, dv, chunk, per_step, True)
     rows = jax.ShapeDtypeStruct((bh, n, 1, chunk), _F32)
     dq, dk_, dv_, dg, db = pl.pallas_call(
         functools.partial(_bwd_kernel, chunk=chunk, per_step=per_step),
@@ -336,14 +356,14 @@ def _bwd_call(q, k, v, g, beta, states, do, chunk, interpret):
                    jax.ShapeDtypeStruct(k.shape, k.dtype),
                    jax.ShapeDtypeStruct(v.shape, v.dtype), rows, rows],
         grid=(bh, steps),
-        in_specs=[qk, qk, vv, row, row, st, vv],
+        in_specs=[qk, qk, vv, row, row, st, inv, vv],
         out_specs=[qk, qk, vv, row, row],
         scratch_shapes=[pltpu.VMEM((dk, dv), _F32)],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret, name=BWD_KERNEL_NAME,
     )(q, k, v, g.reshape(bh, n, 1, chunk), beta.reshape(bh, n, 1, chunk),
-      states, do)
+      states, t_invs, do)
     return dq, dk_, dv_, dg.reshape(bh, t), db.reshape(bh, t)
 
 
@@ -359,34 +379,57 @@ def _unchunked(x):
     return x.reshape((x.shape[0], x.shape[1] * x.shape[2]) + x.shape[3:])
 
 
+def _side_by_side(t_invs, per_step):
+    """(BH, T / C, C, C) -> (BH, steps, C, per_step * C), the kernels'
+    layout of the inverses."""
+    bh, n, c, _ = t_invs.shape
+    t_invs = t_invs.reshape(bh, n // per_step, per_step, c, c)
+    return jnp.swapaxes(t_invs, 2, 3).reshape(bh, n // per_step, c,
+                                              per_step * c)
+
+
+def _one_by_one(t_invs, chunk):
+    """`_side_by_side` undone: (BH, T / C, C, C)."""
+    bh, steps, _, wide = t_invs.shape
+    t_invs = t_invs.reshape(bh, steps, chunk, wide // chunk, chunk)
+    return jnp.swapaxes(t_invs, 2, 3).reshape(bh, -1, chunk, chunk)
+
+
 def _scan_fwd(q, k, v, g, beta, chunk, with_states):
     bh, _, dk = q.shape
     body = jax.vmap(_chunk_fwd)
 
     def step(s, xs):
         qc, kc, vc, gc, bc = xs
-        o, s_next = body(s, qc, kc, vc, gc[:, None, :], bc[:, None, :])
-        return s_next, (o.astype(v.dtype), s)
+        o, s_next, t_inv = body(s, qc, kc, vc, gc[:, None, :],
+                                bc[:, None, :])
+        return s_next, (o.astype(v.dtype), s, t_inv)
 
     xs = tuple(_chunked(x, chunk) for x in (q, k, v, g, beta))
-    _, (o, states) = lax.scan(step, jnp.zeros((bh, dk, v.shape[2]), _F32), xs)
+    _, (o, states, t_invs) = lax.scan(
+        step, jnp.zeros((bh, dk, v.shape[2]), _F32), xs)
     o = _unchunked(o)
-    return (o, jnp.moveaxis(states, 0, 1)) if with_states else o
+    if not with_states:
+        return o
+    return o, jnp.moveaxis(states, 0, 1), _side_by_side(
+        jnp.moveaxis(t_invs, 0, 1), _per_step(t_invs.shape[0]))
 
 
-def _scan_bwd(q, k, v, g, beta, states, do, chunk):
+def _scan_bwd(q, k, v, g, beta, states, t_invs, do, chunk):
     bh, _, dk = q.shape
     body = jax.vmap(_chunk_bwd)
 
     def step(ds, xs):
-        qc, kc, vc, gc, bc, sc, doc = xs
-        dq, dk_, dv_, dg, db, ds = body(sc, qc, kc, vc, gc[:, None, :],
+        qc, kc, vc, gc, bc, sc, tc, doc = xs
+        dq, dk_, dv_, dg, db, ds = body(sc, tc, qc, kc, vc, gc[:, None, :],
                                         bc[:, None, :], doc, ds)
         return ds, (dq.astype(q.dtype), dk_.astype(k.dtype),
                     dv_.astype(v.dtype), dg[:, 0], db[:, 0])
 
     xs = tuple(_chunked(x, chunk) for x in (q, k, v, g, beta)) \
-        + (jnp.moveaxis(states, 1, 0), _chunked(do, chunk))
+        + (jnp.moveaxis(states, 1, 0),
+           jnp.moveaxis(_one_by_one(t_invs, chunk), 1, 0),
+           _chunked(do, chunk))
     _, outs = lax.scan(step, jnp.zeros((bh, dk, v.shape[2]), _F32), xs,
                        reverse=True)
     return tuple(_unchunked(x) for x in outs)
@@ -401,10 +444,10 @@ def _forward(q, k, v, g, beta, chunk, with_states):
         cpu=lambda *a: _scan_fwd(*a, chunk, with_states))
 
 
-@functools.partial(jax.jit, static_argnums=(7,))
-def _backward(q, k, v, g, beta, states, do, chunk):
+@functools.partial(jax.jit, static_argnums=(8,))
+def _backward(q, k, v, g, beta, states, t_invs, do, chunk):
     return lax.platform_dependent(
-        q, k, v, g, beta, states, do,
+        q, k, v, g, beta, states, t_invs, do,
         tpu=lambda *a: _bwd_call(*a, chunk, interpret=False),
         cpu=lambda *a: _scan_bwd(*a, chunk))
 
@@ -415,20 +458,26 @@ def _delta3(q, k, v, g, beta, chunk):
 
 
 def _delta3_fwd(q, k, v, g, beta, chunk):
-    o, states = _forward(q, k, v, g, beta, chunk, True)
+    o, states, t_invs = _forward(q, k, v, g, beta, chunk, True)
     telemetry.gauge(
         "delta_rule_state_saved_bytes",
         help="bytes of chunk-boundary state one differentiated call of the "
              "gated delta rule keeps for its backward (the last call "
              "traced)").set(states.size * states.dtype.itemsize)
-    return o, (q, k, v, g, beta, states)
+    telemetry.gauge(
+        "delta_rule_inverse_saved_bytes",
+        help="bytes of the chunks' (I + A)^{-1} one differentiated call of "
+             "the gated delta rule hands to its backward (the last call "
+             "traced)").set(t_invs.size * t_invs.dtype.itemsize)
+    return o, (q, k, v, g, beta, states, t_invs)
 
 
 def _delta3_bwd(chunk, res, do):
-    q, k, v, g, beta, states = res
+    q, k, v, g, beta, states, t_invs = res
     with telemetry.span("delta_rule.build", category="compile",
                         tags={"pass": "bwd"}):
-        dq, dk, dv, dg, db = _backward(q, k, v, g, beta, states, do, chunk)
+        dq, dk, dv, dg, db = _backward(q, k, v, g, beta, states, t_invs, do,
+                                       chunk)
     return dq, dk, dv, dg.astype(g.dtype), db.astype(beta.dtype)
 
 

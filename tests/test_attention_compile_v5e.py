@@ -82,9 +82,12 @@ def test_attention_gradient_compiles_at_the_hybrid_cells_size(one_chip,
 def test_delta_rule_gradient_compiles_at_the_hybrid_cells_size(one_chip,
                                                                no_cache):
     """olmo-hybrid-7b-fit-s2048's linear-attention layers: batch 4, 30 heads,
-    d_k 96, d_v 192, 2048 tokens, bf16. Two Mosaic calls found by name, the
-    chunk-boundary states the only residual beyond the inputs, and no
-    per-token state (2048 states of 96 x 192 a head) anywhere."""
+    d_k 96, d_v 192, 2048 tokens, bf16. Two Mosaic calls found by name, one
+    of each; the chunk-boundary states and the chunks' triangular inverses
+    (four of a grid step side by side, bfloat16) go from the forward call to
+    the backward call, and no per-token state (2048 states of 96 x 192 a
+    head) is anywhere."""
+    import re
     import jax
     import jax.numpy as jnp
     from mxtpu.ops import delta_rule
@@ -101,8 +104,20 @@ def test_delta_rule_gradient_compiles_at_the_hybrid_cells_size(one_chip,
     compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4))).lower(
         *args).compile()
     text = compiled.as_text()
-    assert "%" + delta_rule.FWD_KERNEL_NAME in text
-    assert "%" + delta_rule.BWD_KERNEL_NAME in text
-    assert "f32[120,32,96,192]" in text          # one state a chunk
+    calls = {name: re.findall(
+        r"^\s*(%%%s[\w.]*) = \((.*?)\) custom-call\((.*?)\), custom_call_target"
+        % name, text, re.M)
+        for name in (delta_rule.FWD_KERNEL_NAME, delta_rule.BWD_KERNEL_NAME)}
+    (fwd, fwd_results, _), = calls[delta_rule.FWD_KERNEL_NAME]    # one call
+    (_, _, bwd_operands), = calls[delta_rule.BWD_KERNEL_NAME]     # of each
+    # o, one state a chunk, one inverse a chunk: 120 x 8 x 4 of 64 x 64
+    assert [r.split("{")[0] for r in fwd_results.split(", ")] == [
+        "bf16[120,2048,192]", "f32[120,32,96,192]", "bf16[120,8,64,256]"]
+    bwd_operands = re.sub(r"/\*.*?\*/", "", bwd_operands).split(", ")
+    for index in (1, 2):
+        element, = re.findall(
+            r"(%%[\w.-]+) = \S+ get-tuple-element\(%s\), index=%d"
+            % (re.escape(fwd), index), text)
+        assert element in bwd_operands, (index, element, bwd_operands)
     assert "2048,96,192]" not in text
     assert compiled.memory_analysis().temp_size_in_bytes < 1.2e9

@@ -73,6 +73,11 @@ def test_the_ops_own_backward_is_the_gradient_of_the_recurrence(t, decay,
         assert gap < 1e-3, (name, gap)
 
 
+def flat(x):
+    """(B, H, ...) -> (B*H, ...), as the op hands its operands on."""
+    return x.reshape((x.shape[0] * x.shape[1],) + x.shape[2:])
+
+
 @pytest.mark.parametrize("t,decay,beta_side", CASES)
 def test_the_kernels_are_the_scan_path(t, decay, beta_side):
     """The chip runs the Pallas kernels, the CPU the same chunk functions
@@ -80,17 +85,103 @@ def test_the_kernels_are_the_scan_path(t, decay, beta_side):
     specs, the state carried in scratch, the backward's reversed walk) give
     what the scan gives, forward and backward; only this test runs them on
     the CPU."""
-    (q, k, v, g, beta), w = inputs(5, t, decay, beta_side)
-    flat = [x.reshape((4,) + x.shape[2:]) for x in (q, k, v, g, beta)]
-    o_k, s_k = dr._fwd_call(*flat, 64, True, interpret=True)
-    o_s, s_s = dr._scan_fwd(*flat, 64, True)
+    args, w = inputs(5, t, decay, beta_side)
+    args = [flat(x) for x in args]
+    o_k, s_k, t_k = dr._fwd_call(*args, 64, True, interpret=True)
+    o_s, s_s, t_s = dr._scan_fwd(*args, 64, True)
     np.testing.assert_allclose(o_k, o_s, atol=1e-5)
     np.testing.assert_allclose(s_k, s_s, atol=1e-5)
+    np.testing.assert_allclose(t_k, t_s, atol=1e-5)
     assert s_k.shape == (4, t // 64, 8, 16)   # a state a chunk, none a token
-    do = w.reshape(4, t, 16)
-    for a, b in zip(dr._bwd_call(*flat, s_k, do, 64, interpret=True),
-                    dr._scan_bwd(*flat, s_s, do, 64)):
+    # a float32 inverse a chunk for float32 operands (nothing is rounded
+    # that was not before), a grid step's side by side
+    per_step = dr._per_step(t // 64)
+    assert t_k.shape == (4, t // 64 // per_step, 64, 64 * per_step)
+    assert t_k.dtype == jnp.float32
+    do = flat(w)
+    for a, b in zip(dr._bwd_call(*args, s_k, t_k, do, 64, interpret=True),
+                    dr._scan_bwd(*args, s_s, t_s, do, 64)):
         np.testing.assert_allclose(a, b, atol=2e-5)
+
+
+def test_the_kernels_hand_over_a_bfloat16_inverse():
+    """bfloat16 operands at four chunks a grid step, as the hybrid cell runs
+    them: the forward kernel keeps T in bfloat16, the cast its own product
+    takes, and the backward kernel reads that; both as the scan path, to a
+    bfloat16 spacing."""
+    args, w = inputs(12, 256, "mixed", "both")
+    bf = jnp.bfloat16
+    q, k, v, g, beta = [flat(x) for x in args]
+    args = (q.astype(bf), k.astype(bf), v.astype(bf), g, beta.astype(bf))
+    kernel = dr._fwd_call(*args, 64, True, interpret=True)
+    scan = dr._scan_fwd(*args, 64, True)
+    assert kernel[2].shape == (4, 1, 64, 4 * 64) and kernel[2].dtype == bf
+    do = flat(w).astype(bf)
+    for a, b in zip(
+            kernel + dr._bwd_call(*args, *kernel[1:], do, 64, interpret=True),
+            scan + dr._scan_bwd(*args, *scan[1:], do, 64)):
+        np.testing.assert_allclose(np.asarray(a, np.float32),
+                                   np.asarray(b, np.float32),
+                                   atol=2e-5, rtol=2 ** -7)
+
+
+def products_by_precision(jaxpr, found=None):
+    """{precision: dot_generals}, through every sub-jaxpr (a scan's body is
+    counted once, whatever its length)."""
+    found = {} if found is None else found
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "dot_general":
+            key = eqn.params["precision"]
+            found[key] = found.get(key, 0) + 1
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            products_by_precision(sub, found)
+    return found
+
+
+def test_only_the_forward_takes_the_inverse():
+    """The inverse's products are the op's only ones at `highest`: ten a
+    chunk body (five levels of two) in the forward, none in the backward,
+    which reads the forward's. `inputs` is float32; the count does not
+    depend on the dtype."""
+    args, w = inputs(10, 128, "mixed", "both")
+    args = [flat(x) for x in args]
+    hi = (jax.lax.Precision.HIGHEST, jax.lax.Precision.HIGHEST)
+    for with_states in (False, True):
+        fwd = products_by_precision(jax.make_jaxpr(
+            lambda *a: dr._scan_fwd(*a, 64, with_states))(*args).jaxpr)
+        assert fwd.get(hi) == 10, fwd
+    _, s, t_inv = dr._scan_fwd(*args, 64, True)
+    bwd = products_by_precision(jax.make_jaxpr(
+        lambda *a: dr._scan_bwd(*a, 64))(*args, s, t_inv, flat(w)).jaxpr)
+    assert bwd and hi not in bwd, bwd
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_a_handed_over_inverse_is_the_rebuilt_one(dtype):
+    """`_chunk_bwd` with the inverse the forward kept against `_chunk_bwd`
+    with the inverse taken again from k, g and beta as the backward used
+    to: the same bits in every gradient, in float32 and with bfloat16
+    operands (the forward keeps T as it cast it for its own product)."""
+    args, w = inputs(11, 192, "mixed", "both")
+    q, k, v, g, beta = [flat(x) for x in args]
+    q, k, v, beta, do = (x.astype(dtype) for x in (q, k, v, beta, flat(w)))
+    _, states, kept = dr._scan_fwd(q, k, v, g, beta, 64, True)
+    assert kept.dtype == dtype
+    kept = dr._one_by_one(kept, 64)
+    ds = jnp.zeros((4, 8, 16), jnp.float32)
+    for n in reversed(range(3)):
+        at = slice(64 * n, 64 * (n + 1))
+        rows = (g[:, None, at], beta[:, None, at])
+        rebuilt = jax.vmap(lambda q, k, g_row, b_row: dr._inverse(
+            dr._chunk_prep(q, k, g_row, b_row)["A"]).astype(q.dtype))(
+                q[:, at], k[:, at], *rows)
+        got, want = (jax.vmap(dr._chunk_bwd)(
+            states[:, n], t_inv, q[:, at], k[:, at], v[:, at], *rows,
+            do[:, at], ds) for t_inv in (kept[:, n], rebuilt))
+        for a, b in zip(got, want):
+            np.testing.assert_array_equal(np.asarray(a, np.float32),
+                                          np.asarray(b, np.float32))
+        ds = got[-1]
 
 
 def test_bfloat16_operands_keep_the_decay_in_float32():
@@ -142,3 +233,11 @@ def test_what_the_op_reports():
     assert telemetry.gauge("delta_rule_chunks_per_row").value == 2
     assert telemetry.gauge("delta_rule_state_saved_bytes").value == \
         2 * 2 * 2 * 8 * 16 * 4
+    # B*H * (T/C) * C * C numbers of the operands' dtype
+    assert telemetry.gauge("delta_rule_inverse_saved_bytes").value == \
+        2 * 2 * 2 * 64 * 64 * 4
+    q, k, v, beta = (x.astype(jnp.bfloat16) for x in (q, k, v, beta))
+    jax.grad(lambda q: jnp.sum(
+        dr.gated_delta_rule(q, k, v, g, beta).astype(jnp.float32)))(q)
+    assert telemetry.gauge("delta_rule_inverse_saved_bytes").value == \
+        2 * 2 * 2 * 64 * 64 * 2
