@@ -3,8 +3,8 @@ seeded schedule fuzzer.
 
 The framework around the dependency engine runs ~10 interacting thread
 domains (engine workers, serving replica workers + hot-swap, the
-snapshot writer, prefetch producers, the watchdog sampler, the online
-tune controller, the supervisor). Their safety argument is the declared
+snapshot writer, prefetch producers, the watchdog sampler, the
+supervisor). Their safety argument is the declared
 lock hierarchy in :mod:`mxtpu.analysis.declarations` — but the AST lint
 can only check *syntactically nested* ``with`` blocks. This module
 checks the same declarations **dynamically**: following the PAPERS
@@ -18,9 +18,7 @@ Three parts:
 * **tracked locks** — :func:`lock` / :func:`rlock` / :func:`condition`
   wrap ``threading`` primitives with the declared ``(owner, attr)``
   key. Disarmed, each acquisition costs one module-global ``None``
-  check plus the raw acquire (the PR-12 guard convention;
-  ``tools/bench_concurrency.py`` pins it under 0.5% of an mlp fit
-  step). Armed (:func:`arm` / ``MXTPU_CONCURRENCY=1``), the witness
+  check plus the raw acquire (the PR-12 guard convention). Armed (:func:`arm` / ``MXTPU_CONCURRENCY=1``), the witness
   keeps a per-thread held-stack and a process-wide observed
   acquisition-order graph, and turns four hazard classes into
   PR-5-schema :class:`~mxtpu.analysis.findings.Finding`\\ s:
@@ -63,7 +61,7 @@ PASS_NAME = "concurrency"
 # ------------------------------------------------------------ the guard
 #: the armed witness; None = off. The tracked-lock fast path below is
 #: the only reader on hot paths — one module-global read + None test
-#: (the PR-12 guard convention, pinned by tools/bench_concurrency.py).
+#: (the PR-12 guard convention).
 _WITNESS = None
 
 _TLS = _threading.local()  # .held: list of (lock_obj, key, rank_or_None)
@@ -248,10 +246,9 @@ class ConcurrencyWitness:
     """Process-wide observer fed by every tracked-lock operation.
 
     All shared structures are guarded by one raw internal lock; the
-    per-thread held-stack lives in TLS and is touched lock-free. The
-    armed per-acquisition cost (TLS access + one dict update under the
-    internal lock) is recorded honestly by ``tools/bench_concurrency.py``
-    — arming is a diagnosis/CI mode, priced accordingly.
+    per-thread held-stack lives in TLS and is touched lock-free. Armed,
+    each acquisition pays a TLS access + one dict update under the
+    internal lock — arming is a diagnosis/CI mode, priced accordingly.
     """
 
     def __init__(self, max_findings=512):

@@ -92,11 +92,10 @@ LOCK_LEVELS = [
     # watchdog singleton construction registers gauges -> must precede
     # the telemetry registry level
     ("watchdog", {("watchdog", "_SINGLETON_LOCK")}),
-    # autotuning config/registry slots: resolve() runs under serving
-    # locks (warm-cache eviction) and use() pokes the compile pipeline,
+    # the knob registry's declaration slot: declare() runs at import,
+    # possibly under serving locks (warm-cache eviction resolves a knob),
     # so tune sits between watchdog and the registry/engine levels
-    ("tune", {("config", "_LOCK"), ("registry", "_LOCK"),
-              ("OnlineController", "_lock")}),
+    ("tune", {("registry", "_LOCK")}),
     # int8 calibration stats fold (compile/quant.py): observe() runs on
     # the instrumented-program return path — possibly under replica
     # dispatch locks — holds only for the per-name dict fold, and emits

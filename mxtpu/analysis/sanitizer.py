@@ -11,8 +11,7 @@ flight-recorder ring and ``debug_state()`` captured at the moment the
 bad value appeared, not three exceptions later when a metric finally
 reads it.
 
-Unset, the cost is one module-global ``None`` check per program call
-(``tools/bench_analysis.py`` pins it under 0.5% of an mlp fit step);
+Unset, the cost is one module-global ``None`` check per program call;
 set, every call pays the check program plus a blocking host read of the
 flag vector — a debugging mode, priced accordingly.
 """

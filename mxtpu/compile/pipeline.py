@@ -37,7 +37,7 @@ __all__ = ["set_output_sanitizer", "set_calib_observer",
            "remove_build_listener", "program_build_count", "notify_build",
            "record_program_build", "instrument_program",
            "prewarm_scope", "in_prewarm", "prewarm_build_count",
-           "configure", "configured", "refresh_from_knobs",
+           "configure", "configured",
            "pipeline_scope", "canonical_order",
            "set_certification", "certification_enabled",
            "transform_graph", "PipelineReport"]
@@ -48,8 +48,7 @@ _log = _logging.getLogger("mxtpu.compile")
 # mxtpu.analysis.sanitizer installs fn(kind, out) here when MXTPU_SANITIZE
 # is armed; every instrumented program (all kinds: fwd_eval/fwd_bwd/
 # fused_step/metric_accum/...) routes its outputs through it. Unset, the
-# cost per call is ONE module-global read + None check — the zero-
-# overhead contract tools/bench_analysis.py pins down.
+# cost per call is ONE module-global read + None check.
 _OUTPUT_SANITIZER = None
 
 
@@ -83,7 +82,7 @@ def set_calib_observer(fn):
 # and a non-certifiable rewrite is refused — rejected and fallen back
 # from exactly like the error-budget path. Disarmed
 # (MXTPU_PIPELINE_CERT=0), the per-pass cost is ONE module-global
-# check — the zero-overhead contract tools/bench_equiv.py pins down.
+# check.
 _CERT_DISARM = ("0", "off", "false", "none", "")
 _CERT_ARMED = (_os.environ.get("MXTPU_PIPELINE_CERT", "1")
                .strip().lower() not in _CERT_DISARM)
@@ -438,16 +437,10 @@ def instrument_program(kind, fn, owner=None, matmul_env=False,
 
 # ---------------------------------------------------------- pipeline config
 def _parse_env():
-    # precision/transform mode is a declared knob (mxtpu.tune): a set
-    # MXTPU_PIPELINE env always wins — including set-but-empty, which
-    # means "explicitly off" and must override a TunedConfig artifact —
-    # otherwise the active artifact's `compile.pipeline` value applies,
-    # and the default stays the empty pipeline (zero behavior change)
-    raw = _os.environ.get("MXTPU_PIPELINE")
-    if raw is None:
-        from ..tune import registry as _knobs
-        raw = _knobs.resolve("compile.pipeline") or ""
-    raw = raw.strip()
+    # the pass list is a declared knob (mxtpu.tune): MXTPU_PIPELINE, else
+    # the empty pipeline
+    from ..tune import registry as _knobs
+    raw = (_knobs.resolve("compile.pipeline") or "").strip()
     if raw.lower() in ("", "0", "none", "off", "false"):
         return ()
     return tuple(p.strip() for p in raw.split(",") if p.strip())
@@ -455,9 +448,6 @@ def _parse_env():
 
 _CONFIGURED = _parse_env()
 _CONFIG_LOCK = _conc.lock("pipeline", "_CONFIG_LOCK")
-# True once configure(names) pinned an explicit pass list — an artifact
-# installed later (refresh_from_knobs) must not clobber it
-_CONFIG_EXPLICIT = False
 
 
 def configured():
@@ -468,28 +458,14 @@ def configured():
 
 def configure(names=None):
     """Set the process-wide pipeline. ``None`` re-reads
-    ``MXTPU_PIPELINE`` (and the active TunedConfig artifact's
-    ``compile.pipeline`` knob); a sequence of registered transform
+    ``MXTPU_PIPELINE``; a sequence of registered transform
     names activates them in order; ``()`` empties the pipeline.
     Affects programs built AFTER the call — already-built executables
     keep the graph they compiled."""
-    global _CONFIGURED, _CONFIG_EXPLICIT
+    global _CONFIGURED
     with _CONFIG_LOCK:
         _CONFIGURED = _parse_env() if names is None \
             else tuple(str(n) for n in names)
-        _CONFIG_EXPLICIT = names is not None
-    return _CONFIGURED
-
-
-def refresh_from_knobs():
-    """Re-resolve the pipeline from env + artifact. The module snapshots
-    its config at import; :func:`mxtpu.tune.use` calls this so an
-    artifact installed AFTER import still applies its
-    ``compile.pipeline`` value — unless an explicit ``configure(names)``
-    pinned the pipeline, which (like an explicit argument everywhere
-    else in the knob precedence) always wins."""
-    if not _CONFIG_EXPLICIT:
-        configure(None)
     return _CONFIGURED
 
 
@@ -500,16 +476,14 @@ def pipeline_scope(names):
         with mxtpu.compile.pipeline_scope(["bf16"]):
             mod.fit(...)
     """
-    global _CONFIGURED, _CONFIG_EXPLICIT
-    prev, prev_explicit = _CONFIGURED, _CONFIG_EXPLICIT
+    global _CONFIGURED
+    prev = _CONFIGURED
     configure(names)
     try:
         yield
     finally:
-        # restore VALUE AND PROVENANCE: a scope over an env/artifact-
-        # derived config must leave it refreshable, not pinned
         with _CONFIG_LOCK:
-            _CONFIGURED, _CONFIG_EXPLICIT = prev, prev_explicit
+            _CONFIGURED = prev
 
 
 # ------------------------------------------------------------ transform gate

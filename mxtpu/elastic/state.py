@@ -44,25 +44,18 @@ class ElasticConfig:
     * ``sync``          — block until each snapshot is durable (tests /
       tiny models; default False = fully async);
     * ``supervisor``    — a :class:`~mxtpu.elastic.Supervisor` to poll
-      for wedge/preemption interrupts between steps;
-    * ``tuned``         — a :class:`~mxtpu.tune.TunedConfig` (or path)
-      the cadence knobs pull their defaults from, with the usual
-      ``default < artifact < env < explicit argument`` precedence
-      (``None`` = the process-active artifact, ``False`` = ignore it).
+      for wedge/preemption interrupts between steps.
     """
 
     def __init__(self, prefix, every_n_steps=None, epoch_period=None,
-                 keep=None, sync=False, supervisor=None, tuned=None):
+                 keep=None, sync=False, supervisor=None):
         from .. import tune as _tune
-        tuned = _tune.artifact(tuned)
         self.prefix = str(prefix)
         self.every_n_steps = _tune.resolve_int(
-            "elastic.every_n_steps", explicit=every_n_steps,
-            artifact=tuned)
+            "elastic.every_n_steps", explicit=every_n_steps)
         self.epoch_period = _tune.resolve_int(
-            "elastic.epoch_period", explicit=epoch_period, artifact=tuned)
-        self.keep = _tune.resolve_int("elastic.keep", explicit=keep,
-                                      artifact=tuned)
+            "elastic.epoch_period", explicit=epoch_period)
+        self.keep = _tune.resolve_int("elastic.keep", explicit=keep)
         self.sync = bool(sync)
         self.supervisor = supervisor
 

@@ -8,8 +8,8 @@ injection points at the existing seams (:data:`POINTS`), armed by a
 seeded schedule, firing deterministically.
 
 The guard is the PR-5 sanitizer convention: ``faults.point("name")``
-costs one module-global read plus a ``None`` test when nothing is armed
-(``tools/bench_faults.py`` pins the overhead), so the points stay in
+costs one module-global read plus a ``None`` test when nothing is armed,
+so the points stay in
 production code permanently — chaos coverage must not require a
 special build.
 
@@ -294,7 +294,7 @@ def _fire(spec):
 # ------------------------------------------------------------ the guard
 #: the armed schedule; None = off. ``point()`` below is the only reader
 #: on hot paths — one module-global read + None test (the PR-5
-#: sanitizer zero-overhead convention, pinned by tools/bench_faults.py).
+#: sanitizer zero-overhead convention).
 _ACTIVE = None
 _CONF_LOCK = _conc.lock("injection", "_CONF_LOCK")
 

@@ -3,8 +3,8 @@
 Each fixture is (symbol_json, params, example_shapes): an inference graph,
 randomly-initialized weights in the checkpoint ``arg:``/``aux:`` naming,
 and per-request input shapes with a leading batch dim of 1 — exactly what
-``ServingSession`` / ``ExecutorPool`` consume. Used by the serving tests,
-``tools/bench_serving.py``, and ``examples/serving``; sized so CPU tier-1
+``ServingSession`` / ``ExecutorPool`` consume. Used by the serving tests
+and ``examples/serving``; sized so CPU tier-1
 runs stay fast while the graphs remain real zoo topologies.
 """
 from __future__ import annotations

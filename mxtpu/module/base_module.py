@@ -137,7 +137,7 @@ class BaseModule:
             begin_epoch=0, num_epoch=None, validation_metric=None,
             monitor=None, max_in_flight=None, metric_sync=None,
             device_metrics=None, device_prefetch=None, mesh=None,
-            elastic=None, resume=None, tuned=None, health=None):
+            elastic=None, resume=None, health=None):
         """Training loop (parity base_module.py:376-525), pipelined.
 
         ``mesh`` — SPMD mesh execution (docs/sharding.md): train
@@ -186,15 +186,6 @@ class BaseModule:
           masters under ``MXTPU_PIPELINE=bf16``), metric accumulators
           and the data-iterator position are all restored.
 
-        Autotuning (docs/tune.md):
-
-        * ``tuned`` — a :class:`~mxtpu.tune.TunedConfig` artifact (or a
-          path) the pipeline knobs above pull their defaults from, with
-          precedence ``default < artifact < env < explicit argument``;
-          ``None`` defers to the process-active artifact
-          (:func:`mxtpu.tune.use` / ``MXTPU_TUNED``), ``False`` ignores
-          it. A stale artifact (knob-registry mismatch) is rejected.
-
         Training health (docs/observability.md):
 
         * ``health`` — arm device-resident per-layer training-health
@@ -214,29 +205,16 @@ class BaseModule:
             assert num_epoch is not None, "please specify number of epochs"
             initializer = initializer or Uniform(0.01)
 
-            # one resolution point for every pipeline knob (the hand-picked
-            # constants moved into the registry catalog; resolution order is
-            # default < artifact < env < this call's explicit arguments)
-            tuned = _tune.artifact(tuned)
+            # one resolution point for every pipeline knob (docs/tune.md:
+            # default < environment < this call's explicit arguments)
             max_in_flight = _tune.resolve_int(
-                "fit.max_in_flight", explicit=max_in_flight, artifact=tuned,
-                floor=1)
-            # metric_sync is special: an explicit arg or env wins outright,
-            # but an ARTIFACT cadence cannot simply preempt the auto-derive
-            # — the search could not see this fit's callbacks, and every
-            # Speedometer window boundary must stay a sync batch. The
-            # artifact value rides along as a preference the derivation
-            # reconciles (gcd) with the callback contract below.
+                "fit.max_in_flight", explicit=max_in_flight, floor=1)
             metric_sync = _tune.resolve(
-                "fit.metric_sync", explicit=metric_sync, artifact=False)
-            tuned_metric_sync = _tune.resolve("fit.metric_sync",
-                                              artifact=tuned) \
-                if metric_sync is None else None
+                "fit.metric_sync", explicit=metric_sync)
             device_metrics = _tune.resolve(
-                "fit.device_metrics", explicit=device_metrics, artifact=tuned)
+                "fit.device_metrics", explicit=device_metrics)
             device_prefetch = _tune.resolve(
-                "fit.device_prefetch", explicit=device_prefetch,
-                artifact=tuned)
+                "fit.device_prefetch", explicit=device_prefetch)
             self._fit_knobs = {"fit.max_in_flight": max_in_flight,
                                "fit.metric_sync": metric_sync,
                                "fit.device_metrics": device_metrics,
@@ -284,7 +262,7 @@ class BaseModule:
                         arg_params, aux_params, allow_missing, force_rebind,
                         force_init, begin_epoch, num_epoch, validation_metric,
                         monitor, max_in_flight, metric_sync, device_metrics,
-                        el_cfg, resume_state, tuned_metric_sync, health)
+                        el_cfg, resume_state, health)
             except Exception as exc:
                 # fatal training exception: capture the flight ring / ledger /
                 # engine state BEFORE the stack unwinds and the evidence GCs.
@@ -307,8 +285,7 @@ class BaseModule:
                   aux_params, allow_missing, force_rebind, force_init,
                   begin_epoch, num_epoch, validation_metric, monitor,
                   max_in_flight, metric_sync, device_metrics,
-                  el_cfg=None, resume_state=None, tuned_metric_sync=None,
-                  health=None):
+                  el_cfg=None, resume_state=None, health=None):
         self.bind(data_shapes=train_data.provide_data,
                   label_shapes=train_data.provide_label,
                   for_training=True, force_rebind=force_rebind)
@@ -397,26 +374,11 @@ class BaseModule:
                 from math import gcd
                 from functools import reduce
                 metric_sync = reduce(gcd, freqs)
-                if tuned_metric_sync:
-                    # the artifact's searched cadence, reconciled: gcd
-                    # keeps every meter boundary a sync batch (never
-                    # sparser than the callbacks allow)
-                    metric_sync = gcd(metric_sync,
-                                      int(tuned_metric_sync))
-            elif tuned_metric_sync is not None:
-                metric_sync = int(tuned_metric_sync)  # no callbacks to
-                # protect: the searched cadence applies as-is
             else:
                 metric_sync = 0   # no batch callbacks: epoch-end only
         metric_sync = max(0, int(metric_sync))
         if hasattr(self, "_fit_knobs"):
             self._fit_knobs["fit.metric_sync"] = metric_sync
-        # the live in-flight window: the online-refinement controller
-        # (mxtpu.tune.online) may nudge it within the certified safe
-        # range while the fit runs — the loop reads the holder per step
-        from ..tune import online as _online
-        inflight_limit = _online.attach_fit(
-            {"v": max(1, int(max_in_flight))})
 
         # one pipeline for training and serving: fit emits into the same
         # process-wide registry the serving /metrics endpoint scrapes
@@ -535,8 +497,7 @@ class BaseModule:
                                 # than K steps are outstanding, and only on the
                                 # oldest — the device never idles waiting for the
                                 # host between steps
-                                while len(inflight) > \
-                                        max(1, int(inflight_limit["v"])):
+                                while len(inflight) > max_in_flight:
                                     with _tracing.span(
                                             "fit.pace",
                                             category="module") as sp_w:
@@ -660,7 +621,6 @@ class BaseModule:
                 if accum is not None:
                     accum.remove_rider(health_session)
                 health_session.close()
-            _online.release(inflight_limit)
 
 
     def check(self, passes=None, pipeline=None):

@@ -347,15 +347,8 @@ class FusedTrainStep:
         #            annotations, like block/conv/all pin their policy.
         import os
         from ..tune import registry as _knobs
-        # a SET MXTPU_REMAT always wins — including set-but-empty,
-        # which keeps its historical "explicitly off" meaning and must
-        # override a TunedConfig artifact (same special case as
-        # MXTPU_PIPELINE in compile.pipeline._parse_env)
-        raw = os.environ.get("MXTPU_REMAT")
-        env_set = raw is not None
-        if raw is None:
-            raw = _knobs.resolve("fit.remat")
-        self._remat = str(raw or "none").lower()
+        env_set = bool(os.environ.get("MXTPU_REMAT", "").strip())
+        self._remat = str(_knobs.resolve("fit.remat") or "none").lower()
         self._remat_pinned_off = False
         if self._remat in ("0", "none", "", "false"):
             self._remat = "none"

@@ -16,8 +16,7 @@ three coupled pieces:
     so gates assert *exactly which* requests carry traces.
   * :mod:`~mxtpu.obs.corpus` — the append-only JSONL measurement
     corpus (``MXTPU_CORPUS_DIR``): program-build features + measured
-    service ms, crash-safe, with a ``load()/summarize()`` reader that
-    reproduces the ``tune.search`` service model offline.
+    service ms, crash-safe, with a ``load()/summarize()`` reader.
   * :mod:`~mxtpu.obs.health` + :mod:`~mxtpu.obs.detectors` —
     device-resident per-layer training-health statistics over the
     fused train step, riding the metric-sync cadence, with a
@@ -25,7 +24,7 @@ three coupled pieces:
     auto-rollback policy (``MXTPU_HEALTH`` / ``fit(health=True)``).
 
 See docs/observability.md (trace contract, span inventory, training
-health) and docs/tune.md (corpus schema).
+health, the measurement corpus).
 """
 from __future__ import annotations
 

@@ -39,11 +39,9 @@ tolerates by contract — every fully-appended row survives.
 The whole corpus is env-gated: without ``MXTPU_CORPUS_DIR`` the hooks
 cost one dict lookup and the hot paths never touch the filesystem.
 
-``summarize()`` folds service rows into exactly the inputs
-``tune.search`` consumes — per-bucket mean exec ms (the
-``bucket_costs`` shape) and the fitted
-:class:`~mxtpu.tune.cost.ServiceLine` — so an offline search over a
-saved corpus reproduces the in-process model. See docs/tune.md.
+``summarize()`` folds service rows into per-bucket mean exec ms (the
+``bucket_costs`` shape serving warmup measures) and the
+:class:`ServiceLine` fitted to them.
 """
 from __future__ import annotations
 
@@ -55,7 +53,7 @@ from ..analysis import concurrency as _conc
 
 __all__ = ["SCHEMA_VERSION", "enabled", "corpus_path", "record_build",
            "record_service", "record_calibration", "record_health",
-           "load", "summarize", "reset"]
+           "load", "summarize", "reset", "ServiceLine"]
 
 # v2: adds the "health" row kind (training-health stats per cadence,
 # obs/health.py). Readers stay version-tolerant: load() keys on the
@@ -143,7 +141,7 @@ _BUILD_FEATURES = ("id", "kind", "owner", "compile_ms", "flops",
 
 def _knob_vector():
     """The full resolved tune-knob vector at build time (default <
-    artifact < env precedence, exactly what the process runs with)."""
+    env precedence, exactly what the process runs with)."""
     from ..tune import registry as _treg
     vec = {}
     for k in _treg.knobs():
@@ -268,13 +266,63 @@ def load(dirpath=None, strict=False):
     return rows
 
 
-def summarize(rows=None, dirpath=None):
-    """Fold the corpus into the shapes ``tune.search`` consumes.
+class ServiceLine:
+    """``service_ms(rows) ≈ fixed + marginal * rows`` — the two-parameter
+    line least-squares-fit to the measured per-bucket rows. ``fixed``
+    captures dispatch + compile-amortized overhead; ``marginal`` is the
+    per-row cost."""
 
-    Returns counts, per-bucket mean service ms in the ``bucket_costs``
-    shape (``{bucket: {"exec_ms": mean}}``, serving rows), and the
-    fitted ``ServiceLine`` over them — the same closed-form fit
-    ``tune.cost`` runs in-process, so offline == online.
+    __slots__ = ("fixed", "marginal", "basis")
+
+    def __init__(self, fixed, marginal, basis):
+        self.fixed = float(fixed)
+        self.marginal = float(marginal)
+        self.basis = basis    # "bucket-rows" / "fallback"
+
+    def __call__(self, rows):
+        return self.fixed + self.marginal * max(0, rows)
+
+    def to_dict(self):
+        return {"fixed_ms": round(self.fixed, 6),
+                "marginal_ms_per_row": round(self.marginal, 6),
+                "basis": self.basis}
+
+    @classmethod
+    def fit(cls, bucket_costs):
+        """Fit the line from ``{bucket: {"exec_ms": ...}}`` rows.
+
+        Two or more buckets: exact least squares (closed form — no
+        numpy dependency, bit-stable across platforms). One bucket:
+        half the measurement is taken as fixed, half as marginal. No
+        rows: a nominal line.
+        """
+        rows = sorted((int(b), float(c["exec_ms"]))
+                      for b, c in (bucket_costs or {}).items()
+                      if c and c.get("exec_ms", 0) > 0)
+        if len(rows) >= 2:
+            n = float(len(rows))
+            sx = sum(b for b, _ in rows)
+            sy = sum(m for _, m in rows)
+            sxx = sum(b * b for b, _ in rows)
+            sxy = sum(b * m for b, m in rows)
+            denom = n * sxx - sx * sx
+            marginal = (n * sxy - sx * sy) / denom if denom else 0.0
+            fixed = (sy - marginal * sx) / n
+            # a super-linear bucket curve can drive the intercept
+            # negative; clamp
+            return cls(max(0.0, fixed), max(0.0, marginal), "bucket-rows")
+        if len(rows) == 1:
+            b, exec_ms = rows[0]
+            marginal = exec_ms / b * 0.5 if b else 0.0
+            return cls(max(0.0, exec_ms - marginal * b), marginal,
+                       "bucket-rows")
+        return cls(0.01, 0.01, "fallback")
+
+
+def summarize(rows=None, dirpath=None):
+    """Fold the corpus: counts, per-bucket mean service ms in the
+    ``bucket_costs`` shape (``{bucket: {"exec_ms": mean}}``, serving
+    rows), and the :class:`ServiceLine` fitted over them.
     """
     if rows is None:
         rows = load(dirpath)
@@ -300,6 +348,5 @@ def summarize(rows=None, dirpath=None):
            "source_ms_mean": {src: s / n
                               for src, (n, s) in per_source.items()}}
     if bucket_costs:
-        from ..tune.cost import ServiceLine
         out["service_line"] = ServiceLine.fit(bucket_costs).to_dict()
     return out

@@ -22,7 +22,7 @@ synced to host **only at the existing metric-sync cadence**: the stat
 accumulator registers as a *rider* on the fit loop's
 :class:`~mxtpu.metric.DeviceMetricAccum`, whose ``sync()`` already is
 the one intended host round-trip — health adds exactly zero sync
-points (``tools/bench_health.py`` proves the counter delta is 0).
+points (``tests/test_health.py`` pins the counter delta at 0).
 
 On the host side of each cadence a deterministic
 :class:`~mxtpu.obs.detectors.DetectorSuite` turns the stats + the

@@ -13,8 +13,7 @@ FlightRecorder-shaped ring fed by the ``tracing.set_span_sink`` hook:
     as ``diagnostics.flight.FlightRecorder`` — a slot is replaced
     atomically, never mutated, so readers always see whole records);
   * bounded: ``MXTPU_TRACE_CAP`` slots (default 4096), oldest spans
-    overwritten — capture cost is O(1) per span and O(cap) memory,
-    measured in ``BENCH_obs.json`` against the PR-2 <0.5%/step budget;
+    overwritten — capture cost is O(1) per span and O(cap) memory;
   * gated: ``MXTPU_TRACE=0`` never installs the sink, so the disabled
     cost is the existing one-global-read in ``Span.__exit__``.
 
@@ -45,8 +44,8 @@ class SpanRing:
 
     def record(self, span):
         """The span sink: called from ``Span.__exit__`` on every finished
-        span. Must stay allocation-light — this is the cost BENCH_obs
-        prices per step."""
+        span. Must stay allocation-light: every span of every step
+        pays it."""
         i = next(self._idx)
         self._slots[i % self.capacity] = (
             i, span.name, span.category, span.t0_ns, span.t1_ns,

@@ -7,12 +7,11 @@ The BN *apply* stage is an affine per-channel transform (scale/shift
 folded from batch stats, gamma, beta — batch_norm-inl.h's normalize step);
 fusing it with the activation and the block-join add means the conv
 output is read ONCE and the block input written ONCE. XLA usually builds
-the same fusion by itself — `tools/bench_epilogue.py` measures whether
-there is anything left on the table (not measured yet; see PERF.md).
+the same fusion by itself; whether there is anything left on the table
+is not measured on the chip (see PERF.md).
 
 Layout: channel-minor (M, C) tiles, the TPU-native layout (C is the
-128-lane axis). NCHW callers reshape/transpose outside; the microbench
-works directly in (N*H*W, C).
+128-lane axis). NCHW callers reshape/transpose outside.
 """
 import functools
 

@@ -29,8 +29,7 @@ p99 under open-loop load. Pieces:
 
 See docs/serving.md for architecture and tuning; docs/observability.md
 for the framework-wide telemetry layer this plugs into;
-``tools/loadgen_serving.py`` for the open-loop (Poisson) load generator
-behind ``BENCH_serving_v2.json``.
+``benchmark/loadgen.py`` for the open-loop (Poisson) load generator.
 """
 from .admission import (ACCEPTING, DEGRADED, SHEDDING, AdmissionPolicy,
                         AdmissionShed, AdmissionSignals, Decision,

@@ -383,11 +383,9 @@ class ContinuousBatcher(DynamicBatcher):
     largest bucket when per-row cost dominates (big models — prefer
     full batches), drop it toward 1 when dispatch overhead dominates
     (the device should never starve). It is a declared tunable
-    (``serving.refill_watermark``, docs/tune.md): a ``TunedConfig``
-    artifact or env can pin it, ``serving.admission.derive_knobs``
-    picks it from the measured per-bucket cost registry rows otherwise,
-    and the online controller may nudge the live value within its
-    certified safe range (``next_fill`` re-reads it per call).
+    (``serving.refill_watermark``, docs/tune.md): the environment can
+    pin it, ``serving.admission.derive_knobs`` picks it from the
+    measured per-bucket cost registry rows otherwise.
     """
 
     def __init__(self, input_names, refill_watermark=None, **kwargs):

@@ -239,8 +239,6 @@ class DecodeSession:
         bf16-pipeline deployments — state round-trips through the
         narrow dtype between steps, a deliberate memory/precision
         trade (tokens may differ from f32-state decode)
-    tuned : TunedConfig artifact (or path); precedence
-        ``default < artifact < env < explicit argument``
     arena : ``"slots"`` (contiguous per-slot state, the default) or
         ``"paged"`` (block-granular :class:`PagedArena`). Paged without
         a ``paged`` bundle stores the SAME recurrent state as one-token
@@ -257,7 +255,7 @@ class DecodeSession:
         geometry and the prefill latency quantum — knobs
         ``decode.block_size`` (16), ``decode.max_blocks_per_seq`` (16),
         ``decode.prefill_chunk_tokens`` (32); explicit argument beats
-        the ``paged`` bundle beats env/artifact/default
+        the ``paged`` bundle beats env/default
     prefill_chunked : False dispatches a sequence's WHOLE remaining
         prompt as one prefill call (the stall baseline the
         ``decode_prefill_stalls`` counter exists to indict)
@@ -275,7 +273,7 @@ class DecodeSession:
                  eos_id=None, contexts=None, cache_size=8, warmup=True,
                  max_queue=None, admission="auto",
                  join_wait_budget_ms=None, version_tag="v0", id2word=None,
-                 state_dtype=None, default_timeout=None, tuned=None,
+                 state_dtype=None, default_timeout=None,
                  arena="slots", paged=None, block_size=None,
                  max_blocks_per_seq=None, prefill_chunk_tokens=None,
                  prefill_chunked=True, prefill_buckets=None,
@@ -331,33 +329,28 @@ class DecodeSession:
                 if name not in example_shapes:
                     raise MXNetError(
                         "decode example_shapes missing %r" % name)
-        tuned = _tune.artifact(tuned)
-        self._tuned = tuned
         self.slot_capacity = _tune.resolve_int(
-            "decode.slot_capacity", explicit=slot_capacity,
-            artifact=tuned, floor=1)
+            "decode.slot_capacity", explicit=slot_capacity, floor=1)
         self.max_new_tokens_default = _tune.resolve_int(
             "decode.max_new_tokens_default",
-            explicit=max_new_tokens_default, artifact=tuned, floor=1)
+            explicit=max_new_tokens_default, floor=1)
         self.join_watermark = _tune.resolve_int(
-            "decode.join_watermark", explicit=join_watermark,
-            artifact=tuned, floor=1)
+            "decode.join_watermark", explicit=join_watermark, floor=1)
         self.max_queue = _tune.resolve_int("serving.max_queue",
-                                           explicit=max_queue,
-                                           artifact=tuned)
+                                           explicit=max_queue)
         # paged geometry: explicit argument beats the bundle beats
-        # env/artifact/knob-default (rows layout pins its own below)
+        # env/knob-default (rows layout pins its own below)
         self.block_size = _tune.resolve_int(
             "decode.block_size",
             explicit=block_size if block_size is not None
-            else pb.get("block_size"), artifact=tuned, floor=1)
+            else pb.get("block_size"), floor=1)
         self.max_blocks_per_seq = _tune.resolve_int(
             "decode.max_blocks_per_seq",
             explicit=max_blocks_per_seq if max_blocks_per_seq is not None
-            else pb.get("max_blocks_per_seq"), artifact=tuned, floor=1)
+            else pb.get("max_blocks_per_seq"), floor=1)
         self.prefill_chunk_tokens = _tune.resolve_int(
             "decode.prefill_chunk_tokens", explicit=prefill_chunk_tokens,
-            artifact=tuned, floor=1)
+            floor=1)
         self.prefill_chunked = bool(prefill_chunked)
         self.prefill_buckets = tuple(sorted(set(
             int(b) for b in (prefill_buckets
@@ -370,8 +363,7 @@ class DecodeSession:
         self._prefill_quantum = self.prefill_chunk_tokens \
             if self.prefill_chunked else self.prefill_buckets[-1]
         join_wait_budget_ms = _tune.resolve(
-            "serving.queue_wait_budget_ms", explicit=join_wait_budget_ms,
-            artifact=tuned)
+            "serving.queue_wait_budget_ms", explicit=join_wait_budget_ms)
         if join_wait_budget_ms is None:
             join_wait_budget_ms = 1000.0
         self.eos_id = eos_id
@@ -449,12 +441,9 @@ class DecodeSession:
             admission = DecodeAdmissionPolicy(
                 join_wait_budget_ms=join_wait_budget_ms,
                 join_watermark=self.join_watermark,
-                watchdog_shed_s=_tune.resolve("serving.watchdog_shed_s",
-                                              artifact=tuned),
-                queue_frac_shed=_tune.resolve("serving.queue_frac_shed",
-                                              artifact=tuned),
-                degrade_frac=_tune.resolve("serving.degrade_frac",
-                                           artifact=tuned))
+                watchdog_shed_s=_tune.resolve("serving.watchdog_shed_s"),
+                queue_frac_shed=_tune.resolve("serving.queue_frac_shed"),
+                degrade_frac=_tune.resolve("serving.degrade_frac"))
         if admission is not None and not hasattr(admission, "decide"):
             raise MXNetError("admission must be an AdmissionPolicy "
                              "(got %r)" % (admission,))

@@ -102,10 +102,9 @@ class WarmExecutableCache:
     def max_versions(self):
         """The retention cap. Resolved LIVE through the knob registry
         when not pinned at construction: the singleton cache is built at
-        import, and a TunedConfig installed later (``mx.tune.use``)
-        must still apply its ``serving.warm_versions`` — eviction is a
-        deploy-time path, so the per-register resolve costs nothing
-        that matters."""
+        import, and ``MXTPU_SERVING_WARM_VERSIONS`` set later must still
+        apply — eviction is a deploy-time path, so the per-register
+        resolve costs nothing that matters."""
         if self._max_versions is not None:
             return self._max_versions
         from ..tune import registry as _knobs

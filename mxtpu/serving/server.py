@@ -119,11 +119,6 @@ class ServingSession:
         signal (default ``MXTPU_SERVING_MEM_BUDGET``; unset = signal off)
     queue_wait_budget_ms : admission latency budget (default: half the
         ``default_timeout`` if set, else 1000ms)
-    tuned : a :class:`~mxtpu.tune.TunedConfig` artifact (or path) the
-        serving knobs above pull their defaults from, with precedence
-        ``default < artifact < env < explicit argument``; ``None``
-        defers to the process-active artifact (``mxtpu.tune.use`` /
-        ``MXTPU_TUNED``), ``False`` ignores it
     """
 
     def __init__(self, symbol_json, params, example_shapes,
@@ -132,7 +127,7 @@ class ServingSession:
                  default_timeout=None, mode="continuous", max_in_flight=None,
                  refill_watermark="auto", admission="auto",
                  version_tag="v0", mem_budget_bytes=None,
-                 queue_wait_budget_ms=None, tuned=None):
+                 queue_wait_budget_ms=None):
         from .. import tune as _tune
         if mode not in ("continuous", "burst"):
             raise MXNetError("serving mode must be 'continuous' or "
@@ -147,24 +142,20 @@ class ServingSession:
         _diag.on_session_start()
         self.buckets = tuple(sorted(set(int(b) for b in buckets)))
         self.default_timeout = default_timeout
-        # every hand-picked constant resolves through the knob registry
-        # (docs/tune.md): default < TunedConfig artifact < env < the
-        # explicit constructor arguments above
-        tuned = _tune.artifact(tuned)
-        self._tuned = tuned
+        # every constant resolves through the knob registry
+        # (docs/tune.md): default < environment < the explicit
+        # constructor arguments above
         self.max_in_flight = _tune.resolve_int(
-            "serving.max_in_flight", explicit=max_in_flight,
-            artifact=tuned, floor=1)
+            "serving.max_in_flight", explicit=max_in_flight, floor=1)
         max_queue = _tune.resolve_int("serving.max_queue",
-                                      explicit=max_queue, artifact=tuned)
+                                      explicit=max_queue)
         max_delay_ms = _tune.resolve("serving.max_delay_ms",
-                                     explicit=max_delay_ms, artifact=tuned)
+                                     explicit=max_delay_ms)
         self.version_tag = version_tag
         self._generation = 0
         self._swap_seq = 0  # monotonic default-tag allocator (swap_model)
         self._mem_budget = _tune.resolve(
-            "serving.mem_budget_bytes", explicit=mem_budget_bytes,
-            artifact=tuned) or None
+            "serving.mem_budget_bytes", explicit=mem_budget_bytes) or None
         # the per-replica executor LRU must hold every bucket or warmup
         # thrashes and evicted buckets re-compile mid-traffic
         self._cache_size = max(cache_size, len(self.buckets))
@@ -206,10 +197,9 @@ class ServingSession:
         # admission policy's service-time prior both read bucket_costs
         knobs = derive_knobs(self._pool.bucket_costs(), self.buckets)
         if refill_watermark == "auto":
-            # artifact/env value wins; otherwise fall through to the
+            # an environment value wins; otherwise fall through to the
             # cost-registry derivation (and its structural default)
-            refill_watermark = _tune.resolve("serving.refill_watermark",
-                                             artifact=tuned)
+            refill_watermark = _tune.resolve("serving.refill_watermark")
             if refill_watermark is None:
                 refill_watermark = knobs["refill_watermark"]
         if mode == "continuous":
@@ -224,22 +214,17 @@ class ServingSession:
                 max_delay_ms=max_delay_ms, max_queue=max_queue,
                 metrics=self.metrics, example_shapes=example_shapes)
         queue_wait_budget_ms = _tune.resolve(
-            "serving.queue_wait_budget_ms", explicit=queue_wait_budget_ms,
-            artifact=tuned)
+            "serving.queue_wait_budget_ms", explicit=queue_wait_budget_ms)
         if queue_wait_budget_ms is None:
             queue_wait_budget_ms = 500.0 * default_timeout \
                 if default_timeout else 1000.0
         if admission == "auto":
             admission = SignalAdmissionPolicy(
                 queue_wait_budget_ms=queue_wait_budget_ms,
-                watchdog_shed_s=_tune.resolve("serving.watchdog_shed_s",
-                                              artifact=tuned),
-                min_mem_headroom=_tune.resolve("serving.min_mem_headroom",
-                                               artifact=tuned),
-                queue_frac_shed=_tune.resolve("serving.queue_frac_shed",
-                                              artifact=tuned),
-                degrade_frac=_tune.resolve("serving.degrade_frac",
-                                           artifact=tuned)) \
+                watchdog_shed_s=_tune.resolve("serving.watchdog_shed_s"),
+                min_mem_headroom=_tune.resolve("serving.min_mem_headroom"),
+                queue_frac_shed=_tune.resolve("serving.queue_frac_shed"),
+                degrade_frac=_tune.resolve("serving.degrade_frac")) \
                 if mode == "continuous" else None
         if admission is not None and not hasattr(admission, "decide"):
             raise MXNetError("admission must be an AdmissionPolicy "
@@ -373,8 +358,7 @@ class ServingSession:
             labels={"bucket": str(bucket)}).observe(service_ms)
         if _obs_corpus.enabled():
             # the measurement-corpus ledger: the same marginal service
-            # fact the admission model learns from, persisted for
-            # offline tune.search fitting (docs/tune.md)
+            # fact the admission model learns from, persisted
             _obs_corpus.record_service("serving", service_ms,
                                        bucket=bucket)
 
@@ -627,9 +611,6 @@ class ServingSession:
         t_slot_free = None    # a retire freed a slot at this time
         t_device_idle = None  # nothing in flight since this time
         while True:
-            # the window depth is re-read every cycle: the online
-            # refinement controller (mxtpu.tune.online) nudges
-            # ``max_in_flight`` within its certified safe range live
             k = max(1, self.max_in_flight)
             if len(inflight) >= k:
                 self._retire(inflight.popleft(), idx)

@@ -356,6 +356,28 @@ def make_synthetic_mnist_idx(directory, n_train=2048, n_test=512, seed=0):
     return directory
 
 
+def make_rec(path, n, edge=256, seed=0):
+    """Pack n JPEG records shaped like resized ImageNet samples."""
+    import os
+
+    from . import recordio
+
+    rng = _np.random.RandomState(seed)
+    idx_path = os.path.splitext(path)[0] + ".idx"
+    rec = recordio.MXIndexedRecordIO(idx_path, path, "w")
+    # structured images compress realistically (~20-60 KB like ImageNet)
+    base = rng.randint(0, 255, size=(edge, edge, 3), dtype=_np.uint8)
+    for i in range(n):
+        img = _np.roll(base, shift=int(rng.randint(0, edge)), axis=1).copy()
+        img[:, :, i % 3] = _np.minimum(255, img[:, :, i % 3] * 1.2).astype(
+            _np.uint8)
+        hdr = recordio.IRHeader(0, float(i % 1000), i, 0)
+        buf = recordio.pack_img(hdr, img, quality=90, img_fmt=".jpg")
+        rec.write_idx(i, buf)
+    rec.close()
+    return path
+
+
 def np_reduce(dat, axis, keepdims, numpy_reduce_func):
     """Apply a numpy reduce function over (possibly several) axes with
     keepdims semantics (parity test_utils.py:383 — the oracle helper the

@@ -1,68 +1,18 @@
-"""mxtpu.tune — cost-registry-driven autotuning.
+"""mxtpu.tune — the knob registry.
 
-The framework *measures* everything (the PR-4 AOT cost/memory registry,
-the PR-2 live telemetry, serving's per-bucket ``exec_ms`` rows) but
-historically *hand-picked* every performance constant. This package
-closes that loop (ROADMAP item 1, grounded in PAPERS "Learning to
-Optimize Tensor Programs" and "Value Function Based Performance
-Optimization of Deep Learning Workloads"):
-
-* :mod:`~mxtpu.tune.registry` — the **knob registry**: every tunable
-  declared once (name, kind, hand-picked default, env override, search
-  candidates, online safe range); ``fit`` / serving / elastic / compile
-  pull their defaults through :func:`resolve` instead of inlining them.
-  With no artifact the registry is a behavior-neutral seam.
-* :mod:`~mxtpu.tune.config` — the **TunedConfig artifact**: a versioned
-  JSON of searched values + cost-model basis + probe evidence +
-  provenance, consumed with precedence
-  ``default < artifact < env < explicit argument`` by
-  ``Module.fit(tuned=)``, ``ServingSession(tuned=)`` and
-  ``ElasticConfig(tuned=)``; stale artifacts (knob-registry mismatch)
-  are rejected.
-* :mod:`~mxtpu.tune.cost` — the **cost model** seeded from the AOT
-  registry rows and per-bucket ``exec_ms``: predicts end-to-end
-  step/request cost per candidate without running it.
-* :mod:`~mxtpu.tune.search` — the **offline search driver**
-  (``python -m mxtpu.tune search``): model-ranked candidates, only the
-  top-K measured with short deterministic probes.
-* :mod:`~mxtpu.tune.online` — **online refinement**: a cadence
-  controller nudging the bounded knobs within search-certified safe
-  ranges from live telemetry, every adjustment recorded as telemetry
-  and artifact provenance.
-* :mod:`~mxtpu.tune.sweep` — the subprocess env-vector sweep backend
-  (``tools/flag_sweep.py`` is a thin wrapper over it).
+:mod:`~mxtpu.tune.registry` declares every tunable once (name, kind,
+default, environment override); ``fit`` / serving / decode / elastic /
+compile pull their values through :func:`resolve`, which holds the one
+precedence rule: default < environment < explicit argument.
 
 See docs/tune.md.
 """
 from __future__ import annotations
 
-from .registry import (Knob, catalog_rows, catalog_table, declare,
-                       get_knob, knobs, registry_version, resolve,
-                       resolve_int)
-from .config import SCHEMA, TunedConfig, active, artifact, use
+from .registry import (Knob, catalog_table, declare, get_knob, knobs,
+                       registry_version, resolve, resolve_int)
 
 __all__ = [
     "Knob", "declare", "get_knob", "knobs", "registry_version",
-    "resolve", "resolve_int", "catalog_rows", "catalog_table",
-    "TunedConfig", "use", "active", "artifact", "SCHEMA",
-    "CostModel", "search", "search_from_rows", "OnlineController",
+    "resolve", "resolve_int", "catalog_table",
 ]
-
-
-def __getattr__(name):
-    # the heavy halves (probes import serving/models) load on demand
-    if name in ("search", "search_from_rows", "probe_fit",
-                "probe_serving", "candidate_space", "enumerate_candidates"):
-        from . import searcher as _searcher
-        return getattr(_searcher, name)
-    if name == "CostModel":
-        from .cost import CostModel
-        return CostModel
-    if name == "OnlineController":
-        from .online import OnlineController
-        return OnlineController
-    if name in ("online", "cost", "sweep", "searcher"):
-        import importlib
-        return importlib.import_module("." + name, __name__)
-    raise AttributeError("module %r has no attribute %r"
-                         % (__name__, name))

@@ -1,34 +1,14 @@
-"""The knob registry: every tunable constant in the framework, declared.
+"""The knob registry: every declared tunable constant of the framework.
 
-Before this module every performance-critical constant was hand-picked
-at its call site: fit's in-flight depth buried in ``base_module.py``,
-serving's watermark in ``batcher.py``, the admission budgets in
-``admission.py`` defaults, elastic cadence in ``state.py``. The cost
-registry (PR-4) and the live telemetry (PR-2/PR-10) MEASURE everything,
-but nothing could systematically SEARCH the knob space because the
-knobs had no names, no domains, and no single resolution point.
+One :class:`Knob` declaration per tunable: name, owning subsystem,
+value kind, the default, and the environment name that overrides it.
+``fit`` / serving / decode / elastic / compile pull their defaults
+through :func:`resolve`, which holds the one precedence rule:
 
-This registry fixes the naming half: one :class:`Knob` declaration per
-tunable — name, owning subsystem, value kind, the hand-picked default
-(preserved bit-for-bit: with no artifact the registry is a
-behavior-neutral seam), the env override the subsystem already honored,
-the finite candidate list the offline search enumerates, and the
-certified safe range the online controller may nudge within.
+    default  <  environment variable  <  explicit argument
 
-Resolution precedence (the ``TunedConfig`` contract, enforced by
-:func:`resolve`):
-
-    hand-picked default  <  TunedConfig artifact  <  env var  <  explicit argument
-
-i.e. an operator's env override always beats the artifact, and an
-explicit keyword argument beats both — exactly the precedence every
-subsystem already implemented for default-vs-env-vs-arg, with the
-artifact slotted between default and env.
-
-``registry_version()`` fingerprints the declarations; a ``TunedConfig``
-saved against a different registry (knobs renamed, domains changed) is
-STALE and rejected at load — searched values for knobs that no longer
-mean the same thing must never be silently applied.
+``registry_version()`` fingerprints the declarations; the measurement
+corpus (``obs/corpus.py``) stamps its rows with it.
 
 This module is intentionally stdlib-only at import time: consumers
 (``compile.pipeline``, ``serving.pool``) resolve knobs during their own
@@ -38,52 +18,35 @@ from __future__ import annotations
 
 import hashlib
 import os
-import threading
 from collections import OrderedDict
 
 from ..analysis import concurrency as _conc
 
-__all__ = ["Knob", "declare", "get_knob", "knobs", "subsystems",
-           "registry_version", "resolve", "resolve_int", "catalog_rows",
-           "catalog_table"]
-
-_UNSET = object()
+__all__ = ["Knob", "declare", "get_knob", "knobs", "registry_version",
+           "resolve", "resolve_int", "catalog_table"]
 
 
 class Knob:
     """One declared tunable.
 
-    * ``name``       — dotted ``<subsystem>.<knob>`` id (artifact key);
-    * ``kind``       — ``int`` / ``float`` / ``bool`` / ``str`` /
-      ``choice``; the ``*_or_none`` suffix admits None ("auto" — the
-      consumer derives the value itself when the knob resolves to None);
-    * ``default``    — the hand-picked constant this knob replaces;
-    * ``env``        — the environment override the subsystem honored
-      before the registry existed (empty-string env values read as
-      unset);
-    * ``choices``    — legal values for ``choice`` kind;
-    * ``candidates`` — finite values the OFFLINE search enumerates
-      (None = not searched);
-    * ``safe_range`` — ``(lo, hi)`` the ONLINE controller may nudge
-      within (None = never adjusted live);
+    * ``name``       — dotted ``<subsystem>.<knob>`` id;
+    * ``kind``       — ``int`` / ``float`` / ``bool`` / ``str``; the
+      ``*_or_none`` suffix admits None ("auto" — the consumer derives
+      the value itself when the knob resolves to None);
+    * ``default``    — the value used when nothing overrides it;
+    * ``env``        — the environment override (empty-string env
+      values read as unset);
     * ``help``       — one line for the generated catalog.
     """
 
-    __slots__ = ("name", "subsystem", "kind", "default", "env", "choices",
-                 "candidates", "safe_range", "help")
+    __slots__ = ("name", "subsystem", "kind", "default", "env", "help")
 
-    def __init__(self, name, kind, default, env=None, choices=None,
-                 candidates=None, safe_range=None, help=""):
+    def __init__(self, name, kind, default, env=None, help=""):
         self.name = str(name)
         self.subsystem = self.name.split(".", 1)[0]
         self.kind = kind
         self.default = default
         self.env = env
-        self.choices = tuple(choices) if choices is not None else None
-        self.candidates = tuple(candidates) if candidates is not None \
-            else None
-        self.safe_range = tuple(safe_range) if safe_range is not None \
-            else None
         self.help = help
 
     # ------------------------------------------------------------ coerce
@@ -109,40 +72,11 @@ class Knob:
             return bool(value)
         if base == "str":
             return str(value)
-        if base == "choice":
-            v = str(value).lower()
-            if v not in self.choices:
-                raise ValueError("knob %s: %r not in %s"
-                                 % (self.name, value, list(self.choices)))
-            return v
         raise ValueError("knob %s: unknown kind %r" % (self.name, self.kind))
 
-    def clamp(self, value):
-        """Pin ``value`` inside the certified safe range (online nudges
-        must never leave it; no-op without one)."""
-        if self.safe_range is None:
-            return value
-        lo, hi = self.safe_range
-        if lo is not None and value < lo:
-            value = lo
-        if hi is not None and value > hi:
-            value = hi
-        return value
-
     def fingerprint(self):
-        """The part of the declaration an artifact's values depend on:
-        identity + semantics, NOT the default (retuning a default must
-        not strand every saved artifact)."""
-        return (self.name, self.kind, self.env, self.choices,
-                self.safe_range)
-
-    def to_dict(self):
-        return {"name": self.name, "subsystem": self.subsystem,
-                "kind": self.kind, "default": self.default,
-                "env": self.env, "choices": list(self.choices or ()) or None,
-                "candidates": list(self.candidates or ()) or None,
-                "safe_range": list(self.safe_range) if self.safe_range
-                else None, "help": self.help}
+        """Identity + semantics of the declaration, NOT the default."""
+        return (self.name, self.kind, self.env)
 
 
 _KNOBS = OrderedDict()
@@ -175,18 +109,9 @@ def knobs():
     return list(_KNOBS.values())
 
 
-def subsystems():
-    out = []
-    for k in _KNOBS.values():
-        if k.subsystem not in out:
-            out.append(k.subsystem)
-    return out
-
-
 def registry_version():
-    """Stable fingerprint of the declared knob set. A ``TunedConfig``
-    records the version it was searched against; a mismatch at load
-    means the knobs' semantics moved and the artifact is stale."""
+    """Stable fingerprint of the declared knob set (the measurement
+    corpus stamps its rows with it)."""
     h = hashlib.sha1()
     for name in sorted(_KNOBS):
         h.update(repr(_KNOBS[name].fingerprint()).encode())
@@ -194,18 +119,11 @@ def registry_version():
 
 
 # ------------------------------------------------------------------ resolve
-def resolve(name, explicit=None, artifact=_UNSET):
+def resolve(name, explicit=None):
     """The single knob-resolution point every subsystem pulls through.
 
-    ``explicit`` — the caller's keyword argument (None = not passed);
-    ``artifact`` — a :class:`~mxtpu.tune.TunedConfig` (or None), or
-    omitted to consult the process-active artifact
-    (:func:`mxtpu.tune.use` / ``MXTPU_TUNED``); pass ``False`` to
-    ignore any active artifact.
-
-    Precedence: default < artifact < env < explicit. With no artifact
-    present this reproduces the subsystem's historical
-    explicit-else-env-else-default behavior exactly.
+    ``explicit`` — the caller's keyword argument (None = not passed).
+    Precedence: default < environment < explicit.
     """
     knob = get_knob(name)
     if explicit is not None:
@@ -214,21 +132,13 @@ def resolve(name, explicit=None, artifact=_UNSET):
         raw = os.environ.get(knob.env)
         if raw is not None and raw.strip() != "":
             return knob.coerce(raw)
-    if artifact is not False:
-        if artifact is _UNSET or artifact is None:
-            from . import config as _config   # lazy: config imports us
-            artifact = _config.active()
-        if artifact is not None:
-            v = artifact.get(name, _UNSET)
-            if v is not _UNSET:
-                return knob.coerce(v)
     return knob.coerce(knob.default) if knob.default is not None else None
 
 
-def resolve_int(name, explicit=None, artifact=_UNSET, floor=None):
+def resolve_int(name, explicit=None, floor=None):
     """``resolve`` + integer floor — the common ``max(1, int(v))``
     pattern at the old call sites."""
-    v = resolve(name, explicit=explicit, artifact=artifact)
+    v = resolve(name, explicit=explicit)
     if v is None:
         return None
     v = int(v)
@@ -238,53 +148,41 @@ def resolve_int(name, explicit=None, artifact=_UNSET, floor=None):
 
 
 # ------------------------------------------------------------------ catalog
-def catalog_rows():
-    """JSON-ready catalog (docs/tune.md table + ``__main__ catalog``)."""
-    return [k.to_dict() for k in knobs()]
-
-
 def catalog_table():
     """The knob catalog as a markdown table — docs/tune.md embeds this
     output so the doc can be regenerated instead of hand-maintained."""
-    lines = ["| knob | kind | default | env | searched | safe range | "
-             "meaning |", "|---|---|---|---|---|---|---|"]
+    lines = ["| knob | kind | default | env | meaning |",
+             "|---|---|---|---|---|"]
     for k in knobs():
         default = "auto" if k.default is None else repr(k.default)
         lines.append(
-            "| `%s` | %s | %s | %s | %s | %s | %s |"
+            "| `%s` | %s | %s | %s | %s |"
             % (k.name, k.kind, default,
-               "`%s`" % k.env if k.env else "—",
-               ", ".join(repr(c) for c in k.candidates)
-               if k.candidates else "—",
-               "[%s, %s]" % k.safe_range if k.safe_range else "—",
-               k.help))
+               "`%s`" % k.env if k.env else "—", k.help))
     return "\n".join(lines)
 
 
 # =================================================================== catalog
-# The declarations. Defaults here ARE the hand-picked constants the
-# subsystems used to inline — docs/tune.md's table and the
-# behavior-neutrality test both read them from this single place.
+# The declarations. docs/tune.md's table and the defaults test both read
+# them from this single place.
 
 # --- fit (Module.fit async-pipeline knobs, docs/training_pipeline.md)
 declare("fit.max_in_flight", "int", 2, env="MXTPU_FIT_INFLIGHT",
-        candidates=(1, 2, 3, 4, 6, 8), safe_range=(1, 8),
         help="dispatched steps kept in flight before fit blocks on the "
              "oldest (pipeline depth)")
 declare("fit.metric_sync", "int_or_none", None, env="MXTPU_FIT_METRIC_SYNC",
-        candidates=(1, 4, 8, 16),
         help="device->host metric sync cadence in batches (auto: derived "
              "from the batch callbacks; 0 = epoch-end only)")
 declare("fit.device_metrics", "bool", True, env="MXTPU_FIT_DEVICE_METRICS",
         help="accumulate eval metrics on device via jitted kernels")
 declare("fit.device_prefetch", "bool", False,
-        env="MXTPU_FIT_DEVICE_PREFETCH", candidates=(False, True),
+        env="MXTPU_FIT_DEVICE_PREFETCH",
         help="stage batch N+1's device transfer from a producer thread "
              "while step N runs")
 declare("fit.batch_size", "int_or_none", None, env="MXTPU_FIT_BATCH_SIZE",
         help="training batch size for drivers that build their own "
-             "iterator (bench.py, tune probes); fit itself keeps the "
-             "caller's iterator")
+             "iterator; fit itself keeps the caller's iterator (no "
+             "reader in the tree)")
 declare("fit.remat", "str", "none", env="MXTPU_REMAT",
         help="selective rematerialization policy of the fused step: "
              "none/auto/block/conv/all (memory-capacity lever). "
@@ -296,24 +194,19 @@ declare("fit.remat", "str", "none", env="MXTPU_REMAT",
 # --- training health (device-resident stats + detectors,
 #     docs/observability.md "Training health")
 declare("health.cadence", "int", 1, env="MXTPU_HEALTH_CADENCE",
-        candidates=(1, 2, 4), safe_range=(1, 16),
         help="detector stride in metric-sync cadences: the stat rows "
              "land every sync, the detector suite runs every Nth")
 declare("health.window", "int", 8, env="MXTPU_HEALTH_WINDOW",
-        candidates=(4, 8, 16), safe_range=(2, 64),
         help="rolling-window length (in detector cadences) of the loss "
              "spike / divergence baselines")
 declare("health.spike_k", "float", 8.0, env="MXTPU_HEALTH_SPIKE_K",
-        safe_range=(2.0, 32.0),
         help="loss-spike threshold in MADs above the rolling median")
 
 # --- serving (ServingSession / batcher / admission, docs/serving.md)
 declare("serving.max_in_flight", "int", 2, env="MXTPU_SERVING_INFLIGHT",
-        candidates=(1, 2, 3, 4, 6), safe_range=(1, 8),
         help="device batches each dispatcher keeps in flight per replica")
 declare("serving.refill_watermark", "int_or_none", None,
-        env="MXTPU_SERVING_WATERMARK", candidates=(1, 2, 4, 8, 32),
-        safe_range=(1, 128),
+        env="MXTPU_SERVING_WATERMARK",
         help="pending rows that trigger an immediate refill of a freed "
              "slot (auto: derived from the measured per-bucket cost rows)")
 declare("serving.max_queue", "int", 256, env="MXTPU_SERVING_MAX_QUEUE",
@@ -325,16 +218,12 @@ declare("serving.max_delay_ms", "float", 5.0,
              "padded partial batch flushes")
 declare("serving.queue_wait_budget_ms", "float_or_none", None,
         env="MXTPU_SERVING_QUEUE_WAIT_BUDGET_MS",
-        candidates=(250.0, 500.0, 1000.0, 2000.0),
-        safe_range=(50.0, 10000.0),
         help="admission latency budget (auto: half the request timeout "
              "when set, else 1000ms)")
 declare("serving.watchdog_shed_s", "float", 10.0,
-        safe_range=(2.0, 60.0),
         help="no-progress seconds after which admission sheds (wedge "
              "signal)")
 declare("serving.min_mem_headroom", "float", 0.03,
-        safe_range=(0.01, 0.25),
         help="ledger headroom fraction below which admission sheds")
 declare("serving.queue_frac_shed", "float", 0.95,
         help="queue occupancy fraction at which admission sheds before "
@@ -352,41 +241,34 @@ declare("serving.warm_versions", "int", 4,
 
 # --- decode (stateful autoregressive decode serving, docs/decode.md)
 declare("decode.slot_capacity", "int", 8, env="MXTPU_DECODE_SLOTS",
-        candidates=(4, 8, 16, 32), safe_range=(1, 256),
         help="sequence slots in the device-resident decode state arena "
              "(in-flight sequences per DecodeSession)")
 declare("decode.max_new_tokens_default", "int", 32,
         env="MXTPU_DECODE_MAX_NEW_TOKENS",
-        candidates=(16, 32, 64, 128), safe_range=(1, 4096),
         help="generated-token budget a /v1/generate request gets when it "
              "does not name its own max_new_tokens")
 declare("decode.join_watermark", "int", 4,
         env="MXTPU_DECODE_JOIN_WATERMARK",
-        candidates=(1, 2, 4, 8), safe_range=(1, 64),
         help="requests allowed to queue while the slot arena is full "
              "before length-aware est-completion pricing starts "
              "shedding (429)")
 declare("decode.block_size", "int", 16, env="MXTPU_DECODE_BLOCK_SIZE",
-        candidates=(8, 16, 32, 64), safe_range=(1, 1024),
         help="tokens per KV-cache block in the paged decode arena "
              "(allocation granularity: a sequence holds "
              "ceil(tokens/block_size) blocks)")
 declare("decode.max_blocks_per_seq", "int", 16,
         env="MXTPU_DECODE_MAX_BLOCKS_PER_SEQ",
-        candidates=(8, 16, 32, 64), safe_range=(1, 512),
         help="block-table length per sequence slot — block_size × this "
              "is the per-request token budget AND the bucketed "
              "attention view's time extent")
 declare("decode.prefill_chunk_tokens", "int", 32,
         env="MXTPU_DECODE_PREFILL_CHUNK",
-        candidates=(16, 32, 64, 128), safe_range=(1, 4096),
         help="prompt tokens per chunked-prefill dispatch — the prefill "
              "latency quantum: a longer prompt never occupies the "
              "decode loop for more than one chunk per iteration")
 
 # --- elastic (async checkpoint cadence, docs/elastic.md)
 declare("elastic.every_n_steps", "int", 0, env="MXTPU_ELASTIC_EVERY_STEPS",
-        candidates=(0, 50, 200, 1000),
         help="mid-epoch snapshot cadence in global steps (0 = epoch "
              "boundaries only)")
 declare("elastic.epoch_period", "int", 1, env="MXTPU_ELASTIC_EPOCH_PERIOD",
@@ -395,28 +277,17 @@ declare("elastic.keep", "int", 2, env="MXTPU_ELASTIC_KEEP",
         help="checkpoint generations retained")
 
 # --- compile (the pipeline seam, docs/compile.md)
-# candidates are pipeline COMPOSITIONS, not single passes: tune.search
-# explores which subset of the transform catalog pays on a workload
-# instead of an operator hand-picking the pass list (the sequencing
-# itself is canonical — compile.pipeline normalizes the order)
 declare("compile.pipeline", "str", "", env="MXTPU_PIPELINE",
-        candidates=("", "bf16", "fuse_opt", "layout", "remat_reuse",
-                    "quant", "bf16,quant",
-                    "bf16,fuse_opt", "bf16,fuse_opt,remat_reuse",
-                    "bf16,fuse_opt,layout,remat_reuse",
-                    "bf16,quant,fuse_opt,layout,remat_reuse"),
         help="transform-pass list the compile pipeline runs (comma-"
              "separated registry names; empty = no rewrites)")
 declare("compile.fuse_opt_max_kb", "float", 32.0,
         env="MXTPU_FUSE_OPT_MAX_KB",
-        candidates=(8.0, 32.0, 128.0, 1024.0), safe_range=(1.0, 4096.0),
         help="fuse_opt class bound: only parameters at or under this "
              "many KB batch into a shared update region (small-param "
              "chains are launch-bound; big weight chains are bandwidth-"
              "bound and the stack would cost real movement)")
 declare("compile.remat_threshold", "float", 4.0,
         env="MXTPU_REMAT_THRESHOLD",
-        candidates=(1.0, 2.0, 4.0, 8.0, 16.0), safe_range=(0.25, 64.0),
         help="remat_reuse annotation bar: a node's residual is "
              "recomputed in backward when its recompute-flops per saved "
              "byte is at or below this ratio")
@@ -424,15 +295,12 @@ declare("compile.remat_threshold", "float", 4.0,
 # --- quant (int8 post-training quantization, docs/compile.md)
 declare("quant.calibration_percentile", "float", 99.9,
         env="MXTPU_QUANT_PERCENTILE",
-        candidates=(99.0, 99.9, 99.99, 100.0), safe_range=(90.0, 100.0),
         help="activation clipping statistic: per-batch percentile of "
              "|x| whose running max sets the per-tensor int8 scale "
              "(100.0 = plain abs-max, no clipping)")
 declare("quant.per_channel", "bool", True, env="MXTPU_QUANT_PER_CHANNEL",
-        candidates=(True, False),
         help="weight scales per output channel (axis 0) when on; one "
              "per-tensor scale per weight when off")
 declare("quant.min_layer_elems", "int", 64, env="MXTPU_QUANT_MIN_ELEMS",
-        candidates=(0, 64, 4096, 65536), safe_range=(0, 1 << 24),
         help="smallest weight (elements) the quant pass rewrites — "
              "below it the dequantize overhead beats the byte savings")

@@ -567,32 +567,32 @@ def test_armed_witness_decode_gate():
 
 
 # ------------------------------------------------------------- tuning
-def test_decode_knobs_resolve_through_tune():
-    """DecodeSession(tuned=) wiring: artifact beats default, env beats
-    artifact, explicit beats both (warmup=False keeps this compile-free)."""
-    cfg = mx.tune.TunedConfig(values={"decode.slot_capacity": 3,
-                                      "decode.max_new_tokens_default": 7,
-                                      "decode.join_watermark": 2})
-    s = _session(tuned=cfg, slot_capacity=None, warmup=False)
+def test_decode_knobs_resolve_through_tune(monkeypatch):
+    """DecodeSession's knobs: default < environment < explicit argument
+    (warmup=False keeps this compile-free)."""
+    for env in ("MXTPU_DECODE_SLOTS", "MXTPU_DECODE_MAX_NEW_TOKENS",
+                "MXTPU_DECODE_JOIN_WATERMARK"):
+        monkeypatch.delenv(env, raising=False)
+    s = _session(slot_capacity=None, warmup=False)
     try:
-        assert s.slot_capacity == 3
+        assert s.slot_capacity == 8
+        assert s.max_new_tokens_default == 32
+        assert s.join_watermark == 4
+    finally:
+        s.close()
+    monkeypatch.setenv("MXTPU_DECODE_SLOTS", "5")
+    monkeypatch.setenv("MXTPU_DECODE_MAX_NEW_TOKENS", "7")
+    monkeypatch.setenv("MXTPU_DECODE_JOIN_WATERMARK", "2")
+    s = _session(slot_capacity=None, warmup=False)
+    try:
+        assert s.slot_capacity == 5           # env beats default
         assert s.max_new_tokens_default == 7
         assert s.join_watermark == 2
     finally:
         s.close()
-    import os
-    os.environ["MXTPU_DECODE_SLOTS"] = "5"
+    s = _session(slot_capacity=4, warmup=False)
     try:
-        s = _session(tuned=cfg, slot_capacity=None, warmup=False)
-        try:
-            assert s.slot_capacity == 5       # env beats artifact
-        finally:
-            s.close()
-    finally:
-        del os.environ["MXTPU_DECODE_SLOTS"]
-    s = _session(tuned=cfg, slot_capacity=4, warmup=False)
-    try:
-        assert s.slot_capacity == 4           # explicit beats both
+        assert s.slot_capacity == 4           # explicit beats env
     finally:
         s.close()
 

@@ -118,11 +118,7 @@ def test_kill_at_step_resume_parity_bf16(tmp_path):
             assert v.dtype == np.float32  # masters, not bf16
     finally:
         os.environ.pop("MXTPU_PIPELINE", None)
-        # re-READ (env now unset -> empty) rather than pin an explicit
-        # (): an explicit configure marks the pipeline operator-pinned,
-        # which would block later TunedConfig artifacts (mxtpu.tune)
-        # from refreshing it for the rest of the process
-        P.configure(None)
+        P.configure(None)   # re-read: env now unset -> empty
 
 
 def test_kill_at_step_resume_parity_mesh(tmp_path):
@@ -155,6 +151,14 @@ def test_resume_from_epoch_boundary_snapshot(tmp_path):
     man = esnap.latest_manifest(prefix)
     assert man["cursor"]["epoch_boundary"] is True
     assert man["cursor"]["epoch"] == 0
+    # a manifest from before the tuner went may carry a "tuned" field:
+    # it is ignored and the generation still loads
+    path = esnap.manifest_path(prefix, man["_generation"])
+    raw = json.load(open(path))
+    raw["tuned"] = {"registry_version": "deadbeef0000",
+                    "values": {"fit.max_in_flight": 4}}
+    with open(path, "w") as f:
+        json.dump(raw, f)
     m_res, w_res, _ = _fit(resume=prefix, elastic=False)
     for k in w_full:
         np.testing.assert_array_equal(w_full[k], w_res[k], err_msg=k)

@@ -738,6 +738,6 @@ def test_supervisor_runs_through_shared_retry_policy():
 
 def test_point_guard_is_noop_when_disarmed():
     """The zero-overhead contract's functional half: with nothing armed
-    every point is a silent no-op (the µs cost is bench_faults.py's)."""
+    every point is a silent no-op."""
     for name in faults.POINTS:
         faults.point(name)

@@ -309,8 +309,7 @@ def test_corpus_health_row_roundtrip_and_torn_tail(tmp_path,
 def test_fit_health_adds_zero_sync_points():
     """Cadence exactness: the armed fit's jax.device_get call count
     equals the disarmed fit's — the stat window rides the metric
-    accum's one cadence transfer (the BENCH_health.json proof, as a
-    regression gate)."""
+    accum's one cadence transfer."""
     import jax
     it = _make_iter()
     mod_off = mx.mod.Module(_mlp.get_symbol(10), context=mx.cpu())

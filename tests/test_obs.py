@@ -18,12 +18,11 @@ the repo convention:
   capture is asserted exactly, not probabilistically;
 * **corpus** — N builds + M service rows round-trip to exactly N+M
   schema-valid rows, a writer killed mid-append leaves a tolerated torn
-  tail, and `summarize()` reproduces the ServiceLine fit `tune.search`
-  derives in-process.
+  tail, and `summarize()` carries the ServiceLine fitted to the
+  per-bucket means.
 """
 import json
 import os
-import subprocess
 import sys
 import threading
 import time
@@ -436,9 +435,18 @@ def test_corpus_torn_tail_tolerated_mid_file_raises(tmp_path,
         obs_corpus.load(d)
 
 
+def test_service_line_least_squares():
+    from mxtpu.obs.corpus import ServiceLine
+    line = ServiceLine.fit({1: {"exec_ms": 2.0}, 8: {"exec_ms": 3.0}})
+    assert line.basis == "bucket-rows"
+    assert line.fixed == pytest.approx(2.0 - line.marginal)
+    assert line(8) == pytest.approx(3.0)
+    assert line(1) == pytest.approx(2.0)
+
+
 def test_corpus_summarize_reproduces_service_line(tmp_path,
                                                   monkeypatch):
-    from mxtpu.tune.cost import ServiceLine
+    from mxtpu.obs.corpus import ServiceLine
     d = str(tmp_path)
     monkeypatch.setenv("MXTPU_CORPUS_DIR", d)
     obs_corpus.reset()
@@ -455,7 +463,6 @@ def test_corpus_summarize_reproduces_service_line(tmp_path,
     assert out["bucket_costs"] == want_costs
     assert out["bucket_counts"] == {1: 3, 8: 2, 32: 1}
     assert out["source_ms_mean"]["fit_step"] == 33.0
-    # offline == online: the exact fit tune.search runs in-process
     assert out["service_line"] == ServiceLine.fit(want_costs).to_dict()
 
 
@@ -485,25 +492,3 @@ def test_corpus_disabled_is_free(monkeypatch):
     assert obs_corpus.record_service("serving", 1.0) is False
     assert obs_corpus.record_build(_build_row(0)) is False
     assert obs_corpus.load(None) == []
-
-
-# ------------------------------------------------------------ CI tools
-def test_check_bench_basis_flags_missing_basis(tmp_path):
-    tool = os.path.join(ROOT, "tools", "check_bench_basis.py")
-    # a verdict without any basis block fails
-    with open(str(tmp_path / "BENCH_bad.json"), "w") as f:
-        json.dump({"speedup": 3.2, "pass": True}, f)
-    proc = subprocess.run([sys.executable, tool, "--root",
-                           str(tmp_path)],
-                          capture_output=True, text=True)
-    assert proc.returncode == 1 and "BENCH_bad.json" in proc.stdout
-    # raw run logs and basis-carrying verdicts pass
-    with open(str(tmp_path / "BENCH_bad.json"), "w") as f:
-        json.dump({"speedup": 3.2, "pass": True,
-                   "verdict_basis": "min-of-5 trials, n=4096"}, f)
-    with open(str(tmp_path / "BENCH_r99.json"), "w") as f:
-        json.dump({"cmd": "python x.py", "rc": 0, "tail": ""}, f)
-    proc = subprocess.run([sys.executable, tool, "--root",
-                           str(tmp_path)],
-                          capture_output=True, text=True)
-    assert proc.returncode == 0, proc.stdout + proc.stderr

@@ -12,6 +12,7 @@ Tier-1 (CPU, `not slow`). Contracts under test:
   to a warm-cached version costs zero compiles.
 """
 import json
+import math
 import threading
 import time
 import urllib.error
@@ -28,6 +29,7 @@ from mxtpu.serving import (ACCEPTING, DEGRADED, SHEDDING, AdmissionShed,
                            ServingHTTPServer, ServingSession,
                            SignalAdmissionPolicy, derive_knobs, pad_rows,
                            prewarm)
+from mxtpu.serving.admission import mix_service_model
 
 
 def _rand(shape, seed):
@@ -406,3 +408,107 @@ def test_version_endpoint_and_debug_panels():
     finally:
         server.shutdown()
         server.server_close()
+
+
+# --------------------------------------------- mix-aware admission (ISSUE)
+def test_mix_service_model_learns_live_mix():
+    buckets = (1, 128)
+    cost_rows = {1: {"exec_ms": 2.0}, 128: {"exec_ms": 50.0}}
+    prior = mix_service_model({}, cost_rows, buckets)
+    assert prior["basis"] == "cost-rows"
+    assert prior["est_batch_ms"] == 50.0
+    assert prior["est_rows_per_batch"] == 128.0
+    live = mix_service_model({1: (20, 2.0)}, cost_rows, buckets)
+    assert live["basis"] == "live-mix"
+    assert live["est_batch_ms"] == pytest.approx(2.0)   # tracks measured
+    assert live["est_rows_per_batch"] == pytest.approx(1.0)
+    # a mixed stream weights by traffic, not by the largest bucket
+    mixed = mix_service_model({1: (30, 2.0), 128: (10, 50.0)},
+                              cost_rows, buckets)
+    assert mixed["est_batch_ms"] == pytest.approx((30 * 2 + 10 * 50) / 40)
+    assert mixed["est_rows_per_batch"] == pytest.approx(
+        (30 * 1 + 10 * 128) / 40)
+
+
+def test_mix_aware_estimate_stops_over_shedding():
+    """The ROADMAP item-1 acceptance: a small-bucket-heavy mix must not
+    be priced at largest-bucket service. 4 pending single-row requests
+    + 2 small batches in flight: the old largest-bucket model estimates
+    3 batches x 50ms = 150ms and SHEDS at a 100ms budget; the live mix
+    (bucket-1 batches measured at 2ms) estimates 12ms and ADMITS —
+    tracking the measured per-bucket service, not the shape assumption."""
+    buckets = (1, 128)
+    cost_rows = {1: {"exec_ms": 2.0}, 128: {"exec_ms": 50.0}}
+    pol = SignalAdmissionPolicy(queue_wait_budget_ms=100.0)
+
+    def signals(model, pending, inflight):
+        batches = math.ceil(pending / model["est_rows_per_batch"]) \
+            + inflight
+        return AdmissionSignals(
+            queue_depth=pending, queue_limit=256, pending_rows=pending,
+            inflight_depth=inflight, inflight_limit=4, replicas=1,
+            est_batch_ms=model["est_batch_ms"],
+            est_queue_wait_ms=model["est_batch_ms"] * batches)
+
+    prior = mix_service_model({}, cost_rows, buckets)
+    live = mix_service_model({1: (20, 2.0)}, cost_rows, buckets)
+    assert pol.decide(signals(prior, 4, 2)).admit is False   # over-shed
+    d = pol.decide(signals(live, 4, 2))
+    assert d.admit is True                                   # mix-aware
+
+
+def test_serving_session_service_model_goes_mix_aware():
+    sym_json, params, shapes = get_fixture("mlp", seed=0)
+    with ServingSession(sym_json, params, shapes,
+                                   buckets=(1, 8), warmup=True) as s:
+        pre = s._service_model()
+        assert pre["basis"] == "cost-rows"
+        assert pre["est_rows_per_batch"] == 8.0
+        # a skewed single-row mix lands in the per-worker aggregates
+        # (the same call the dispatcher makes at retire time)
+        for _ in range(16):
+            s._record_service(0, 1, 2.0)
+        post = s._service_model()
+        assert post["basis"] == "live-mix"
+        assert post["est_batch_ms"] == pytest.approx(2.0, rel=0.1)
+        assert post["est_rows_per_batch"] == pytest.approx(1.0)
+        assert s._est_batch_ms() == pytest.approx(2.0, rel=0.1)
+        # the signals consume the learned mix
+        sig = s._signals()
+        assert sig.est_batch_ms == pytest.approx(2.0, rel=0.1)
+        # ...and the same observations were mirrored into the labeled
+        # telemetry series for dashboards
+        h = s.metrics.histogram("batch_service_ms",
+                                labels={"bucket": "1"})
+        assert h.count == 16
+
+
+def test_swap_model_resets_service_aggregates():
+    """A hot-swapped model has a new service profile: the mix-aware
+    estimate must re-learn from its batches, not price them with the
+    old model's history."""
+    sym_json, params, shapes = get_fixture("mlp", seed=0)
+    with ServingSession(sym_json, params, shapes,
+                                   buckets=(1, 8), warmup=False) as s:
+        for _ in range(16):
+            s._record_service(0, 1, 2.0)
+        assert s._service_model()["basis"] == "live-mix"
+        s.swap_model(sym_json, params, version_tag="v-next", warmup=False)
+        assert s._service_model()["basis"] != "live-mix"
+        assert all(not d for d in s._bucket_service)
+
+
+def test_serving_traffic_populates_per_bucket_series():
+    """End to end: real single-row traffic produces labeled per-bucket
+    service observations (the series the estimate learns from)."""
+    sym_json, params, shapes = get_fixture("mlp", seed=0)
+    rng = np.random.RandomState(0)
+    payload = {"data": rng.rand(*shapes["data"]).astype(np.float32)}
+    with ServingSession(sym_json, params, shapes,
+                                   buckets=(1, 8), warmup=True,
+                                   max_delay_ms=1.0) as s:
+        for _ in range(12):
+            s.predict(payload, timeout=30)
+        labeled = [m for m in s.metrics.series()
+                   if m.name == "batch_service_ms" and m.labels]
+        assert labeled and sum(m.count for m in labeled) > 0

@@ -1,22 +1,12 @@
-"""mxtpu.tune: knob registry, TunedConfig artifact, search, online
-refinement, and the mix-aware admission estimate.
+"""mxtpu.tune: the knob registry and its one precedence rule.
 
-Covers the ISSUE-11 acceptance surface:
-
-* the registry is a behavior-neutral seam (no artifact => the
-  hand-picked defaults, bit-identical);
-* precedence ``default < artifact < env < explicit argument`` across
-  fit, serving and elastic;
-* artifact save/load roundtrip + stale-artifact rejection
-  (knob-registry version mismatch);
-* seeded-search determinism (same registry rows -> same winner);
-* the online controller nudges only within certified safe ranges and
-  records every adjustment (telemetry + provenance);
-* admission's queue-wait estimate learns the live per-bucket mix
-  instead of assuming largest-bucket-shaped service.
+* with the environment clean every knob resolves to its declared
+  default;
+* precedence ``default < environment < explicit argument``, for every
+  declared knob and at the call sites that resolve them (fit, serving,
+  elastic, the compile pipeline);
+* every declared knob is in docs/tune.md.
 """
-import json
-import math
 import os
 
 import numpy as np
@@ -24,28 +14,17 @@ import pytest
 
 import mxtpu as mx
 from mxtpu import tune
-from mxtpu.base import MXNetError
-from mxtpu.serving.admission import (SignalAdmissionPolicy,
-                                     AdmissionSignals, mix_service_model)
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
-#: every env var a tune test may flip — cleared around each test so the
-#: suite's own environment never leaks into precedence assertions
-_ENVS = ("MXTPU_TUNED", "MXTPU_FIT_INFLIGHT", "MXTPU_FIT_METRIC_SYNC",
-         "MXTPU_SERVING_INFLIGHT", "MXTPU_SERVING_MAX_QUEUE",
-         "MXTPU_ELASTIC_EVERY_STEPS", "MXTPU_ELASTIC_KEEP",
-         "MXTPU_PIPELINE")
-
 
 @pytest.fixture(autouse=True)
-def _clean_tune(monkeypatch):
-    from mxtpu.tune import config as tcfg
-    for e in _ENVS:
-        monkeypatch.delenv(e, raising=False)
-    tcfg._reset_for_tests()
-    yield
-    tcfg._reset_for_tests()
+def _clean_env(monkeypatch):
+    """No knob's environment name is set while a test of this file runs,
+    so the suite's own environment never leaks into an assertion."""
+    for k in tune.knobs():
+        if k.env:
+            monkeypatch.delenv(k.env, raising=False)
 
 
 def _mlp_module_and_iter(steps=4, batch=16, seed=0):
@@ -60,8 +39,8 @@ def _mlp_module_and_iter(steps=4, batch=16, seed=0):
 
 # ------------------------------------------------------------------ registry
 def test_registry_defaults_are_the_hand_picked_constants():
-    """The behavior-neutral contract: with no artifact and no env, every
-    knob resolves to the constant its call site used to inline."""
+    """With no env, every knob resolves to the constant its call site
+    used to inline."""
     expect = {"fit.max_in_flight": 2, "fit.metric_sync": None,
               "fit.device_metrics": True, "fit.device_prefetch": False,
               "fit.remat": "none",
@@ -78,32 +57,60 @@ def test_registry_defaults_are_the_hand_picked_constants():
               "elastic.every_n_steps": 0, "elastic.epoch_period": 1,
               "elastic.keep": 2, "compile.pipeline": ""}
     for name, want in expect.items():
-        assert tune.resolve(name, artifact=False) == want, name
+        assert tune.resolve(name) == want, name
 
 
-def test_registry_precedence_artifact_env_explicit(monkeypatch):
-    cfg = tune.TunedConfig(values={"fit.max_in_flight": 4})
-    # default < artifact
-    assert tune.resolve("fit.max_in_flight", artifact=cfg) == 4
-    # artifact < env
+def _case(knob):
+    """For a knob's kind: the Python type a resolved value has, a string
+    an operator would put in the environment (never the declared
+    default), what it parses to, and an explicit argument that differs
+    from that."""
+    if knob.kind == "bool":
+        return (bool, "0" if knob.default else "1", not knob.default,
+                knob.default)
+    return {"int": (int, "7", 7, 9),
+            "float": (float, "123.5", 123.5, 77.25),
+            "str": (str, "bf16", "bf16", "layout"),
+            }[knob.kind.replace("_or_none", "")]
+
+
+@pytest.mark.parametrize("name", [k.name for k in tune.knobs()])
+def test_knob_default_env_explicit(name, monkeypatch):
+    """default < environment < explicit argument, for every declared
+    knob: the one rule of ``tune.resolve``."""
+    knob = tune.get_knob(name)
+    typ, raw, parsed, explicit = _case(knob)
+    assert parsed != knob.default and explicit != parsed
+
+    got = tune.resolve(name)
+    assert got == knob.default
+    assert got is None or type(got) is typ
+
+    if knob.env:
+        monkeypatch.setenv(knob.env, raw)
+        got = tune.resolve(name)
+        assert got == parsed and type(got) is typ
+        # an empty string reads as unset (not a crash, not a zero)
+        monkeypatch.setenv(knob.env, "")
+        assert tune.resolve(name) == knob.default
+        monkeypatch.setenv(knob.env, raw)
+
+    got = tune.resolve(name, explicit=explicit)
+    assert got == explicit and type(got) is typ
+
+
+def test_registry_precedence_default_env_explicit(monkeypatch):
+    # default
+    assert tune.resolve("fit.max_in_flight") == 2
+    assert tune.resolve_int("fit.max_in_flight", floor=4) == 4
+    # default < env
     monkeypatch.setenv("MXTPU_FIT_INFLIGHT", "6")
-    assert tune.resolve("fit.max_in_flight", artifact=cfg) == 6
+    assert tune.resolve("fit.max_in_flight") == 6
     # env < explicit
-    assert tune.resolve("fit.max_in_flight", explicit=3, artifact=cfg) == 3
-    # empty env string reads as unset (not a crash, not a zero)
-    monkeypatch.setenv("MXTPU_FIT_INFLIGHT", "")
-    assert tune.resolve("fit.max_in_flight", artifact=cfg) == 4
-
-
-def test_registry_active_artifact_via_use():
-    cfg = tune.TunedConfig(values={"serving.max_queue": 64})
-    tune.use(cfg)
-    try:
-        assert tune.resolve("serving.max_queue") == 64
-        # artifact=False opts a call site out of the ambient artifact
-        assert tune.resolve("serving.max_queue", artifact=False) == 256
-    finally:
-        tune.use(None)
+    assert tune.resolve("fit.max_in_flight", explicit=3) == 3
+    assert tune.resolve_int("fit.max_in_flight", explicit=0, floor=1) == 1
+    # an "auto" knob stays None through resolve_int
+    assert tune.resolve_int("fit.metric_sync") is None
 
 
 def test_registry_version_is_stable_and_knob_sensitive():
@@ -123,162 +130,43 @@ def test_bool_coercion_matches_env_contract():
     assert k.coerce(False) is False
 
 
-# ------------------------------------------------------------------ artifact
-def test_tuned_config_roundtrip(tmp_path):
-    cfg = tune.TunedConfig(
-        values={"fit.max_in_flight": "4", "serving.refill_watermark": 8},
-        basis={"fixture": "mlp"}, evidence=[{"stage": "probe"}],
-        created="2026-08-04T00:00:00")
-    cfg.record("offline-search", top_k=2)
-    path = str(tmp_path / "tuned.json")
-    cfg.save(path)
-    back = tune.TunedConfig.load(path)
-    assert back.values == {"fit.max_in_flight": 4,   # coerced int
-                           "serving.refill_watermark": 8}
-    assert back.basis == {"fixture": "mlp"}
-    assert back.evidence == [{"stage": "probe"}]
-    assert back.provenance[0]["event"] == "offline-search"
-    assert back.registry_version == tune.registry_version()
-    assert not back.stale
-
-
-def test_stale_artifact_rejected(tmp_path):
-    path = str(tmp_path / "stale.json")
-    raw = tune.TunedConfig(values={"fit.max_in_flight": 4}).to_dict()
-    raw["registry_version"] = "deadbeef0000"   # a different knob registry
-    with open(path, "w") as f:
-        json.dump(raw, f)
-    # strict (explicit tuned= / tune.use): loud rejection
-    with pytest.raises(MXNetError, match="STALE"):
-        tune.TunedConfig.load(path)
-    with pytest.raises(MXNetError, match="STALE"):
-        tune.use(path)
-    # ambient env path: ignored with a log, never applied
-    assert tune.TunedConfig.load(path, strict=False) is None
-
-
-def test_ambient_env_artifact_applies_and_stale_is_ignored(tmp_path,
-                                                           monkeypatch):
-    from mxtpu.tune import config as tcfg
-    good = str(tmp_path / "good.json")
-    tune.TunedConfig(values={"fit.max_in_flight": 5}).save(good)
-    monkeypatch.setenv("MXTPU_TUNED", good)
-    tcfg._reset_for_tests()
-    assert tune.resolve("fit.max_in_flight") == 5
-    stale = str(tmp_path / "stale.json")
-    raw = tune.TunedConfig(values={"fit.max_in_flight": 7}).to_dict()
-    raw["registry_version"] = "deadbeef0000"
-    with open(stale, "w") as f:
-        json.dump(raw, f)
-    monkeypatch.setenv("MXTPU_TUNED", stale)
-    tcfg._reset_for_tests()
-    assert tune.resolve("fit.max_in_flight") == 2   # the default survives
-
-
 def test_unknown_knob_rejected():
-    with pytest.raises(MXNetError, match="unknown knob"):
-        tune.TunedConfig(values={"fit.no_such_knob": 1})
-
-
-# ------------------------------------------------------------------- search
-_ROWS = {1: {"exec_ms": 2.0, "flops": 1e6},
-         8: {"exec_ms": 3.0, "flops": 8e6}}
-_FIT_BASIS = {"step_exec_ms": 5.0, "dispatch_ms": 1.0,
-              "metric_sync_ms": 2.0, "assemble_ms": 0.5}
-
-
-def test_seeded_search_determinism():
-    """Same registry rows -> same winner, bit for bit (the ranking is
-    pure arithmetic; enumeration order is the tiebreak)."""
-    w1, r1, _ = tune.search_from_rows(bucket_costs=_ROWS,
-                                      fit_basis=_FIT_BASIS,
-                                      buckets=(1, 8))
-    w2, r2, _ = tune.search_from_rows(bucket_costs=dict(_ROWS),
-                                      fit_basis=dict(_FIT_BASIS),
-                                      buckets=(1, 8))
-    assert w1 == w2
-    assert r1["fit"] == r2["fit"]
-    assert r1["serving"] == r2["serving"]
-    # the winner carries exactly the searched knobs
-    assert set(w1) == {"fit.max_in_flight", "fit.metric_sync",
-                       "fit.device_prefetch", "serving.max_in_flight",
-                       "serving.refill_watermark"}
-
-
-def test_cost_model_tradeoffs_are_monotone():
-    m = tune.CostModel(bucket_costs=_ROWS, fit_basis=_FIT_BASIS)
-    # deeper fit window: never slower (pacing amortizes)
-    s = [m.predict_step_ms(k, 4) for k in (1, 2, 4, 8)]
-    assert s == sorted(s, reverse=True)
-    # sparser metric sync: never slower
-    s = [m.predict_step_ms(2, c) for c in (1, 4, 16)]
-    assert s == sorted(s, reverse=True)
-    # prefetch hides the assembly stall
-    assert m.predict_step_ms(2, 4, True) < m.predict_step_ms(2, 4, False)
-    # deeper serving window hides dispatch overhead
-    assert m.predict_request_ms(4, 4, buckets=(1, 8)) < \
-        m.predict_request_ms(4, 1, buckets=(1, 8))
-    # predicted sync points: exact arithmetic
-    assert m.predict_sync_points(2, 1, steps=24) == 22 + 24 + 1
-    assert m.predict_sync_points(8, 16, steps=24) == 16 + 1 + 1
-
-
-def test_service_line_least_squares():
-    from mxtpu.tune.cost import ServiceLine
-    line = ServiceLine.fit({1: {"exec_ms": 2.0}, 8: {"exec_ms": 3.0}})
-    assert line.basis == "bucket-rows"
-    assert line.fixed == pytest.approx(2.0 - line.marginal)
-    assert line(8) == pytest.approx(3.0)
-    assert line(1) == pytest.approx(2.0)
+    with pytest.raises(KeyError, match="unknown knob"):
+        tune.resolve("fit.no_such_knob")
 
 
 # ------------------------------------------------------- fit integration
 def test_fit_resolves_knobs_with_precedence(monkeypatch):
-    cfg = tune.TunedConfig(values={"fit.max_in_flight": 4,
-                                   "fit.metric_sync": 8})
     mod, it = _mlp_module_and_iter()
-    mod.fit(it, num_epoch=1, eval_metric="acc", tuned=cfg)
-    assert mod._fit_knobs["fit.max_in_flight"] == 4
-    assert mod._fit_knobs["fit.metric_sync"] == 8
-    # env beats artifact
+    # env beats default
     monkeypatch.setenv("MXTPU_FIT_INFLIGHT", "3")
-    it.reset()
-    mod.fit(it, num_epoch=1, eval_metric="acc", tuned=cfg,
-            force_init=False)
+    monkeypatch.setenv("MXTPU_FIT_METRIC_SYNC", "8")
+    mod.fit(it, num_epoch=1, eval_metric="acc")
     assert mod._fit_knobs["fit.max_in_flight"] == 3
+    assert mod._fit_knobs["fit.metric_sync"] == 8
     # explicit beats env
     it.reset()
-    mod.fit(it, num_epoch=1, eval_metric="acc", tuned=cfg,
-            max_in_flight=1, force_init=False)
+    mod.fit(it, num_epoch=1, eval_metric="acc", max_in_flight=1,
+            metric_sync=2, force_init=False)
     assert mod._fit_knobs["fit.max_in_flight"] == 1
-
-
-def test_fit_artifact_metric_sync_reconciles_with_speedometer():
-    """An artifact cadence must not bypass the callback contract: every
-    Speedometer window boundary stays a sync batch (gcd), and the
-    searched cadence applies as-is only when no callbacks constrain
-    it. Explicit/env values still preempt (user's call)."""
-    from mxtpu import callback as cb
-    cfg = tune.TunedConfig(values={"fit.metric_sync": 16})
-    mod, it = _mlp_module_and_iter(steps=4)
-    mod.fit(it, num_epoch=1, eval_metric="acc", tuned=cfg,
-            batch_end_callback=cb.Speedometer(16, frequent=10, log=False))
-    # gcd(10, 16) = 2 — never sparser than the meter boundaries allow
     assert mod._fit_knobs["fit.metric_sync"] == 2
-    it.reset()
-    mod.fit(it, num_epoch=1, eval_metric="acc", tuned=cfg,
-            force_init=False)
-    # no callbacks: the searched cadence applies directly
-    assert mod._fit_knobs["fit.metric_sync"] == 16
 
 
-def test_fit_without_artifact_uses_defaults():
+def test_fit_uses_defaults():
+    from mxtpu import callback as cb
     mod, it = _mlp_module_and_iter(steps=2)
     mod.fit(it, num_epoch=1, eval_metric="acc")
     assert mod._fit_knobs["fit.max_in_flight"] == 2
     assert mod._fit_knobs["fit.device_metrics"] is True
     assert mod._fit_knobs["fit.device_prefetch"] is False
     assert mod._fit_knobs["fit.metric_sync"] == 0   # no batch callbacks
+    # the callback-derived default: every Speedometer window boundary is
+    # a sync batch (gcd of the meters' frequents)
+    it.reset()
+    mod.fit(it, num_epoch=1, eval_metric="acc", force_init=False,
+            batch_end_callback=[cb.Speedometer(16, frequent=10, log=False),
+                                cb.Speedometer(16, frequent=4, log=False)])
+    assert mod._fit_knobs["fit.metric_sync"] == 2
 
 
 # --------------------------------------------------- serving integration
@@ -289,29 +177,28 @@ def _serving_fixture():
 
 def test_serving_session_resolves_knobs_with_precedence(monkeypatch):
     sym_json, params, shapes = _serving_fixture()
-    cfg = tune.TunedConfig(values={"serving.max_in_flight": 5,
-                                   "serving.max_queue": 64,
-                                   "serving.refill_watermark": 4,
-                                   "serving.queue_wait_budget_ms": 321.0})
+    monkeypatch.setenv("MXTPU_SERVING_INFLIGHT", "6")
+    monkeypatch.setenv("MXTPU_SERVING_MAX_QUEUE", "64")
+    monkeypatch.setenv("MXTPU_SERVING_WATERMARK", "4")
+    monkeypatch.setenv("MXTPU_SERVING_QUEUE_WAIT_BUDGET_MS", "321.0")
     with mx.serving.ServingSession(sym_json, params, shapes,
-                                   buckets=(1, 8), warmup=False,
-                                   tuned=cfg) as s:
-        assert s.max_in_flight == 5
+                                   buckets=(1, 8), warmup=False) as s:
+        assert s.max_in_flight == 6           # env beats default
         assert s.batcher.max_queue == 64
         assert s.batcher.refill_watermark == 4
         assert s._admission.queue_wait_budget_ms == 321.0
-    monkeypatch.setenv("MXTPU_SERVING_INFLIGHT", "6")
     with mx.serving.ServingSession(sym_json, params, shapes,
                                    buckets=(1, 8), warmup=False,
-                                   tuned=cfg) as s:
-        assert s.max_in_flight == 6           # env beats artifact
-    with mx.serving.ServingSession(sym_json, params, shapes,
-                                   buckets=(1, 8), warmup=False,
-                                   tuned=cfg, max_in_flight=1) as s:
+                                   max_in_flight=1, max_queue=32,
+                                   refill_watermark=2,
+                                   queue_wait_budget_ms=99.0) as s:
         assert s.max_in_flight == 1           # explicit beats env
+        assert s.batcher.max_queue == 32
+        assert s.batcher.refill_watermark == 2
+        assert s._admission.queue_wait_budget_ms == 99.0
 
 
-def test_serving_session_defaults_unchanged_without_artifact():
+def test_serving_session_defaults():
     sym_json, params, shapes = _serving_fixture()
     with mx.serving.ServingSession(sym_json, params, shapes,
                                    buckets=(1, 8), warmup=False) as s:
@@ -324,214 +211,40 @@ def test_serving_session_defaults_unchanged_without_artifact():
 
 # --------------------------------------------------- elastic integration
 def test_elastic_config_resolves_knobs(tmp_path, monkeypatch):
-    cfg = tune.TunedConfig(values={"elastic.every_n_steps": 50,
-                                   "elastic.keep": 5})
-    ec = mx.elastic.ElasticConfig(str(tmp_path / "ck"), tuned=cfg)
-    assert ec.every_n_steps == 50 and ec.keep == 5 and ec.epoch_period == 1
-    monkeypatch.setenv("MXTPU_ELASTIC_KEEP", "7")
-    ec = mx.elastic.ElasticConfig(str(tmp_path / "ck"), tuned=cfg)
-    assert ec.keep == 7                        # env beats artifact
-    ec = mx.elastic.ElasticConfig(str(tmp_path / "ck"), tuned=cfg, keep=3)
-    assert ec.keep == 3                        # explicit beats env
     ec = mx.elastic.ElasticConfig(str(tmp_path / "ck"))
-    assert ec.every_n_steps == 0 and ec.keep == 7  # env only
+    assert ec.every_n_steps == 0 and ec.keep == 2 and ec.epoch_period == 1
+    monkeypatch.setenv("MXTPU_ELASTIC_KEEP", "7")
+    monkeypatch.setenv("MXTPU_ELASTIC_EVERY_STEPS", "50")
+    ec = mx.elastic.ElasticConfig(str(tmp_path / "ck"))
+    assert ec.keep == 7 and ec.every_n_steps == 50   # env beats default
+    ec = mx.elastic.ElasticConfig(str(tmp_path / "ck"), keep=3)
+    assert ec.keep == 3                        # explicit beats env
+    assert ec.every_n_steps == 50              # env only
 
 
 # --------------------------------------------------- compile integration
 def test_compile_pipeline_knob(monkeypatch):
     from mxtpu.compile import pipeline
     try:
-        # an earlier test may have left the pipeline operator-pinned
-        # (explicit configure()); un-pin so the refresh path is testable
-        pipeline.configure(None)
-        cfg = tune.TunedConfig(values={"compile.pipeline": "bf16"})
-        # use() refreshes the module's import-time snapshot itself — an
-        # artifact installed AFTER import must still apply (bench.py
-        # --tuned installs it long after `import mxtpu`)
-        tune.use(cfg)
-        assert pipeline.configured() == ("bf16",)
-        # a SET env var always wins — including set-but-empty ("off")
-        monkeypatch.setenv("MXTPU_PIPELINE", "")
-        assert pipeline.configure(None) == ()
-        monkeypatch.delenv("MXTPU_PIPELINE")
-        tune.use(None)
-        assert pipeline.configured() == ()
-        # an explicit configure() pins the pipeline against refreshes
-        # (explicit beats artifact, like everywhere in the precedence)
-        pipeline.configure(["bf16"])
-        tune.use(tune.TunedConfig(values={"compile.pipeline": ""}))
+        assert pipeline.configure(None) == ()          # the default
+        monkeypatch.setenv("MXTPU_PIPELINE", "bf16, layout")
+        assert pipeline.configure(None) == ("bf16", "layout")
+        # set-but-empty and the "off" spellings read as the empty pipeline
+        for off in ("", "0", "none", "off"):
+            monkeypatch.setenv("MXTPU_PIPELINE", off)
+            assert pipeline.configure(None) == ()
+        # an explicit configure() beats the environment; a scope restores
+        monkeypatch.setenv("MXTPU_PIPELINE", "layout")
+        assert pipeline.configure(["bf16"]) == ("bf16",)
+        with pipeline.pipeline_scope(()):
+            assert pipeline.configured() == ()
         assert pipeline.configured() == ("bf16",)
     finally:
-        tune.use(None)
-        pipeline.configure(None)   # back to env/artifact-derived (empty)
+        monkeypatch.delenv("MXTPU_PIPELINE", raising=False)
+        pipeline.configure(None)   # back to the env-derived (empty) one
 
 
-# --------------------------------------------------------------- online
-def test_online_controller_nudges_within_safe_range():
-    ctl = tune.OnlineController(artifact=tune.TunedConfig())
-    holder = {"v": 2}
-    ctl.bind_holder("fit.max_in_flight", holder)
-    sig = {"fit_pacing_waits": 5, "fit_sync_wait_mean_ms": 3.0,
-           "fit_dispatch_mean_ms": 1.0}
-    adjs = ctl.step(signals=sig)
-    assert holder["v"] == 3
-    assert adjs and adjs[0]["knob"] == "fit.max_in_flight"
-    # repeated pressure saturates at the certified hi bound, never past
-    for _ in range(20):
-        ctl.step(signals=sig)
-    lo, hi = tune.get_knob("fit.max_in_flight").safe_range
-    assert holder["v"] == hi
-    # memory pressure backs off, floored at the lo bound
-    for _ in range(20):
-        ctl.step(signals={"mem_headroom_frac": 0.01})
-    assert holder["v"] == lo
-    # every adjustment is provenance-logged with its signals
-    ev = [e for e in ctl.artifact.provenance
-          if e["event"] == "online-adjust"]
-    assert len(ev) >= 2
-    assert all("signals" in e and "from" in e and "to" in e for e in ev)
-    # ...and mirrored as telemetry
-    reg = mx.telemetry.registry()
-    c = reg.counter("tune_adjustments",
-                    labels={"knob": "fit.max_in_flight"})
-    assert c.value >= len(ev)
-
-
-def test_online_controller_refuses_unranged_knobs():
-    ctl = tune.OnlineController()
-    with pytest.raises(ValueError, match="safe_range"):
-        ctl.bind_holder("serving.max_queue", {"v": 256})
-
-
-def test_online_controller_binds_serving_session():
-    sym_json, params, shapes = _serving_fixture()
-    with mx.serving.ServingSession(sym_json, params, shapes,
-                                   buckets=(1, 8), warmup=False) as s:
-        ctl = tune.OnlineController().bind_session(s)
-        assert s.max_in_flight == 2
-        adjs = ctl.step(signals={"idle_gaps": 2, "queue_depth": 3})
-        assert s.max_in_flight == 3 and adjs
-        # the dispatcher loop re-reads the live value; the sampler sees
-        # the session's registries without error
-        assert isinstance(ctl.sample(), dict)
-
-
-def test_fit_binds_inflight_holder_to_active_controller():
-    ctl = tune.OnlineController().activate()
-    try:
-        mod, it = _mlp_module_and_iter(steps=2)
-        mod.fit(it, num_epoch=1, eval_metric="acc")
-        # the holder was bound during fit and released on return
-        assert "fit.max_in_flight" not in ctl._bound
-    finally:
-        ctl.deactivate()
-
-
-# --------------------------------------------- mix-aware admission (ISSUE)
-def test_mix_service_model_learns_live_mix():
-    buckets = (1, 128)
-    cost_rows = {1: {"exec_ms": 2.0}, 128: {"exec_ms": 50.0}}
-    prior = mix_service_model({}, cost_rows, buckets)
-    assert prior["basis"] == "cost-rows"
-    assert prior["est_batch_ms"] == 50.0
-    assert prior["est_rows_per_batch"] == 128.0
-    live = mix_service_model({1: (20, 2.0)}, cost_rows, buckets)
-    assert live["basis"] == "live-mix"
-    assert live["est_batch_ms"] == pytest.approx(2.0)   # tracks measured
-    assert live["est_rows_per_batch"] == pytest.approx(1.0)
-    # a mixed stream weights by traffic, not by the largest bucket
-    mixed = mix_service_model({1: (30, 2.0), 128: (10, 50.0)},
-                              cost_rows, buckets)
-    assert mixed["est_batch_ms"] == pytest.approx((30 * 2 + 10 * 50) / 40)
-    assert mixed["est_rows_per_batch"] == pytest.approx(
-        (30 * 1 + 10 * 128) / 40)
-
-
-def test_mix_aware_estimate_stops_over_shedding():
-    """The ROADMAP item-1 acceptance: a small-bucket-heavy mix must not
-    be priced at largest-bucket service. 4 pending single-row requests
-    + 2 small batches in flight: the old largest-bucket model estimates
-    3 batches x 50ms = 150ms and SHEDS at a 100ms budget; the live mix
-    (bucket-1 batches measured at 2ms) estimates 12ms and ADMITS —
-    tracking the measured per-bucket service, not the shape assumption."""
-    buckets = (1, 128)
-    cost_rows = {1: {"exec_ms": 2.0}, 128: {"exec_ms": 50.0}}
-    pol = SignalAdmissionPolicy(queue_wait_budget_ms=100.0)
-
-    def signals(model, pending, inflight):
-        batches = math.ceil(pending / model["est_rows_per_batch"]) \
-            + inflight
-        return AdmissionSignals(
-            queue_depth=pending, queue_limit=256, pending_rows=pending,
-            inflight_depth=inflight, inflight_limit=4, replicas=1,
-            est_batch_ms=model["est_batch_ms"],
-            est_queue_wait_ms=model["est_batch_ms"] * batches)
-
-    prior = mix_service_model({}, cost_rows, buckets)
-    live = mix_service_model({1: (20, 2.0)}, cost_rows, buckets)
-    assert pol.decide(signals(prior, 4, 2)).admit is False   # over-shed
-    d = pol.decide(signals(live, 4, 2))
-    assert d.admit is True                                   # mix-aware
-
-
-def test_serving_session_service_model_goes_mix_aware():
-    sym_json, params, shapes = _serving_fixture()
-    with mx.serving.ServingSession(sym_json, params, shapes,
-                                   buckets=(1, 8), warmup=True) as s:
-        pre = s._service_model()
-        assert pre["basis"] == "cost-rows"
-        assert pre["est_rows_per_batch"] == 8.0
-        # a skewed single-row mix lands in the per-worker aggregates
-        # (the same call the dispatcher makes at retire time)
-        for _ in range(16):
-            s._record_service(0, 1, 2.0)
-        post = s._service_model()
-        assert post["basis"] == "live-mix"
-        assert post["est_batch_ms"] == pytest.approx(2.0, rel=0.1)
-        assert post["est_rows_per_batch"] == pytest.approx(1.0)
-        assert s._est_batch_ms() == pytest.approx(2.0, rel=0.1)
-        # the signals consume the learned mix
-        sig = s._signals()
-        assert sig.est_batch_ms == pytest.approx(2.0, rel=0.1)
-        # ...and the same observations were mirrored into the labeled
-        # telemetry series for dashboards
-        h = s.metrics.histogram("batch_service_ms",
-                                labels={"bucket": "1"})
-        assert h.count == 16
-
-
-def test_swap_model_resets_service_aggregates():
-    """A hot-swapped model has a new service profile: the mix-aware
-    estimate must re-learn from its batches, not price them with the
-    old model's history."""
-    sym_json, params, shapes = _serving_fixture()
-    with mx.serving.ServingSession(sym_json, params, shapes,
-                                   buckets=(1, 8), warmup=False) as s:
-        for _ in range(16):
-            s._record_service(0, 1, 2.0)
-        assert s._service_model()["basis"] == "live-mix"
-        s.swap_model(sym_json, params, version_tag="v-next", warmup=False)
-        assert s._service_model()["basis"] != "live-mix"
-        assert all(not d for d in s._bucket_service)
-
-
-def test_serving_traffic_populates_per_bucket_series():
-    """End to end: real single-row traffic produces labeled per-bucket
-    service observations (the series the estimate learns from)."""
-    sym_json, params, shapes = _serving_fixture()
-    rng = np.random.RandomState(0)
-    payload = {"data": rng.rand(*shapes["data"]).astype(np.float32)}
-    with mx.serving.ServingSession(sym_json, params, shapes,
-                                   buckets=(1, 8), warmup=True,
-                                   max_delay_ms=1.0) as s:
-        for _ in range(12):
-            s.predict(payload, timeout=30)
-        labeled = [m for m in s.metrics.series()
-                   if m.name == "batch_service_ms" and m.labels]
-        assert labeled and sum(m.count for m in labeled) > 0
-
-
-# ----------------------------------------------------------------- docs/CLI
+# ---------------------------------------------------------------------- docs
 def test_catalog_documented_in_docs():
     """Every declared knob appears in docs/tune.md (the catalog table
     there is generated from this registry — rot guard)."""
@@ -545,13 +258,5 @@ def test_catalog_table_renders():
     table = tune.catalog_table()
     assert table.startswith("| knob |")
     assert "`fit.max_in_flight`" in table
-    rows = tune.catalog_rows()
-    assert all({"name", "kind", "default", "env"} <= set(r) for r in rows)
-
-
-def test_cli_version_and_catalog(capsys):
-    from mxtpu.tune.__main__ import main as cli
-    assert cli(["version"]) == 0
-    assert capsys.readouterr().out.strip() == tune.registry_version()
-    assert cli(["catalog"]) == 0
-    assert "`serving.refill_watermark`" in capsys.readouterr().out
+    # header, rule and a row a declared knob
+    assert len(table.splitlines()) == 2 + len(tune.knobs())

@@ -95,8 +95,8 @@ MODULES = [
      "Faults — seeded fault injection (declared points, schedule "
      "grammar) and the shared RetryPolicy"),
     ("tune", "mxtpu.tune",
-     "Tune — cost-registry-driven autotuning: knob registry, TunedConfig "
-     "artifact, offline search, online refinement"),
+     "Tune — the knob registry: every tunable declared once, resolved "
+     "default < environment < explicit argument"),
     ("export", "mxtpu.export", "AOT export"),
     ("test_utils", "mxtpu.test_utils", "Test utilities"),
     ("torch_bridge", "mxtpu.torch_bridge",
