@@ -4,16 +4,36 @@ Beyond reference parity (the reference has no attention operator —
 SURVEY.md §5 'Long-context'), but the hot op of any long-context model, so
 it gets the full TPU treatment per /opt/skills/guides/pallas_guide.md.
 
-Forward:
+Forward, for the shapes the backward tiles (``_fwd_blocks``: T and S
+multiples of 128, T = S under a causal mask, a head's K and V in VMEM):
 
-- grid (batch*heads, q_blocks, kv_blocks), iterated sequentially on-core
-  so VMEM scratch (running max / normalizer / accumulator) carries the
-  online-softmax state across the kv dimension;
-- q@k^T and p@v on the MXU with f32 accumulation (preferred_element_type);
-- causal masking per block via broadcasted iotas;
-- output written once, on the last kv block, normalized by the running
-  sum; under differentiation the same step writes each row's log-sum-exp
-  (float32), the one number the backward needs to rebuild the softmax.
+- grid (batch*heads, q_blocks); the head's K and V stay resident and the
+  kernel walks the key blocks itself. Under a causal mask a query block
+  is several key blocks long (the whole sequence at the LM cells' sizes):
+  a ``fori_loop`` visits the key blocks under its diagonal, then the ones
+  the diagonal crosses, the only ones masked, follow as straight-line
+  code, each against the queries from its own first on; the rest is
+  neither computed nor a grid step;
+- scores are held transposed, (block_k, block_q), as the backward holds
+  them: the running maximum and sum are rows and their reductions run down
+  the sublanes. They and the (D, block_q) accumulator are the loop's own
+  values, float32;
+- k@q^T and v^T@p on the MXU with f32 accumulation
+  (preferred_element_type), ``p`` cast to the operands' dtype;
+- the output is normalized by the running sum and turned to (block_q, D)
+  once a query block; under differentiation the same step writes each
+  row's log-sum-exp (float32), the one number the backward needs to
+  rebuild the softmax;
+- ``block_q`` / ``block_k`` follow T, S, D and the dtype
+  (``_fwd_blocks``), as the backward's follow the shape; a caller's own
+  are kept where they tile.
+
+Other shapes (a ragged key length, a causal mask with T != S, a caller's
+blocks that do not divide T and S or, under a causal mask, each other)
+take the grid kernel: grid (batch*heads, q_blocks,
+kv_blocks) iterated sequentially on-core, the online-softmax state in VMEM
+scratch across the kv dimension, every live block masked by iotas, padded
+key columns kept out of the softmax.
 
 Backward (``jax.custom_vjp``): the residuals are ``(q, k, v, o, lse)``,
 O(T) numbers a head. Nothing of size T x S is saved or written to HBM. One
@@ -24,8 +44,8 @@ and accumulates dQ, dK and dV in VMEM in float32 (scores, ``lse``,
 the operands' dtype for the MXU, as the forward casts ``p``). Under a
 causal mask the blocks above the diagonal are never visited and only the
 blocks on it are masked. Its block sizes follow T, S, D and the dtype
-(``_bwd_blocks``); the forward's ``block_q`` / ``block_k`` are not
-consulted. Shapes it does not tile (sequence lengths that are no multiple
+(``_bwd_blocks``); a caller's ``block_q`` / ``block_k`` are the forward's
+alone. Shapes it does not tile (sequence lengths that are no multiple
 of 128, a causal mask with T != S, a head too long for VMEM) save
 ``(q, k, v)`` and take the VJP of the jnp reference, which materializes
 the scores.
@@ -56,9 +76,11 @@ NEG_INF = -1e30
 FWD_KERNEL_NAME = "mxtpu_flash_fwd"
 BWD_KERNEL_NAME = "mxtpu_flash_bwd"
 
-# scoped VMEM asked for the backward kernel, which keeps a whole head
-# resident: under a third of a v5e core's 128 MiB
-_BWD_VMEM_BYTES = 40 << 20
+# scoped VMEM asked for the kernels that keep a whole head resident (the
+# backward, and the forward's K and V): under a third of a v5e core's
+# 128 MiB
+_HEAD_VMEM_BYTES = 40 << 20
+
 
 
 def _scores(q, k, scale, causal):
@@ -201,21 +223,198 @@ def _flash_call(q, k, v, scale, causal, block_q, block_k, interpret,
     return out[0], out[1].reshape(bh, nq * block_q)[:, :t]
 
 
+def _tile(n):
+    """The largest of 512, 256, 128 that divides n."""
+    return next(b for b in (512, 256, 128) if n % b == 0)
+
+
+def _fwd_blocks(t, s_len, d, itemsize, causal, block_q=0, block_k=0):
+    """(block_q, block_k) of the walked forward, or None where the shape
+    takes the grid kernel: the backward's predicate (`_bwd_blocks`), and a
+    caller's own blocks only where they tile the shape and, under a causal
+    mask, block_q is a multiple of block_k. Left to the shape, block_k is
+    the largest of 512, 256, 128 that divides S, and block_q the same of T
+    or, under a causal mask, the largest of 2048 down to 128 that divides
+    T and fits: the key blocks the diagonal crosses are straight-line
+    code, which a v5e runs faster than the loop (PERF.md section 6,
+    PR 31)."""
+    if t % 128 or s_len % 128 or (causal and t != s_len):
+        return None
+    block_k = min(block_k, s_len) or _tile(s_len)
+    if block_q:
+        wide = (min(block_q, t),)
+    else:
+        wide = (2048, 1024) * causal + (512, 256, 128)
+    for block_q in wide:
+        if (block_q % 128 or block_k % 128 or t % block_q or s_len % block_k
+                or (causal and block_q % block_k)):
+            continue
+        if _walk_vmem(s_len, d, itemsize, block_q,
+                      block_k) <= _HEAD_VMEM_BYTES:
+            return block_q, block_k
+    return None
+
+
+def _walk_vmem(s_len, d, itemsize, block_q, block_k):
+    """Scoped VMEM the walked forward asks for: a third over what is
+    resident, the head's k and v and a block of q and o, double-buffered
+    (lanes padded to 128), the float32 accumulator, and four block-pair
+    temporaries (scores, p and their casts); never under Mosaic's own
+    16 MiB. Asking for more than it needs costs the program around it: XLA
+    counts the request against what it may keep in VMEM between
+    operations (the LM cell's forward alone, compiled for a described v5e:
+    32.5 MiB of temporaries at 16 MiB, 64.5 at 40)."""
+    lanes = -(-d // 128) * 128
+    resident = (4 * (s_len + block_q) * lanes * itemsize
+                + block_q * lanes * 4 + 4 * block_q * block_k * 4)
+    return max(16 << 20, resident * 4 // 3)
+
+
+def _grid_blocks(t, s_len, block_q, block_k):
+    """The grid kernel's blocks: the caller's or 512 x 1024, clamped to the
+    sequence lengths."""
+    return min(block_q or 512, t), min(block_k or 1024, s_len)
+
+
+def _live_share(t, s_len, block_q, block_k, causal, walked):
+    """Scores a forward with these blocks computes, over all of them: under
+    a causal mask the grid kernel takes every block pair the diagonal
+    touches whole, the walked one a key block against the queries from the
+    block's own first on."""
+    if not causal:
+        return 1.0
+    nq, nk = pl.cdiv(t, block_q), pl.cdiv(s_len, block_k)
+    rows = sum(min(block_q, (i + 1) * block_q - j * block_k)
+               if walked else block_q
+               for i in range(nq)
+               for j in range(min(nk, pl.cdiv((i + 1) * block_q, block_k))))
+    return rows / (nq * block_q * nk)
+
+
+def _walk_kernel(q_ref, k_ref, v_ref, o_ref, *lse_ref, scale, causal,
+                 block_q, block_k):
+    """One (head, query block): the head's K and V are resident and the
+    kernel walks the key blocks itself. Scores are held transposed,
+    (keys, queries), as the backward holds them: the running maximum and
+    sum are then rows, their reductions run down the sublanes and the
+    log-sum-exp needs no turn. They and the accumulator (D, block_q) are the
+    loop's own values; the output is normalized and turned once, at the
+    end."""
+    i = pl.program_id(1)
+    q = q_ref[0]
+    d = q.shape[-1]
+    a_bt = (((1,), (1,)), ((), ()))   # a @ b.T
+    at_b = (((0,), (0,)), ((), ()))   # a.T @ b
+
+    def visit(carry, keys, queries, on_diagonal):
+        """The running softmax of the rows `queries` of q over `keys`."""
+        m, l, acc = carry
+        k_j, v_j = k_ref[0, keys, :], v_ref[0, keys, :]
+        s_t = jax.lax.dot_general(
+            k_j, queries, a_bt, preferred_element_type=jnp.float32) * scale
+        if on_diagonal:
+            # the first key is the first query's own: the offsets cancel
+            key = jax.lax.broadcasted_iota(jnp.int32, s_t.shape, 0)
+            query = jax.lax.broadcasted_iota(jnp.int32, s_t.shape, 1)
+            s_t = jnp.where(key <= query, s_t, NEG_INF)
+        m_new = jnp.maximum(m, jnp.max(s_t, axis=0, keepdims=True))
+        alpha = jnp.exp(m - m_new)
+        p_t = jnp.exp(s_t - m_new)
+        l = alpha * l + jnp.sum(p_t, axis=0, keepdims=True)
+        acc = acc * alpha + jax.lax.dot_general(
+            v_j, p_t.astype(v_j.dtype), at_b,
+            preferred_element_type=jnp.float32)
+        return m_new, l, acc
+
+    def below(j, carry):
+        keys = pl.ds(pl.multiple_of(j * block_k, block_k), block_k)
+        return visit(carry, keys, q, False)
+
+    carry = (jnp.full((1, block_q), NEG_INF, jnp.float32),
+             jnp.zeros((1, block_q), jnp.float32),
+             jnp.zeros((d, block_q), jnp.float32))
+    if causal:
+        # block_q is a multiple of block_k. Key blocks under the diagonal
+        # take the scores as they are and those above it are never
+        # visited. The ones the diagonal crosses are straight-line code,
+        # each against the queries from its own first on, so what lies
+        # above the diagonal inside the query block is not computed
+        # either, but for each strip's own upper triangle
+        strips = block_q // block_k
+        m, l, acc = jax.lax.fori_loop(0, i * strips, below, carry)
+        for lo in range(0, block_q, block_k):
+            keys = pl.ds(pl.multiple_of(i * block_q + lo, block_k), block_k)
+            part = visit((m[:, lo:], l[:, lo:], acc[:, lo:]), keys,
+                         q[lo:, :], True)
+            m, l, acc = part if lo == 0 else tuple(
+                jnp.concatenate([a[:, :lo], b], axis=1)
+                for a, b in zip((m, l, acc), part))
+    else:
+        m, l, acc = jax.lax.fori_loop(0, k_ref.shape[1] // block_k, below,
+                                      carry)
+    o_ref[0] = (acc * (1.0 / l)).T.astype(o_ref.dtype)
+    if lse_ref:
+        lse_ref[0][0, 0] = m + jnp.log(l)
+
+
+def _walk_call(q, k, v, scale, causal, block_q, block_k, interpret,
+               with_lse):
+    bh, t, d = q.shape
+    s_len = k.shape[1]
+    nq = t // block_q
+    kernel = functools.partial(_walk_kernel, scale=scale, causal=causal,
+                               block_q=block_q, block_k=block_k)
+    kv_spec = pl.BlockSpec((1, s_len, d), lambda b, i: (b, 0, 0))
+    out_shape = [jax.ShapeDtypeStruct((bh, t, d), q.dtype)]
+    out_specs = [pl.BlockSpec((1, block_q, d), lambda b, i: (b, i, 0))]
+    if with_lse:
+        out_shape.append(jax.ShapeDtypeStruct((bh, nq, 1, block_q),
+                                              jnp.float32))
+        out_specs.append(pl.BlockSpec((1, 1, 1, block_q),
+                                      lambda b, i: (b, i, 0, 0)))
+    out = pl.pallas_call(
+        kernel,
+        out_shape=out_shape,
+        grid=(bh, nq),
+        in_specs=[pl.BlockSpec((1, block_q, d), lambda b, i: (b, i, 0)),
+                  kv_spec, kv_spec],
+        out_specs=out_specs,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel"),
+            vmem_limit_bytes=_walk_vmem(s_len, d, q.dtype.itemsize, block_q,
+                                        block_k)),
+        interpret=interpret, name=FWD_KERNEL_NAME,
+    )(q, k, v)
+    if not with_lse:
+        return out[0]
+    return out[0], out[1].reshape(bh, t)
+
+
 @functools.partial(jax.jit, static_argnums=(3, 4, 5, 6, 7))
 def _forward(q, k, v, scale, causal, block_q, block_k, with_lse):
     """The forward for the platform this program is compiled for. Jitted
     so the choice follows the operands' device even when called eagerly
     (a CPU-committed operand on a chip host must not reach Mosaic)."""
+    blocks = _fwd_blocks(q.shape[1], k.shape[1], q.shape[2],
+                         q.dtype.itemsize, causal, block_q, block_k)
+
+    def kernel(q, k, v, interpret):
+        if blocks is not None:
+            return _walk_call(q, k, v, scale, causal, *blocks,
+                              interpret=interpret, with_lse=with_lse)
+        return _flash_call(q, k, v, scale, causal,
+                           *_grid_blocks(q.shape[1], k.shape[1], block_q,
+                                         block_k),
+                           interpret=interpret, with_lse=with_lse)
+
     def on_tpu(q, k, v):
-        return _flash_call(q, k, v, scale, causal, block_q, block_k,
-                           interpret=False, with_lse=with_lse)
+        return kernel(q, k, v, False)
 
     def on_cpu(q, k, v):
         # interpret mode exercises the kernel logic on CPU for small
         # problems; big CPU shapes take the reference path
         if _interpretable(q, k):
-            return _flash_call(q, k, v, scale, causal, block_q, block_k,
-                               interpret=True, with_lse=with_lse)
+            return kernel(q, k, v, True)
         out = _reference(q, k, v, scale, causal)
         if not with_lse:
             return out
@@ -231,16 +430,15 @@ def _bwd_blocks(t, s_len, d, itemsize, causal):
     causal mask still skips one block pair of four."""
     if t % 128 or s_len % 128 or (causal and t != s_len):
         return None
-    block_q = next(b for b in (512, 256, 128) if t % b == 0)
-    block_k = block_q if causal else next(
-        b for b in (512, 256, 128) if s_len % b == 0)
+    block_q = _tile(t)
+    block_k = block_q if causal else _tile(s_len)
     # a whole head is resident: q, dO, dQ and k, v, dK, dV double-buffered
     # (lanes padded to 128), dQ's float32 accumulator, and eight
     # block-pair temporaries (scores, p, dP, dS and their casts)
     lanes = -(-d // 128) * 128
     resident = (2 * (3 * t + 4 * s_len) * lanes * itemsize
                 + t * lanes * 4 + 8 * block_q * block_k * 4)
-    if resident > _BWD_VMEM_BYTES * 3 // 4:
+    if resident > _HEAD_VMEM_BYTES * 3 // 4:
         return None
     return block_q, block_k
 
@@ -330,7 +528,7 @@ def _bwd_call(q, k, v, do, lse, delta, scale, causal, block_q, block_k,
         ],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",),
-            vmem_limit_bytes=_BWD_VMEM_BYTES),
+            vmem_limit_bytes=_HEAD_VMEM_BYTES),
         interpret=interpret, name=BWD_KERNEL_NAME,
     )(q, k, v, do, lse.reshape(rows), delta.reshape(rows))
 
@@ -361,6 +559,13 @@ def _flash3(q, k, v, scale, causal, block_q, block_k):
 
 
 def _flash3_fwd(q, k, v, scale, causal, block_q, block_k):
+    walked = _fwd_blocks(q.shape[1], k.shape[1], q.shape[2],
+                         q.dtype.itemsize, causal, block_q, block_k)
+    telemetry.counter(
+        "attention_fwd_builds",
+        labels={"path": "grid" if walked is None else "walk"},
+        help="differentiated attention forward passes traced, by the kernel "
+             "their shape takes").inc()
     if _bwd_blocks(q.shape[1], k.shape[1], q.shape[2], q.dtype.itemsize,
                    causal) is None:
         out = _forward(q, k, v, scale, causal, block_q, block_k, False)
@@ -386,17 +591,31 @@ def _flash3_bwd(scale, causal, block_q, block_k, res, g):
 _flash3.defvjp(_flash3_fwd, _flash3_bwd)
 
 
-def flash_attention(q, k, v, causal=False, sm_scale=None, block_q=512,
-                    block_k=1024):
-    """Multi-head attention, (B, H, T, D) layout (B/H merged internally)."""
+def flash_attention(q, k, v, causal=False, sm_scale=None, block_q=0,
+                    block_k=0):
+    """Multi-head attention, (B, H, T, D) layout (B/H merged internally).
+    `block_q` / `block_k` are the forward's tiles; 0 leaves them to the
+    shape."""
     b, h, t, d = q.shape
     s_len = k.shape[2]
     scale = float(sm_scale) if sm_scale is not None else 1.0 / (d ** 0.5)
+    causal, block_q, block_k = bool(causal), int(block_q), int(block_k)
+    walked = _fwd_blocks(t, s_len, d, q.dtype.itemsize, causal, block_q,
+                         block_k)
+    bq, bk = walked or _grid_blocks(t, s_len, block_q, block_k)
+    telemetry.gauge("flash_fwd_block_q", help="query rows a block of the "
+                    "flash forward (the last call traced)").set(bq)
+    telemetry.gauge("flash_fwd_block_k", help="keys a block of the flash "
+                    "forward (the last call traced)").set(bk)
+    telemetry.gauge(
+        "flash_fwd_live_block_share",
+        help="scores the flash forward computes over all T x S of them "
+             "(the last call traced)").set(
+                 _live_share(t, s_len, bq, bk, causal, walked is not None))
     qf = q.reshape(b * h, t, d)
     kf = k.reshape(b * h, s_len, d)
     vf = v.reshape(b * h, s_len, d)
-    out = _flash3(qf, kf, vf, scale, bool(causal), int(block_q),
-                  int(block_k))
+    out = _flash3(qf, kf, vf, scale, causal, block_q, block_k)
     return out.reshape(b, h, t, d)
 
 
@@ -409,6 +628,6 @@ def _flash_op(a, q, k, v):
 
 register("_contrib_FlashAttention", _flash_op,
          arg_names=["query", "key", "value"],
-         attrs={"causal": False, "sm_scale": 0.0, "block_q": 512,
-                "block_k": 1024},
+         attrs={"causal": False, "sm_scale": 0.0, "block_q": 0,
+                "block_k": 0},
          aliases=("flash_attention",))

@@ -1,5 +1,6 @@
-"""Flash-attention Pallas kernel tests: interpret-mode kernels, forward and
-backward, vs the jnp reference oracle, causal masking, gradients, which
+"""Flash-attention Pallas kernel tests: interpret-mode kernels, forward
+(the one that walks its key blocks and the grid one) and backward, vs the
+jnp reference oracle, causal masking, gradients, which forward and which
 backward a shape takes, op registration, and the ring-attention
 cross-check."""
 import numpy as np
@@ -155,6 +156,123 @@ def test_attention_bwd_builds_counts_the_path():
     grad((1, 4, 2, 4))   # the gradient sweep's shape: nothing to tile
     assert (built("kernel"), built("reference")) == (before[0] + 1,
                                                      before[1] + 1)
+
+
+def _series(name, labels=None):
+    from mxtpu import telemetry
+    if labels is None:
+        return telemetry.gauge(name).value
+    return telemetry.counter(name, labels=labels).value
+
+
+@pytest.mark.parametrize("with_lse", [False, True])
+@pytest.mark.parametrize("d", [64, 128])
+@pytest.mark.parametrize("t", [128, 256, 512, 1024])
+@pytest.mark.parametrize("dtype,tol", [("float32", 2e-5), ("bfloat16", 2e-2)])
+@pytest.mark.parametrize("causal", [False, True])
+def test_walked_forward_matches_reference(causal, dtype, tol, t, d, with_lse):
+    """The forward that walks its key blocks inside the kernel (interpret
+    mode), blocks from the shape, against the jnp reference: the output
+    and, as the differentiated program asks for it, the rows' log-sum-exp."""
+    bh = 2
+    q, k, v = (_rand((bh, t, d), i).astype(dtype) for i in range(3))
+    scale = 1.0 / d ** 0.5
+    # under a causal mask the query block is the whole sequence here
+    assert att._fwd_blocks(t, t, d, q.dtype.itemsize, causal) == (
+        t if causal else min(t, 512), min(t, 512))
+    got = att._forward(q, k, v, scale, causal, 0, 0, with_lse)
+    want = att._reference(q, k, v, scale, causal)
+    if with_lse:
+        got, lse = got
+        assert lse.dtype == jnp.float32 and lse.shape == (bh, t)
+        np.testing.assert_allclose(
+            np.asarray(lse), np.asarray(jax.nn.logsumexp(
+                att._scores(q.astype("float32"), k.astype("float32"), scale,
+                            causal), axis=-1)),
+            rtol=1e-5, atol=1e-5)
+    assert got.dtype == want.dtype and got.shape == want.shape
+    np.testing.assert_allclose(np.asarray(got, "f4"), np.asarray(want, "f4"),
+                               rtol=tol, atol=tol)
+
+
+@pytest.mark.parametrize("causal,blocks,walked", [
+    (True, (128, 128), (128, 128)), (True, (256, 256), (256, 256)),
+    (True, (512, 1024), (512, 512)),            # clamped to T
+    (True, (256, 128), (256, 128)), (True, (128, 256), None),
+    (False, (256, 128), (256, 128)), (False, (128, 256), (128, 256))])
+def test_forward_with_the_callers_blocks(causal, blocks, walked):
+    """A caller's blocks keep their meaning: the walked forward takes them
+    where they tile the shape and, under a causal mask, block_q is whole
+    key blocks; the grid kernel takes the others."""
+    bh, t, d = 2, 512, 64
+    q, k, v = (_rand((bh, t, d), i) for i in range(3))
+    assert att._fwd_blocks(t, t, d, 4, causal, *blocks) == walked
+    got = att._forward(q, k, v, 0.125, causal, *blocks, False)
+    np.testing.assert_allclose(
+        np.asarray(got), np.asarray(att._reference(q, k, v, 0.125, causal)),
+        rtol=2e-5, atol=2e-5)
+
+
+@pytest.mark.parametrize("dtype,tol", [("float32", 1e-4), ("bfloat16", 3e-2)])
+@pytest.mark.parametrize("causal,t,d", [(True, 256, 64), (True, 1024, 64),
+                                        (True, 512, 128), (False, 256, 64),
+                                        (False, 512, 128)])
+def test_gradient_through_the_walked_forward(causal, t, d, dtype, tol):
+    """The new forward's output and log-sum-exp feed the unchanged backward
+    kernel: (dq, dk, dv) against the reference's VJP."""
+    bh = 2
+    q, k, v = (_rand((bh, t, d), i).astype(dtype) for i in range(3))
+    g = _rand((bh, t, d), 3).astype(dtype)
+    scale = 1.0 / d ** 0.5
+    walk = _series("attention_fwd_builds", {"path": "walk"})
+    _, vjp = jax.vjp(
+        lambda a, b, c: att.flash_attention(
+            a[None], b[None], c[None], causal=causal)[0], q, k, v)
+    assert _series("attention_fwd_builds", {"path": "walk"}) == walk + 1
+    for got, want in zip(vjp(g), att._reference_vjp(q, k, v, g, scale,
+                                                    causal)):
+        assert got.dtype == want.dtype and got.shape == want.shape
+        np.testing.assert_allclose(np.asarray(got, "f4"),
+                                   np.asarray(want, "f4"),
+                                   rtol=tol, atol=tol)
+
+
+def test_attention_fwd_builds_counts_the_grid_where_nothing_tiles():
+    """T no multiple of 128: the differentiated forward is the grid kernel,
+    the one that handles a ragged key length."""
+    def built():
+        return tuple(_series("attention_fwd_builds", {"path": p})
+                     for p in ("grid", "walk"))
+
+    grid, walk = built()
+    q = _rand((1, 2, 320, 64))
+    out, vjp = jax.vjp(
+        lambda a: att.flash_attention(a, q, q, causal=True), q)
+    assert built() == (grid + 1, walk)
+    assert (_series("flash_fwd_block_q"), _series("flash_fwd_block_k")) == (
+        320, 320)
+    np.testing.assert_allclose(
+        np.asarray(out[0]),
+        np.asarray(att._reference(q[0], q[0], q[0], 0.125, True)),
+        rtol=2e-3, atol=2e-3)
+    att.flash_attention(q, q, q, causal=True)   # no gradient: not counted
+    assert built() == (grid + 1, walk)
+
+
+def test_flash_fwd_live_block_share_at_256_wide_blocks():
+    q = _rand((1, 1, 1024, 64))
+    att.flash_attention(q, q, q, causal=True, block_q=256, block_k=256)
+    assert (_series("flash_fwd_block_q"), _series("flash_fwd_block_k")) == (
+        256, 256)
+    assert _series("flash_fwd_live_block_share") == 0.625
+    att.flash_attention(q, q, q, causal=True)       # 1024 x 512: two strips
+    assert (_series("flash_fwd_block_q"), _series("flash_fwd_block_k")) == (
+        1024, 512)
+    assert _series("flash_fwd_live_block_share") == 0.75
+    att.flash_attention(q, q, q, causal=True, block_q=512, block_k=1024)
+    assert _series("flash_fwd_live_block_share") == 1.0     # the grid's
+    att.flash_attention(q, q, q, causal=False, block_q=256, block_k=256)
+    assert _series("flash_fwd_live_block_share") == 1.0
 
 
 def test_pallas_epilogue_matches_reference():

@@ -1,5 +1,6 @@
-"""Compile-only check, for a described (not attached) TPU v5e, of the
-attention gradient at the LM cell's size. Nothing runs: a compile that
+"""Compile-only checks, for a described (not attached) TPU v5e, of the
+attention forward and gradient and of the delta rule's gradient at the LM
+cells' sizes. Nothing runs: a compile that
 passes here is not a chip run. The fixture skips, as
 tests/bench_yardstick/test_compile_v5e.py does, where no topology can be
 described (another process of the run may hold the TPU's compiler)."""
@@ -77,6 +78,30 @@ def test_attention_gradient_compiles_at_the_hybrid_cells_size(one_chip,
     assert "%" + attention.FWD_KERNEL_NAME in text
     assert "%" + attention.BWD_KERNEL_NAME in text
     assert "[120,2048,2048]" not in text
+
+
+@pytest.mark.parametrize("b,h,t,d", [(4, 32, 1024, 64), (4, 30, 2048, 128)],
+                         ids=["opt-1.3b-fit-s1024",
+                              "olmo-hybrid-7b-fit-s2048"])
+@pytest.mark.parametrize("with_lse", [False, True])
+def test_walked_forward_compiles_at_the_cells_sizes(one_chip, no_cache, b, h,
+                                                    t, d, with_lse):
+    """Both LM cells' attention shapes tile, so their forward is the kernel
+    that walks its key blocks, a head's queries in one block: one Mosaic
+    call by the forward's name, with and without the log-sum-exp output,
+    inside the scoped VMEM it asks for (Mosaic refuses a kernel that does
+    not fit)."""
+    import jax
+    import jax.numpy as jnp
+    from mxtpu.ops import attention
+    assert attention._fwd_blocks(t, t, d, 2, True) == (t, 512)
+    q = jax.ShapeDtypeStruct((b * h, t, d), jnp.bfloat16, sharding=one_chip)
+    text = jax.jit(lambda q, k, v: attention._forward(
+        q, k, v, d ** -0.5, True, 0, 0, with_lse)).lower(
+            q, q, q).compile().as_text()
+    assert text.count("custom_call_target=\"tpu_custom_call\"") == 1
+    assert "%" + attention.FWD_KERNEL_NAME in text
+    assert ("f32[%d,1,1,%d]" % (b * h, t) in text) == with_lse
 
 
 def test_delta_rule_gradient_compiles_at_the_hybrid_cells_size(one_chip,

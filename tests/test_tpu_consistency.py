@@ -163,8 +163,17 @@ _FLASH_CASES = [
     ("bfloat16", 2, 4, 1024, 128, 512, 1024, 2e-2),
     # bf16 with a tail block
     ("bfloat16", 1, 4, 640, 128, 256, 256, 2e-2),
-    # opt-1.3b-fit-s1024's own shape: head 64, the op's default blocks
+    # opt-1.3b-fit-s1024's own shape: head 64, the grid kernel's blocks
     ("bfloat16", 4, 32, 1024, 64, 512, 1024, 2e-2),
+    # blocks left to the shape (0): the forward that walks its key blocks.
+    # Both LM cells' shapes (the whole sequence a query block, the diagonal
+    # in strips of 512 keys), f32 over three blocks a side, a caller's 256
+    # and a caller's 512 x 256 (a loop under the diagonal, then two strips)
+    ("bfloat16", 4, 32, 1024, 64, 0, 0, 2e-2),
+    ("bfloat16", 4, 30, 2048, 128, 0, 0, 2e-2),
+    ("float32", 2, 4, 384, 64, 0, 0, 2e-3),
+    ("bfloat16", 1, 4, 1024, 128, 256, 256, 2e-2),
+    ("bfloat16", 1, 4, 1024, 128, 512, 256, 2e-2),
 ]
 
 
@@ -173,8 +182,10 @@ def test_pallas_flash_kernel_on_chip(dtype, B, H, T, D, bq, bk, tol):
     """The Mosaic-compiled flash kernels, forward and backward, against
     float32 reference math on the chip — values and gradients. CPU runs
     reach the same kernels only in interpret mode, so this validates the
-    lowered kernels themselves. T=320 does not tile for the backward and
-    takes the reference's VJP."""
+    lowered kernels themselves. T=320, T=640 with 256-wide blocks and a
+    caller's 512 x 1024 do not tile for the walked forward and take the
+    grid kernel; T=320 does not tile for the backward either and takes the
+    reference's VJP."""
     import jax
     import jax.numpy as jnp
     from mxtpu.ops import attention as att
