@@ -1,5 +1,5 @@
 # GENERATED by R-package/gen_r_ops.py -- do not edit.
-# One mx.symbol.<op> function per registered op (295 ops); tensor inputs
+# One mx.symbol.<op> function per registered op (301 ops); tensor inputs
 # are mx.symbol objects passed by name, everything else becomes a string
 # param (the reference R package generates its op surface the same way).
 
@@ -399,6 +399,18 @@
   mx.symbol.create("_contrib_GatedDeltaRule", a[is.sym], a[!is.sym], name)
 }
 
+`mx.symbol._contrib_MoEExperts` <- function(..., name = "") {
+  a <- list(...)
+  is.sym <- vapply(a, function(v) inherits(v, "mx.symbol"), TRUE)
+  mx.symbol.create("_contrib_MoEExperts", a[is.sym], a[!is.sym], name)
+}
+
+`mx.symbol._contrib_MoERouter` <- function(..., name = "") {
+  a <- list(...)
+  is.sym <- vapply(a, function(v) inherits(v, "mx.symbol"), TRUE)
+  mx.symbol.create("_contrib_MoERouter", a[is.sym], a[!is.sym], name)
+}
+
 `mx.symbol._contrib_MultiBoxDetection` <- function(..., name = "") {
   a <- list(...)
   is.sym <- vapply(a, function(v) inherits(v, "mx.symbol"), TRUE)
@@ -433,6 +445,12 @@
   a <- list(...)
   is.sym <- vapply(a, function(v) inherits(v, "mx.symbol"), TRUE)
   mx.symbol.create("_contrib_Proposal", a[is.sym], a[!is.sym], name)
+}
+
+`mx.symbol._contrib_RotaryEmbedding` <- function(..., name = "") {
+  a <- list(...)
+  is.sym <- vapply(a, function(v) inherits(v, "mx.symbol"), TRUE)
+  mx.symbol.create("_contrib_RotaryEmbedding", a[is.sym], a[!is.sym], name)
 }
 
 `mx.symbol._contrib_count_sketch` <- function(..., name = "") {
@@ -1383,6 +1401,18 @@
   mx.symbol.create("min", a[is.sym], a[!is.sym], name)
 }
 
+`mx.symbol.moe_experts` <- function(..., name = "") {
+  a <- list(...)
+  is.sym <- vapply(a, function(v) inherits(v, "mx.symbol"), TRUE)
+  mx.symbol.create("moe_experts", a[is.sym], a[!is.sym], name)
+}
+
+`mx.symbol.moe_router` <- function(..., name = "") {
+  a <- list(...)
+  is.sym <- vapply(a, function(v) inherits(v, "mx.symbol"), TRUE)
+  mx.symbol.create("moe_router", a[is.sym], a[!is.sym], name)
+}
+
 `mx.symbol.mp_sgd_mom_update` <- function(..., name = "") {
   a <- list(...)
   is.sym <- vapply(a, function(v) inherits(v, "mx.symbol"), TRUE)
@@ -1519,6 +1549,12 @@
   a <- list(...)
   is.sym <- vapply(a, function(v) inherits(v, "mx.symbol"), TRUE)
   mx.symbol.create("rmspropalex_update", a[is.sym], a[!is.sym], name)
+}
+
+`mx.symbol.rotary_embedding` <- function(..., name = "") {
+  a <- list(...)
+  is.sym <- vapply(a, function(v) inherits(v, "mx.symbol"), TRUE)
+  mx.symbol.create("rotary_embedding", a[is.sym], a[!is.sym], name)
 }
 
 `mx.symbol.round` <- function(..., name = "") {

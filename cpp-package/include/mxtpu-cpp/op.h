@@ -1087,6 +1087,54 @@ inline std::vector<NDArray> _contrib_GatedDeltaRule(const NDArray &query, const 
   return op_.Invoke();
 }
 
+inline Symbol _contrib_MoEExperts(const std::string &symbol_name, const Symbol &data, const Symbol &topk_weight, const Symbol &topk_index, const Symbol &gate_weight, const Symbol &up_weight, const Symbol &down_weight, int num_experts, int experts_held, int hidden, const std::map<std::string, std::string> &kwargs = {}) {
+  Operator op_("_contrib_MoEExperts");
+  op_.SetParam("num_experts", num_experts);
+  op_.SetParam("experts_held", experts_held);
+  op_.SetParam("hidden", hidden);
+  for (const auto &kv : kwargs) op_.SetParam(kv.first, kv.second);
+  op_.SetInput("data", data);
+  op_.SetInput("topk_weight", topk_weight);
+  op_.SetInput("topk_index", topk_index);
+  op_.SetInput("gate_weight", gate_weight);
+  op_.SetInput("up_weight", up_weight);
+  op_.SetInput("down_weight", down_weight);
+  return op_.CreateSymbol(symbol_name);
+}
+inline std::vector<NDArray> _contrib_MoEExperts(const NDArray &data, const NDArray &topk_weight, const NDArray &topk_index, const NDArray &gate_weight, const NDArray &up_weight, const NDArray &down_weight, int num_experts, int experts_held, int hidden, const std::map<std::string, std::string> &kwargs = {}) {
+  Operator op_("_contrib_MoEExperts");
+  op_.SetParam("num_experts", num_experts);
+  op_.SetParam("experts_held", experts_held);
+  op_.SetParam("hidden", hidden);
+  for (const auto &kv : kwargs) op_.SetParam(kv.first, kv.second);
+  op_.AddInput(data);
+  op_.AddInput(topk_weight);
+  op_.AddInput(topk_index);
+  op_.AddInput(gate_weight);
+  op_.AddInput(up_weight);
+  op_.AddInput(down_weight);
+  return op_.Invoke();
+}
+
+inline Symbol _contrib_MoERouter(const std::string &symbol_name, const Symbol &data, const Symbol &weight, int num_experts, int top_k, const std::map<std::string, std::string> &kwargs = {}) {
+  Operator op_("_contrib_MoERouter");
+  op_.SetParam("num_experts", num_experts);
+  op_.SetParam("top_k", top_k);
+  for (const auto &kv : kwargs) op_.SetParam(kv.first, kv.second);
+  op_.SetInput("data", data);
+  op_.SetInput("weight", weight);
+  return op_.CreateSymbol(symbol_name);
+}
+inline std::vector<NDArray> _contrib_MoERouter(const NDArray &data, const NDArray &weight, int num_experts, int top_k, const std::map<std::string, std::string> &kwargs = {}) {
+  Operator op_("_contrib_MoERouter");
+  op_.SetParam("num_experts", num_experts);
+  op_.SetParam("top_k", top_k);
+  for (const auto &kv : kwargs) op_.SetParam(kv.first, kv.second);
+  op_.AddInput(data);
+  op_.AddInput(weight);
+  return op_.Invoke();
+}
+
 inline Symbol _contrib_MultiBoxDetection(const std::string &symbol_name, const Symbol &cls_prob, const Symbol &loc_pred, const Symbol &anchor, const std::map<std::string, std::string> &kwargs = {}) {
   Operator op_("_contrib_MultiBoxDetection");
   for (const auto &kv : kwargs) op_.SetParam(kv.first, kv.second);
@@ -1186,6 +1234,19 @@ inline std::vector<NDArray> _contrib_Proposal(const NDArray &cls_prob, const NDA
   op_.AddInput(cls_prob);
   op_.AddInput(bbox_pred);
   op_.AddInput(im_info);
+  return op_.Invoke();
+}
+
+inline Symbol _contrib_RotaryEmbedding(const std::string &symbol_name, const Symbol &data, const std::map<std::string, std::string> &kwargs = {}) {
+  Operator op_("_contrib_RotaryEmbedding");
+  for (const auto &kv : kwargs) op_.SetParam(kv.first, kv.second);
+  op_.SetInput("data", data);
+  return op_.CreateSymbol(symbol_name);
+}
+inline std::vector<NDArray> _contrib_RotaryEmbedding(const NDArray &data, const std::map<std::string, std::string> &kwargs = {}) {
+  Operator op_("_contrib_RotaryEmbedding");
+  for (const auto &kv : kwargs) op_.SetParam(kv.first, kv.second);
+  op_.AddInput(data);
   return op_.Invoke();
 }
 
@@ -3556,6 +3617,54 @@ inline std::vector<NDArray> min(const NDArray &data, const std::map<std::string,
   return op_.Invoke();
 }
 
+inline Symbol moe_experts(const std::string &symbol_name, const Symbol &data, const Symbol &topk_weight, const Symbol &topk_index, const Symbol &gate_weight, const Symbol &up_weight, const Symbol &down_weight, int num_experts, int experts_held, int hidden, const std::map<std::string, std::string> &kwargs = {}) {
+  Operator op_("moe_experts");
+  op_.SetParam("num_experts", num_experts);
+  op_.SetParam("experts_held", experts_held);
+  op_.SetParam("hidden", hidden);
+  for (const auto &kv : kwargs) op_.SetParam(kv.first, kv.second);
+  op_.SetInput("data", data);
+  op_.SetInput("topk_weight", topk_weight);
+  op_.SetInput("topk_index", topk_index);
+  op_.SetInput("gate_weight", gate_weight);
+  op_.SetInput("up_weight", up_weight);
+  op_.SetInput("down_weight", down_weight);
+  return op_.CreateSymbol(symbol_name);
+}
+inline std::vector<NDArray> moe_experts(const NDArray &data, const NDArray &topk_weight, const NDArray &topk_index, const NDArray &gate_weight, const NDArray &up_weight, const NDArray &down_weight, int num_experts, int experts_held, int hidden, const std::map<std::string, std::string> &kwargs = {}) {
+  Operator op_("moe_experts");
+  op_.SetParam("num_experts", num_experts);
+  op_.SetParam("experts_held", experts_held);
+  op_.SetParam("hidden", hidden);
+  for (const auto &kv : kwargs) op_.SetParam(kv.first, kv.second);
+  op_.AddInput(data);
+  op_.AddInput(topk_weight);
+  op_.AddInput(topk_index);
+  op_.AddInput(gate_weight);
+  op_.AddInput(up_weight);
+  op_.AddInput(down_weight);
+  return op_.Invoke();
+}
+
+inline Symbol moe_router(const std::string &symbol_name, const Symbol &data, const Symbol &weight, int num_experts, int top_k, const std::map<std::string, std::string> &kwargs = {}) {
+  Operator op_("moe_router");
+  op_.SetParam("num_experts", num_experts);
+  op_.SetParam("top_k", top_k);
+  for (const auto &kv : kwargs) op_.SetParam(kv.first, kv.second);
+  op_.SetInput("data", data);
+  op_.SetInput("weight", weight);
+  return op_.CreateSymbol(symbol_name);
+}
+inline std::vector<NDArray> moe_router(const NDArray &data, const NDArray &weight, int num_experts, int top_k, const std::map<std::string, std::string> &kwargs = {}) {
+  Operator op_("moe_router");
+  op_.SetParam("num_experts", num_experts);
+  op_.SetParam("top_k", top_k);
+  for (const auto &kv : kwargs) op_.SetParam(kv.first, kv.second);
+  op_.AddInput(data);
+  op_.AddInput(weight);
+  return op_.Invoke();
+}
+
 inline Symbol mp_sgd_mom_update(const std::string &symbol_name, const Symbol &weight, const Symbol &grad, const Symbol &mom, const Symbol &weight32, double lr, const std::map<std::string, std::string> &kwargs = {}) {
   Operator op_("mp_sgd_mom_update");
   op_.SetParam("lr", lr);
@@ -3913,6 +4022,19 @@ inline std::vector<NDArray> rmspropalex_update(const NDArray &weight, const NDAr
   op_.AddInput(n);
   op_.AddInput(g);
   op_.AddInput(delta);
+  return op_.Invoke();
+}
+
+inline Symbol rotary_embedding(const std::string &symbol_name, const Symbol &data, const std::map<std::string, std::string> &kwargs = {}) {
+  Operator op_("rotary_embedding");
+  for (const auto &kv : kwargs) op_.SetParam(kv.first, kv.second);
+  op_.SetInput("data", data);
+  return op_.CreateSymbol(symbol_name);
+}
+inline std::vector<NDArray> rotary_embedding(const NDArray &data, const std::map<std::string, std::string> &kwargs = {}) {
+  Operator op_("rotary_embedding");
+  for (const auto &kv : kwargs) op_.SetParam(kv.first, kv.second);
+  op_.AddInput(data);
   return op_.Invoke();
 }
 

@@ -49,6 +49,15 @@ __all__ = ["Executor", "add_build_listener", "remove_build_listener",
            "prewarm_build_count"]
 
 
+def head_cotangent(out):
+    """What a head takes when backward is given no head gradient: ones, as
+    the reference's loss heads; an integer head (a count that rides beside
+    the loss) takes no gradient, which JAX spells float0."""
+    if jnp.issubdtype(out.dtype, jnp.inexact):
+        return jnp.ones_like(out)
+    return _np.zeros(out.shape, jax.dtypes.float0)
+
+
 def device_wait(x):
     """Block until ``x`` — a device array / NDArray, or a list of them —
     has finished computing: the explicit engine-sync point of the
@@ -475,7 +484,7 @@ class Executor:
                     return outs, auxu
 
                 (outs, auxu), vjp = jax.vjp(f, gvals)
-                cts = [jnp.ones_like(o) for o in outs]
+                cts = [head_cotangent(o) for o in outs]
                 (grads,) = vjp((cts, {k: jnp.zeros_like(v)
                                       for k, v in auxu.items()}))
                 return outs, auxu, grads
@@ -740,7 +749,7 @@ class Executor:
             grads = self._pending_grads
             if grads is None and self._cached_vjp is not None:
                 vjp, auxu = self._cached_vjp
-                cts = [jnp.ones_like(o._data) for o in self.outputs]
+                cts = [head_cotangent(o._data) for o in self.outputs]
                 grads = self._get_fn("vjp_apply")(vjp, cts, auxu)
                 self._cached_vjp = None
             if grads is None and getattr(self, "_profiled_pending", False):

@@ -5,21 +5,35 @@ a token mixer and a feed-forward sublayer, between an embedding and an
 output head. What differs between members is a few choices:
 
 - ``layer_types``: one entry a layer, ``"full_attention"`` (causal softmax
-  attention through ``_contrib_FlashAttention``) or ``"linear_attention"``
-  (the gated delta rule through ``_contrib_GatedDeltaRule``, behind causal
-  short convolutions, with a gated RMSNorm on its output);
-- ``norm``: ``"layer_pre"`` (LayerNorm before each sublayer, OPT's block)
-  or ``"rms_post"`` (RMSNorm on each sublayer's output before the residual
-  add, and on q and k of full attention: the Olmo 2/3 block);
-- ``ffn``: ``"relu"`` (two biased matrices) or ``"silu_gated"`` (three,
-  (silu(x W_gate) * x W_up) W_down, no bias);
-- ``positions``: ``"learned"`` (a ``pos_emb`` table) or ``"none"``.
+  attention through ``_contrib_FlashAttention``), ``"sliding_attention"``
+  (the same under a window of ``window`` keys that ends in the query's
+  own) or ``"linear_attention"`` (the gated delta rule through
+  ``_contrib_GatedDeltaRule``, behind causal short convolutions, with a
+  gated RMSNorm on its output);
+- ``norm``: ``"layer_pre"`` (LayerNorm before each sublayer, OPT's block),
+  ``"rms_post"`` (RMSNorm on each sublayer's output before the residual
+  add, and on q and k of full attention: the Olmo 2/3 block) or
+  ``"rms_pre"`` (RMSNorm before each sublayer; q and k each through an
+  RMSNorm over the dims of a head);
+- ``ffn``: ``"relu"`` (two biased matrices), ``"silu_gated"`` (three,
+  (silu(x W_gate) * x W_up) W_down, no bias) or ``"moe"`` (a router over
+  ``num_experts``, the share of the routed experts held here through
+  ``_contrib_MoEExperts``, and a shared SiLU-gated expert beside them);
+  one name, or one a layer;
+- ``positions``: ``"learned"`` (a ``pos_emb`` table), ``"none"`` or
+  ``"rotary"`` (``_contrib_RotaryEmbedding`` on q and k inside each
+  attention layer, its parameters by the layer's type);
+- ``num_heads`` may differ by layer, ``num_kv_heads`` key/value heads are
+  shared by the query heads in groups, and ``gate="per_head"`` multiplies
+  each head's output by a sigmoid of the block's normed input.
 
 ``mxtpu.models.transformer.get_symbol`` is the ``layer_pre`` / ``relu`` /
 ``learned`` member and keeps its graph and parameter names;
 ``get_symbol`` here builds the hybrid ``rms_post`` / ``silu_gated`` /
 ``none`` member (Olmo-Hybrid: three linear-attention layers, then one of
-full attention).
+full attention); ``get_laguna_symbol`` the ``rms_pre`` / ``rotary`` member
+with window and full attention at unequal head counts over shared key/value
+heads, a per-head gate and expert layers (Laguna).
 
 Parameter names of the hybrid member (shapes as FullyConnected keeps them,
 (out, in); H heads, d = ``d_model``, f = ``d_ff``):
@@ -35,12 +49,31 @@ Parameter names of the hybrid member (shapes as FullyConnected keeps them,
   ``a_weight``, ``b_weight`` (H, d); ``A_log``, ``dt_bias`` (H,), float32
   whatever ``dtype`` is; ``o_norm_gamma`` (d_v); ``proj_weight`` (d, H d_v).
 
+Parameter names of the ``rms_pre`` member (G key/value heads of dh, H query
+heads in that layer, E experts held of width f_e):
+
+- ``tok_emb_weight``, ``norm_f_gamma``, ``lm_head_weight`` as above; every
+  layer ``mix_norm_gamma``, ``ffn_norm_gamma`` (d); ``q_weight`` (H dh, d),
+  ``k_weight``, ``v_weight`` (G dh, d), ``q_norm_gamma``, ``k_norm_gamma``
+  (dh), ``gate_weight`` (H, d), ``proj_weight`` (d, H dh);
+- a dense layer ``ff_gate_weight``, ``ff_up_weight``, ``ff_down_weight``; an
+  expert layer ``router_weight`` (num_experts, d), float32 whatever
+  ``dtype`` is, ``experts_gate_weight``, ``experts_up_weight`` (E, f_e, d),
+  ``experts_down_weight`` (E, d, f_e), and the shared expert's
+  ``shared_ff_gate_weight``, ``shared_ff_up_weight``,
+  ``shared_ff_down_weight``.
+
+With expert layers the symbol is a group: the softmax first, then each
+expert layer's loads ((E,) int32, the pairs each held expert received), which
+take no gradient and which ``fit`` fetches with the metric.
+
 Layout discipline as transformer.py's: tokens (B, T) -> (B, T, D); both
 mixers in (B, H, T, dh); every matmul a FullyConnected(flatten=False).
 """
 from .. import symbol as sym
 
 FULL, LINEAR = "full_attention", "linear_attention"
+SLIDING = "sliding_attention"
 
 
 def _fc(x, num_hidden, name, no_bias=False, flatten=False):
@@ -78,6 +111,45 @@ def attention_mix(x, seq_len, num_heads, d_model, prefix, no_bias=False,
     att = sym.transpose(att, axes=(0, 2, 1, 3))
     att = sym.reshape(att, shape=(-1, seq_len, d_model))
     return _fc(att, d_model, "%s_proj" % prefix, no_bias)
+
+
+def grouped_attention_mix(x, seq_len, num_heads, num_kv_heads, head_dim,
+                          d_model, prefix, window=0, rope=None, gate=None,
+                          norm_eps=1e-6):
+    """Proj(gate * Attn(x)): `num_heads` query heads of `head_dim` over
+    `num_kv_heads` key/value heads (query head j reads key/value head
+    j // (H / G)), causal, under `window` keys if it is not 0. q and k each
+    pass an RMSNorm over a head's dims (one learned vector a layer), then
+    `rope` (the attributes of ``_contrib_RotaryEmbedding``), if any. With
+    `gate` ``"per_head"`` each head's output is multiplied by
+    sigmoid(x W_g) before W_o. No bias anywhere."""
+
+    def heads(tag, n):
+        p = _fc(x, n * head_dim, "%s_%s" % (prefix, tag), True)
+        p = sym.reshape(p, shape=(-1, seq_len, n, head_dim))
+        if tag != "v":
+            p = sym.RMSNorm(p, eps=norm_eps, name="%s_%s_norm" % (prefix, tag))
+        p = sym.transpose(p, axes=(0, 2, 1, 3))
+        if tag != "v" and rope is not None:
+            p = sym.contrib.RotaryEmbedding(
+                p, name="%s_%s_rope" % (prefix, tag), **rope)
+        return p
+
+    q, k, v = (heads("q", num_heads), heads("k", num_kv_heads),
+               heads("v", num_kv_heads))
+    kw = {"window": window} if window else {}
+    att = sym.contrib.FlashAttention(q, k, v, causal=True,
+                                     name="%s_attn" % prefix, **kw)
+    att = sym.transpose(att, axes=(0, 2, 1, 3))
+    if gate == "per_head":
+        g = sym.Activation(_fc(x, num_heads, "%s_gate" % prefix, True),
+                           act_type="sigmoid")
+        att = sym.broadcast_mul(
+            att, sym.reshape(g, shape=(-1, seq_len, num_heads, 1)))
+    elif gate is not None:
+        raise ValueError("unknown attention gate %r" % (gate,))
+    att = sym.reshape(att, shape=(-1, seq_len, num_heads * head_dim))
+    return _fc(att, d_model, "%s_proj" % prefix, True)
 
 
 def delta_rule_mix(x, seq_len, num_heads, d_model, prefix, key_dim, value_dim,
@@ -140,10 +212,36 @@ def silu_gated_ffn(x, d_model, d_ff, prefix):
     return _fc(f, d_model, "%s_ff_down" % prefix, True)
 
 
+def moe_ffn(x, d_model, prefix, num_experts, top_k, experts_held, hidden,
+            shared_hidden=0, expert_offset=0, scale=1.0,
+            score_func="sigmoid", norm_topk=True):
+    """(y, loads): the routed experts held here, each a SiLU-gated FFN of
+    width `hidden`, weighted by a float32 router over all `num_experts`
+    (`top_k` a token), and beside them one shared, ungated expert of width
+    `shared_hidden` if it is not 0. `loads` is the second output of
+    ``_contrib_MoEExperts``."""
+    router = sym.Variable("%s_router_weight" % prefix, dtype="float32")
+    chosen = sym.contrib.MoERouter(
+        x, weight=router, num_experts=num_experts, top_k=top_k, scale=scale,
+        score_func=score_func, norm_topk=norm_topk,
+        name="%s_router" % prefix)
+    routed = sym.contrib.MoEExperts(
+        x, chosen[0], chosen[1], num_experts=num_experts,
+        experts_held=experts_held, hidden=hidden,
+        expert_offset=expert_offset, name="%s_experts" % prefix)
+    y = routed[0]
+    if shared_hidden:
+        y = y + silu_gated_ffn(x, d_model, shared_hidden, prefix + "_shared")
+    return y, routed[1]
+
+
 def _sublayer(h, fn, norm, name, eps, dropout):
-    """h + fn(LN(h)) (`layer_pre`) or h + RMSNorm(fn(h)) (`rms_post`)."""
+    """h + fn(LN(h)) (`layer_pre`), h + fn(RMSNorm(h)) (`rms_pre`) or
+    h + RMSNorm(fn(h)) (`rms_post`)."""
     if norm == "layer_pre":
         y = fn(sym.LayerNorm(h, name=name))
+    elif norm == "rms_pre":
+        y = fn(sym.RMSNorm(h, eps=eps, name=name))
     else:
         y = sym.RMSNorm(fn(h), eps=eps, name=name)
     if dropout > 0:
@@ -153,13 +251,32 @@ def _sublayer(h, fn, norm, name, eps, dropout):
 
 def build(vocab_size, seq_len, layer_types, num_heads, d_model, d_ff,
           norm="rms_post", ffn="silu_gated", positions="none", dropout=0.0,
-          max_len=None, dtype=None, norm_eps=1e-6, linear=None):
+          max_len=None, dtype=None, norm_eps=1e-6, linear=None,
+          num_kv_heads=None, head_dim=None, window=0, gate=None, rope=None,
+          moe=None):
     """Causal LM of the family: data (B, T) int tokens -> SoftmaxOutput over
     (B*T, vocab). `linear` holds the linear-attention layers' sizes
     (``key_dim``, ``value_dim``, ``conv_kernel``, ``neg_eigval``, and
-    ``num_heads`` if it differs)."""
-    assert d_model % num_heads == 0, "d_model must divide into heads"
-    assert norm in ("layer_pre", "rms_post") and ffn in ("relu", "silu_gated")
+    ``num_heads`` if it differs). `num_heads` and `ffn` may be one a layer.
+    The ``rms_pre`` member's attention takes `num_kv_heads`, `head_dim`,
+    `window` (its ``sliding_attention`` layers'), `gate` and, under
+    ``positions="rotary"``, `rope`: {layer type: attributes of
+    ``_contrib_RotaryEmbedding``}. `moe` holds the expert layers' sizes
+    (`moe_ffn`'s arguments)."""
+    layer_types = list(layer_types)
+    heads_of = list(num_heads) if isinstance(num_heads, (list, tuple)) \
+        else [num_heads] * len(layer_types)
+    ffn_of = list(ffn) if isinstance(ffn, (list, tuple)) \
+        else [ffn] * len(layer_types)
+    assert len(heads_of) == len(ffn_of) == len(layer_types), \
+        "one head count and one ffn kind a layer"
+    assert norm in ("layer_pre", "rms_post", "rms_pre")
+    assert all(f in ("relu", "silu_gated", "moe") for f in ffn_of)
+    assert positions in ("learned", "none", "rotary")
+    grouped = norm == "rms_pre"
+    if not grouped:
+        num_heads = heads_of[0]
+        assert d_model % num_heads == 0, "d_model must divide into heads"
     pre = norm == "layer_pre"
     data = sym.Variable("data")
     h = sym.Embedding(data, input_dim=vocab_size, output_dim=d_model,
@@ -177,9 +294,19 @@ def build(vocab_size, seq_len, layer_types, num_heads, d_model, d_ff,
         h = sym.broadcast_add(h, pos)
     lin = dict(linear or {})
     lin_heads = lin.pop("num_heads", num_heads)
+    loads = []
     for i, kind in enumerate(layer_types):
         p = "l%d" % i
-        if kind == FULL:
+        if grouped and kind in (FULL, SLIDING):
+            def mix(x, p=p, kind=kind, heads=heads_of[i]):
+                return grouped_attention_mix(
+                    x, seq_len, heads, num_kv_heads or heads,
+                    head_dim or d_model // heads, d_model, p,
+                    window=window if kind == SLIDING else 0,
+                    rope=(rope or {}).get(kind)
+                    if positions == "rotary" else None,
+                    gate=gate, norm_eps=norm_eps)
+        elif kind == FULL:
             def mix(x, p=p):
                 return attention_mix(
                     x, seq_len, num_heads, d_model, p, no_bias=not pre,
@@ -192,9 +319,14 @@ def build(vocab_size, seq_len, layer_types, num_heads, d_model, d_ff,
             raise ValueError("layer %d: unknown layer type %r" % (i, kind))
         h = _sublayer(h, mix, norm, p + ("_ln1" if pre else "_mix_norm"),
                       norm_eps, dropout)
-        if ffn == "relu":
+        if ffn_of[i] == "relu":
             def feed(x, p=p):
                 return relu_ffn(x, d_model, d_ff, p)
+        elif ffn_of[i] == "moe":
+            def feed(x, p=p):
+                y, load = moe_ffn(x, d_model, p, **moe)
+                loads.append(load)
+                return y
         else:
             def feed(x, p=p):
                 return silu_gated_ffn(x, d_model, d_ff, p)
@@ -208,7 +340,8 @@ def build(vocab_size, seq_len, layer_types, num_heads, d_model, d_ff,
     logits = _fc(h, vocab_size, "lm_head", no_bias=not pre, flatten=True)
     if dtype is not None:
         logits = sym.Cast(logits, dtype="float32")
-    return sym.SoftmaxOutput(logits, name="softmax")
+    out = sym.SoftmaxOutput(logits, name="softmax")
+    return sym.Group([out] + loads) if loads else out
 
 
 def get_symbol(vocab_size, seq_len, layer_types, num_heads, d_model, d_ff,
@@ -228,3 +361,25 @@ def get_symbol(vocab_size, seq_len, layer_types, num_heads, d_model, d_ff,
                          "value_dim": linear_value_dim,
                          "conv_kernel": linear_conv_kernel,
                          "neg_eigval": linear_allow_neg_eigval})
+
+
+def get_laguna_symbol(vocab_size, seq_len, layer_types, num_heads,
+                      num_kv_heads, head_dim, d_model, d_ff, mlp_layer_types,
+                      window, rope, moe, gate="per_head", norm_eps=1e-6,
+                      dtype=None):
+    """The window/full-attention expert member (Laguna): RMSNorm before each
+    sublayer and after the last block, `num_heads[i]` query heads in layer i
+    over `num_kv_heads` key/value heads of `head_dim`, a window of `window`
+    keys in the ``sliding_attention`` layers, q and k normed per head and
+    rotated by `rope[layer type]`, a per-head sigmoid gate, a SiLU-gated FFN
+    of `d_ff` in the ``dense`` layers of `mlp_layer_types` and `moe` (the
+    arguments of `moe_ffn`) in the ``sparse`` ones, no biases, an untied
+    head. Train with label = data shifted left by one, flattened to
+    (B*T,)."""
+    kinds = {"dense": "silu_gated", "sparse": "moe"}
+    return build(vocab_size, seq_len, list(layer_types), list(num_heads),
+                 d_model, d_ff, norm="rms_pre",
+                 ffn=[kinds[m] for m in mlp_layer_types], positions="rotary",
+                 dtype=dtype, norm_eps=norm_eps, num_kv_heads=num_kv_heads,
+                 head_dim=head_dim, window=window, gate=gate, rope=rope,
+                 moe=moe)

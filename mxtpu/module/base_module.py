@@ -332,6 +332,12 @@ class BaseModule:
         # cadence syncs) consumes this snapshot instead of forcing a sync
         eval_metric._device_accum = accum
 
+        # statistic heads beside the loss (an expert layer's loads) ride the
+        # metric sync's transfer; a metric never sees them
+        stat_rider = self.stat_heads_rider()
+        if stat_rider is not None and accum is not None:
+            accum.add_rider(stat_rider)
+
         # training health (docs/observability.md): the device-resident
         # stat kernels + detector suite, riding the metric-sync cadence.
         # The Monitor adapter reuses the same session detectors-off —
@@ -617,6 +623,8 @@ class BaseModule:
             # post-fit reads (and the next fit) must see live values,
             # not this run's last cadence snapshot
             eval_metric._device_accum = None
+            if stat_rider is not None and accum is not None:
+                accum.remove_rider(stat_rider)
             if health_session is not None:
                 if accum is not None:
                     accum.remove_rider(health_session)
@@ -741,6 +749,12 @@ class BaseModule:
 
     def update_metric(self, eval_metric, labels):
         raise NotImplementedError
+
+    def stat_heads_rider(self):
+        """A rider for `DeviceMetricAccum.add_rider` that carries statistic
+        heads beside the loss to the host with the metric sync, or None
+        (`Module.stat_heads_rider`)."""
+        return None
 
     def bind(self, data_shapes, label_shapes=None, for_training=True,
              inputs_need_grad=False, force_rebind=False, shared_module=None,

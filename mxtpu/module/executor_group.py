@@ -211,11 +211,15 @@ class DataParallelExecutorGroup:
                     for g in grads]
         return grads
 
-    def update_metric(self, eval_metric, labels):
+    def update_metric(self, eval_metric, labels, skip=()):
+        """`skip`: indices of outputs that are no prediction (statistic
+        heads, `Module._stat_heads`)."""
         for texec, islice in zip(self.execs, self.slices):
             labels_slice = [label[self._scaled_slice(islice, label.shape[0])]
                             for label in labels]
-            eval_metric.update(labels_slice, texec.outputs)
+            eval_metric.update(labels_slice,
+                               [o for i, o in enumerate(texec.outputs)
+                                if i not in skip])
 
     def install_monitor(self, mon):
         for exe in self.execs:

@@ -32,7 +32,7 @@ from .. import diagnostics as _diag
 from .. import random as _rnd
 from ..base import NumericsError
 from ..compile import pipeline as _pipeline
-from ..executor import _trace_graph
+from ..executor import _trace_graph, head_cotangent
 from ..ops import optimizer_ops as _ops
 
 
@@ -668,7 +668,7 @@ class FusedTrainStep:
                                                   has_aux=True)
             else:
                 (outs, auxu), vjp = jax.vjp(f, train_p)
-            cts = ([jnp.ones_like(o) for o in outs],
+            cts = ([head_cotangent(o) for o in outs],
                    {k: jnp.zeros_like(v) for k, v in auxu.items()})
             (grads,) = vjp(cts)
             new_params = dict(fixed)

@@ -60,6 +60,7 @@ class Module(BaseModule):
         self._fixed_param_names = fixed_param_names
         self._aux_names = symbol.list_auxiliary_states()
         self._output_names = symbol.list_outputs()
+        self._stat_heads_found = None
 
         self._arg_params = self._aux_params = None
         self._params_dirty = False
@@ -674,9 +675,40 @@ class Module(BaseModule):
 
     def update_metric(self, eval_metric, labels):
         if self._last_step_fused:
-            eval_metric.update(list(labels), self.get_outputs())
+            eval_metric.update(list(labels),
+                               self._metric_outputs(self.get_outputs()))
             return
-        self._exec_group.update_metric(eval_metric, labels)
+        self._exec_group.update_metric(eval_metric, labels,
+                                       skip=self._stat_head_indices())
+
+    # -------------------------------------------- statistics beside the loss
+    def _stat_heads(self):
+        """[(output index, op, attrs)] of the symbol's heads that are no
+        prediction but a statistic riding beside the loss (an expert
+        layer's loads): outputs of an op that says what to make of them
+        (`OpDef.on_fetch`). A metric never sees them; `fit` fetches them
+        with the metric's sums (`stat_heads_rider`)."""
+        if self._stat_heads_found is None:
+            self._stat_heads_found = [
+                (i, node.op, node.parsed_attrs(), idx)
+                for i, (node, idx) in enumerate(self._symbol._outputs)
+                if node.op is not None and node.op.on_fetch is not None]
+        return self._stat_heads_found
+
+    def _stat_head_indices(self):
+        return {h[0] for h in self._stat_heads()}
+
+    def _metric_outputs(self, outs):
+        skip = self._stat_head_indices()
+        return [o for i, o in enumerate(outs) if i not in skip] if skip \
+            else list(outs)
+
+    def stat_heads_rider(self):
+        """The rider (`DeviceMetricAccum.add_rider`) that takes the last
+        step's statistic heads to the host with the metric sync and hands
+        each op its own (`OpDef.on_fetch`); None where the symbol has no
+        such head."""
+        return _StatHeadsRider(self) if self._stat_heads() else None
 
     def _device_step_view(self, data_batch):
         """(labels, outputs, pacing_token) for the last step, all device
@@ -688,7 +720,7 @@ class Module(BaseModule):
             # loop must keep calling its override, not bypass it
             return None
         if self._last_step_fused:
-            outs = list(self._fused.outputs)
+            outs = self._metric_outputs(self._fused.outputs)
             labels = self._fused.last_labels
             if labels is None or len(labels) != len(data_batch.label or []):
                 labels = list(data_batch.label or [])
@@ -699,7 +731,8 @@ class Module(BaseModule):
             # metrics (MSE/MAE/RMSE: mean over merged batch != mean of
             # per-slice means); keep the numpy path's exact numerics
             return None
-        outs = self._exec_group.get_outputs(merge_multi_context=True)
+        outs = self._metric_outputs(
+            self._exec_group.get_outputs(merge_multi_context=True))
         return (list(data_batch.label or []), outs,
                 (outs[0]._data if outs else None))
 
@@ -837,3 +870,31 @@ def _parse_shapes(data_shapes, label_shapes, data_names, label_names):
         ls = [x if isinstance(x, DataDesc) else DataDesc(*x)
               for x in label_shapes]
     return ds, ls
+
+
+class _StatHeadsRider:
+    """Takes a Module's statistic heads (`Module._stat_heads`) of the last
+    step to the host in the metric sync's one transfer and hands each op its
+    own outputs."""
+
+    def __init__(self, module):
+        self._module = module
+
+    def pull(self):
+        mod = self._module
+        if mod._last_step_fused:
+            outs = mod._fused.outputs
+        else:
+            outs = [o._data for o in
+                    mod._exec_group.get_outputs(merge_multi_context=True)]
+        if not outs:
+            return None
+        return [outs[h[0]] for h in mod._stat_heads()]
+
+    def deliver(self, host):
+        by_op = {}
+        for (_, op, attrs, idx), value in zip(self._module._stat_heads(),
+                                              host):
+            by_op.setdefault(op.name, (op, []))[1].append((attrs, idx, value))
+        for op, heads in by_op.values():
+            op.on_fetch(heads)

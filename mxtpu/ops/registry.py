@@ -77,7 +77,8 @@ class OpDef:
 
     def __init__(self, name, fn, arg_names=("data",), attrs=None, num_outputs=1,
                  variadic=None, needs_rng=False, aliases=(), loss_like=False,
-                 aux_names=(), mutate_inputs=(), infer_args=None, doc=None):
+                 aux_names=(), mutate_inputs=(), infer_args=None, doc=None,
+                 on_fetch=None):
         self.name = name
         self.fn = fn
         self.arg_names = arg_names if callable(arg_names) else list(arg_names)
@@ -97,6 +98,10 @@ class OpDef:
         # InferShape pass is semantically required: weights/bias/bn stats)
         self.infer_args = infer_args
         self.mutate_inputs = mutate_inputs  # indices of inputs updated in place via out=
+        # on_fetch([(attrs, output index, host value)]): what the op makes
+        # of its own outputs among a Module's heads (a statistic that rides
+        # beside the loss) when fit fetches them at a metric sync
+        self.on_fetch = on_fetch
         self.doc = doc or (fn.__doc__ or "")
         self._jit_cache = {}
 

@@ -7,6 +7,12 @@ experts with top-1 gating; an `all_to_all` carries each device's tokens
 to the devices owning their experts and a second one brings results back
 — the standard expert-parallel exchange, riding ICI.
 
+Scores, top-k and weights come from the router of the Symbol-level expert
+layer (`mxtpu.ops.moe.route`), so the repo has one router; the dropless,
+sorted dispatch of that layer (`_contrib_MoEExperts`) runs one chip's share
+without an exchange, and this module keeps the capacity dispatch and the
+all-to-all.
+
 Capacity is fixed (static shapes for XLA): each expert takes
 ``capacity_factor * tokens / n_experts`` tokens; overflow tokens pass
 through unchanged (standard MoE overflow semantics).
@@ -17,6 +23,8 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as P
+
+from ..ops.moe import route
 
 __all__ = ["moe_apply", "moe_apply_topk", "load_balancing_loss"]
 
@@ -42,10 +50,11 @@ def moe_apply(expert_fn, expert_params, gate_logits, x, mesh=None,
     capacity = max(1, int(capacity_factor * tokens / n_experts))
 
     def local_fn(params, gates, xl):
-        probs = jax.nn.softmax(gates, axis=-1)
-        choice = jnp.argmax(probs, axis=-1)              # (tokens,)
-        gate_p = jnp.take_along_axis(probs, choice[:, None],
-                                     axis=1)[:, 0]
+        # the repo's one router (mxtpu/ops/moe.py): softmax scores, the
+        # best expert, its probability as the weight
+        gate_w, chosen = route(gates, 1, score_func="softmax",
+                               norm_topk=False)
+        choice, gate_p = chosen[:, 0], gate_w[:, 0]      # (tokens,)
 
         # slot assignment: position of each token within its expert queue
         onehot = jax.nn.one_hot(choice, n_experts, dtype=jnp.int32)
@@ -130,10 +139,9 @@ def moe_apply_topk(expert_fn, expert_params, gate_logits, x, k=2, mesh=None,
     capacity = max(1, int(capacity_factor * tokens * k / n_experts))
 
     def local_fn(params, gates, xl):
-        probs = jax.nn.softmax(gates, axis=-1)
-        topv, topi = lax.top_k(probs, k)                  # (tokens, k)
-        wsum = jnp.sum(topv, axis=-1, keepdims=True)
-        weights = topv / jnp.maximum(wsum, 1e-9)          # renormalized
+        # the repo's one router (mxtpu/ops/moe.py): softmax scores, the k
+        # best, weights renormalized over the chosen
+        weights, topi = route(gates, k, score_func="softmax")  # (tokens, k)
 
         # GShard priority: rank-0 decisions claim slots first. Build the
         # flattened decision list in rank-major order and cumsum it.

@@ -1,5 +1,5 @@
 # GENERATED by perl-package/gen_perl_ops.py — do not edit.
-# One sub per registered op (295 ops). Regenerate after registry changes.
+# One sub per registered op (301 ops). Regenerate after registry changes.
 package AI::MXTPU::NDOps;
 use strict;
 use warnings;
@@ -394,6 +394,18 @@ sub _contrib_GatedDeltaRule {
     return AI::MXTPU::invoke('_contrib_GatedDeltaRule', \@ins, %a);
 }
 
+sub _contrib_MoEExperts {
+    my @ins = splice(@_, 0, 6);
+    my %a = @_;
+    return AI::MXTPU::invoke('_contrib_MoEExperts', \@ins, %a);
+}
+
+sub _contrib_MoERouter {
+    my @ins = splice(@_, 0, 2);
+    my %a = @_;
+    return AI::MXTPU::invoke('_contrib_MoERouter', \@ins, %a);
+}
+
 sub _contrib_MultiBoxDetection {
     my @ins = splice(@_, 0, 3);
     my %a = @_;
@@ -428,6 +440,12 @@ sub _contrib_Proposal {
     my @ins = splice(@_, 0, 3);
     my %a = @_;
     return AI::MXTPU::invoke('_contrib_Proposal', \@ins, %a);
+}
+
+sub _contrib_RotaryEmbedding {
+    my @ins = splice(@_, 0, 1);
+    my %a = @_;
+    return AI::MXTPU::invoke('_contrib_RotaryEmbedding', \@ins, %a);
 }
 
 sub _contrib_count_sketch {
@@ -1372,6 +1390,18 @@ sub min {
     return AI::MXTPU::invoke('min', \@ins, %a);
 }
 
+sub moe_experts {
+    my @ins = splice(@_, 0, 6);
+    my %a = @_;
+    return AI::MXTPU::invoke('moe_experts', \@ins, %a);
+}
+
+sub moe_router {
+    my @ins = splice(@_, 0, 2);
+    my %a = @_;
+    return AI::MXTPU::invoke('moe_router', \@ins, %a);
+}
+
 sub mp_sgd_mom_update {
     my @ins = splice(@_, 0, 4);
     my %a = @_;
@@ -1508,6 +1538,12 @@ sub rmspropalex_update {
     my @ins = splice(@_, 0, 5);
     my %a = @_;
     return AI::MXTPU::invoke('rmspropalex_update', \@ins, %a);
+}
+
+sub rotary_embedding {
+    my @ins = splice(@_, 0, 1);
+    my %a = @_;
+    return AI::MXTPU::invoke('rotary_embedding', \@ins, %a);
 }
 
 sub round {

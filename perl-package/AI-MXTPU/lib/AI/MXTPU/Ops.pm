@@ -1,5 +1,5 @@
 # GENERATED by perl-package/gen_perl_ops.py — do not edit.
-# One sub per registered op (295 ops). Regenerate after registry changes.
+# One sub per registered op (301 ops). Regenerate after registry changes.
 package AI::MXTPU::Ops;
 use strict;
 use warnings;
@@ -524,6 +524,26 @@ sub _contrib_GatedDeltaRule {
     return AI::MXTPU::Symbol->create('_contrib_GatedDeltaRule', \%in, %a);
 }
 
+sub _contrib_MoEExperts {
+    my %a = @_;
+    my %in;
+    $in{'data'} = delete $a{'data'} if exists $a{'data'};
+    $in{'topk_weight'} = delete $a{'topk_weight'} if exists $a{'topk_weight'};
+    $in{'topk_index'} = delete $a{'topk_index'} if exists $a{'topk_index'};
+    $in{'gate_weight'} = delete $a{'gate_weight'} if exists $a{'gate_weight'};
+    $in{'up_weight'} = delete $a{'up_weight'} if exists $a{'up_weight'};
+    $in{'down_weight'} = delete $a{'down_weight'} if exists $a{'down_weight'};
+    return AI::MXTPU::Symbol->create('_contrib_MoEExperts', \%in, %a);
+}
+
+sub _contrib_MoERouter {
+    my %a = @_;
+    my %in;
+    $in{'data'} = delete $a{'data'} if exists $a{'data'};
+    $in{'weight'} = delete $a{'weight'} if exists $a{'weight'};
+    return AI::MXTPU::Symbol->create('_contrib_MoERouter', \%in, %a);
+}
+
 sub _contrib_MultiBoxDetection {
     my %a = @_;
     my %in;
@@ -573,6 +593,13 @@ sub _contrib_Proposal {
     $in{'bbox_pred'} = delete $a{'bbox_pred'} if exists $a{'bbox_pred'};
     $in{'im_info'} = delete $a{'im_info'} if exists $a{'im_info'};
     return AI::MXTPU::Symbol->create('_contrib_Proposal', \%in, %a);
+}
+
+sub _contrib_RotaryEmbedding {
+    my %a = @_;
+    my %in;
+    $in{'data'} = delete $a{'data'} if exists $a{'data'};
+    return AI::MXTPU::Symbol->create('_contrib_RotaryEmbedding', \%in, %a);
 }
 
 sub _contrib_count_sketch {
@@ -1747,6 +1774,26 @@ sub min {
     return AI::MXTPU::Symbol->create('min', \%in, %a);
 }
 
+sub moe_experts {
+    my %a = @_;
+    my %in;
+    $in{'data'} = delete $a{'data'} if exists $a{'data'};
+    $in{'topk_weight'} = delete $a{'topk_weight'} if exists $a{'topk_weight'};
+    $in{'topk_index'} = delete $a{'topk_index'} if exists $a{'topk_index'};
+    $in{'gate_weight'} = delete $a{'gate_weight'} if exists $a{'gate_weight'};
+    $in{'up_weight'} = delete $a{'up_weight'} if exists $a{'up_weight'};
+    $in{'down_weight'} = delete $a{'down_weight'} if exists $a{'down_weight'};
+    return AI::MXTPU::Symbol->create('moe_experts', \%in, %a);
+}
+
+sub moe_router {
+    my %a = @_;
+    my %in;
+    $in{'data'} = delete $a{'data'} if exists $a{'data'};
+    $in{'weight'} = delete $a{'weight'} if exists $a{'weight'};
+    return AI::MXTPU::Symbol->create('moe_router', \%in, %a);
+}
+
 sub mp_sgd_mom_update {
     my %a = @_;
     my %in;
@@ -1919,6 +1966,13 @@ sub rmspropalex_update {
     $in{'g'} = delete $a{'g'} if exists $a{'g'};
     $in{'delta'} = delete $a{'delta'} if exists $a{'delta'};
     return AI::MXTPU::Symbol->create('rmspropalex_update', \%in, %a);
+}
+
+sub rotary_embedding {
+    my %a = @_;
+    my %in;
+    $in{'data'} = delete $a{'data'} if exists $a{'data'};
+    return AI::MXTPU::Symbol->create('rotary_embedding', \%in, %a);
 }
 
 sub round {

@@ -1,7 +1,7 @@
 package ml.dmlc.mxtpu;
 
 // GENERATED by scala-package/gen_jvm_ops.py - do not edit.
-// One static method per registered op (295 ops). Regenerate after
+// One static method per registered op (301 ops). Regenerate after
 // registry changes. Parity: the reference scala-package's generated op
 // surface (scala-package/macros SymbolMacro/NDArrayMacro over
 // MXListAllOpNames).
@@ -422,6 +422,18 @@ public final class NDArrayOps {
     return invoke("_contrib_GatedDeltaRule", attrs, inputs);
   }
 
+  /** _contrib_MoEExperts(data, topk_weight, topk_index, gate_weight, up_weight, down_weight) */
+  public static NDArray[] _contrib_MoEExperts(
+      java.util.Map<String, String> attrs, NDArray... inputs) {
+    return invoke("_contrib_MoEExperts", attrs, inputs);
+  }
+
+  /** _contrib_MoERouter(data, weight) */
+  public static NDArray[] _contrib_MoERouter(
+      java.util.Map<String, String> attrs, NDArray... inputs) {
+    return invoke("_contrib_MoERouter", attrs, inputs);
+  }
+
   /** _contrib_MultiBoxDetection(cls_prob, loc_pred, anchor) */
   public static NDArray[] _contrib_MultiBoxDetection(
       java.util.Map<String, String> attrs, NDArray... inputs) {
@@ -456,6 +468,12 @@ public final class NDArrayOps {
   public static NDArray[] _contrib_Proposal(
       java.util.Map<String, String> attrs, NDArray... inputs) {
     return invoke("_contrib_Proposal", attrs, inputs);
+  }
+
+  /** _contrib_RotaryEmbedding(data) */
+  public static NDArray[] _contrib_RotaryEmbedding(
+      java.util.Map<String, String> attrs, NDArray... inputs) {
+    return invoke("_contrib_RotaryEmbedding", attrs, inputs);
   }
 
   /** _contrib_count_sketch(data, h, s) */
@@ -1406,6 +1424,18 @@ public final class NDArrayOps {
     return invoke("min", attrs, inputs);
   }
 
+  /** moe_experts(data, topk_weight, topk_index, gate_weight, up_weight, down_weight) */
+  public static NDArray[] moe_experts(
+      java.util.Map<String, String> attrs, NDArray... inputs) {
+    return invoke("moe_experts", attrs, inputs);
+  }
+
+  /** moe_router(data, weight) */
+  public static NDArray[] moe_router(
+      java.util.Map<String, String> attrs, NDArray... inputs) {
+    return invoke("moe_router", attrs, inputs);
+  }
+
   /** mp_sgd_mom_update(weight, grad, mom, weight32) */
   public static NDArray[] mp_sgd_mom_update(
       java.util.Map<String, String> attrs, NDArray... inputs) {
@@ -1542,6 +1572,12 @@ public final class NDArrayOps {
   public static NDArray[] rmspropalex_update(
       java.util.Map<String, String> attrs, NDArray... inputs) {
     return invoke("rmspropalex_update", attrs, inputs);
+  }
+
+  /** rotary_embedding(data) */
+  public static NDArray[] rotary_embedding(
+      java.util.Map<String, String> attrs, NDArray... inputs) {
+    return invoke("rotary_embedding", attrs, inputs);
   }
 
   /** round(data) */

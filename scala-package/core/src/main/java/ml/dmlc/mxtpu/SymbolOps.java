@@ -1,7 +1,7 @@
 package ml.dmlc.mxtpu;
 
 // GENERATED by scala-package/gen_jvm_ops.py - do not edit.
-// One static method per registered op (295 ops). Regenerate after
+// One static method per registered op (301 ops). Regenerate after
 // registry changes. Parity: the reference scala-package's generated op
 // surface (scala-package/macros SymbolMacro/NDArrayMacro over
 // MXListAllOpNames).
@@ -405,6 +405,18 @@ public final class SymbolOps {
     return Symbol.create("_contrib_GatedDeltaRule", name, attrs, new String[] {"query", "key", "value", "g", "beta"}, inputs);
   }
 
+  /** _contrib_MoEExperts(data, topk_weight, topk_index, gate_weight, up_weight, down_weight) */
+  public static Symbol _contrib_MoEExperts(String name,
+      java.util.Map<String, String> attrs, Symbol... inputs) {
+    return Symbol.create("_contrib_MoEExperts", name, attrs, new String[] {"data", "topk_weight", "topk_index", "gate_weight", "up_weight", "down_weight"}, inputs);
+  }
+
+  /** _contrib_MoERouter(data, weight) */
+  public static Symbol _contrib_MoERouter(String name,
+      java.util.Map<String, String> attrs, Symbol... inputs) {
+    return Symbol.create("_contrib_MoERouter", name, attrs, new String[] {"data", "weight"}, inputs);
+  }
+
   /** _contrib_MultiBoxDetection(cls_prob, loc_pred, anchor) */
   public static Symbol _contrib_MultiBoxDetection(String name,
       java.util.Map<String, String> attrs, Symbol... inputs) {
@@ -439,6 +451,12 @@ public final class SymbolOps {
   public static Symbol _contrib_Proposal(String name,
       java.util.Map<String, String> attrs, Symbol... inputs) {
     return Symbol.create("_contrib_Proposal", name, attrs, new String[] {"cls_prob", "bbox_pred", "im_info"}, inputs);
+  }
+
+  /** _contrib_RotaryEmbedding(data) */
+  public static Symbol _contrib_RotaryEmbedding(String name,
+      java.util.Map<String, String> attrs, Symbol... inputs) {
+    return Symbol.create("_contrib_RotaryEmbedding", name, attrs, new String[] {"data"}, inputs);
   }
 
   /** _contrib_count_sketch(data, h, s) */
@@ -1389,6 +1407,18 @@ public final class SymbolOps {
     return Symbol.create("min", name, attrs, new String[] {"data"}, inputs);
   }
 
+  /** moe_experts(data, topk_weight, topk_index, gate_weight, up_weight, down_weight) */
+  public static Symbol moe_experts(String name,
+      java.util.Map<String, String> attrs, Symbol... inputs) {
+    return Symbol.create("moe_experts", name, attrs, new String[] {"data", "topk_weight", "topk_index", "gate_weight", "up_weight", "down_weight"}, inputs);
+  }
+
+  /** moe_router(data, weight) */
+  public static Symbol moe_router(String name,
+      java.util.Map<String, String> attrs, Symbol... inputs) {
+    return Symbol.create("moe_router", name, attrs, new String[] {"data", "weight"}, inputs);
+  }
+
   /** mp_sgd_mom_update(weight, grad, mom, weight32) */
   public static Symbol mp_sgd_mom_update(String name,
       java.util.Map<String, String> attrs, Symbol... inputs) {
@@ -1525,6 +1555,12 @@ public final class SymbolOps {
   public static Symbol rmspropalex_update(String name,
       java.util.Map<String, String> attrs, Symbol... inputs) {
     return Symbol.create("rmspropalex_update", name, attrs, new String[] {"weight", "grad", "n", "g", "delta"}, inputs);
+  }
+
+  /** rotary_embedding(data) */
+  public static Symbol rotary_embedding(String name,
+      java.util.Map<String, String> attrs, Symbol... inputs) {
+    return Symbol.create("rotary_embedding", name, attrs, new String[] {"data"}, inputs);
   }
 
   /** round(data) */

@@ -298,3 +298,105 @@ def test_pallas_epilogue_matches_reference():
     want2 = bn_apply_relu_add_reference(x, scale, shift, None)
     np.testing.assert_allclose(np.asarray(got2), np.asarray(want2),
                                rtol=1e-5, atol=1e-5)
+
+
+# ------------------------------------------- grouped heads and the window
+def _grouped_case(b, h, g, t, d, dtype=jnp.float32):
+    q = _rand((b, h, t, d), 0).astype(dtype)
+    k = _rand((b, g, t, d), 1).astype(dtype)
+    v = _rand((b, g, t, d), 2).astype(dtype)
+    w = _rand((b, h, t, d), 3).astype(dtype)
+    return q, k, v, w
+
+
+def _plain_softmax(q, k, v, window):
+    """Causal attention as written, one query head at a time over the
+    key/value head it reads: no kernel, no repeat of K or V."""
+    b, h, t, d = q.shape
+    rep = h // k.shape[1]
+    rows, cols = np.arange(t)[:, None], np.arange(t)[None, :]
+    seen = cols <= rows
+    if window:
+        seen = seen & (cols > rows - window)
+    out = []
+    for j in range(h):
+        s = jnp.einsum("btd,bsd->bts", q[:, j], k[:, j // rep],
+                       precision="highest") / d ** 0.5
+        p = jax.nn.softmax(jnp.where(seen, s, -jnp.inf), axis=-1)
+        out.append(jnp.einsum("bts,bsd->btd", p, v[:, j // rep],
+                              precision="highest"))
+    return jnp.stack(out, axis=1)
+
+
+@pytest.mark.parametrize("h,g,t,window,walked", [
+    (4, 2, 256, 0, True),        # groups, full: the walk's strips
+    (6, 2, 256, 128, True),      # window = one block, edge and diagonal
+    (6, 2, 384, 256, True),      # T no multiple of the window: 128 blocks
+    (4, 4, 512, 128, True),      # no groups, several blocks between
+    (3, 1, 384, 384, True),      # a window as long as the row is none
+    (6, 2, 64, 8, False),        # the rehearsal's size: the grid kernel
+    (4, 2, 192, 100, False),     # a window that is no multiple of 128
+])
+def test_grouped_and_windowed_attention_matches_the_plain_softmax(
+        h, g, t, window, walked):
+    """Forward and gradient, kernels in interpret mode, against the
+    softmax over an explicit mask."""
+    q, k, v, w = _grouped_case(2, h, g, t, 16)
+    blocks = att._fwd_blocks(t, t, 16, 4, True, 0, 0,
+                             0 if window >= t else window)
+    assert (blocks is not None) == walked
+
+    def loss(fn):
+        return lambda q, k, v: jnp.sum(fn(q, k, v) * w)
+
+    got = att.flash_attention(q, k, v, causal=True, window=window)
+    want = _plain_softmax(q, k, v, window)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=2e-5, atol=2e-5)
+    g_got = jax.grad(loss(lambda *a: att.flash_attention(
+        *a, causal=True, window=window)), argnums=(0, 1, 2))(q, k, v)
+    g_want = jax.grad(loss(lambda *a: _plain_softmax(*a, window)),
+                      argnums=(0, 1, 2))(q, k, v)
+    for a, b_ in zip(g_got, g_want):
+        assert a.shape == b_.shape
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b_),
+                                   rtol=5e-5, atol=5e-5)
+
+
+def test_the_window_and_the_groups_are_counted():
+    """`attention_fwd_builds{walk_window}`, `attention_bwd_builds
+    {kernel_window}` and the gauges of a windowed, grouped call."""
+    from mxtpu import telemetry
+
+    def count(name, path):
+        return telemetry.counter(name, labels={"path": path}).value
+
+    def gauge(name):
+        return [m.value for m in telemetry.registry().series()
+                if m.name == name][0]
+
+    before = (count("attention_fwd_builds", "walk_window"),
+              count("attention_bwd_builds", "kernel_window"))
+    q, k, v, w = _grouped_case(1, 6, 2, 512, 16)
+    jax.grad(lambda q: jnp.sum(att.flash_attention(
+        q, k, v, causal=True, window=256) * w))(q)
+    assert count("attention_fwd_builds", "walk_window") == before[0] + 1
+    assert count("attention_bwd_builds", "kernel_window") == before[1] + 1
+    assert gauge("attention_window") == 256
+    assert gauge("attention_kv_groups") == 2
+    # 256-wide blocks, two of them: the edge block and the diagonal of the
+    # second query block, the diagonal alone of the first: 3 of 4
+    assert gauge("flash_win_live_block_share") == pytest.approx(0.75)
+    with pytest.raises(ValueError):
+        att.flash_attention(q, k, v, causal=False, window=256)
+    with pytest.raises(ValueError):
+        att.flash_attention(q, k[:, :1], v, causal=True)
+
+
+def test_the_window_op_attribute_reaches_the_kernel():
+    q, k, v, _ = _grouped_case(1, 4, 2, 128, 16)
+    got = mx.nd.contrib.FlashAttention(
+        mx.nd.NDArray(q), mx.nd.NDArray(k), mx.nd.NDArray(v), causal=True,
+        window=32).asnumpy()
+    np.testing.assert_allclose(got, np.asarray(_plain_softmax(q, k, v, 32)),
+                               rtol=2e-5, atol=2e-5)

@@ -146,3 +146,54 @@ def test_delta_rule_gradient_compiles_at_the_hybrid_cells_size(one_chip,
         assert element in bwd_operands, (index, element, bwd_operands)
     assert "2048,96,192]" not in text
     assert compiled.memory_analysis().temp_size_in_bytes < 1.2e9
+
+
+@pytest.mark.parametrize("heads,window", [(48, 0), (72, 512)],
+                         ids=["full-48-over-8", "window-72-over-8"])
+def test_grouped_attention_gradient_compiles_at_the_laguna_cells_size(
+        one_chip, no_cache, heads, window):
+    """laguna-s-2.1-fit-s4096's two attention kinds: batch 2, 48 (full) or
+    72 (window 512) query heads over 8 key/value heads of 128, 4096 tokens,
+    bf16. One forward and one backward Mosaic call, by the kind's names; K
+    and V go in as 16 heads and dK, dV come out as 16, so nothing is
+    repeated to the query heads in HBM; no (T, T) buffer."""
+    import re
+    import jax
+    import jax.numpy as jnp
+    from mxtpu.ops import attention
+    b, g, t, d = 2, 8, 4096, 128
+    q = jax.ShapeDtypeStruct((b, heads, t, d), jnp.bfloat16, sharding=one_chip)
+    kv = jax.ShapeDtypeStruct((b, g, t, d), jnp.bfloat16, sharding=one_chip)
+    assert attention._fwd_blocks(t, t, d, 2, True, 0, 0, window) == (
+        (512, 512) if window else (2048, 512))
+    assert attention._bwd_blocks(t, t, d, 2, True, window,
+                                 heads // g) == (512, 512)
+
+    def loss(q, k, v):
+        return jnp.sum(attention.flash_attention(
+            q, k, v, causal=True, window=window).astype(jnp.float32))
+
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        q, kv, kv).compile()
+    text = compiled.as_text()
+    fwd, bwd = ((attention.WIN_FWD_KERNEL_NAME, attention.WIN_BWD_KERNEL_NAME)
+                if window else
+                (attention.FWD_KERNEL_NAME, attention.BWD_KERNEL_NAME))
+    per_q, per_kv = "bf16[%d,4096,128]" % (b * heads), "bf16[16,4096,128]"
+    for name, operands in ((fwd, [per_q, per_kv, per_kv]),
+                           (bwd, [per_q, per_kv, per_kv, per_q])):
+        (line,) = [ln for ln in text.splitlines()
+                   if re.match(r"\s*%%%s[\w.]* = " % name, ln)]
+        layouts = line.split("operand_layout_constraints={", 1)[1]
+        got = re.findall(r"[a-z0-9]+\[[\d,]*\]", layouts)
+        assert got[:len(operands)] == operands, (name, got)
+    # dQ per query head, dK and dV per key/value head, from the one call
+    (results,) = re.findall(r"%%%s[\w.]* = \((.*?)\) custom-call" % bwd, text)
+    assert [r.split("{")[0] for r in results.split(", ")] == [
+        per_q, per_kv, per_kv]
+    assert text.count("custom_call_target=\"tpu_custom_call\"") == 2
+    assert "4096,4096]" not in text
+    # q, k, v, o, dO, the gradients and the float32 rows: no copy of K or V
+    # at the query heads' count beside them
+    assert compiled.memory_analysis().temp_size_in_bytes < 3 * (
+        b * heads * t * d * 2)

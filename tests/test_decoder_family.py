@@ -192,3 +192,217 @@ def test_the_kernels_carry_their_names_in_the_lowered_hlo():
     text = jax.jit(jax.grad(delta, argnums=(0, 1, 2, 3, 4))).trace(
         k, k, v, g, g).lower(lowering_platforms=("tpu",)).as_text()
     assert "mxtpu_delta_rule_fwd" in text and "mxtpu_delta_rule_bwd" in text
+
+
+# ------------------------------------------------------ the Laguna member
+import json  # noqa: E402
+import math  # noqa: E402
+import os  # noqa: E402
+
+from mxtpu.ops import rotary  # noqa: E402
+
+_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+with open(os.path.join(_ROOT, "benchmark", "configs", "laguna-s-2.1-train",
+                       "config.json")) as _f:
+    LAGUNA = json.load(_f)
+
+# sha256 of the symbols' JSON at the parent commit (ae4eb87), each built
+# under a NameManager of its own: the OPT and the
+# Olmo-Hybrid members as their cells build them, at the published and at the
+# rehearsal sizes. Their references and limits depend on names and graph.
+PARENT_JSON = {
+    ("opt-1.3b-fit-s1024", False):
+        "cec68f99864419071f9aefa595857a3098342ba96825a1f53ff208981f3b56a0",
+    ("opt-1.3b-fit-s1024", True):
+        "fd4823d640e2cf6ef7a6823814d0c38229dc258cf7b78f2c55450cab0ee16fcb",
+    ("olmo-hybrid-7b-fit-s2048", False):
+        "d5954aa2a101034f0fe8234456255df52812ff742aa435e9f16f451c5220e3fc",
+    ("olmo-hybrid-7b-fit-s2048", True):
+        "2b0b14a1e2b563066ce77df58c41b6dec4fd1967ce11cdb932efbae89e84130e",
+}
+
+
+@pytest.mark.parametrize("cell,rehearse", sorted(PARENT_JSON))
+def test_the_older_members_json_is_the_parents(cell, rehearse):
+    from benchmark import manifest
+    c = manifest.Cell(cell, rehearse=rehearse)
+    with mx.name.NameManager():
+        sym = c.config_module("program").symbol(c.config, c.traffic)
+    assert hashlib.sha256(sym.tojson().encode()).hexdigest() == \
+        PARENT_JSON[cell, rehearse]
+
+
+def test_attention_factor_is_yarns():
+    full = LAGUNA["rope_parameters"]["full_attention"]
+    assert full["attention_factor"] == pytest.approx(
+        0.1 * math.log(full["factor"]) + 1, rel=1e-12)
+
+
+def _rope_attrs(kind):
+    from benchmark import manifest
+    cell = manifest.Cell("laguna-s-2.1-fit-s4096")
+    return cell.config_module("program")._rope(LAGUNA, kind)
+
+
+@pytest.mark.parametrize("kind", ["full_attention", "sliding_attention"])
+def test_the_inverse_frequencies_are_the_formulas(kind):
+    """Both of the configuration's sets, at the published head size."""
+    a = _rope_attrs(kind)
+    got = rotary.inv_freq(a["rotary_dims"], a["rope_type"], a["theta"],
+                          a.get("factor", 1.0),
+                          a.get("original_max_position", 0),
+                          a.get("beta_fast", 32.0), a.get("beta_slow", 1.0))
+    if kind == "sliding_attention":
+        assert a["rotary_dims"] == 128 and a.get("scale", 1.0) == 1.0
+        want = [10000.0 ** (-2 * i / 128) for i in range(64)]
+    else:
+        assert a["rotary_dims"] == 64
+        assert a["scale"] == pytest.approx(1.4852030263919618)
+
+        def c(beta):
+            return 64 * math.log(8192 / (2 * math.pi * beta)) / (
+                2 * math.log(500000))
+        lo, hi = math.floor(c(32)), math.ceil(c(1))
+        assert (lo, hi) == (9, 18)
+        want = []
+        for i in range(32):
+            e = 500000.0 ** (-2 * i / 64)
+            ramp = min(max((i - lo) / (hi - lo), 0.0), 1.0)
+            want.append(e / 128 * ramp + e * (1 - ramp))
+        # below lo the published frequency, above hi a 128th of it
+        assert got[0] == pytest.approx(1.0)
+        assert got[31] == pytest.approx(500000.0 ** (-62 / 64) / 128)
+    np.testing.assert_allclose(got, np.asarray(want, np.float32), rtol=1e-6)
+
+
+@pytest.mark.parametrize("dims,scale", [(16, 1.0), (8, 1.4852030263919618)])
+def test_the_rotation_is_the_formula_and_its_gradient_the_transpose(dims,
+                                                                     scale):
+    import jax
+    import jax.numpy as jnp
+    x = np.random.RandomState(0).randn(2, 3, 24, 16).astype("float32")
+    got = rotary.rotary_embedding(jnp.asarray(x), dims, "default", 100.0,
+                                  scale=scale)
+    f = np.asarray([100.0 ** (-2 * i / dims) for i in range(dims // 2)])
+    ang = np.arange(24)[:, None] * f[None, :]
+    c, s = scale * np.cos(ang), scale * np.sin(ang)
+    u1, u2 = x[..., :dims // 2], x[..., dims // 2:dims]
+    want = np.concatenate([u1 * c - u2 * s, u2 * c + u1 * s, x[..., dims:]],
+                          axis=-1)
+    np.testing.assert_allclose(np.asarray(got), want, rtol=2e-5, atol=2e-5)
+    # <R x, y> = <x, R^T y>: the op's own gradient is the transpose
+    y = np.random.RandomState(1).randn(*x.shape).astype("float32")
+    g = jax.grad(lambda t: jnp.sum(rotary.rotary_embedding(
+        t, dims, "default", 100.0, scale=scale) * y))(jnp.asarray(x))
+    h1, h2 = y[..., :dims // 2], y[..., dims // 2:dims]
+    want_g = np.concatenate([h1 * c + h2 * s, h2 * c - h1 * s, y[..., dims:]],
+                            axis=-1)
+    np.testing.assert_allclose(np.asarray(g), want_g, rtol=2e-5, atol=2e-5)
+    # bfloat16 in, bfloat16 out
+    assert rotary.rotary_embedding(jnp.asarray(x, jnp.bfloat16), dims
+                                   ).dtype == jnp.bfloat16
+
+
+def _laguna(dtype=None, t=64):
+    from benchmark import manifest
+    cell = manifest.Cell("laguna-s-2.1-fit-s4096", rehearse=True)
+    cfg = dict(cell.config, dtype=dtype)
+    return cell.config_module("program").symbol(
+        cfg, dict(cell.traffic, seq_len=t)), cfg
+
+
+def test_the_laguna_members_parameters():
+    sym, cfg = _laguna("bfloat16")
+    assert sym.list_outputs() == ["softmax_output"] + [
+        "l%d_experts_output1" % i for i in (1, 2, 3, 4)]
+    args = sym.list_arguments()
+    shapes = dict(zip(args, sym.infer_shape(data=(2, 64))[0]))
+    types = dict(zip(args, sym.infer_type(data="float32")[0]))
+    # 4 query heads in the full layers, 6 in the window layers, over 2
+    # key/value heads of 16
+    assert shapes["l0_q_weight"] == (64, 64) and shapes["l1_q_weight"] == (96, 64)
+    assert shapes["l1_k_weight"] == shapes["l1_v_weight"] == (32, 64)
+    assert shapes["l1_q_norm_gamma"] == shapes["l1_k_norm_gamma"] == (16,)
+    assert shapes["l1_gate_weight"] == (6, 64) and shapes["l4_gate_weight"] == (4, 64)
+    assert shapes["l1_proj_weight"] == (64, 96)
+    # layer 0 dense, the others sparse: 4 of 16 experts held, a shared one
+    assert shapes["l0_ff_gate_weight"] == (128, 64) and "l0_router_weight" not in shapes
+    assert shapes["l2_router_weight"] == (16, 64)
+    assert shapes["l2_experts_gate_weight"] == shapes["l2_experts_up_weight"] == (4, 32, 64)
+    assert shapes["l2_experts_down_weight"] == (4, 64, 32)
+    assert shapes["l2_shared_ff_down_weight"] == (64, 32) and "l2_ff_gate_weight" not in shapes
+    # the router and the embedding stay float32, the rest follows bfloat16
+    assert str(np.dtype(types["l2_router_weight"])) == "float32"
+    assert str(np.dtype(types["tok_emb_weight"])) == "float32"
+    assert str(np.dtype(types["l2_experts_up_weight"])) == "bfloat16"
+    assert not [n for n in args if n.endswith("_bias") or n == "pos_emb"]
+    # the window is on the sliding layers' attention alone, rotary on q and k
+    nodes = {n["name"]: n for n in json.loads(sym.tojson())["nodes"]}
+    attr = lambda n: nodes[n].get("attrs", nodes[n].get("attr", {}))  # noqa: E731
+    assert attr("l1_attn")["window"] == "8" and "window" not in attr("l0_attn")
+    assert attr("l0_q_rope")["rope_type"] == "yarn" and attr("l0_q_rope")["rotary_dims"] == "8"
+    assert attr("l1_k_rope")["rope_type"] == "default" and attr("l1_k_rope")["rotary_dims"] == "16"
+    assert "l1_v_rope" not in nodes
+    with pytest.raises(ValueError):
+        decoder.grouped_attention_mix(mx.sym.Variable("x"), 8, 4, 2, 8, 32,
+                                      "p", gate="per_dim")
+
+
+def test_the_laguna_member_trains_through_module_fit_with_its_loads():
+    """`Module.fit`'s fused step takes the group: the metric sees the
+    softmax alone, the loads ride the metric sync and are counted."""
+    from mxtpu import telemetry
+    sym, cfg = _laguna("bfloat16")
+    ids = np.random.RandomState(3).randint(0, 512, (8, 65))
+
+    class Rows(mx.io.DataIter):
+        def __init__(self):
+            super().__init__()
+            self.batch_size = 2
+            self.provide_data = [mx.io.DataDesc("data", (2, 64))]
+            self.provide_label = [mx.io.DataDesc("softmax_label", (128,))]
+            self.at = 0
+
+        def reset(self):
+            self.at = 0
+
+        def next(self):
+            if self.at >= 8:
+                raise StopIteration
+            rows = ids[self.at:self.at + 2]
+            self.at += 2
+            return mx.io.DataBatch(
+                [mx.nd.array(rows[:, :-1].astype("float32"))],
+                [mx.nd.array(rows[:, 1:].reshape(-1).astype("float32"))],
+                pad=0, provide_data=self.provide_data,
+                provide_label=self.provide_label)
+
+    def value(name):
+        return sum(m.value for m in telemetry.registry().series()
+                   if m.name == name)
+
+    before = value("moe_pairs_routed"), value("moe_tokens_seen")
+    mod = mx.mod.Module(sym, context=mx.cpu())
+    metric = mx.metric.create("ce")
+    mod.fit(Rows(), num_epoch=2, optimizer="sgd", eval_metric=metric,
+            optimizer_params={"learning_rate": 0.05, "momentum": 0.9},
+            initializer=mx.init.Normal(0.02))
+    assert mod._fused is not None
+    outs = mod.get_outputs()
+    assert [o.shape for o in outs] == [(128, 512)] + [(4,)] * 4
+    assert np.isfinite(metric.get()[1]) and metric.get()[1] < 7.0
+    # two metric syncs (an epoch's end each), four expert layers, 128 tokens
+    assert value("moe_tokens_seen") - before[1] == 2 * 4 * 128
+    pairs = value("moe_pairs_routed") - before[0]
+    assert 0 < pairs <= 2 * 4 * 128 * 3
+    assert int(sum(o.asnumpy().sum() for o in outs[1:])) <= 4 * 128 * 3
+    # score() goes through the executors: the metric is not handed the loads
+    mod.score(Rows(), mx.metric.create("ce"))
+
+
+def test_the_laguna_member_survives_its_json():
+    sym, _ = _laguna()
+    again = mx.sym.load_json(sym.tojson())
+    assert again.list_arguments() == sym.list_arguments()
+    assert again.list_outputs() == sym.list_outputs()
+    assert again.tojson() == sym.tojson()

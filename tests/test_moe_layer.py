@@ -1,0 +1,207 @@
+"""The expert layer's two operators (`mxtpu/ops/moe.py`) against the plain
+reference's expert layer (`benchmark/references/laguna.py`): the shares of
+the result add up, nothing is dropped, the loads count the pairs."""
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+import mxtpu as mx
+from benchmark.references import laguna
+from mxtpu.ops import moe
+from mxtpu.ops.registry import get_op
+
+# the rehearsal's expert layer: 16 experts of width 32, 3 a token, a shared
+# one of 32; a chip holds 4
+CFG = {"hidden_size": 64, "router_num_experts": 16, "num_experts_per_tok": 3,
+       "moe_intermediate_size": 32, "moe_routed_scaling_factor": 2.5}
+TOKENS = 96
+
+
+def _weights(seed=0, experts=16, d=64, f=32, std=0.3):
+    ks = jax.random.split(jax.random.PRNGKey(seed), 8)
+    n = jax.random.normal
+    return {"x": n(ks[0], (TOKENS, d)),
+            "router_weight": n(ks[1], (experts, d)) * 0.5,
+            "experts_gate_weight": n(ks[2], (experts, f, d)) * std,
+            "experts_up_weight": n(ks[3], (experts, f, d)) * std,
+            "experts_down_weight": n(ks[4], (experts, d, f)) * std,
+            "shared_ff_gate_weight": n(ks[5], (f, d)) * std,
+            "shared_ff_up_weight": n(ks[6], (f, d)) * std,
+            "shared_ff_down_weight": n(ks[7], (d, f)) * std}
+
+
+def _reference_layer(w, held, offset):
+    """The plain reference's expert layer, shared expert included, holding
+    experts offset .. offset + held - 1."""
+    cfg = dict(CFG, num_experts=held, expert_offset=offset)
+    lp = {k: (v[offset:offset + held] if k.startswith("experts_") else v)
+          for k, v in w.items() if k != "x"}
+    with jax.default_matmul_precision("highest"):
+        return laguna._moe(w["x"], lp, cfg, lambda a: a, None)
+
+
+def _program_share(w, held, offset, chunk=0, shared=False):
+    """The two operators' share of the routed experts' result (and the
+    shared expert's output, if asked), and the loads. `chunk`: rows a trip
+    of the dispatch loop, so that these few pairs take several trips."""
+    rows, moe.CHUNK = moe.CHUNK, chunk or moe.CHUNK
+    try:
+        return _share(w, held, offset, shared)
+    finally:
+        moe.CHUNK = rows
+
+
+def _share(w, held, offset, shared):
+    with jax.default_matmul_precision("highest"):
+        tw, ti = moe._router_op(
+            get_op("_contrib_MoERouter").parse_attrs(
+                {"num_experts": 16, "top_k": 3, "scale": 2.5}),
+            w["x"], w["router_weight"])
+        cut = slice(offset, offset + held)
+        out, loads = moe.moe_experts(
+            w["x"], tw, ti, w["experts_gate_weight"][cut],
+            w["experts_up_weight"][cut], w["experts_down_weight"][cut],
+            16, offset)
+        if shared:
+            out = out + laguna._ffn(
+                w["x"], w["shared_ff_gate_weight"], w["shared_ff_up_weight"],
+                w["shared_ff_down_weight"], lambda a: a)
+    return out, loads, ti
+
+
+@pytest.mark.parametrize("chunk", [0, 16])
+def test_the_shares_add_up_to_the_uncut_layer(chunk):
+    """Four chips of 4 experts each (offsets 0, 4, 8, 12), the shared expert
+    counted once, give what the uncut reference gives for the whole layer:
+    forward and gradient."""
+    w = _weights(3)
+    cot = jax.random.normal(jax.random.PRNGKey(9), (TOKENS, 64))
+
+    def whole(w):
+        return jnp.sum(_reference_layer(w, 16, 0) * cot)
+
+    def shares(w):
+        total = sum(_program_share(w, 4, off, chunk, shared=off == 0)[0]
+                    for off in (0, 4, 8, 12))
+        return jnp.sum(total * cot)
+
+    np.testing.assert_allclose(
+        np.asarray(sum(_program_share(w, 4, off, chunk, shared=off == 0)[0]
+                       for off in (0, 4, 8, 12))),
+        np.asarray(_reference_layer(w, 16, 0)), rtol=2e-5, atol=2e-5)
+    g_want, g_got = jax.grad(whole)(w), jax.grad(shares)(w)
+    for name in w:
+        np.testing.assert_allclose(np.asarray(g_got[name]),
+                                   np.asarray(g_want[name]),
+                                   rtol=2e-4, atol=2e-4, err_msg=name)
+
+
+@pytest.mark.parametrize("offset", [0, 4, 12])
+def test_one_chips_share_is_the_references_share(offset):
+    w = _weights(5)
+    got, loads, ti = _program_share(w, 4, offset, shared=True)
+    np.testing.assert_allclose(np.asarray(got),
+                               np.asarray(_reference_layer(w, 4, offset)),
+                               rtol=2e-5, atol=2e-5)
+    # the loads sum to the pairs routed here, expert by expert
+    ti = np.asarray(ti)
+    want = [(ti == offset + e).sum() for e in range(4)]
+    assert list(np.asarray(loads)) == want
+    assert loads.dtype == jnp.int32
+
+
+def test_nothing_is_dropped_when_every_token_takes_one_expert():
+    """A router forced to send every token to held expert 2 (and to two
+    experts held elsewhere): all 96 pairs land in one group, none is dropped
+    or padded in, and the result is the reference's; several trips of the
+    dispatch loop."""
+    w = _weights(7)
+    bias = np.zeros((16, 64), np.float32)
+    w["x"] = w["x"].at[:, 0].set(40.0)      # one loud channel
+    bias[2, 0] = bias[9, 0] = bias[13, 0] = 1.0
+    w["router_weight"] = w["router_weight"] * 0.01 + jnp.asarray(bias)
+    got, loads, ti = _program_share(w, 4, 0, chunk=32, shared=True)
+    assert sorted(np.unique(np.asarray(ti))) == [2, 9, 13]
+    assert list(np.asarray(loads)) == [0, 0, TOKENS, 0]
+    np.testing.assert_allclose(np.asarray(got),
+                               np.asarray(_reference_layer(w, 4, 0)),
+                               rtol=1e-4, atol=1e-3)   # outputs in the 100s
+    # and a token with no expert here gets the shared expert's output alone
+    alone, loads, _ = _program_share(w, 4, 4, shared=False)
+    assert int(np.asarray(loads).sum()) == 0
+    assert float(jnp.max(jnp.abs(alone))) == 0.0
+
+
+def test_the_router_is_the_references():
+    w = _weights(11)
+    cfg = dict(CFG, num_experts=4, expert_offset=0)
+    with jax.default_matmul_precision("highest"):
+        want_w, want_i = laguna.route(w["x"], w["router_weight"], cfg)
+        got_w, got_i = moe.route(w["x"] @ w["router_weight"].T, 3, 2.5)
+    assert np.array_equal(np.asarray(got_i), np.asarray(want_i))
+    np.testing.assert_allclose(np.asarray(got_w), np.asarray(want_w),
+                               rtol=1e-6)
+    np.testing.assert_allclose(np.asarray(got_w).sum(-1), 2.5, rtol=1e-6)
+    # ties go to the lower index
+    _, tie = moe.route(jnp.zeros((2, 8)), 3)
+    assert np.asarray(tie).tolist() == [[0, 1, 2], [0, 1, 2]]
+    # bfloat16 activations still score in float32
+    tw, _ = moe._router_op(
+        get_op("_contrib_MoERouter").parse_attrs(
+            {"num_experts": 16, "top_k": 3}),
+        w["x"].astype(jnp.bfloat16), w["router_weight"])
+    assert tw.dtype == jnp.float32
+
+
+def test_the_operators_as_a_symbol_with_loads_beside_the_output():
+    """Through the Symbol: weights inferred from the attributes, the loads a
+    second, integer head that takes no gradient."""
+    x = mx.sym.Variable("data")
+    r = mx.sym.contrib.MoERouter(x, num_experts=16, top_k=3, scale=2.5,
+                                 name="router")
+    e = mx.sym.contrib.MoEExperts(x, r[0], r[1], num_experts=16,
+                                  experts_held=4, hidden=32, expert_offset=4,
+                                  name="experts")
+    net = mx.sym.Group([mx.sym.MakeLoss(mx.sym.sum(e[0])), e[1]])
+    shapes = dict(zip(net.list_arguments(),
+                      net.infer_shape(data=(2, 48, 64))[0]))
+    assert shapes == {"data": (2, 48, 64), "router_weight": (16, 64),
+                      "experts_gate_weight": (4, 32, 64),
+                      "experts_up_weight": (4, 32, 64),
+                      "experts_down_weight": (4, 64, 32)}
+    w = _weights(13)
+    args = {"data": mx.nd.NDArray(w["x"].reshape(2, 48, 64)),
+            "router_weight": mx.nd.NDArray(w["router_weight"])}
+    for k in ("gate", "up", "down"):
+        args["experts_%s_weight" % k] = mx.nd.NDArray(
+            w["experts_%s_weight" % k][4:8])
+    grads = {k: mx.nd.zeros(v.shape) for k, v in args.items()}
+    ex = net.bind(mx.cpu(), args, args_grad=grads)
+    outs = ex.forward(is_train=True)
+    ex.backward()
+    _, loads, ti = _program_share(w, 4, 4)
+    assert outs[1].asnumpy().tolist() == np.asarray(loads).tolist()
+    assert str(outs[1].dtype) == "int32"
+    assert float(np.abs(grads["experts_down_weight"].asnumpy()).sum()) > 0
+    assert float(np.abs(grads["router_weight"].asnumpy()).sum()) > 0
+
+
+def test_the_fetched_loads_are_counted():
+    from mxtpu import telemetry
+
+    def value(name):
+        return [m.value for m in telemetry.registry().series()
+                if m.name == name][0]
+
+    w = _weights(17)
+    _program_share(w, 4, 0)     # traces a layer of 96 tokens
+    moe.observe_loads([np.array([1, 2, 3, 2])])      # the series exist
+    pairs, seen = value("moe_pairs_routed"), value("moe_tokens_seen")
+    moe.observe_loads([np.array([10, 30, 20, 20]), np.array([24, 24, 24, 24])])
+    assert value("moe_pairs_routed") == pairs + 176
+    assert value("moe_tokens_seen") == seen + 2 * TOKENS
+    assert value("moe_load_max_over_mean") == pytest.approx(1.5)
+    assert value("moe_experts_held") == 4
+    assert value("moe_dispatch_rows_bound") == TOKENS * 3

@@ -210,6 +210,9 @@ SPECS = {
         attrs={"chunk": 4}, tol=dict(rtol=3e-2, atol=3e-3)),
     "_contrib_CausalConv1D": dict(primary={"data": (2, 5, 3)},
                                   attrs={"kernel": 3}),
+    "_contrib_RotaryEmbedding": dict(
+        primary={"data": (1, 2, 4, 6)},
+        attrs={"rotary_dims": 4, "theta": 100.0, "scale": 1.3}),
     "RMSNorm": dict(primary={"data": S}),
     "RMSNorm_gated": dict(op="RMSNorm", primary={"data": S},
                           attrs={"gated": True}),
@@ -224,6 +227,12 @@ SPECS = {
 
 # ops whose gradient is NOT finite-difference checked, with the reason.
 SKIP = {
+    # the expert layer's two operators: the router's second output and the
+    # experts' third input are expert indices, the experts' second output
+    # integer loads; the weights' and the layer's gradients are checked
+    # against the plain reference in tests/test_moe_layer.py
+    "_contrib_MoERouter": "index output beside the weights",
+    "_contrib_MoEExperts": "index input, integer loads beside the output",
     # integer / boolean / index outputs (no gradient by definition)
     "argmax": "integer output", "argmin": "integer output",
     "argmax_channel": "integer output", "argsort": "integer output",
