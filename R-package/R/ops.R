@@ -1,5 +1,5 @@
 # GENERATED by R-package/gen_r_ops.py -- do not edit.
-# One mx.symbol.<op> function per registered op (301 ops); tensor inputs
+# One mx.symbol.<op> function per registered op (305 ops); tensor inputs
 # are mx.symbol objects passed by name, everything else becomes a string
 # param (the reference R package generates its op surface the same way).
 
@@ -397,6 +397,18 @@
   a <- list(...)
   is.sym <- vapply(a, function(v) inherits(v, "mx.symbol"), TRUE)
   mx.symbol.create("_contrib_GatedDeltaRule", a[is.sym], a[!is.sym], name)
+}
+
+`mx.symbol._contrib_HeadGate` <- function(..., name = "") {
+  a <- list(...)
+  is.sym <- vapply(a, function(v) inherits(v, "mx.symbol"), TRUE)
+  mx.symbol.create("_contrib_HeadGate", a[is.sym], a[!is.sym], name)
+}
+
+`mx.symbol._contrib_HeadNormRotary` <- function(..., name = "") {
+  a <- list(...)
+  is.sym <- vapply(a, function(v) inherits(v, "mx.symbol"), TRUE)
+  mx.symbol.create("_contrib_HeadNormRotary", a[is.sym], a[!is.sym], name)
 }
 
 `mx.symbol._contrib_MoEExperts` <- function(..., name = "") {
@@ -1279,6 +1291,18 @@
   a <- list(...)
   is.sym <- vapply(a, function(v) inherits(v, "mx.symbol"), TRUE)
   mx.symbol.create("gather_nd", a[is.sym], a[!is.sym], name)
+}
+
+`mx.symbol.head_gate` <- function(..., name = "") {
+  a <- list(...)
+  is.sym <- vapply(a, function(v) inherits(v, "mx.symbol"), TRUE)
+  mx.symbol.create("head_gate", a[is.sym], a[!is.sym], name)
+}
+
+`mx.symbol.head_norm_rotary` <- function(..., name = "") {
+  a <- list(...)
+  is.sym <- vapply(a, function(v) inherits(v, "mx.symbol"), TRUE)
+  mx.symbol.create("head_norm_rotary", a[is.sym], a[!is.sym], name)
 }
 
 `mx.symbol.identity` <- function(..., name = "") {

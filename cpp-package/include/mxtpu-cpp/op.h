@@ -1087,6 +1087,38 @@ inline std::vector<NDArray> _contrib_GatedDeltaRule(const NDArray &query, const 
   return op_.Invoke();
 }
 
+inline Symbol _contrib_HeadGate(const std::string &symbol_name, const Symbol &data, const Symbol &gate, const std::map<std::string, std::string> &kwargs = {}) {
+  Operator op_("_contrib_HeadGate");
+  for (const auto &kv : kwargs) op_.SetParam(kv.first, kv.second);
+  op_.SetInput("data", data);
+  op_.SetInput("gate", gate);
+  return op_.CreateSymbol(symbol_name);
+}
+inline std::vector<NDArray> _contrib_HeadGate(const NDArray &data, const NDArray &gate, const std::map<std::string, std::string> &kwargs = {}) {
+  Operator op_("_contrib_HeadGate");
+  for (const auto &kv : kwargs) op_.SetParam(kv.first, kv.second);
+  op_.AddInput(data);
+  op_.AddInput(gate);
+  return op_.Invoke();
+}
+
+inline Symbol _contrib_HeadNormRotary(const std::string &symbol_name, const Symbol &data, const Symbol &gamma, int num_heads, const std::map<std::string, std::string> &kwargs = {}) {
+  Operator op_("_contrib_HeadNormRotary");
+  op_.SetParam("num_heads", num_heads);
+  for (const auto &kv : kwargs) op_.SetParam(kv.first, kv.second);
+  op_.SetInput("data", data);
+  op_.SetInput("gamma", gamma);
+  return op_.CreateSymbol(symbol_name);
+}
+inline std::vector<NDArray> _contrib_HeadNormRotary(const NDArray &data, const NDArray &gamma, int num_heads, const std::map<std::string, std::string> &kwargs = {}) {
+  Operator op_("_contrib_HeadNormRotary");
+  op_.SetParam("num_heads", num_heads);
+  for (const auto &kv : kwargs) op_.SetParam(kv.first, kv.second);
+  op_.AddInput(data);
+  op_.AddInput(gamma);
+  return op_.Invoke();
+}
+
 inline Symbol _contrib_MoEExperts(const std::string &symbol_name, const Symbol &data, const Symbol &topk_weight, const Symbol &topk_index, const Symbol &gate_weight, const Symbol &up_weight, const Symbol &down_weight, int num_experts, int experts_held, int hidden, const std::map<std::string, std::string> &kwargs = {}) {
   Operator op_("_contrib_MoEExperts");
   op_.SetParam("num_experts", num_experts);
@@ -3344,6 +3376,38 @@ inline std::vector<NDArray> gather_nd(const NDArray &data, const NDArray &indice
   for (const auto &kv : kwargs) op_.SetParam(kv.first, kv.second);
   op_.AddInput(data);
   op_.AddInput(indices);
+  return op_.Invoke();
+}
+
+inline Symbol head_gate(const std::string &symbol_name, const Symbol &data, const Symbol &gate, const std::map<std::string, std::string> &kwargs = {}) {
+  Operator op_("head_gate");
+  for (const auto &kv : kwargs) op_.SetParam(kv.first, kv.second);
+  op_.SetInput("data", data);
+  op_.SetInput("gate", gate);
+  return op_.CreateSymbol(symbol_name);
+}
+inline std::vector<NDArray> head_gate(const NDArray &data, const NDArray &gate, const std::map<std::string, std::string> &kwargs = {}) {
+  Operator op_("head_gate");
+  for (const auto &kv : kwargs) op_.SetParam(kv.first, kv.second);
+  op_.AddInput(data);
+  op_.AddInput(gate);
+  return op_.Invoke();
+}
+
+inline Symbol head_norm_rotary(const std::string &symbol_name, const Symbol &data, const Symbol &gamma, int num_heads, const std::map<std::string, std::string> &kwargs = {}) {
+  Operator op_("head_norm_rotary");
+  op_.SetParam("num_heads", num_heads);
+  for (const auto &kv : kwargs) op_.SetParam(kv.first, kv.second);
+  op_.SetInput("data", data);
+  op_.SetInput("gamma", gamma);
+  return op_.CreateSymbol(symbol_name);
+}
+inline std::vector<NDArray> head_norm_rotary(const NDArray &data, const NDArray &gamma, int num_heads, const std::map<std::string, std::string> &kwargs = {}) {
+  Operator op_("head_norm_rotary");
+  op_.SetParam("num_heads", num_heads);
+  for (const auto &kv : kwargs) op_.SetParam(kv.first, kv.second);
+  op_.AddInput(data);
+  op_.AddInput(gamma);
   return op_.Invoke();
 }
 

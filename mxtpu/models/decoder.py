@@ -21,8 +21,9 @@ output head. What differs between members is a few choices:
   ``_contrib_MoEExperts``, and a shared SiLU-gated expert beside them);
   one name, or one a layer;
 - ``positions``: ``"learned"`` (a ``pos_emb`` table), ``"none"`` or
-  ``"rotary"`` (``_contrib_RotaryEmbedding`` on q and k inside each
-  attention layer, its parameters by the layer's type);
+  ``"rotary"`` (q and k turned inside each attention layer as
+  ``_contrib_RotaryEmbedding`` turns them, its parameters by the layer's
+  type);
 - ``num_heads`` may differ by layer, ``num_kv_heads`` key/value heads are
   shared by the query heads in groups, and ``gate="per_head"`` multiplies
   each head's output by a sigmoid of the block's normed input.
@@ -120,35 +121,35 @@ def grouped_attention_mix(x, seq_len, num_heads, num_kv_heads, head_dim,
     `num_kv_heads` key/value heads (query head j reads key/value head
     j // (H / G)), causal, under `window` keys if it is not 0. q and k each
     pass an RMSNorm over a head's dims (one learned vector a layer), then
-    `rope` (the attributes of ``_contrib_RotaryEmbedding``), if any. With
-    `gate` ``"per_head"`` each head's output is multiplied by
-    sigmoid(x W_g) before W_o. No bias anywhere."""
+    `rope` (the attributes of ``_contrib_RotaryEmbedding``), if any: one
+    ``_contrib_HeadNormRotary`` each. With `gate` ``"per_head"`` each
+    head's output is multiplied by sigmoid(x W_g) before W_o
+    (``_contrib_HeadGate``). No bias anywhere."""
 
     def heads(tag, n):
         p = _fc(x, n * head_dim, "%s_%s" % (prefix, tag), True)
-        p = sym.reshape(p, shape=(-1, seq_len, n, head_dim))
-        if tag != "v":
-            p = sym.RMSNorm(p, eps=norm_eps, name="%s_%s_norm" % (prefix, tag))
-        p = sym.transpose(p, axes=(0, 2, 1, 3))
-        if tag != "v" and rope is not None:
-            p = sym.contrib.RotaryEmbedding(
-                p, name="%s_%s_rope" % (prefix, tag), **rope)
-        return p
+        if tag == "v":
+            return _heads(p, seq_len, n, head_dim)
+        # norm, rotation and head transpose in one pass over the projection
+        return sym.contrib.HeadNormRotary(
+            p, num_heads=n, eps=norm_eps, name="%s_%s_norm" % (prefix, tag),
+            **(rope or {"rope_type": "none"}))
 
     q, k, v = (heads("q", num_heads), heads("k", num_kv_heads),
                heads("v", num_kv_heads))
     kw = {"window": window} if window else {}
     att = sym.contrib.FlashAttention(q, k, v, causal=True,
                                      name="%s_attn" % prefix, **kw)
-    att = sym.transpose(att, axes=(0, 2, 1, 3))
     if gate == "per_head":
-        g = sym.Activation(_fc(x, num_heads, "%s_gate" % prefix, True),
-                           act_type="sigmoid")
-        att = sym.broadcast_mul(
-            att, sym.reshape(g, shape=(-1, seq_len, num_heads, 1)))
+        # the gate and the transpose back in one pass over the output
+        att = sym.contrib.HeadGate(
+            att, _fc(x, num_heads, "%s_gate" % prefix, True),
+            name="%s_gated" % prefix)
     elif gate is not None:
         raise ValueError("unknown attention gate %r" % (gate,))
-    att = sym.reshape(att, shape=(-1, seq_len, num_heads * head_dim))
+    else:
+        att = sym.reshape(sym.transpose(att, axes=(0, 2, 1, 3)),
+                          shape=(-1, seq_len, num_heads * head_dim))
     return _fc(att, d_model, "%s_proj" % prefix, True)
 
 

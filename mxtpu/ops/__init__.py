@@ -14,5 +14,6 @@ from . import custom  # noqa: F401
 from . import attention  # noqa: F401
 from . import delta_rule  # noqa: F401
 from . import rotary  # noqa: F401
+from . import heads  # noqa: F401
 from . import moe  # noqa: F401
 from .registry import OpDef, get_op, list_ops, op_exists, register  # noqa: F401

@@ -1,5 +1,5 @@
 # GENERATED by perl-package/gen_perl_ops.py — do not edit.
-# One sub per registered op (301 ops). Regenerate after registry changes.
+# One sub per registered op (305 ops). Regenerate after registry changes.
 package AI::MXTPU::NDOps;
 use strict;
 use warnings;
@@ -392,6 +392,18 @@ sub _contrib_GatedDeltaRule {
     my @ins = splice(@_, 0, 5);
     my %a = @_;
     return AI::MXTPU::invoke('_contrib_GatedDeltaRule', \@ins, %a);
+}
+
+sub _contrib_HeadGate {
+    my @ins = splice(@_, 0, 2);
+    my %a = @_;
+    return AI::MXTPU::invoke('_contrib_HeadGate', \@ins, %a);
+}
+
+sub _contrib_HeadNormRotary {
+    my @ins = splice(@_, 0, 2);
+    my %a = @_;
+    return AI::MXTPU::invoke('_contrib_HeadNormRotary', \@ins, %a);
 }
 
 sub _contrib_MoEExperts {
@@ -1269,6 +1281,18 @@ sub gather_nd {
     my @ins = splice(@_, 0, 2);
     my %a = @_;
     return AI::MXTPU::invoke('gather_nd', \@ins, %a);
+}
+
+sub head_gate {
+    my @ins = splice(@_, 0, 2);
+    my %a = @_;
+    return AI::MXTPU::invoke('head_gate', \@ins, %a);
+}
+
+sub head_norm_rotary {
+    my @ins = splice(@_, 0, 2);
+    my %a = @_;
+    return AI::MXTPU::invoke('head_norm_rotary', \@ins, %a);
 }
 
 sub identity {

@@ -1,5 +1,5 @@
 # GENERATED by perl-package/gen_perl_ops.py — do not edit.
-# One sub per registered op (301 ops). Regenerate after registry changes.
+# One sub per registered op (305 ops). Regenerate after registry changes.
 package AI::MXTPU::Ops;
 use strict;
 use warnings;
@@ -522,6 +522,22 @@ sub _contrib_GatedDeltaRule {
     $in{'g'} = delete $a{'g'} if exists $a{'g'};
     $in{'beta'} = delete $a{'beta'} if exists $a{'beta'};
     return AI::MXTPU::Symbol->create('_contrib_GatedDeltaRule', \%in, %a);
+}
+
+sub _contrib_HeadGate {
+    my %a = @_;
+    my %in;
+    $in{'data'} = delete $a{'data'} if exists $a{'data'};
+    $in{'gate'} = delete $a{'gate'} if exists $a{'gate'};
+    return AI::MXTPU::Symbol->create('_contrib_HeadGate', \%in, %a);
+}
+
+sub _contrib_HeadNormRotary {
+    my %a = @_;
+    my %in;
+    $in{'data'} = delete $a{'data'} if exists $a{'data'};
+    $in{'gamma'} = delete $a{'gamma'} if exists $a{'gamma'};
+    return AI::MXTPU::Symbol->create('_contrib_HeadNormRotary', \%in, %a);
 }
 
 sub _contrib_MoEExperts {
@@ -1627,6 +1643,22 @@ sub gather_nd {
     $in{'data'} = delete $a{'data'} if exists $a{'data'};
     $in{'indices'} = delete $a{'indices'} if exists $a{'indices'};
     return AI::MXTPU::Symbol->create('gather_nd', \%in, %a);
+}
+
+sub head_gate {
+    my %a = @_;
+    my %in;
+    $in{'data'} = delete $a{'data'} if exists $a{'data'};
+    $in{'gate'} = delete $a{'gate'} if exists $a{'gate'};
+    return AI::MXTPU::Symbol->create('head_gate', \%in, %a);
+}
+
+sub head_norm_rotary {
+    my %a = @_;
+    my %in;
+    $in{'data'} = delete $a{'data'} if exists $a{'data'};
+    $in{'gamma'} = delete $a{'gamma'} if exists $a{'gamma'};
+    return AI::MXTPU::Symbol->create('head_norm_rotary', \%in, %a);
 }
 
 sub identity {

@@ -1,7 +1,7 @@
 package ml.dmlc.mxtpu;
 
 // GENERATED by scala-package/gen_jvm_ops.py - do not edit.
-// One static method per registered op (301 ops). Regenerate after
+// One static method per registered op (305 ops). Regenerate after
 // registry changes. Parity: the reference scala-package's generated op
 // surface (scala-package/macros SymbolMacro/NDArrayMacro over
 // MXListAllOpNames).
@@ -420,6 +420,18 @@ public final class NDArrayOps {
   public static NDArray[] _contrib_GatedDeltaRule(
       java.util.Map<String, String> attrs, NDArray... inputs) {
     return invoke("_contrib_GatedDeltaRule", attrs, inputs);
+  }
+
+  /** _contrib_HeadGate(data, gate) */
+  public static NDArray[] _contrib_HeadGate(
+      java.util.Map<String, String> attrs, NDArray... inputs) {
+    return invoke("_contrib_HeadGate", attrs, inputs);
+  }
+
+  /** _contrib_HeadNormRotary(data, gamma) */
+  public static NDArray[] _contrib_HeadNormRotary(
+      java.util.Map<String, String> attrs, NDArray... inputs) {
+    return invoke("_contrib_HeadNormRotary", attrs, inputs);
   }
 
   /** _contrib_MoEExperts(data, topk_weight, topk_index, gate_weight, up_weight, down_weight) */
@@ -1302,6 +1314,18 @@ public final class NDArrayOps {
   public static NDArray[] gather_nd(
       java.util.Map<String, String> attrs, NDArray... inputs) {
     return invoke("gather_nd", attrs, inputs);
+  }
+
+  /** head_gate(data, gate) */
+  public static NDArray[] head_gate(
+      java.util.Map<String, String> attrs, NDArray... inputs) {
+    return invoke("head_gate", attrs, inputs);
+  }
+
+  /** head_norm_rotary(data, gamma) */
+  public static NDArray[] head_norm_rotary(
+      java.util.Map<String, String> attrs, NDArray... inputs) {
+    return invoke("head_norm_rotary", attrs, inputs);
   }
 
   /** identity(data) */

@@ -1,7 +1,7 @@
 package ml.dmlc.mxtpu;
 
 // GENERATED by scala-package/gen_jvm_ops.py - do not edit.
-// One static method per registered op (301 ops). Regenerate after
+// One static method per registered op (305 ops). Regenerate after
 // registry changes. Parity: the reference scala-package's generated op
 // surface (scala-package/macros SymbolMacro/NDArrayMacro over
 // MXListAllOpNames).
@@ -403,6 +403,18 @@ public final class SymbolOps {
   public static Symbol _contrib_GatedDeltaRule(String name,
       java.util.Map<String, String> attrs, Symbol... inputs) {
     return Symbol.create("_contrib_GatedDeltaRule", name, attrs, new String[] {"query", "key", "value", "g", "beta"}, inputs);
+  }
+
+  /** _contrib_HeadGate(data, gate) */
+  public static Symbol _contrib_HeadGate(String name,
+      java.util.Map<String, String> attrs, Symbol... inputs) {
+    return Symbol.create("_contrib_HeadGate", name, attrs, new String[] {"data", "gate"}, inputs);
+  }
+
+  /** _contrib_HeadNormRotary(data, gamma) */
+  public static Symbol _contrib_HeadNormRotary(String name,
+      java.util.Map<String, String> attrs, Symbol... inputs) {
+    return Symbol.create("_contrib_HeadNormRotary", name, attrs, new String[] {"data", "gamma"}, inputs);
   }
 
   /** _contrib_MoEExperts(data, topk_weight, topk_index, gate_weight, up_weight, down_weight) */
@@ -1285,6 +1297,18 @@ public final class SymbolOps {
   public static Symbol gather_nd(String name,
       java.util.Map<String, String> attrs, Symbol... inputs) {
     return Symbol.create("gather_nd", name, attrs, new String[] {"data", "indices"}, inputs);
+  }
+
+  /** head_gate(data, gate) */
+  public static Symbol head_gate(String name,
+      java.util.Map<String, String> attrs, Symbol... inputs) {
+    return Symbol.create("head_gate", name, attrs, new String[] {"data", "gate"}, inputs);
+  }
+
+  /** head_norm_rotary(data, gamma) */
+  public static Symbol head_norm_rotary(String name,
+      java.util.Map<String, String> attrs, Symbol... inputs) {
+    return Symbol.create("head_norm_rotary", name, attrs, new String[] {"data", "gamma"}, inputs);
   }
 
   /** identity(data) */

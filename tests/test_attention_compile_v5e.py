@@ -197,3 +197,92 @@ def test_grouped_attention_gradient_compiles_at_the_laguna_cells_size(
     # at the query heads' count beside them
     assert compiled.memory_analysis().temp_size_in_bytes < 3 * (
         b * heads * t * d * 2)
+
+
+_LAGUNA_ROPE = {
+    # the window layers': every dim, paired (i, i + 64)
+    "default": dict(rotary_dims=0, rope_type="default", theta=10000.0),
+    # the full layers': YaRN over the first 64 dims, paired (i, i + 32)
+    "yarn": dict(rotary_dims=64, rope_type="yarn", theta=500000.0,
+                 factor=32.0, original_max_position=4096, beta_fast=32.0,
+                 beta_slow=1.0, scale=1.4852),
+}
+
+
+@pytest.mark.parametrize("heads,kind", [(72, "default"), (48, "yarn"),
+                                        (8, "default")],
+                         ids=["window-q-72", "full-q-48", "k-8"])
+def test_head_prep_gradient_compiles_at_the_laguna_cells_size(
+        one_chip, no_cache, heads, kind):
+    """laguna-s-2.1-fit-s4096's head preparation: batch 2, 4096 tokens, 72
+    or 48 query heads or the 8 key/value heads of 128, bf16, both rotary
+    kinds. One forward and one backward Mosaic call by their names; the
+    projection goes in as (B, T, n dh) and the kernel's operand comes out
+    as (B, n, T, dh), and back; no float32 buffer of a whole q, either
+    way round, is anywhere."""
+    import re
+    import jax
+    import jax.numpy as jnp
+    from mxtpu.ops import heads as hd
+    b, t, dh = 2, 4096, 128
+    x = jax.ShapeDtypeStruct((b, t, heads * dh), jnp.bfloat16,
+                             sharding=one_chip)
+    gamma = jax.ShapeDtypeStruct((dh,), jnp.bfloat16, sharding=one_chip)
+    w = jax.ShapeDtypeStruct((b, heads, t, dh), jnp.bfloat16,
+                             sharding=one_chip)
+    assert hd._prep_blocks(t, heads, dh, 2) == (512, 8)
+
+    def both(x, gamma, w):
+        out, vjp = jax.vjp(lambda x, gamma: hd.head_norm_rotary(
+            x, gamma, heads, 1e-6, **_LAGUNA_ROPE[kind]), x, gamma)
+        return (out,) + vjp(w)
+
+    text = jax.jit(both).lower(x, gamma, w).compile().as_text()
+    flat, by_head = ("bf16[2,4096,%d]" % (heads * dh),
+                     "bf16[2,%d,4096,128]" % heads)
+    (fwd,) = re.findall(r"%%%s[\w.]* = (\S+?)\{.*? custom-call"
+                        % hd.PREP_FWD_KERNEL_NAME, text)
+    assert fwd == by_head
+    (bwd,) = re.findall(r"%%%s[\w.]* = \((.*?)\) custom-call"
+                        % hd.PREP_BWD_KERNEL_NAME, text)
+    assert [r.split("{")[0] for r in bwd.split(", ")] == [
+        flat, "f32[2,8,8,128]"]
+    assert text.count("custom_call_target=\"tpu_custom_call\"") == 2
+    assert not re.findall(r"f32\[2,(?:%d,4096|4096,%d),128\]"
+                          % (heads, heads), text)
+    assert "f32[2,4096,%d]" % (heads * dh) not in text
+
+
+@pytest.mark.parametrize("heads", [72, 48])
+def test_head_gate_gradient_compiles_at_the_laguna_cells_size(
+        one_chip, no_cache, heads):
+    """The gate of a window and of a full layer: the kernel's output goes in
+    as (B, H, T, dh) and what the output projection reads comes out as
+    (B, T, H dh); the backward returns dAtt in the kernel's layout and the
+    logits' gradient as float32 rows."""
+    import re
+    import jax
+    import jax.numpy as jnp
+    from mxtpu.ops import heads as hd
+    b, t, dh = 2, 4096, 128
+    att = jax.ShapeDtypeStruct((b, heads, t, dh), jnp.bfloat16,
+                               sharding=one_chip)
+    g = jax.ShapeDtypeStruct((b, t, heads), jnp.bfloat16, sharding=one_chip)
+    w = jax.ShapeDtypeStruct((b, t, heads * dh), jnp.bfloat16,
+                             sharding=one_chip)
+
+    def both(att, g, w):
+        out, vjp = jax.vjp(hd.head_gate, att, g)
+        return (out,) + vjp(w)
+
+    text = jax.jit(both).lower(att, g, w).compile().as_text()
+    (fwd,) = re.findall(r"%%%s[\w.]* = (\S+?)\{.*? custom-call"
+                        % hd.GATE_FWD_KERNEL_NAME, text)
+    assert fwd == "bf16[2,4096,%d]" % (heads * dh)
+    (bwd,) = re.findall(r"%%%s[\w.]* = \((.*?)\) custom-call"
+                        % hd.GATE_BWD_KERNEL_NAME, text)
+    assert [r.split("{")[0] for r in bwd.split(", ")] == [
+        "bf16[2,%d,4096,128]" % heads, "f32[2,4096,%d]" % heads]
+    assert text.count("custom_call_target=\"tpu_custom_call\"") == 2
+    assert not re.findall(r"f32\[2,(?:%d,4096|4096,%d),128\]"
+                          % (heads, heads), text)

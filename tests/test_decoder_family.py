@@ -340,9 +340,12 @@ def test_the_laguna_members_parameters():
     nodes = {n["name"]: n for n in json.loads(sym.tojson())["nodes"]}
     attr = lambda n: nodes[n].get("attrs", nodes[n].get("attr", {}))  # noqa: E731
     assert attr("l1_attn")["window"] == "8" and "window" not in attr("l0_attn")
-    assert attr("l0_q_rope")["rope_type"] == "yarn" and attr("l0_q_rope")["rotary_dims"] == "8"
-    assert attr("l1_k_rope")["rope_type"] == "default" and attr("l1_k_rope")["rotary_dims"] == "16"
-    assert "l1_v_rope" not in nodes
+    # (one node norms, turns and transposes a q or a k)
+    assert attr("l0_q_norm")["rope_type"] == "yarn" and attr("l0_q_norm")["rotary_dims"] == "8"
+    assert attr("l1_k_norm")["rope_type"] == "default" and attr("l1_k_norm")["rotary_dims"] == "16"
+    assert attr("l1_q_norm")["num_heads"] == "6" and attr("l1_k_norm")["num_heads"] == "2"
+    assert nodes["l1_q_norm"]["op"] == "_contrib_HeadNormRotary"
+    assert nodes["l1_gated"]["op"] == "_contrib_HeadGate" and "l1_v_norm" not in nodes
     with pytest.raises(ValueError):
         decoder.grouped_attention_mix(mx.sym.Variable("x"), 8, 4, 2, 8, 32,
                                       "p", gate="per_dim")

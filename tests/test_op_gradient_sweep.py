@@ -213,6 +213,12 @@ SPECS = {
     "_contrib_RotaryEmbedding": dict(
         primary={"data": (1, 2, 4, 6)},
         attrs={"rotary_dims": 4, "theta": 100.0, "scale": 1.3}),
+    "_contrib_HeadNormRotary": dict(
+        primary={"data": (1, 4, 12)},
+        attrs={"num_heads": 2, "rotary_dims": 4, "theta": 100.0,
+               "scale": 1.3}),
+    "_contrib_HeadGate": dict(primary={"data": (1, 2, 4, 3),
+                                       "gate": (1, 4, 2)}),
     "RMSNorm": dict(primary={"data": S}),
     "RMSNorm_gated": dict(op="RMSNorm", primary={"data": S},
                           attrs={"gated": True}),

@@ -255,3 +255,94 @@ def test_pallas_epilogue_kernel_on_chip(m, c, residual):
         None if r is None else r.astype(jnp.float32)))
     # one bf16 rounding of the output: 2^-8 relative
     np.testing.assert_allclose(got, want, rtol=1e-2, atol=1e-2)
+
+
+def _rel(got, want):
+    import jax.numpy as jnp
+    got, want = (jnp.asarray(a, "float32") for a in (got, want))
+    return float(jnp.linalg.norm(got - want) / jnp.linalg.norm(want))
+
+
+def _with_grads(f):
+    """(f(a, b), its gradients under the weights w) as one program."""
+    import jax
+
+    def run(a, b, w):
+        out, vjp = jax.vjp(f, a, b)
+        return (out,) + vjp(w.astype(out.dtype))
+    return jax.jit(run)
+
+
+_HEAD_ROPES = {
+    "default": dict(rotary_dims=0, rope_type="default", theta=10000.0),
+    "yarn": dict(rotary_dims=64, rope_type="yarn", theta=500000.0,
+                 factor=32.0, original_max_position=4096, beta_fast=32.0,
+                 beta_slow=1.0, scale=1.4852),
+}
+
+
+@pytest.mark.parametrize("heads,kind", [(72, "default"), (48, "yarn"),
+                                        (8, "yarn")])
+def test_head_prep_kernels_on_chip(heads, kind):
+    """laguna-s-2.1-fit-s4096's head preparation at the cell's sizes, the
+    Mosaic kernels: values and both gradients against the chain it replaces
+    (RMSNorm, transpose, RotaryEmbedding) taken in float32, at a bfloat16
+    rounding or two, and no further from it than the bfloat16 chain is."""
+    import jax
+    import jax.numpy as jnp
+    from mxtpu.ops import heads as hd, rotary
+    from mxtpu.ops.nn import _rms_norm
+    from mxtpu.ops.registry import AttrDict
+    _require_accel()
+    b, t, dh, rope = 2, 4096, 128, _HEAD_ROPES[kind]
+    rng = np.random.RandomState(0)
+    x = jnp.asarray(rng.randn(b, t, heads * dh), "bfloat16")
+    gamma = jnp.asarray(1.0 + 0.2 * rng.randn(dh), "bfloat16")
+    w = jnp.asarray(rng.randn(b, heads, t, dh), "bfloat16")
+
+    def fused(x, gamma):
+        return hd.head_norm_rotary(x, gamma, heads, 1e-6, **rope)
+
+    def chain(x, gamma):
+        p = _rms_norm(AttrDict(axis=-1, eps=1e-6),
+                      x.reshape(b, t, heads, dh), gamma)
+        return rotary.rotary_embedding(p.transpose(0, 2, 1, 3), **rope)
+
+    assert _with_grads(fused).lower(x, gamma, w).as_text().count(
+        "tpu_custom_call") == 2
+    got = _with_grads(fused)(x, gamma, w)
+    old = _with_grads(chain)(x, gamma, w)
+    want = _with_grads(chain)(*(a.astype("float32") for a in (x, gamma, w)))
+    for new_, old_, want_ in zip(got, old, want):
+        assert new_.dtype == jnp.bfloat16
+        assert _rel(new_, want_) < 6e-3
+        assert _rel(new_, want_) <= 1.05 * _rel(old_, want_)
+
+
+@pytest.mark.parametrize("heads", [72, 48])
+def test_head_gate_kernels_on_chip(heads):
+    """The head gate at the cell's sizes against transpose, sigmoid,
+    broadcast multiply and reshape in float32."""
+    import jax
+    import jax.numpy as jnp
+    from mxtpu.ops import heads as hd
+    _require_accel()
+    b, t, dh = 2, 4096, 128
+    rng = np.random.RandomState(1)
+    att = jnp.asarray(rng.randn(b, heads, t, dh), "bfloat16")
+    g = jnp.asarray(rng.randn(b, t, heads), "bfloat16")
+    w = jnp.asarray(rng.randn(b, t, heads * dh), "bfloat16")
+
+    def chain(att, g):
+        out = att.transpose(0, 2, 1, 3) * jax.nn.sigmoid(g)[..., None]
+        return out.reshape(b, t, heads * dh)
+
+    assert _with_grads(hd.head_gate).lower(att, g, w).as_text().count(
+        "tpu_custom_call") == 2
+    got = _with_grads(hd.head_gate)(att, g, w)
+    old = _with_grads(chain)(att, g, w)
+    want = _with_grads(chain)(*(a.astype("float32") for a in (att, g, w)))
+    for new_, old_, want_ in zip(got, old, want):
+        assert new_.dtype == jnp.bfloat16
+        assert _rel(new_, want_) < 6e-3
+        assert _rel(new_, want_) <= 1.05 * _rel(old_, want_)
