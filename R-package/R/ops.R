@@ -1,5 +1,5 @@
 # GENERATED by R-package/gen_r_ops.py -- do not edit.
-# One mx.symbol.<op> function per registered op (305 ops); tensor inputs
+# One mx.symbol.<op> function per registered op (307 ops); tensor inputs
 # are mx.symbol objects passed by name, everything else becomes a string
 # param (the reference R package generates its op surface the same way).
 
@@ -463,6 +463,12 @@
   a <- list(...)
   is.sym <- vapply(a, function(v) inherits(v, "mx.symbol"), TRUE)
   mx.symbol.create("_contrib_RotaryEmbedding", a[is.sym], a[!is.sym], name)
+}
+
+`mx.symbol._contrib_SSDScan` <- function(..., name = "") {
+  a <- list(...)
+  is.sym <- vapply(a, function(v) inherits(v, "mx.symbol"), TRUE)
+  mx.symbol.create("_contrib_SSDScan", a[is.sym], a[!is.sym], name)
 }
 
 `mx.symbol._contrib_count_sketch` <- function(..., name = "") {
@@ -1747,6 +1753,12 @@
   a <- list(...)
   is.sym <- vapply(a, function(v) inherits(v, "mx.symbol"), TRUE)
   mx.symbol.create("square", a[is.sym], a[!is.sym], name)
+}
+
+`mx.symbol.ssd_scan` <- function(..., name = "") {
+  a <- list(...)
+  is.sym <- vapply(a, function(v) inherits(v, "mx.symbol"), TRUE)
+  mx.symbol.create("ssd_scan", a[is.sym], a[!is.sym], name)
 }
 
 `mx.symbol.stack` <- function(..., name = "") {

@@ -1282,6 +1282,29 @@ inline std::vector<NDArray> _contrib_RotaryEmbedding(const NDArray &data, const 
   return op_.Invoke();
 }
 
+inline Symbol _contrib_SSDScan(const std::string &symbol_name, const Symbol &data, const Symbol &dt, const Symbol &A_log, const Symbol &B, const Symbol &C, const Symbol &D, const std::map<std::string, std::string> &kwargs = {}) {
+  Operator op_("_contrib_SSDScan");
+  for (const auto &kv : kwargs) op_.SetParam(kv.first, kv.second);
+  op_.SetInput("data", data);
+  op_.SetInput("dt", dt);
+  op_.SetInput("A_log", A_log);
+  op_.SetInput("B", B);
+  op_.SetInput("C", C);
+  op_.SetInput("D", D);
+  return op_.CreateSymbol(symbol_name);
+}
+inline std::vector<NDArray> _contrib_SSDScan(const NDArray &data, const NDArray &dt, const NDArray &A_log, const NDArray &B, const NDArray &C, const NDArray &D, const std::map<std::string, std::string> &kwargs = {}) {
+  Operator op_("_contrib_SSDScan");
+  for (const auto &kv : kwargs) op_.SetParam(kv.first, kv.second);
+  op_.AddInput(data);
+  op_.AddInput(dt);
+  op_.AddInput(A_log);
+  op_.AddInput(B);
+  op_.AddInput(C);
+  op_.AddInput(D);
+  return op_.Invoke();
+}
+
 inline Symbol _contrib_count_sketch(const std::string &symbol_name, const Symbol &data, const Symbol &h, const Symbol &s, int out_dim, const std::map<std::string, std::string> &kwargs = {}) {
   Operator op_("_contrib_count_sketch");
   op_.SetParam("out_dim", out_dim);
@@ -4629,6 +4652,29 @@ inline std::vector<NDArray> square(const NDArray &data, const std::map<std::stri
   Operator op_("square");
   for (const auto &kv : kwargs) op_.SetParam(kv.first, kv.second);
   op_.AddInput(data);
+  return op_.Invoke();
+}
+
+inline Symbol ssd_scan(const std::string &symbol_name, const Symbol &data, const Symbol &dt, const Symbol &A_log, const Symbol &B, const Symbol &C, const Symbol &D, const std::map<std::string, std::string> &kwargs = {}) {
+  Operator op_("ssd_scan");
+  for (const auto &kv : kwargs) op_.SetParam(kv.first, kv.second);
+  op_.SetInput("data", data);
+  op_.SetInput("dt", dt);
+  op_.SetInput("A_log", A_log);
+  op_.SetInput("B", B);
+  op_.SetInput("C", C);
+  op_.SetInput("D", D);
+  return op_.CreateSymbol(symbol_name);
+}
+inline std::vector<NDArray> ssd_scan(const NDArray &data, const NDArray &dt, const NDArray &A_log, const NDArray &B, const NDArray &C, const NDArray &D, const std::map<std::string, std::string> &kwargs = {}) {
+  Operator op_("ssd_scan");
+  for (const auto &kv : kwargs) op_.SetParam(kv.first, kv.second);
+  op_.AddInput(data);
+  op_.AddInput(dt);
+  op_.AddInput(A_log);
+  op_.AddInput(B);
+  op_.AddInput(C);
+  op_.AddInput(D);
   return op_.Invoke();
 }
 

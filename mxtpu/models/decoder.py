@@ -1,25 +1,31 @@
 """The decoder-only language-model family (symbol factory).
 
 One builder for every causal LM of the zoo: a stack of residual blocks, each
-a token mixer and a feed-forward sublayer, between an embedding and an
-output head. What differs between members is a few choices:
+a token mixer and a feed-forward sublayer (or one of the two alone), between
+an embedding and an output head. What differs between members is a few
+choices:
 
 - ``layer_types``: one entry a layer, ``"full_attention"`` (causal softmax
   attention through ``_contrib_FlashAttention``), ``"sliding_attention"``
   (the same under a window of ``window`` keys that ends in the query's
-  own) or ``"linear_attention"`` (the gated delta rule through
+  own), ``"linear_attention"`` (the gated delta rule through
   ``_contrib_GatedDeltaRule``, behind causal short convolutions, with a
-  gated RMSNorm on its output);
+  gated RMSNorm on its output), ``"mamba2"`` (the selective state-space
+  scan through ``_contrib_SSDScan``, behind one in-projection and a causal
+  short convolution, with a gated group RMSNorm on its output) or
+  ``"none"``: the layer has no mixer, one norm and one residual add;
 - ``norm``: ``"layer_pre"`` (LayerNorm before each sublayer, OPT's block),
   ``"rms_post"`` (RMSNorm on each sublayer's output before the residual
   add, and on q and k of full attention: the Olmo 2/3 block) or
   ``"rms_pre"`` (RMSNorm before each sublayer; q and k each through an
   RMSNorm over the dims of a head);
 - ``ffn``: ``"relu"`` (two biased matrices), ``"silu_gated"`` (three,
-  (silu(x W_gate) * x W_up) W_down, no bias) or ``"moe"`` (a router over
+  (silu(x W_gate) * x W_up) W_down, no bias), ``"relu2"`` (two,
+  relu(x W_up)^2 W_down, no bias), ``"moe"`` (a router over
   ``num_experts``, the share of the routed experts held here through
-  ``_contrib_MoEExperts``, and a shared SiLU-gated expert beside them);
-  one name, or one a layer;
+  ``_contrib_MoEExperts``, and a shared expert beside them, both SiLU-gated
+  or both ``relu2`` by the expert layers' ``activation``) or ``"none"``:
+  the layer has no feed-forward part; one name, or one a layer;
 - ``positions``: ``"learned"`` (a ``pos_emb`` table), ``"none"`` or
   ``"rotary"`` (q and k turned inside each attention layer as
   ``_contrib_RotaryEmbedding`` turns them, its parameters by the layer's
@@ -34,7 +40,11 @@ output head. What differs between members is a few choices:
 ``none`` member (Olmo-Hybrid: three linear-attention layers, then one of
 full attention); ``get_laguna_symbol`` the ``rms_pre`` / ``rotary`` member
 with window and full attention at unequal head counts over shared key/value
-heads, a per-head gate and expert layers (Laguna).
+heads, a per-head gate and expert layers (Laguna); ``get_nemotron_h_symbol``
+the ``rms_pre`` member whose layers are one sublayer each, a ``mamba2``
+mixer, plain attention over shared key/value heads (no q/k norm, rotation
+or gate) or ``relu2`` expert layers, by a pattern of ``M``, ``*`` and ``E``
+(Nemotron-H).
 
 Parameter names of the hybrid member (shapes as FullyConnected keeps them,
 (out, in); H heads, d = ``d_model``, f = ``d_ff``):
@@ -64,6 +74,19 @@ heads in that layer, E experts held of width f_e):
   ``shared_ff_gate_weight``, ``shared_ff_up_weight``,
   ``shared_ff_down_weight``.
 
+Parameter names of the one-sublayer member (a layer has ``mix_norm_gamma``
+or ``ffn_norm_gamma`` (d), not both; H heads of P, G groups of state N):
+
+- a ``mamba2`` layer: ``in_proj_weight`` (2 H P + 2 G N + H, d), its rows
+  [z, x, B, C, dt]; ``conv_weight`` (H P + 2 G N, K), ``conv_bias``
+  (H P + 2 G N); ``A_log``, ``dt_bias``, ``D`` (H,), float32 whatever
+  ``dtype`` is; ``o_norm_gamma`` (H P); ``proj_weight`` (d, H P);
+- an attention layer: ``q_weight``, ``k_weight``, ``v_weight``,
+  ``proj_weight`` alone;
+- an expert layer: ``router_weight`` (float32), ``experts_up_weight``
+  (E, f_e, d), ``experts_down_weight`` (E, d, f_e), ``shared_ff_up_weight``
+  (f_s, d), ``shared_ff_down_weight`` (d, f_s).
+
 With expert layers the symbol is a group: the softmax first, then each
 expert layer's loads ((E,) int32, the pairs each held expert received), which
 take no gradient and which ``fit`` fetches with the metric.
@@ -74,7 +97,7 @@ mixers in (B, H, T, dh); every matmul a FullyConnected(flatten=False).
 from .. import symbol as sym
 
 FULL, LINEAR = "full_attention", "linear_attention"
-SLIDING = "sliding_attention"
+SLIDING, MAMBA2, NONE = "sliding_attention", "mamba2", "none"
 
 
 def _fc(x, num_hidden, name, no_bias=False, flatten=False):
@@ -116,7 +139,7 @@ def attention_mix(x, seq_len, num_heads, d_model, prefix, no_bias=False,
 
 def grouped_attention_mix(x, seq_len, num_heads, num_kv_heads, head_dim,
                           d_model, prefix, window=0, rope=None, gate=None,
-                          norm_eps=1e-6):
+                          norm_eps=1e-6, qk_norm=True):
     """Proj(gate * Attn(x)): `num_heads` query heads of `head_dim` over
     `num_kv_heads` key/value heads (query head j reads key/value head
     j // (H / G)), causal, under `window` keys if it is not 0. q and k each
@@ -124,11 +147,13 @@ def grouped_attention_mix(x, seq_len, num_heads, num_kv_heads, head_dim,
     `rope` (the attributes of ``_contrib_RotaryEmbedding``), if any: one
     ``_contrib_HeadNormRotary`` each. With `gate` ``"per_head"`` each
     head's output is multiplied by sigmoid(x W_g) before W_o
-    (``_contrib_HeadGate``). No bias anywhere."""
+    (``_contrib_HeadGate``). Without `qk_norm` q and k are the plain
+    projections (and take no rotation). No bias anywhere."""
+    assert qk_norm or rope is None, "rotation rides in the q/k norm's pass"
 
     def heads(tag, n):
         p = _fc(x, n * head_dim, "%s_%s" % (prefix, tag), True)
-        if tag == "v":
+        if tag == "v" or not qk_norm:
             return _heads(p, seq_len, n, head_dim)
         # norm, rotation and head transpose in one pass over the projection
         return sym.contrib.HeadNormRotary(
@@ -200,6 +225,47 @@ def delta_rule_mix(x, seq_len, num_heads, d_model, prefix, key_dim, value_dim,
     return _fc(o, d_model, "%s_proj" % prefix, True)
 
 
+def mamba2_mix(x, seq_len, d_model, prefix, num_heads, head_dim, n_groups,
+               state_size, conv_kernel=4, chunk=128, norm_eps=1e-5):
+    """The Mamba-2 mixer (arXiv:2405.21060): [z, xBC, dt] = x W_in; xBC
+    through a causal short convolution with bias and SiLU, split into x
+    (H heads of P), B and C (G groups of N); dt = softplus(dt + dt_bias);
+    the state-space scan with A = -exp(A_log) and the skip D; y * silu(z)
+    through an RMSNorm over each of the G groups of channels; W_out. dt,
+    A_log, dt_bias and D are float32 whatever x is."""
+    h, p, g, n = num_heads, head_dim, n_groups, state_size
+    inner, conv_dim = h * p, h * p + 2 * g * n
+
+    def cut(v, begin, end):
+        return sym.slice_axis(v, axis=2, begin=begin, end=end)
+
+    zxbcdt = _fc(x, 2 * inner + 2 * g * n + h, "%s_in_proj" % prefix, True)
+    z = cut(zxbcdt, 0, inner)
+    xbc = sym.contrib.CausalConv1D(cut(zxbcdt, inner, inner + conv_dim),
+                                   kernel=conv_kernel, bias=True,
+                                   name="%s_conv" % prefix)
+    xbc = sym.Activation(xbc, act_type="silu")
+    xs = sym.reshape(cut(xbc, 0, inner), shape=(-1, seq_len, h, p))
+    bm = sym.reshape(cut(xbc, inner, inner + g * n),
+                     shape=(-1, seq_len, g, n))
+    cm = sym.reshape(cut(xbc, inner + g * n, conv_dim),
+                     shape=(-1, seq_len, g, n))
+    a_log, dt_bias, skip = (
+        sym.Variable("%s_%s" % (prefix, name), shape=(h,), dtype="float32")
+        for name in ("A_log", "dt_bias", "D"))
+    dt = sym.broadcast_add(
+        sym.Cast(cut(zxbcdt, inner + conv_dim, inner + conv_dim + h),
+                 dtype="float32"),
+        sym.reshape(dt_bias, shape=(1, 1, h)))
+    dt = sym.Activation(dt, act_type="softrelu")
+    y = sym.contrib.SSDScan(xs, dt, a_log, bm, cm, skip, chunk=chunk,
+                            name="%s_ssd" % prefix)
+    y = sym.reshape(y, shape=(-1, seq_len, inner))
+    y = sym.RMSNorm(y * sym.Activation(z, act_type="silu"), eps=norm_eps,
+                    groups=g, name="%s_o_norm" % prefix)
+    return _fc(y, d_model, "%s_proj" % prefix, True)
+
+
 def relu_ffn(x, d_model, d_ff, prefix):
     f = _fc(x, d_ff, "%s_ff1" % prefix)
     f = sym.Activation(f, act_type="relu")
@@ -213,14 +279,28 @@ def silu_gated_ffn(x, d_model, d_ff, prefix):
     return _fc(f, d_model, "%s_ff_down" % prefix, True)
 
 
+def relu2_ffn(x, d_model, d_ff, prefix):
+    up = _fc(x, d_ff, "%s_ff_up" % prefix, True)
+    f = sym.square(sym.Activation(up, act_type="relu"))
+    return _fc(f, d_model, "%s_ff_down" % prefix, True)
+
+
+_DENSE_FFN = {"relu": relu_ffn, "relu2": relu2_ffn,
+              "silu_gated": silu_gated_ffn}
+
+
 def moe_ffn(x, d_model, prefix, num_experts, top_k, experts_held, hidden,
             shared_hidden=0, expert_offset=0, scale=1.0,
-            score_func="sigmoid", norm_topk=True):
-    """(y, loads): the routed experts held here, each a SiLU-gated FFN of
-    width `hidden`, weighted by a float32 router over all `num_experts`
-    (`top_k` a token), and beside them one shared, ungated expert of width
-    `shared_hidden` if it is not 0. `loads` is the second output of
+            score_func="sigmoid", norm_topk=True, activation="silu_gated"):
+    """(y, loads): the routed experts held here, each an FFN of width
+    `hidden` (SiLU-gated, or ``relu2``: relu(x W_up)^2 W_down), weighted by
+    a float32 router over all `num_experts` (`top_k` a token), and beside
+    them one shared expert of the same form and width `shared_hidden`,
+    outside the router, if it is not 0. `loads` is the second output of
     ``_contrib_MoEExperts``."""
+    # the gated form's graph is written as it was: no attribute it lacked
+    kw = {} if activation == "silu_gated" else {"activation": activation}
+    shared = _DENSE_FFN[activation]
     router = sym.Variable("%s_router_weight" % prefix, dtype="float32")
     chosen = sym.contrib.MoERouter(
         x, weight=router, num_experts=num_experts, top_k=top_k, scale=scale,
@@ -229,10 +309,10 @@ def moe_ffn(x, d_model, prefix, num_experts, top_k, experts_held, hidden,
     routed = sym.contrib.MoEExperts(
         x, chosen[0], chosen[1], num_experts=num_experts,
         experts_held=experts_held, hidden=hidden,
-        expert_offset=expert_offset, name="%s_experts" % prefix)
+        expert_offset=expert_offset, name="%s_experts" % prefix, **kw)
     y = routed[0]
     if shared_hidden:
-        y = y + silu_gated_ffn(x, d_model, shared_hidden, prefix + "_shared")
+        y = y + shared(x, d_model, shared_hidden, prefix + "_shared")
     return y, routed[1]
 
 
@@ -254,7 +334,7 @@ def build(vocab_size, seq_len, layer_types, num_heads, d_model, d_ff,
           norm="rms_post", ffn="silu_gated", positions="none", dropout=0.0,
           max_len=None, dtype=None, norm_eps=1e-6, linear=None,
           num_kv_heads=None, head_dim=None, window=0, gate=None, rope=None,
-          moe=None):
+          moe=None, mamba=None, qk_norm=True):
     """Causal LM of the family: data (B, T) int tokens -> SoftmaxOutput over
     (B*T, vocab). `linear` holds the linear-attention layers' sizes
     (``key_dim``, ``value_dim``, ``conv_kernel``, ``neg_eigval``, and
@@ -263,16 +343,21 @@ def build(vocab_size, seq_len, layer_types, num_heads, d_model, d_ff,
     `window` (its ``sliding_attention`` layers'), `gate` and, under
     ``positions="rotary"``, `rope`: {layer type: attributes of
     ``_contrib_RotaryEmbedding``}. `moe` holds the expert layers' sizes
-    (`moe_ffn`'s arguments)."""
-    layer_types = list(layer_types)
+    (`moe_ffn`'s arguments), `mamba` the ``mamba2`` layers' (`mamba2_mix`'s).
+    A layer whose type or whose ffn is ``"none"`` (or None) has the other
+    sublayer alone."""
+    layer_types = [k or NONE for k in layer_types]
     heads_of = list(num_heads) if isinstance(num_heads, (list, tuple)) \
         else [num_heads] * len(layer_types)
-    ffn_of = list(ffn) if isinstance(ffn, (list, tuple)) \
+    ffn_of = [f or NONE for f in ffn] if isinstance(ffn, (list, tuple)) \
         else [ffn] * len(layer_types)
     assert len(heads_of) == len(ffn_of) == len(layer_types), \
         "one head count and one ffn kind a layer"
     assert norm in ("layer_pre", "rms_post", "rms_pre")
-    assert all(f in ("relu", "silu_gated", "moe") for f in ffn_of)
+    assert all(f in ("relu", "silu_gated", "relu2", "moe", NONE)
+               for f in ffn_of)
+    assert all(k != NONE or f != NONE for k, f in zip(layer_types, ffn_of)), \
+        "a layer is a mixer, a feed-forward part or both"
     assert positions in ("learned", "none", "rotary")
     grouped = norm == "rms_pre"
     if not grouped:
@@ -306,7 +391,7 @@ def build(vocab_size, seq_len, layer_types, num_heads, d_model, d_ff,
                     window=window if kind == SLIDING else 0,
                     rope=(rope or {}).get(kind)
                     if positions == "rotary" else None,
-                    gate=gate, norm_eps=norm_eps)
+                    gate=gate, norm_eps=norm_eps, qk_norm=qk_norm)
         elif kind == FULL:
             def mix(x, p=p):
                 return attention_mix(
@@ -316,21 +401,25 @@ def build(vocab_size, seq_len, layer_types, num_heads, d_model, d_ff,
             def mix(x, p=p):
                 return delta_rule_mix(x, seq_len, lin_heads, d_model, p,
                                       norm_eps=norm_eps, **lin)
-        else:
+        elif kind == MAMBA2:
+            def mix(x, p=p):
+                return mamba2_mix(x, seq_len, d_model, p, norm_eps=norm_eps,
+                                  **mamba)
+        elif kind != NONE:
             raise ValueError("layer %d: unknown layer type %r" % (i, kind))
-        h = _sublayer(h, mix, norm, p + ("_ln1" if pre else "_mix_norm"),
-                      norm_eps, dropout)
-        if ffn_of[i] == "relu":
-            def feed(x, p=p):
-                return relu_ffn(x, d_model, d_ff, p)
-        elif ffn_of[i] == "moe":
+        if kind != NONE:
+            h = _sublayer(h, mix, norm, p + ("_ln1" if pre else "_mix_norm"),
+                          norm_eps, dropout)
+        if ffn_of[i] == NONE:
+            continue
+        if ffn_of[i] == "moe":
             def feed(x, p=p):
                 y, load = moe_ffn(x, d_model, p, **moe)
                 loads.append(load)
                 return y
         else:
-            def feed(x, p=p):
-                return silu_gated_ffn(x, d_model, d_ff, p)
+            def feed(x, p=p, ffn=_DENSE_FFN[ffn_of[i]]):
+                return ffn(x, d_model, d_ff, p)
         h = _sublayer(h, feed, norm, p + ("_ln2" if pre else "_ffn_norm"),
                       norm_eps, dropout)
     if pre:
@@ -384,3 +473,24 @@ def get_laguna_symbol(vocab_size, seq_len, layer_types, num_heads,
                  dtype=dtype, norm_eps=norm_eps, num_kv_heads=num_kv_heads,
                  head_dim=head_dim, window=window, gate=gate, rope=rope,
                  moe=moe)
+
+
+def get_nemotron_h_symbol(vocab_size, seq_len, pattern, d_model, num_heads,
+                          num_kv_heads, head_dim, mamba, moe, d_ff=0,
+                          norm_eps=1e-5, dtype=None):
+    """The one-sublayer member (Nemotron-H): layer i is `pattern[i]`, ``M`` a
+    ``mamba2`` mixer (`mamba`: the arguments of `mamba2_mix`), ``*`` causal
+    attention of `num_heads` query heads over `num_kv_heads` key/value heads
+    of `head_dim` with no q/k norm, rotation, gate or bias, ``E`` an expert
+    layer (`moe`: the arguments of `moe_ffn`, ``activation="relu2"``) and
+    ``-`` a dense ``relu2`` FFN of `d_ff`; each x + f(RMSNorm(x)), a final
+    RMSNorm, no position table, an untied head. Train with label = data
+    shifted left by one, flattened to (B*T,). `seq_len` must be a multiple
+    of the scan's chunk where the pattern has an ``M``."""
+    mixer = {"M": MAMBA2, "*": FULL, "E": NONE, "-": NONE}
+    feed = {"M": NONE, "*": NONE, "E": "moe", "-": "relu2"}
+    return build(vocab_size, seq_len, [mixer[c] for c in pattern], num_heads,
+                 d_model, d_ff, norm="rms_pre", ffn=[feed[c] for c in pattern],
+                 positions="none", dtype=dtype, norm_eps=norm_eps,
+                 num_kv_heads=num_kv_heads, head_dim=head_dim, moe=moe,
+                 mamba=mamba, qk_norm=False)

@@ -13,6 +13,7 @@ from . import spatial  # noqa: F401
 from . import custom  # noqa: F401
 from . import attention  # noqa: F401
 from . import delta_rule  # noqa: F401
+from . import ssd  # noqa: F401
 from . import rotary  # noqa: F401
 from . import heads  # noqa: F401
 from . import moe  # noqa: F401

@@ -90,24 +90,29 @@ register("Convolution", _convolution,
          aliases=("Convolution_v1",))
 
 
-def _causal_conv1d(a, data, weight):
+def _causal_conv1d(a, data, weight, bias=None):
     """Causal depthwise convolution over time, channels last: data
     (B, T, C), weight (C, K), y[t] = sum_i weight[:, i] * x[t - (K-1) + i],
-    nothing before the row's start. The short convolution of the
-    linear-attention mixers (K = 4): K shifted multiply-adds that XLA fuses
-    into one pass, where a grouped Convolution with one channel a group
-    would go through the convolution emitter. Accumulates in float32."""
+    nothing before the row's start; with ``bias`` a third input (C,) is
+    added. The short convolution of the linear-attention and state-space
+    mixers (K = 4): K shifted multiply-adds that XLA fuses into one pass,
+    where a grouped Convolution with one channel a group would go through
+    the convolution emitter. Accumulates in float32."""
     k = int(a.kernel)
     t = data.shape[1]
     x = jnp.pad(data, ((0, 0), (k - 1, 0), (0, 0)))
     w = weight.astype(jnp.float32)
     out = sum(x[:, i:i + t, :].astype(jnp.float32) * w[:, i]
               for i in range(k))
+    if bias is not None:
+        out = out + bias.astype(jnp.float32)
     return out.astype(data.dtype)
 
 
 register("_contrib_CausalConv1D", _causal_conv1d,
-         arg_names=["data", "weight"], attrs={"kernel": Required(int)},
+         arg_names=lambda a: ["data", "weight", "bias"] if a.get("bias")
+         else ["data", "weight"],
+         attrs={"kernel": Required(int), "bias": False},
          aliases=("causal_conv1d",))
 
 
@@ -303,10 +308,23 @@ def _rms_norm(a, data, gamma, gate=None):
     arXiv:1910.07467), no mean and no shift. The reduction and the scaling
     run in float32 whatever the input's dtype; one fused pass, as
     _layer_norm. With ``gated`` a third input scales the result by
-    silu(gate), the output gate of the linear-attention mixers."""
+    silu(gate), the output gate of the linear-attention mixers. With
+    ``groups`` > 1 the mean square is taken over each of that many equal
+    groups of the axis (the last), gamma still one number a channel: the
+    output norm of the state-space mixers."""
     ax = int(a.axis) % data.ndim
     x = data.astype(jnp.float32)
-    ms = jnp.mean(jnp.square(x), axis=ax, keepdims=True)
+    groups = int(a.get("groups", 1))
+    if groups > 1:
+        if ax != data.ndim - 1 or data.shape[ax] % groups:
+            raise ValueError("RMSNorm: %d groups of axis %d of %r" % (
+                groups, ax, data.shape))
+        grouped = x.reshape(x.shape[:-1] + (groups, -1))
+        ms = jnp.broadcast_to(
+            jnp.mean(jnp.square(grouped), axis=-1, keepdims=True),
+            grouped.shape).reshape(x.shape)
+    else:
+        ms = jnp.mean(jnp.square(x), axis=ax, keepdims=True)
     bshape = tuple(data.shape[ax] if i == ax else 1 for i in range(data.ndim))
     out = x * lax.rsqrt(ms + a.eps) * gamma.astype(jnp.float32).reshape(bshape)
     if gate is not None:
@@ -317,7 +335,7 @@ def _rms_norm(a, data, gamma, gate=None):
 register("RMSNorm", _rms_norm,
          arg_names=lambda a: ["data", "gamma", "gate"] if a.get("gated") else
          ["data", "gamma"],
-         attrs={"eps": 1e-6, "axis": -1, "gated": False})
+         attrs={"eps": 1e-6, "axis": -1, "gated": False, "groups": 1})
 
 # ---------------------------------------------------------------- activations
 
@@ -804,7 +822,8 @@ _get_op("RMSNorm").infer_args = _rms_infer
 
 
 def _causal_conv_infer(a, shapes):
-    return [shapes[0], (shapes[0][-1], int(a.kernel))]
+    c = shapes[0][-1]
+    return [shapes[0], (c, int(a.kernel))] + ([(c,)] if a.get("bias") else [])
 
 
 _get_op("_contrib_CausalConv1D").infer_args = _causal_conv_infer

@@ -1,5 +1,5 @@
 # GENERATED by perl-package/gen_perl_ops.py — do not edit.
-# One sub per registered op (305 ops). Regenerate after registry changes.
+# One sub per registered op (307 ops). Regenerate after registry changes.
 package AI::MXTPU::NDOps;
 use strict;
 use warnings;
@@ -458,6 +458,12 @@ sub _contrib_RotaryEmbedding {
     my @ins = splice(@_, 0, 1);
     my %a = @_;
     return AI::MXTPU::invoke('_contrib_RotaryEmbedding', \@ins, %a);
+}
+
+sub _contrib_SSDScan {
+    my @ins = splice(@_, 0, 6);
+    my %a = @_;
+    return AI::MXTPU::invoke('_contrib_SSDScan', \@ins, %a);
 }
 
 sub _contrib_count_sketch {
@@ -1736,6 +1742,12 @@ sub square {
     my @ins = splice(@_, 0, 1);
     my %a = @_;
     return AI::MXTPU::invoke('square', \@ins, %a);
+}
+
+sub ssd_scan {
+    my @ins = splice(@_, 0, 6);
+    my %a = @_;
+    return AI::MXTPU::invoke('ssd_scan', \@ins, %a);
 }
 
 sub stack {

@@ -1,5 +1,5 @@
 # GENERATED by perl-package/gen_perl_ops.py — do not edit.
-# One sub per registered op (305 ops). Regenerate after registry changes.
+# One sub per registered op (307 ops). Regenerate after registry changes.
 package AI::MXTPU::Ops;
 use strict;
 use warnings;
@@ -616,6 +616,18 @@ sub _contrib_RotaryEmbedding {
     my %in;
     $in{'data'} = delete $a{'data'} if exists $a{'data'};
     return AI::MXTPU::Symbol->create('_contrib_RotaryEmbedding', \%in, %a);
+}
+
+sub _contrib_SSDScan {
+    my %a = @_;
+    my %in;
+    $in{'data'} = delete $a{'data'} if exists $a{'data'};
+    $in{'dt'} = delete $a{'dt'} if exists $a{'dt'};
+    $in{'A_log'} = delete $a{'A_log'} if exists $a{'A_log'};
+    $in{'B'} = delete $a{'B'} if exists $a{'B'};
+    $in{'C'} = delete $a{'C'} if exists $a{'C'};
+    $in{'D'} = delete $a{'D'} if exists $a{'D'};
+    return AI::MXTPU::Symbol->create('_contrib_SSDScan', \%in, %a);
 }
 
 sub _contrib_count_sketch {
@@ -2211,6 +2223,18 @@ sub square {
     my %in;
     $in{'data'} = delete $a{'data'} if exists $a{'data'};
     return AI::MXTPU::Symbol->create('square', \%in, %a);
+}
+
+sub ssd_scan {
+    my %a = @_;
+    my %in;
+    $in{'data'} = delete $a{'data'} if exists $a{'data'};
+    $in{'dt'} = delete $a{'dt'} if exists $a{'dt'};
+    $in{'A_log'} = delete $a{'A_log'} if exists $a{'A_log'};
+    $in{'B'} = delete $a{'B'} if exists $a{'B'};
+    $in{'C'} = delete $a{'C'} if exists $a{'C'};
+    $in{'D'} = delete $a{'D'} if exists $a{'D'};
+    return AI::MXTPU::Symbol->create('ssd_scan', \%in, %a);
 }
 
 sub stack {

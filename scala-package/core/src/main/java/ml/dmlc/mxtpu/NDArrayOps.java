@@ -1,7 +1,7 @@
 package ml.dmlc.mxtpu;
 
 // GENERATED by scala-package/gen_jvm_ops.py - do not edit.
-// One static method per registered op (305 ops). Regenerate after
+// One static method per registered op (307 ops). Regenerate after
 // registry changes. Parity: the reference scala-package's generated op
 // surface (scala-package/macros SymbolMacro/NDArrayMacro over
 // MXListAllOpNames).
@@ -486,6 +486,12 @@ public final class NDArrayOps {
   public static NDArray[] _contrib_RotaryEmbedding(
       java.util.Map<String, String> attrs, NDArray... inputs) {
     return invoke("_contrib_RotaryEmbedding", attrs, inputs);
+  }
+
+  /** _contrib_SSDScan(data, dt, A_log, B, C, D) */
+  public static NDArray[] _contrib_SSDScan(
+      java.util.Map<String, String> attrs, NDArray... inputs) {
+    return invoke("_contrib_SSDScan", attrs, inputs);
   }
 
   /** _contrib_count_sketch(data, h, s) */
@@ -1770,6 +1776,12 @@ public final class NDArrayOps {
   public static NDArray[] square(
       java.util.Map<String, String> attrs, NDArray... inputs) {
     return invoke("square", attrs, inputs);
+  }
+
+  /** ssd_scan(data, dt, A_log, B, C, D) */
+  public static NDArray[] ssd_scan(
+      java.util.Map<String, String> attrs, NDArray... inputs) {
+    return invoke("ssd_scan", attrs, inputs);
   }
 
   /** stack(variadic) */

@@ -1,7 +1,7 @@
 package ml.dmlc.mxtpu;
 
 // GENERATED by scala-package/gen_jvm_ops.py - do not edit.
-// One static method per registered op (305 ops). Regenerate after
+// One static method per registered op (307 ops). Regenerate after
 // registry changes. Parity: the reference scala-package's generated op
 // surface (scala-package/macros SymbolMacro/NDArrayMacro over
 // MXListAllOpNames).
@@ -469,6 +469,12 @@ public final class SymbolOps {
   public static Symbol _contrib_RotaryEmbedding(String name,
       java.util.Map<String, String> attrs, Symbol... inputs) {
     return Symbol.create("_contrib_RotaryEmbedding", name, attrs, new String[] {"data"}, inputs);
+  }
+
+  /** _contrib_SSDScan(data, dt, A_log, B, C, D) */
+  public static Symbol _contrib_SSDScan(String name,
+      java.util.Map<String, String> attrs, Symbol... inputs) {
+    return Symbol.create("_contrib_SSDScan", name, attrs, new String[] {"data", "dt", "A_log", "B", "C", "D"}, inputs);
   }
 
   /** _contrib_count_sketch(data, h, s) */
@@ -1753,6 +1759,12 @@ public final class SymbolOps {
   public static Symbol square(String name,
       java.util.Map<String, String> attrs, Symbol... inputs) {
     return Symbol.create("square", name, attrs, new String[] {"data"}, inputs);
+  }
+
+  /** ssd_scan(data, dt, A_log, B, C, D) */
+  public static Symbol ssd_scan(String name,
+      java.util.Map<String, String> attrs, Symbol... inputs) {
+    return Symbol.create("ssd_scan", name, attrs, new String[] {"data", "dt", "A_log", "B", "C", "D"}, inputs);
   }
 
   /** stack(variadic) */

@@ -286,3 +286,72 @@ def test_head_gate_gradient_compiles_at_the_laguna_cells_size(
     assert text.count("custom_call_target=\"tpu_custom_call\"") == 2
     assert not re.findall(r"f32\[2,(?:%d,4096|4096,%d),128\]"
                           % (heads, heads), text)
+
+
+def test_ssd_gradient_compiles_at_the_nemotron_cells_size(one_chip, no_cache):
+    """nemotron-twotower-30b-fit-s4096's Mamba-2 layers: batch 2, 64 heads
+    of 64 in 8 groups of state 128, 4096 tokens in chunks of 128, bf16. Two
+    Mosaic calls found by name, one of each; x and y stay (B, T, H P) and B
+    and C (B, T, G N), so nothing is repeated to the heads or transposed in
+    HBM; the chunk-first states, float32, are the one residual the forward
+    call hands the backward call; no `while` at the level of XLA and no
+    per-token state (4096 states of 128 x 64 a head) anywhere."""
+    import re
+    import jax
+    import jax.numpy as jnp
+    from mxtpu.ops import ssd
+
+    def s(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    args = (s((2, 4096, 64, 64)), s((2, 4096, 64), jnp.float32),
+            s((64,), jnp.float32), s((2, 4096, 8, 128)),
+            s((2, 4096, 8, 128)), s((64,), jnp.float32))
+
+    def loss(*a):
+        return jnp.sum(ssd.ssd_scan(*a).astype(jnp.float32))
+
+    compiled = jax.jit(jax.grad(loss, argnums=tuple(range(6)))).lower(
+        *args).compile()
+    text = compiled.as_text()
+    calls = {name: re.findall(
+        r"^\s*(%%%s[\w.]*) = \((.*?)\) custom-call\((.*?)\), custom_call_target"
+        % name, text, re.M)
+        for name in (ssd.FWD_KERNEL_NAME, ssd.BWD_KERNEL_NAME)}
+    (fwd, fwd_results, _), = calls[ssd.FWD_KERNEL_NAME]     # one call
+    (_, bwd_results, bwd_operands), = calls[ssd.BWD_KERNEL_NAME]    # of each
+    # y, and one state a chunk a group: 2 x 8 x 32 of 128 x 512
+    assert [r.split("{")[0] for r in fwd_results.split(", ")] == [
+        "bf16[2,4096,4096]", "f32[2,8,32,128,512]"]
+    # dx; ddt and dcs as rows; dB and dC a group; dD's row a batch a group
+    bwd_results = re.sub(r"/\*.*?\*/", "", bwd_results)
+    assert [r.split("{")[0] for r in bwd_results.split(", ")] == [
+        "bf16[2,4096,4096]", "f32[2,8,32,8,128]", "f32[2,8,32,8,128]",
+        "bf16[2,4096,1024]", "bf16[2,4096,1024]", "f32[2,8,1,512]"]
+    states, = re.findall(
+        r"(%%[\w.-]+) = \S+ get-tuple-element\(%s\), index=1"
+        % re.escape(fwd), text)
+    assert states in re.sub(r"/\*.*?\*/", "", bwd_operands).split(", ")
+    assert text.count("custom_call_target=\"tpu_custom_call\"") == 2
+    assert not re.findall(r"^\s*%?while", text, re.M)
+    assert "4096,128,64]" not in text and "4096,64,128]" not in text
+    # x, y, dy, dx, B, C and their gradients, the states (134 MB) and rows
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.35e9
+
+
+def test_ssd_forward_alone_keeps_no_states(one_chip, no_cache):
+    """Outside differentiation the forward call writes y alone."""
+    import jax
+    import jax.numpy as jnp
+    from mxtpu.ops import ssd
+
+    def s(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    text = jax.jit(ssd.ssd_scan).lower(
+        s((2, 4096, 64, 64)), s((2, 4096, 64), jnp.float32),
+        s((64,), jnp.float32), s((2, 4096, 8, 128)), s((2, 4096, 8, 128)),
+        s((64,), jnp.float32)).compile().as_text()
+    assert "%" + ssd.FWD_KERNEL_NAME in text
+    assert "%" + ssd.BWD_KERNEL_NAME not in text
+    assert "f32[2,8,32,128,512]" not in text

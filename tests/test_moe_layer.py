@@ -205,3 +205,206 @@ def test_the_fetched_loads_are_counted():
     assert value("moe_load_max_over_mean") == pytest.approx(1.5)
     assert value("moe_experts_held") == 4
     assert value("moe_dispatch_rows_bound") == TOKENS * 3
+
+
+# ------------------------------------------ the ungated squared-ReLU form
+# the Nemotron-H rehearsal's expert layer: 16 experts of width 32, 3 a
+# token, a shared one of 64; 16 shares of ONE expert each add up below
+NCFG = {"router_num_experts": 16, "num_experts_per_tok": 3,
+        "routed_scaling_factor": 2.5}
+
+
+def _relu2_weights(seed):
+    w = _weights(seed)
+    del w["experts_gate_weight"], w["shared_ff_gate_weight"]
+    return w
+
+
+def _relu2_dense(w, held, offset, shared=True):
+    """relu(x W_up^T)^2 W_down^T, a dense loop over the experts held, each
+    over all tokens times the weight the router gave it (0 if not chosen)."""
+    with jax.default_matmul_precision("highest"):
+        tw, ti = moe.route(w["x"] @ w["router_weight"].T, 3, 2.5)
+        out = 0.0
+        if shared:
+            out = jnp.square(jax.nn.relu(
+                w["x"] @ w["shared_ff_up_weight"].T)) @ \
+                w["shared_ff_down_weight"].T
+        for e in range(offset, offset + held):
+            mine = jnp.sum(jnp.where(ti == e, tw, 0.0), axis=-1)
+            h = jnp.square(jax.nn.relu(w["x"] @ w["experts_up_weight"][e].T))
+            out = out + mine[:, None] * (h @ w["experts_down_weight"][e].T)
+    return out
+
+
+def _relu2_share(w, held, offset, chunk=0):
+    rows, moe.CHUNK = moe.CHUNK, chunk or moe.CHUNK
+    try:
+        with jax.default_matmul_precision("highest"):
+            tw, ti = moe.route(w["x"] @ w["router_weight"].T, 3, 2.5)
+            cut = slice(offset, offset + held)
+            return moe.moe_experts(w["x"], tw, ti, None,
+                                   w["experts_up_weight"][cut],
+                                   w["experts_down_weight"][cut], 16, offset)
+    finally:
+        moe.CHUNK = rows
+
+
+@pytest.mark.parametrize("held,chunk", [(4, 16), (16, 0)])
+def test_relu2_experts_are_a_dense_loop_over_the_experts(held, chunk):
+    """Forward and every gradient (x, the router through the weights, both
+    stacked leaves), in one trip and in several."""
+    w = _relu2_weights(21)
+    cot = jax.random.normal(jax.random.PRNGKey(5), (TOKENS, 64))
+    got, loads = _relu2_share(w, held, 4 if held == 4 else 0, chunk)
+    offset = 4 if held == 4 else 0
+    np.testing.assert_allclose(
+        np.asarray(got), np.asarray(_relu2_dense(w, held, offset, False)),
+        rtol=2e-5, atol=2e-5)
+    assert int(np.asarray(loads).sum()) > 0 and loads.dtype == jnp.int32
+    g_got = jax.grad(lambda w: jnp.sum(
+        _relu2_share(w, held, offset, chunk)[0] * cot))(w)
+    g_want = jax.grad(lambda w: jnp.sum(
+        _relu2_dense(w, held, offset, False) * cot))(w)
+    for name in ("x", "router_weight", "experts_up_weight",
+                 "experts_down_weight"):
+        assert float(jnp.abs(g_want[name]).sum()) > 0, name
+        np.testing.assert_allclose(np.asarray(g_got[name]),
+                                   np.asarray(g_want[name]),
+                                   rtol=2e-4, atol=2e-4, err_msg=name)
+
+
+def test_the_sixteen_relu2_shares_add_up_to_the_uncut_reference_layer():
+    """All 16 shares of one expert each (expert_offset 0, 1, ..., 15: at the
+    cell's size 0, 8, ..., 120 of 8 each), the shared expert counted once,
+    give what the uncut plain reference (benchmark/references/nemotron_h.py)
+    gives for the whole layer: forward and gradient."""
+    from benchmark.references import nemotron_h
+    w = _relu2_weights(23)
+    cot = jax.random.normal(jax.random.PRNGKey(6), (TOKENS, 64))
+
+    def whole(w):
+        cfg = dict(NCFG, n_routed_experts=16, expert_offset=0)
+        lp = {k: v for k, v in w.items() if k != "x"}
+        with jax.default_matmul_precision("highest"):
+            return nemotron_h._moe(w["x"], lp, cfg, lambda a: a, None)
+
+    def shares(w):
+        with jax.default_matmul_precision("highest"):
+            once = jnp.square(jax.nn.relu(
+                w["x"] @ w["shared_ff_up_weight"].T)) @ \
+                w["shared_ff_down_weight"].T
+        return once + sum(_relu2_share(w, 1, off)[0] for off in range(16))
+
+    np.testing.assert_allclose(np.asarray(shares(w)), np.asarray(whole(w)),
+                               rtol=2e-5, atol=2e-5)
+    np.testing.assert_allclose(np.asarray(whole(w)),
+                               np.asarray(_relu2_dense(w, 16, 0)),
+                               rtol=2e-5, atol=2e-5)
+    pairs = sum(int(_relu2_share(w, 1, off)[1][0]) for off in range(16))
+    assert pairs == TOKENS * 3          # every pair lands on one share
+    g_want = jax.grad(lambda w: jnp.sum(whole(w) * cot))(w)
+    g_got = jax.grad(lambda w: jnp.sum(shares(w) * cot))(w)
+    for name in w:
+        np.testing.assert_allclose(np.asarray(g_got[name]),
+                                   np.asarray(g_want[name]),
+                                   rtol=2e-4, atol=2e-4, err_msg=name)
+
+
+def test_the_relu2_operator_takes_two_stacked_leaves():
+    """Through the Symbol: no gate leaf, shapes from the attributes; the
+    gated form's arguments are what they were; an unknown form is refused."""
+    x = mx.sym.Variable("data")
+    r = mx.sym.contrib.MoERouter(x, num_experts=16, top_k=3, scale=2.5,
+                                 name="router")
+
+    def experts(**kw):
+        return mx.sym.contrib.MoEExperts(
+            x, r[0], r[1], num_experts=16, experts_held=4, hidden=32,
+            expert_offset=4, name="experts", **kw)
+
+    e = experts(activation="relu2")
+    assert e.list_arguments() == ["data", "router_weight",
+                                  "experts_up_weight", "experts_down_weight"]
+    shapes = dict(zip(e.list_arguments(),
+                      e.infer_shape(data=(2, 48, 64))[0]))
+    assert shapes["experts_up_weight"] == (4, 32, 64)
+    assert shapes["experts_down_weight"] == (4, 64, 32)
+    assert experts().list_arguments() == [
+        "data", "router_weight", "experts_gate_weight", "experts_up_weight",
+        "experts_down_weight"]
+    assert "activation" not in experts().tojson()
+    w = _relu2_weights(25)
+    args = {"data": mx.nd.NDArray(w["x"].reshape(2, 48, 64)),
+            "router_weight": mx.nd.NDArray(w["router_weight"]),
+            "experts_up_weight": mx.nd.NDArray(w["experts_up_weight"][4:8]),
+            "experts_down_weight": mx.nd.NDArray(w["experts_down_weight"][4:8])}
+    outs = e.bind(mx.cpu(), args).forward(is_train=False)
+    want, loads = _relu2_share(w, 4, 4)
+    np.testing.assert_allclose(outs[0].asnumpy().reshape(TOKENS, 64),
+                               np.asarray(want), rtol=1e-4, atol=1e-4)
+    assert outs[1].asnumpy().tolist() == np.asarray(loads).tolist()
+    moe.observe_loads([np.asarray(loads)])      # feeds the same counters
+    with pytest.raises(Exception):
+        experts(activation="gelu").infer_shape(data=(2, 48, 64))
+
+
+def test_relu2_experts_on_tiles_of_their_own_and_at_a_padded_width():
+    """The relu2 plan gives every held expert rows of its own in whole
+    tiles, and a width over one tile is padded with zeros to whole tiles:
+    neither moves the result or a gradient, nothing is dropped, and the
+    padding takes no gradient."""
+    ks = jax.random.split(jax.random.PRNGKey(31), 5)
+    n_tok, d, f = 160, 32, 576          # 576 -> 1024 columns
+    w = {"x": jax.random.normal(ks[0], (n_tok, d)),
+         "router_weight": jax.random.normal(ks[1], (16, d)) * 0.5,
+         "experts_up_weight": jax.random.normal(ks[2], (16, f, d)) * 0.2,
+         "experts_down_weight": jax.random.normal(ks[3], (16, d, f)) * 0.2}
+    cot = jax.random.normal(ks[4], (n_tok, d))
+    assert moe._widened(w["experts_up_weight"][:4],
+                        w["experts_down_weight"][:4])[0].shape == (4, 1024, d)
+
+    def share(w):
+        with jax.default_matmul_precision("highest"):
+            tw, ti = moe.route(w["x"] @ w["router_weight"].T, 3, 2.5)
+            return moe.moe_experts(w["x"], tw, ti, None,
+                                   w["experts_up_weight"][4:8],
+                                   w["experts_down_weight"][4:8], 16, 4)
+
+    def dense(w):
+        with jax.default_matmul_precision("highest"):
+            tw, ti = moe.route(w["x"] @ w["router_weight"].T, 3, 2.5)
+            out = 0.0
+            for e in range(4, 8):
+                mine = jnp.sum(jnp.where(ti == e, tw, 0.0), axis=-1)
+                h = jnp.square(jax.nn.relu(
+                    w["x"] @ w["experts_up_weight"][e].T))
+                out = out + mine[:, None] * (h @ w["experts_down_weight"][e].T)
+        return out
+
+    got, loads = share(w)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(dense(w)),
+                               rtol=2e-4, atol=2e-4)
+    g_got = jax.grad(lambda w: jnp.sum(share(w)[0] * cot))(w)
+    g_want = jax.grad(lambda w: jnp.sum(dense(w) * cot))(w)
+    for name in w:
+        np.testing.assert_allclose(np.asarray(g_got[name]),
+                                   np.asarray(g_want[name]),
+                                   rtol=5e-4, atol=5e-4, err_msg=name)
+    # the plan: every expert's rows start at a multiple of the tile, every
+    # pair routed here has one row, no row holds a pair routed elsewhere
+    _, ti = moe.route(w["x"] @ w["router_weight"].T, 3, 2.5)
+    slots, ends, planned = moe._plan_tiled(ti, 4, 4, 128)
+    slots, ends = np.asarray(slots), np.asarray(ends)
+    assert planned.tolist() == np.asarray(loads).tolist()
+    assert (ends % 128 == 0).all() and slots.shape == (n_tok * 3 + 4 * 128,)
+    held = slots[slots < n_tok * 3]
+    assert sorted(held) == sorted(np.flatnonzero(
+        (np.asarray(ti).reshape(-1) >= 4) & (np.asarray(ti).reshape(-1) < 8)))
+    start = 0
+    for e, end in enumerate(ends):
+        rows = slots[start:end]
+        rows = rows[rows < n_tok * 3]
+        assert (np.asarray(ti).reshape(-1)[rows] == 4 + e).all()
+        assert len(rows) == planned[e]
+        start = end

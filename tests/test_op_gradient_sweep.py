@@ -208,8 +208,16 @@ SPECS = {
         primary={"query": (1, 2, 8, 2), "key": (1, 2, 8, 2),
                  "value": (1, 2, 8, 3), "g": (1, 2, 8), "beta": (1, 2, 8)},
         attrs={"chunk": 4}, tol=dict(rtol=3e-2, atol=3e-3)),
+    "_contrib_SSDScan": dict(
+        primary={"data": (1, 8, 2, 3), "dt": (1, 8, 2), "A_log": (2,),
+                 "B": (1, 8, 1, 4), "C": (1, 8, 1, 4), "D": (2,)},
+        attrs={"chunk": 4}, domain=(0.2, 1.0, 0),
+        tol=dict(rtol=3e-2, atol=3e-3)),
     "_contrib_CausalConv1D": dict(primary={"data": (2, 5, 3)},
                                   attrs={"kernel": 3}),
+    "_contrib_CausalConv1D_bias": dict(op="_contrib_CausalConv1D",
+                                       primary={"data": (2, 5, 3)},
+                                       attrs={"kernel": 3, "bias": True}),
     "_contrib_RotaryEmbedding": dict(
         primary={"data": (1, 2, 4, 6)},
         attrs={"rotary_dims": 4, "theta": 100.0, "scale": 1.3}),
@@ -222,6 +230,8 @@ SPECS = {
     "RMSNorm": dict(primary={"data": S}),
     "RMSNorm_gated": dict(op="RMSNorm", primary={"data": S},
                           attrs={"gated": True}),
+    "RMSNorm_groups": dict(op="RMSNorm", primary={"data": (3, 4)},
+                           attrs={"groups": 2}),
     "_slice_assign": dict(primary={"lhs": S, "rhs": (2, 2)},
                           attrs={"begin": (0, 0), "end": (2, 2)}),
     "_slice_assign_scalar": dict(primary={"data": S},

@@ -346,3 +346,59 @@ def test_head_gate_kernels_on_chip(heads):
         assert new_.dtype == jnp.bfloat16
         assert _rel(new_, want_) < 6e-3
         assert _rel(new_, want_) <= 1.05 * _rel(old_, want_)
+
+
+def test_ssd_kernels_on_chip():
+    """nemotron-twotower-30b-fit-s4096's state-space scan at the cell's
+    sizes, the Mosaic kernels with bfloat16 operands: the output and all six
+    gradients against the op's chunk path in float32 at `highest` (plain
+    JAX, which the CPU tests tie to the token-by-token recurrence), at a
+    bfloat16 rounding or two (my chip run, PR 34: 0.0018 and 0.0007-0.0030)."""
+    import jax
+    import jax.numpy as jnp
+    from mxtpu.ops import ssd
+    _require_accel()
+    b, t, h, p, g, n, chunk = 2, 4096, 64, 64, 8, 128, 128
+    ks = jax.random.split(jax.random.PRNGKey(5), 6)
+    x = jax.nn.silu(jax.random.normal(ks[0], (b, t, h, p))).astype("bfloat16")
+    dt = jax.nn.softplus(jax.random.normal(ks[1], (b, t, h)))
+    a_log = 2.0 * jax.random.normal(ks[2], (h,))
+    bm, cm = (jax.nn.silu(jax.random.normal(k, (b, t, g, n))).astype("bfloat16")
+              for k in ks[3:5])
+    d = jnp.ones((h,))
+    w = jax.random.normal(ks[5], (b, t, h, p))
+
+    def kernels(*a):
+        return ssd.ssd_scan(*a, chunk=chunk)
+
+    def plain(x, dt, a_log, bm, cm, d):
+        r, f32 = h // g, jnp.float32
+
+        def rows(z):
+            z = z.astype(f32).reshape(b, t // chunk, chunk, g, r)
+            return jnp.transpose(z, (0, 3, 1, 4, 2))
+
+        ops = (x.astype(f32).reshape(b, t, h * p), rows(dt),
+               jnp.cumsum(rows(dt * -jnp.exp(a_log)), -1),
+               bm.astype(f32).reshape(b, t, g * n),
+               cm.astype(f32).reshape(b, t, g * n),
+               jnp.repeat(d, p).reshape(g, 1, r * p))
+        return ssd._scan_fwd(*ops, p, False).reshape(b, t, h, p)
+
+    def with_grads(fn):
+        def loss(*a):
+            return jnp.sum(fn(*a).astype(jnp.float32) * w)
+        return jax.jit(lambda *a: (fn(*a),) + jax.grad(
+            loss, argnums=tuple(range(6)))(*a))
+
+    args = (x, dt, a_log, bm, cm, d)
+    # the forward for y alone, the differentiated forward, the backward
+    text = with_grads(kernels).lower(*args).as_text()
+    assert text.count("tpu_custom_call") == 3 and "stablehlo.while" not in text
+    got = with_grads(kernels)(*args)
+    with jax.default_matmul_precision("highest"):
+        want = with_grads(plain)(*(a.astype("float32") for a in args))
+    assert got[0].dtype == jnp.bfloat16
+    for name, a, b_ in zip(("y", "dx", "ddt", "dA_log", "dB", "dC", "dD"),
+                           got, want):
+        assert _rel(a, b_) < 8e-3, (name, _rel(a, b_))
