@@ -19,28 +19,34 @@ runs without its exchange). A second output is the pairs each held expert
 received, (held,) int32, which takes no gradient. With
 ``activation="relu2"`` an expert is ungated, relu(x W_up^T)^2 W_down^T, and
 the operator takes two stacked leaves (``up_weight``, ``down_weight``)
-through the same loop and grouped products; its plan gives every held
-expert rows of its own in whole tiles (`_plan_tiled`) and its products run
-at a width of whole tiles (`_widened`), so that a trip's time does not
-follow where the groups' boundaries fall. What calls for the tiles is the
-grouped product's geometry, not the activation: the gated form keeps the
-packed plan because its programs were held to what they were (PR 34), and
-one plan for both is ROADMAP Speed 10 (a).
+through the same walk and grouped products.
 
-Dispatch is dropless by construction. The (token, slot) pairs are sorted
-by expert (a stable argsort of the local expert index, pairs routed
-elsewhere last), and a loop walks the sorted pairs `chunk` rows at a time
-for as many trips as the pairs held here need: gather the rows' tokens, a
-grouped product over the chunk's ragged groups (`jax.lax.ragged_dot_general`,
-which XLA lowers to a Mosaic grouped matmul on the TPU) for gate, up and
-down, weight, scatter-add into the tokens' rows. Nothing has a capacity, so
-nothing overflows; the static bound is the pairs' array itself (tokens x
-top_k int32) and one chunk of activations, and **time follows the pairs
-routed here**, not tokens x experts held. The loop's trip count depends on
-the data, so the layer brings its own gradient (``jax.custom_vjp``): the
-same walk again, recomputing gate and up (the up product alone in the
-ungated form), with the weight gradients accumulated in float32 by grouped
-products whose ragged dimension is the contraction.
+Dispatch is dropless by construction. One plan serves both forms
+(`_plan_tiled`): the (token, slot) pairs are sorted by expert (a stable
+argsort of the local expert index, pairs routed elsewhere last) and every
+held expert's pairs are given rows of their own in whole tiles, because the
+grouped product's time follows the tiles it visits and not the rows that
+hold a pair; the tile follows the shape (512 rows where a trip's rows divide
+by it, else 128), and a width over one tile that is not whole tiles is
+padded with zeros inside the operator (`_widened`). The walk (`_walk_tiled`)
+takes the rows `chunk` at a time for as many trips as the pairs held here
+need: gather the rows' tokens, a grouped product over the chunk's ragged
+groups (`jax.lax.ragged_dot_general`, which XLA lowers to a Mosaic grouped
+matmul on the TPU) for gate, up and down, weight, scatter-add into the
+tokens' rows. Nothing has a capacity, so nothing overflows; the static bound
+is the pairs' array itself (tokens x top_k int32, and a tile an expert) and
+one chunk of activations, and **time follows the pairs routed here**, not
+tokens x experts held. The trip count depends on the data, so the layer
+brings its own gradient (``jax.custom_vjp``, one pair for both forms, the
+activation a function it is told): the same walk again, each thing once a
+trip. Gate and up are recomputed (the up product alone in the ungated form)
+and the activation's own derivative is taken from them; the down product is
+not: the router weight's gradient comes from the product that carries the
+cotangent to the experts' width. Forward and backward take their first trip
+as straight-line code (`_peeled`): the backward's grouped products over the
+ragged contraction ARE the float32 weight gradients, and a loop behind the
+first trip adds to them only where the pairs need a second one, which
+`moe_trips_after_first` over `moe_layer_steps_seen` counts.
 """
 from __future__ import annotations
 
@@ -57,9 +63,9 @@ _F32 = jnp.float32
 CHUNK = 4096        # rows a trip of the dispatch loop, at most
 TILE = 512          # rows and columns a tile of XLA's grouped matmul (TPU)
 
-# tokens one call of the last expert layer traced takes: what the fetched
-# loads are counted against (`observe_loads`)
-_tokens_last_traced = 0
+# (tokens, tile, rows a trip) of the last expert layer traced: what the
+# fetched loads are counted against (`observe_loads`)
+_last_traced = (0, TILE, CHUNK)
 
 
 # ------------------------------------------------------------------ router
@@ -123,42 +129,62 @@ def _grouped(lhs, rhs, sizes, mode):
         preferred_element_type=_F32)
 
 
-def _plan(index, held, offset):
-    """Sorts the (token, slot) pairs by the expert held here that they
-    name: (order (N k,) pair ids, pairs routed elsewhere last; ends (held,)
-    the running sum of the loads; loads (held,))."""
-    key, order, loads = _sorted_pairs(index, held, offset)
-    return order, jnp.cumsum(loads), loads
-
-
-def _sorted_pairs(index, held, offset):
-    """(key (N k,), the held expert each pair names, `held` for one routed
-    elsewhere; order, the pair ids sorted by it, stably; loads (held,))."""
+def _plan_tiled(index, held, offset, tile):
+    """Sorts the (token, slot) pairs by the expert held here that they name
+    and gives every held expert rows of its own in whole tiles: (order (N k,)
+    pair ids, stably sorted, pairs routed elsewhere last; ends (held,), the
+    running sum of the loads rounded up to whole tiles of `tile` rows; loads
+    (held,)). Which pair a row holds is `_slots`. XLA's grouped matmul on
+    the TPU walks the rows in tiles of 512 and takes a tile once for every
+    group that has rows in it (0.16 ms a visit at the Nemotron cell's sizes,
+    my chip run, PR 34): with the groups packed end to end the visits, and
+    so the time, follow where the boundaries happen to fall; with each group
+    on tiles of its own they are the experts' own tiles and no more. Six
+    seeds of that cell spread by 0.46% packed end to end at the padded width
+    and by 0.17% on tiles of their own, at the same median step (my chip
+    runs, PR 34). The loads are counted by comparison and the rows' pairs
+    found by gathers a trip: a histogram by scatter-add took 0.72 ms a layer
+    at Laguna's 81,920 pairs and every row's pair up front 0.61-0.96 ms,
+    more than the forward's three products together (my chip runs, PR 35)."""
     local = index.reshape(-1) - offset
     key = jnp.where((local >= 0) & (local < held), local, held)
     order = jnp.argsort(key, stable=True).astype(jnp.int32)
-    loads = jnp.zeros((held + 1,), jnp.int32).at[key].add(1)[:held]
-    return key, order, loads
+    loads = jnp.sum(key[:, None] == jnp.arange(held, dtype=key.dtype),
+                    axis=0, dtype=jnp.int32)
+    return order, jnp.cumsum(-(-loads // tile) * tile), loads
 
 
-def _walk(x, order, ends, k, chunk):
-    """What every trip of the dispatch loop starts from: `trips`, and
-    `rows(c)` -> (pair ids, their tokens, which rows hold a pair, the
-    chunk's group sizes)."""
-    total = ends[-1]
-    pad = -order.shape[0] % chunk
-    order = jnp.pad(order, (0, pad + chunk))
+def _slots(order, ends, loads, row):
+    """The pair id each of the plan's rows `row` holds, `order.shape[0]`
+    where it holds none: by row, the expert whose tiles it lies in, its rank
+    among that expert's pairs, and the pair of that rank in the sorted
+    order."""
+    held = loads.shape[0]
     before = jnp.concatenate([jnp.zeros((1,), ends.dtype), ends[:-1]])
+    mine = jnp.minimum(jnp.sum(row[:, None] >= ends, axis=1), held - 1)
+    rank = row - before[mine]
+    pair = order[jnp.minimum((jnp.cumsum(loads) - loads)[mine] + rank,
+                             order.shape[0] - 1)]
+    return jnp.where(rank < loads[mine], pair, order.shape[0])
+
+
+def _walk_tiled(order, ends, loads, k, chunk):
+    """What every trip of the dispatch starts from: `trips`, and `rows(c)`
+    -> (pair ids, their tokens, which rows hold a pair, the chunk's group
+    sizes, whole tiles) over the plan's rows."""
+    edges = jnp.concatenate([jnp.zeros((1,), ends.dtype), ends])
 
     def rows(c):
         start = c * chunk
-        pairs = jax.lax.dynamic_slice(order, (start,), (chunk,))
-        live = start + jnp.arange(chunk, dtype=jnp.int32) < total
-        sizes = (jnp.clip(ends - start, 0, chunk)
-                 - jnp.clip(before - start, 0, chunk)).astype(jnp.int32)
+        pairs = _slots(order, ends, loads,
+                       start + jnp.arange(chunk, dtype=jnp.int32))
+        live = pairs < order.shape[0]
+        pairs = jnp.where(live, pairs, 0)
+        inside = jnp.clip(edges - start, 0, chunk)
+        sizes = (inside[1:] - inside[:-1]).astype(jnp.int32)
         return pairs, pairs // k, live, sizes
 
-    return (total + chunk - 1) // chunk, rows
+    return (ends[-1] + chunk - 1) // chunk, rows
 
 
 def _up(xs, sizes, live, *turned):
@@ -175,196 +201,111 @@ def _turned(*weights):
     return tuple(jnp.swapaxes(w, 1, 2) for w in weights)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(7, 8))
-def _experts(x, w, order, ends, wg, wu, wd, k, chunk):
-    trips, rows = _walk(x, order, ends, k, chunk)
+def _widened(leaves):
+    """The stacked leaves (gate and up, or up alone; down last) with the
+    experts' width padded with zeros to whole tiles of `TILE` columns (both
+    activations give 0 there, which adds nothing and takes no gradient): at
+    width 1856 a grouped product took 1.27-1.55 ms where at 2048 it takes
+    0.48-0.61 (my chip run, PR 34). A width of whole tiles, or under one, is
+    left as it is."""
+    f = leaves[-1].shape[2]
+    extra = -f % TILE if f > TILE else 0
+    if not extra:
+        return leaves
+    return (tuple(jnp.pad(u, ((0, 0), (0, extra), (0, 0)))
+                  for u in leaves[:-1])
+            + (jnp.pad(leaves[-1], ((0, 0), (0, 0), (0, extra))),))
+
+
+def _silu_gated(a, b):
+    return jax.nn.silu(a) * b
+
+
+def _relu2(a):
+    return jnp.square(jnp.maximum(a, 0.0))
+
+
+def _plus(acc, new):
+    """A sum that its first term starts: no zeros to add it to."""
+    return new if acc is None else acc + new
+
+
+def _peeled(trips, trip, start):
+    """The dispatch, `trip(c, carry)` for c < trips, with trip 0 outside
+    the loop as straight-line code. It is always taken: with no pair held
+    every group is empty and every row dead, which any trip has to get
+    right for an expert with no rows. What it returns is what the loop
+    behind it carries on, so a sum may start from its first term (`_plus`)
+    and XLA schedules the trip nearly every layer-step stops at with the
+    code around it."""
+    return jax.lax.fori_loop(1, trips, trip, trip(0, start))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6))
+def _experts(x, w, plan, leaves, act, k, chunk):
+    """sum over the pairs held here of w E(x): `plan` is `_plan_tiled`'s,
+    `leaves` the stacked leaves an expert's `act` reads x through (gate and
+    up, or up alone) and, last, the down leaf that takes its result back."""
+    trips, rows = _walk_tiled(*plan, k, chunk)
     w_flat = w.reshape(-1)
-    wg_t, wu_t, wd_t = _turned(wg, wu, wd)
+    *ups_t, wd_t = _turned(*_widened(leaves))
 
     def trip(c, out):
         pairs, tok, live, sizes = rows(c)
-        a, b = _up(x[tok], sizes, live, wg_t, wu_t)
-        h = (jax.nn.silu(a) * b).astype(x.dtype)
+        h = act(*_up(x[tok], sizes, live, *ups_t)).astype(x.dtype)
         y = _grouped(h, wd_t, sizes, "nn") * w_flat[pairs][:, None]
         return out.at[tok].add(jnp.where(live[:, None], y, 0.0))
 
-    out = jax.lax.fori_loop(0, trips, trip, jnp.zeros(x.shape, _F32))
-    return out.astype(x.dtype)
+    return _peeled(trips, trip, jnp.zeros(x.shape, _F32)).astype(x.dtype)
 
 
-def _experts_fwd(x, w, order, ends, wg, wu, wd, k, chunk):
-    return (_experts(x, w, order, ends, wg, wu, wd, k, chunk),
-            (x, w, order, ends, wg, wu, wd))
+def _experts_fwd(x, w, plan, leaves, act, k, chunk):
+    return _experts(x, w, plan, leaves, act, k, chunk), (x, w, plan, leaves)
 
 
-def _experts_bwd(k, chunk, res, g):
-    x, w, order, ends, wg, wu, wd = res
+def _experts_bwd(act, k, chunk, res, g):
+    """The same walk again. The first trip's grouped products ARE the
+    float32 weight gradients (`_peeled`, `_plus`), and the loop behind it
+    adds to them only where there is a second trip. The router weight's
+    gradient sum(g * (h W_down^T)) is taken as sum((g W_down) * h) from the
+    product the backward needs anyway, the pair's weight applied in float32
+    after it."""
+    x, w, plan, leaves = res
     with telemetry.span("moe.build", category="compile",
                         tags={"pass": "bwd"}):
-        trips, rows = _walk(x, order, ends, k, chunk)
+        trips, rows = _walk_tiled(*plan, k, chunk)
         w_flat = w.reshape(-1)
-        wg_t, wu_t, wd_t = _turned(wg, wu, wd)
+        *wide, wide_d = _widened(leaves)
+        ups_t = _turned(*wide)
 
         def trip(c, carry):
-            dx, dw, dwg, dwu, dwd = carry
+            dx, dw, dleaves = carry
             pairs, tok, live, sizes = rows(c)
-            xs = x[tok]
-            a, b = _up(xs, sizes, live, wg_t, wu_t)
-            sa = jax.nn.sigmoid(a)
-            act = a * sa
-            h = (act * b).astype(x.dtype)
-            gy = jnp.where(live[:, None], g[tok].astype(_F32), 0.0)
-            y = _grouped(h, wd_t, sizes, "nn")
-            dw = dw.at[pairs].add(
-                jnp.where(live, jnp.sum(gy * y, axis=-1), 0.0))
-            dy = (gy * w_flat[pairs][:, None]).astype(x.dtype)
-            dh = jnp.where(live[:, None], _grouped(dy, wd, sizes, "nn"), 0.0)
-            dwd = dwd + _grouped(dy, h, sizes, "tn")
-            da = (dh * b * (sa + act * (1.0 - sa))).astype(x.dtype)
-            db = (dh * act).astype(x.dtype)
-            dwg = dwg + _grouped(da, xs, sizes, "tn")
-            dwu = dwu + _grouped(db, xs, sizes, "tn")
-            dxs = _grouped(da, wg, sizes, "nn") + _grouped(db, wu, sizes,
-                                                           "nn")
+            xs, gs, wp = x[tok], g[tok], w_flat[pairs][:, None]
+            h, pull = jax.vjp(act, *_up(xs, sizes, live, *ups_t))
+            dh = jnp.where(live[:, None],
+                           _grouped(gs, wide_d, sizes, "nn"), 0.0)
+            dw = dw.at[pairs].add(jnp.sum(dh * h, axis=-1))
+            das = [d.astype(x.dtype) for d in pull(dh * wp)]
+            new = [_grouped(da, xs, sizes, "tn") for da in das] + [
+                _grouped(gs, (h * wp).astype(x.dtype), sizes, "tn")]
+            dxs = sum(_grouped(da, u, sizes, "nn")
+                      for da, u in zip(das, wide))
             dx = dx.at[tok].add(jnp.where(live[:, None], dxs, 0.0))
-            return dx, dw, dwg, dwu, dwd
+            return dx, dw, tuple(map(_plus, dleaves, new))
 
-        dx, dw, dwg, dwu, dwd = jax.lax.fori_loop(
-            0, trips, trip,
-            (jnp.zeros(x.shape, _F32), jnp.zeros(w_flat.shape, _F32),
-             jnp.zeros(wg.shape, _F32), jnp.zeros(wu.shape, _F32),
-             jnp.zeros(wd.shape, _F32)))
-    zero = functools.partial(np.zeros, dtype=jax.dtypes.float0)
+        dx, dw, dleaves = _peeled(trips, trip, (
+            jnp.zeros(x.shape, _F32), jnp.zeros(w_flat.shape, _F32),
+            (None,) * len(leaves)))
+    f = leaves[-1].shape[2]      # the published width, under the padding
+    dleaves = [d[:, :f] for d in dleaves[:-1]] + [dleaves[-1][:, :, :f]]
     return (dx.astype(x.dtype), dw.reshape(w.shape).astype(w.dtype),
-            zero(order.shape), zero(ends.shape), dwg.astype(wg.dtype),
-            dwu.astype(wu.dtype), dwd.astype(wd.dtype))
+            jax.tree.map(lambda v: np.zeros(v.shape, jax.dtypes.float0),
+                         plan),
+            tuple(d.astype(v.dtype) for d, v in zip(dleaves, leaves)))
 
 
 _experts.defvjp(_experts_fwd, _experts_bwd)
-
-
-def _plan_tiled(index, held, offset, tile):
-    """`_plan` with every held expert's pairs starting at a multiple of
-    `tile` rows: (slots, pair ids by row with `tokens x k` where a row holds
-    none; ends (held,), the running sum of the loads rounded up to whole
-    tiles; loads (held,)). XLA's grouped matmul on the TPU walks the rows in
-    tiles of 512 and takes a tile once for every group that has rows in it
-    (0.16 ms a visit at the Nemotron cell's sizes, my chip run, PR 34): with
-    the groups packed end to end the visits, and so the time, follow where
-    the boundaries happen to fall; with each group on tiles of its own they
-    are the experts' own tiles and no more. Six seeds of that cell spread by
-    0.46% packed end to end at the padded width and by 0.17% on tiles of
-    their own, at the same median step (my chip runs, PR 34)."""
-    key, order, loads = _sorted_pairs(index, held, offset)
-    room = -(-loads // tile) * tile
-    ends = jnp.cumsum(room)
-    mine = jnp.minimum(key[order], held - 1)
-    rank = jnp.arange(order.shape[0], dtype=jnp.int32) \
-        - (jnp.cumsum(loads) - loads)[mine]
-    rows = order.shape[0] + held * tile     # every expert pads under a tile
-    slot = jnp.where(key[order] < held, (ends - room)[mine] + rank, rows)
-    slots = jnp.full((rows,), order.shape[0], jnp.int32).at[slot].set(
-        order, mode="drop")
-    return slots, ends, loads
-
-
-def _walk_tiled(x, slots, ends, k, chunk):
-    """`_walk` over `_plan_tiled`'s rows: a row is live where it holds a
-    pair, and a chunk's group sizes are whole tiles."""
-    none = x.shape[0] * k
-    slots = jnp.pad(slots, (0, -slots.shape[0] % chunk + chunk),
-                    constant_values=none)
-    before = jnp.concatenate([jnp.zeros((1,), ends.dtype), ends[:-1]])
-
-    def rows(c):
-        start = c * chunk
-        pairs = jax.lax.dynamic_slice(slots, (start,), (chunk,))
-        live = pairs < none
-        pairs = jnp.where(live, pairs, 0)
-        sizes = (jnp.clip(ends - start, 0, chunk)
-                 - jnp.clip(before - start, 0, chunk)).astype(jnp.int32)
-        return pairs, pairs // k, live, sizes
-
-    return (ends[-1] + chunk - 1) // chunk, rows
-
-
-def _widened(wu, wd):
-    """The two stacked leaves with the experts' width padded with zeros to
-    whole tiles of `TILE` columns (relu(0)^2 = 0 adds nothing, and takes no
-    gradient): at width 1856 a grouped product took 1.27-1.55 ms where at
-    2048 it takes 0.48-0.61 (my chip run, PR 34)."""
-    extra = -wu.shape[1] % TILE if wu.shape[1] > TILE else 0
-    if not extra:
-        return wu, wd
-    return (jnp.pad(wu, ((0, 0), (0, extra), (0, 0))),
-            jnp.pad(wd, ((0, 0), (0, 0), (0, extra))))
-
-
-@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7))
-def _experts_relu2(x, w, slots, ends, wu, wd, k, chunk):
-    """The ungated form: relu(x W_up^T)^2 W_down^T a pair."""
-    trips, rows = _walk_tiled(x, slots, ends, k, chunk)
-    w_flat = w.reshape(-1)
-    wu_t, wd_t = _turned(*_widened(wu, wd))
-
-    def trip(c, out):
-        pairs, tok, live, sizes = rows(c)
-        a, = _up(x[tok], sizes, live, wu_t)
-        h = jnp.square(jnp.maximum(a, 0.0)).astype(x.dtype)
-        y = _grouped(h, wd_t, sizes, "nn") * w_flat[pairs][:, None]
-        return out.at[tok].add(jnp.where(live[:, None], y, 0.0))
-
-    out = jax.lax.fori_loop(0, trips, trip, jnp.zeros(x.shape, _F32))
-    return out.astype(x.dtype)
-
-
-def _experts_relu2_fwd(x, w, slots, ends, wu, wd, k, chunk):
-    return (_experts_relu2(x, w, slots, ends, wu, wd, k, chunk),
-            (x, w, slots, ends, wu, wd))
-
-
-def _experts_relu2_bwd(k, chunk, res, g):
-    x, w, slots, ends, wu, wd = res
-    with telemetry.span("moe.build", category="compile",
-                        tags={"pass": "bwd"}):
-        trips, rows = _walk_tiled(x, slots, ends, k, chunk)
-        w_flat = w.reshape(-1)
-        wide_u, wide_d = _widened(wu, wd)
-        wu_t, wd_t = _turned(wide_u, wide_d)
-
-        def trip(c, carry):
-            dx, dw, dwu, dwd = carry
-            pairs, tok, live, sizes = rows(c)
-            xs = x[tok]
-            a, = _up(xs, sizes, live, wu_t)
-            act = jnp.maximum(a, 0.0)
-            h = jnp.square(act).astype(x.dtype)
-            gy = jnp.where(live[:, None], g[tok].astype(_F32), 0.0)
-            y = _grouped(h, wd_t, sizes, "nn")
-            dw = dw.at[pairs].add(
-                jnp.where(live, jnp.sum(gy * y, axis=-1), 0.0))
-            dy = (gy * w_flat[pairs][:, None]).astype(x.dtype)
-            dh = jnp.where(live[:, None],
-                           _grouped(dy, wide_d, sizes, "nn"), 0.0)
-            dwd = dwd + _grouped(dy, h, sizes, "tn")
-            da = (dh * 2.0 * act).astype(x.dtype)
-            dwu = dwu + _grouped(da, xs, sizes, "tn")
-            dxs = _grouped(da, wide_u, sizes, "nn")
-            dx = dx.at[tok].add(jnp.where(live[:, None], dxs, 0.0))
-            return dx, dw, dwu, dwd
-
-        dx, dw, dwu, dwd = jax.lax.fori_loop(
-            0, trips, trip,
-            (jnp.zeros(x.shape, _F32), jnp.zeros(w_flat.shape, _F32),
-             jnp.zeros(wide_u.shape, _F32), jnp.zeros(wide_d.shape, _F32)))
-    zero = functools.partial(np.zeros, dtype=jax.dtypes.float0)
-    f = wu.shape[1]
-    return (dx.astype(x.dtype), dw.reshape(w.shape).astype(w.dtype),
-            zero(slots.shape), zero(ends.shape),
-            dwu[:, :f].astype(wu.dtype), dwd[:, :, :f].astype(wd.dtype))
-
-
-_experts_relu2.defvjp(_experts_relu2_fwd, _experts_relu2_bwd)
 
 
 def moe_experts(x, topk_weight, topk_index, gate_weight, up_weight,
@@ -373,7 +314,7 @@ def moe_experts(x, topk_weight, topk_index, gate_weight, up_weight,
     `expert_offset` .. + held - 1 of `num_experts` give for x (.., d), and
     the pairs each of them received, (held,) int32. `gate_weight` None: the
     ungated squared-ReLU experts."""
-    global _tokens_last_traced
+    global _last_traced
     held, d = up_weight.shape[0], x.shape[-1]
     if expert_offset < 0 or expert_offset + held > num_experts:
         raise ValueError("moe_experts: experts %d..%d of %d" % (
@@ -383,7 +324,8 @@ def moe_experts(x, topk_weight, topk_index, gate_weight, up_weight,
     n = xf.shape[0]
     bound = n * min(k, held)
     chunk = min(CHUNK, -(-bound // 128) * 128)
-    _tokens_last_traced = n
+    tile = TILE if chunk % TILE == 0 else 128
+    _last_traced = (n, tile, chunk)
     telemetry.gauge("moe_experts_held", help="experts the last expert "
                     "layer traced holds").set(held)
     telemetry.gauge("moe_dispatch_rows_bound", help="(token, expert) pairs "
@@ -394,30 +336,36 @@ def moe_experts(x, topk_weight, topk_index, gate_weight, up_weight,
                         tags={"pass": "fwd", "tokens": n, "held": held,
                               "chunk": chunk}):
         w = topk_weight.reshape(n, k).astype(_F32)
-        if gate_weight is None:
-            slots, ends, loads = _plan_tiled(
-                topk_index, held, int(expert_offset),
-                TILE if chunk % TILE == 0 else 128)
-            out = _experts_relu2(xf, w, slots, ends, up_weight, down_weight,
-                                 k, chunk)
-        else:
-            order, ends, loads = _plan(topk_index, held, int(expert_offset))
-            out = _experts(xf, w, order, ends, gate_weight, up_weight,
-                           down_weight, k, chunk)
-    return out.reshape(x.shape), loads
+        plan = _plan_tiled(topk_index, held, int(expert_offset), tile)
+        leaves, act = (((up_weight, down_weight), _relu2)
+                       if gate_weight is None else
+                       ((gate_weight, up_weight, down_weight), _silu_gated))
+        out = _experts(xf, w, plan, leaves, act, k, chunk)
+    return out.reshape(x.shape), plan[2]
 
 
 def observe_loads(loads):
     """Counts what `fit` fetched at a metric sync: `loads` is one (held,)
     array an expert layer, of one step."""
-    loads = [np.asarray(v, np.float64) for v in loads]
+    tokens, tile, chunk = _last_traced
+    loads = [np.asarray(v, np.int64) for v in loads]
     telemetry.counter("moe_pairs_routed", help="(token, expert) pairs the "
                       "expert layers were handed, over the steps whose "
                       "loads fit fetched (one a metric sync)"
                       ).inc(int(sum(v.sum() for v in loads)))
     telemetry.counter("moe_tokens_seen", help="tokens the expert layers "
                       "took, a layer a count, over the same steps"
-                      ).inc(_tokens_last_traced * len(loads))
+                      ).inc(tokens * len(loads))
+    telemetry.counter("moe_layer_steps_seen", help="expert layers whose "
+                      "loads fit fetched, a layer of a step a count"
+                      ).inc(len(loads))
+    telemetry.counter("moe_trips_after_first", help="trips of the dispatch "
+                      "those layers took behind the first, which runs "
+                      "outside the loop: the loads in whole tiles over the "
+                      "rows of a trip, less one").inc(sum(
+                          max(-(-rows // chunk), 1) - 1 for rows in (
+                              int((-(-v // tile)).sum()) * tile
+                              for v in loads)))
     worst = max((float(v.max() / v.mean()) for v in loads if v.sum()),
                 default=0.0)
     telemetry.gauge("moe_load_max_over_mean", help="the fullest held "

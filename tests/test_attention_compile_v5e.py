@@ -355,3 +355,56 @@ def test_ssd_forward_alone_keeps_no_states(one_chip, no_cache):
     assert "%" + ssd.FWD_KERNEL_NAME in text
     assert "%" + ssd.BWD_KERNEL_NAME not in text
     assert "f32[2,8,32,128,512]" not in text
+
+
+@pytest.mark.parametrize(
+    "d,f,k,experts,gated,leaves,fwd,bwd",
+    [(3072, 1024, 10, 256, True, ("f32[8,1024,3072]", "f32[8,3072,1024]"),
+      3, 8),
+     (2688, 1856, 6, 128, False, ("f32[8,2048,2688]", "f32[8,2688,2048]"),
+      2, 5)],
+    ids=["laguna-s-2.1-fit-s4096", "nemotron-twotower-30b-fit-s4096"])
+def test_expert_layer_takes_its_first_trip_outside_the_loop(
+        one_chip, no_cache, d, f, k, experts, gated, leaves, fwd, bwd):
+    """The expert layer and its gradient at the two cells' sizes: 8,192
+    tokens, 8 experts held, bf16, trips of 4,096 rows. The grouped products
+    of the forward and of the backward stand in the entry computation (the
+    first trip) as well as in the loop body behind each, and the backward
+    takes one product fewer than the walk once did (the down product is not
+    recomputed for the router weight's gradient: 8 where the gated form had
+    9, 5 where `relu2` had 6); the weight gradients are the first trip's
+    products themselves, so no float32 zeros of a stacked leaf's shape are
+    made for the loop to add to."""
+    import re
+    import jax
+    import jax.numpy as jnp
+    from mxtpu.ops import moe
+
+    def s(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    ups = [s((8, f, d))] * (2 if gated else 1)
+
+    def loss(x, tw, ups, wd, ti, cot):
+        out, _ = moe.moe_experts(x, tw, ti, ups[0] if gated else None,
+                                 ups[-1], wd, experts, 0)
+        return jnp.sum(out.astype(jnp.float32) * cot)
+
+    text = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2, 3))).lower(
+        s((8192, d)), s((8192, k), jnp.float32), ups, s((8, d, f)),
+        s((8192, k), jnp.int32), s((8192, d), jnp.float32)
+    ).compile().as_text()
+    products = {}
+    for head, body in re.findall(
+            r"^((?:ENTRY )?%[\w.-]+) \(.*?\n(.*?)^\}", text, re.M | re.S):
+        calls = len(re.findall(r"^\s*%ragged-dot-none[\w.]* = ", body, re.M))
+        if calls:
+            products["entry" if head.startswith("ENTRY") else head] = calls
+    # both first trips; XLA may share the forward's gate and up products
+    # with the backward's recomputed ones, which then stand there once
+    assert bwd < products.pop("entry") <= fwd + bwd, products
+    assert sorted(products.values()) == [fwd, bwd], products
+    # the weight gradients: products' results, and the loop's sums of them
+    made = re.findall(r"= (f32\[8,\d+,\d+\])\S* (\w[\w-]*)\(", text)
+    assert {shape for shape, _ in made} == set(leaves)
+    assert "broadcast" not in {op for _, op in made}, made

@@ -87,11 +87,15 @@ def test_the_shares_add_up_to_the_uncut_layer(chunk):
                     for off in (0, 4, 8, 12))
         return jnp.sum(total * cot)
 
+    # jitted: op by op these few calls take the file's longest minute
+    want, g_want = jax.jit(jax.value_and_grad(whole))(w)
+    got, g_got = jax.jit(jax.value_and_grad(shares))(w)
+    np.testing.assert_allclose(float(got), float(want), rtol=2e-5)
     np.testing.assert_allclose(
-        np.asarray(sum(_program_share(w, 4, off, chunk, shared=off == 0)[0]
-                       for off in (0, 4, 8, 12))),
+        np.asarray(jax.jit(lambda w: sum(
+            _program_share(w, 4, off, chunk, shared=off == 0)[0]
+            for off in (0, 4, 8, 12)))(w)),
         np.asarray(_reference_layer(w, 16, 0)), rtol=2e-5, atol=2e-5)
-    g_want, g_got = jax.grad(whole)(w), jax.grad(shares)(w)
     for name in w:
         np.testing.assert_allclose(np.asarray(g_got[name]),
                                    np.asarray(g_want[name]),
@@ -207,6 +211,123 @@ def test_the_fetched_loads_are_counted():
     assert value("moe_dispatch_rows_bound") == TOKENS * 3
 
 
+def test_trips_behind_the_first_are_counted_from_the_loads():
+    """`observe_loads` counts the layers of the steps fetched and the trips
+    their dispatch took behind the first (which runs outside the loop): the
+    loads in whole tiles over the rows a trip of the last layer traced."""
+    from mxtpu import telemetry
+
+    def value(name):
+        return [m.value for m in telemetry.registry().series()
+                if m.name == name][0]
+
+    _program_share(_weights(17), 4, 0)   # 96 tokens: trips of 384 rows
+    assert moe._last_traced == (TOKENS, 128, 384)      # in tiles of 128
+    moe.observe_loads([np.array([0, 0, 0, 0])])        # the series exist
+    steps, trips = (value("moe_layer_steps_seen"),
+                    value("moe_trips_after_first"))
+    # 3 tiles: one trip; 4 tiles: a second; none: the first trip alone;
+    # 129 pairs take two tiles, so 7 tiles in the last: a third trip
+    moe.observe_loads([np.array([128, 1, 0, 5]), np.array([10, 30, 20, 20]),
+                       np.array([0, 0, 0, 0]), np.array([129, 128, 130, 200])])
+    assert value("moe_layer_steps_seen") == steps + 4
+    assert value("moe_trips_after_first") == trips + 0 + 1 + 0 + 2
+
+
+# ------------------------------------ every gradient against a dense loop
+FORMS = {"gated": (moe._silu_gated, 2), "relu2": (moe._relu2, 1)}
+
+
+def _dense(x, tw, ti, ups, wd, offset, act):
+    """A dense loop over the experts held: each over all tokens, times the
+    weight the router gave it (0 where not chosen)."""
+    out = 0.0
+    for e in range(wd.shape[0]):
+        mine = jnp.sum(jnp.where(ti == offset + e, tw, 0.0), axis=-1)
+        h = act(*[x @ u[e].T for u in ups])
+        out = out + mine[:, None] * (h @ wd[e].T)
+    return out
+
+
+# case: (the experts its tokens choose among, rows a trip if not `CHUNK`'s,
+# the held experts (4..7 as 0..3) that so get no rows). 40 tokens: a held
+# expert with rows fills one tile of 128, and 128 rows a trip make it a trip
+CASES = {
+    "one_trip": (range(16), 0, []),
+    "four_trips": (range(2, 10), 128, []),
+    "three_trips_round_an_expert_with_no_rows": ([0, 1, 4, 6, 7, 9], 128,
+                                                 [1]),
+    "an_expert_with_no_rows": ([0, 1, 4, 6, 7, 9], 0, [1]),
+    # a share that is handed no pair at all: the first trip runs on nothing
+    "no_pair": (range(8, 16), 0, [0, 1, 2, 3]),
+    # a chosen weight that is exactly 0 still takes its gradient
+    "a_weight_of_zero": (range(4, 8), 0, []),
+}
+
+
+@pytest.mark.parametrize("case", list(CASES))
+@pytest.mark.parametrize("form", list(FORMS))
+def test_every_gradient_is_the_dense_loops(form, case):
+    """x, `topk_weight` (the router's way in), and every stacked leaf, for
+    experts 4..7 of 16 held: the peeled first trip, the loop behind it, and
+    both with nothing to do."""
+    act, n_ups = FORMS[form]
+    among, chunk, empty = CASES[case]
+    among = np.asarray(list(among))
+    n_tok, d, f, k, held, offset = 40, 16, 8, 3, 4, 4
+    ks = jax.random.split(jax.random.PRNGKey(41), 7)
+    # k distinct experts a token, of those the case allows
+    ti = jnp.asarray(among)[jnp.argsort(jax.random.uniform(
+        ks[0], (n_tok, len(among))), axis=-1)[:, :k]].astype(jnp.int32)
+    tw = jax.random.uniform(ks[1], (n_tok, k), minval=0.2, maxval=1.0)
+    if case == "a_weight_of_zero":
+        tw = tw.at[0, 0].set(0.0).at[7, 2].set(0.0)
+    args = {"x": jax.random.normal(ks[2], (n_tok, d)), "tw": tw,
+            "ups": tuple(jax.random.normal(ks[3 + i], (held, f, d)) * 0.3
+                         for i in range(n_ups)),
+            "wd": jax.random.normal(ks[5], (held, d, f)) * 0.3}
+    cot = jax.random.normal(ks[6], (n_tok, d))
+
+    def program(a):
+        out, loads = moe.moe_experts(
+            a["x"], a["tw"], ti, a["ups"][0] if n_ups == 2 else None,
+            a["ups"][-1], a["wd"], 16, offset)
+        return jnp.sum(out * cot), loads
+
+    def dense(a):
+        return jnp.sum(_dense(a["x"], a["tw"], ti, a["ups"], a["wd"], offset,
+                              act) * cot)
+
+    rows, moe.CHUNK = moe.CHUNK, chunk or moe.CHUNK
+    try:
+        with jax.default_matmul_precision("highest"):
+            (got, loads), g_got = jax.jit(jax.value_and_grad(
+                program, has_aux=True))(args)
+            want, g_want = jax.jit(jax.value_and_grad(dense))(args)
+    finally:
+        moe.CHUNK = rows
+    loads = np.asarray(loads).tolist()
+    assert loads == [int((np.asarray(ti) == offset + e).sum())
+                     for e in range(held)]
+    assert [e for e in range(held) if not loads[e]] == empty, loads
+    np.testing.assert_allclose(float(got), float(want), rtol=2e-5, atol=2e-5)
+    flat_got, tree = jax.tree_util.tree_flatten(g_got)
+    flat_want, tree_want = jax.tree_util.tree_flatten(g_want)
+    assert tree == tree_want
+    for got_leaf, want_leaf in zip(flat_got, flat_want):
+        assert got_leaf.dtype == want_leaf.dtype
+        np.testing.assert_allclose(np.asarray(got_leaf),
+                                   np.asarray(want_leaf),
+                                   rtol=2e-4, atol=2e-4)
+    if case == "no_pair":
+        assert all(float(jnp.abs(v).max()) == 0.0 for v in flat_got)
+    else:
+        assert all(float(jnp.abs(v).sum()) > 0 for v in flat_want)
+    if case == "a_weight_of_zero":
+        assert float(jnp.abs(g_want["tw"][0, 0])) > 1e-3
+        assert float(jnp.abs(g_want["tw"][7, 2])) > 1e-3
+
+
 # ------------------------------------------ the ungated squared-ReLU form
 # the Nemotron-H rehearsal's expert layer: 16 experts of width 32, 3 a
 # token, a shared one of 64; 16 shares of ONE expert each add up below
@@ -221,19 +342,17 @@ def _relu2_weights(seed):
 
 
 def _relu2_dense(w, held, offset, shared=True):
-    """relu(x W_up^T)^2 W_down^T, a dense loop over the experts held, each
-    over all tokens times the weight the router gave it (0 if not chosen)."""
+    """relu(x W_up^T)^2 W_down^T, the dense loop over the experts held
+    (`_dense`) behind the router, beside the shared expert if asked."""
     with jax.default_matmul_precision("highest"):
         tw, ti = moe.route(w["x"] @ w["router_weight"].T, 3, 2.5)
-        out = 0.0
+        cut = slice(offset, offset + held)
+        out = _dense(w["x"], tw, ti, (w["experts_up_weight"][cut],),
+                     w["experts_down_weight"][cut], offset, moe._relu2)
         if shared:
-            out = jnp.square(jax.nn.relu(
+            out = out + jnp.square(jax.nn.relu(
                 w["x"] @ w["shared_ff_up_weight"].T)) @ \
                 w["shared_ff_down_weight"].T
-        for e in range(offset, offset + held):
-            mine = jnp.sum(jnp.where(ti == e, tw, 0.0), axis=-1)
-            h = jnp.square(jax.nn.relu(w["x"] @ w["experts_up_weight"][e].T))
-            out = out + mine[:, None] * (h @ w["experts_down_weight"][e].T)
     return out
 
 
@@ -296,15 +415,16 @@ def test_the_sixteen_relu2_shares_add_up_to_the_uncut_reference_layer():
                 w["shared_ff_down_weight"].T
         return once + sum(_relu2_share(w, 1, off)[0] for off in range(16))
 
-    np.testing.assert_allclose(np.asarray(shares(w)), np.asarray(whole(w)),
-                               rtol=2e-5, atol=2e-5)
+    np.testing.assert_allclose(np.asarray(jax.jit(shares)(w)),
+                               np.asarray(whole(w)), rtol=2e-5, atol=2e-5)
     np.testing.assert_allclose(np.asarray(whole(w)),
                                np.asarray(_relu2_dense(w, 16, 0)),
                                rtol=2e-5, atol=2e-5)
-    pairs = sum(int(_relu2_share(w, 1, off)[1][0]) for off in range(16))
+    pairs = sum(int(v) for v in jax.jit(lambda w: [
+        _relu2_share(w, 1, off)[1][0] for off in range(16)])(w))
     assert pairs == TOKENS * 3          # every pair lands on one share
-    g_want = jax.grad(lambda w: jnp.sum(whole(w) * cot))(w)
-    g_got = jax.grad(lambda w: jnp.sum(shares(w) * cot))(w)
+    g_want = jax.jit(jax.grad(lambda w: jnp.sum(whole(w) * cot)))(w)
+    g_got = jax.jit(jax.grad(lambda w: jnp.sum(shares(w) * cot)))(w)
     for name in w:
         np.testing.assert_allclose(np.asarray(g_got[name]),
                                    np.asarray(g_want[name]),
@@ -349,55 +469,67 @@ def test_the_relu2_operator_takes_two_stacked_leaves():
         experts(activation="gelu").infer_shape(data=(2, 48, 64))
 
 
-def test_relu2_experts_on_tiles_of_their_own_and_at_a_padded_width():
-    """The relu2 plan gives every held expert rows of its own in whole
-    tiles, and a width over one tile is padded with zeros to whole tiles:
-    neither moves the result or a gradient, nothing is dropped, and the
-    padding takes no gradient."""
-    ks = jax.random.split(jax.random.PRNGKey(31), 5)
+@pytest.mark.parametrize("form", list(FORMS))
+def test_experts_on_tiles_of_their_own_and_at_a_padded_width(form):
+    """The one plan, for either form, gives every held expert rows of its
+    own in whole tiles, and a width over one tile is padded with zeros to
+    whole tiles: neither moves the result or a gradient, nothing is dropped,
+    and the padding takes no gradient."""
+    act, n_ups = FORMS[form]
+    ks = jax.random.split(jax.random.PRNGKey(31), 6)
     n_tok, d, f = 160, 32, 576          # 576 -> 1024 columns
     w = {"x": jax.random.normal(ks[0], (n_tok, d)),
          "router_weight": jax.random.normal(ks[1], (16, d)) * 0.5,
-         "experts_up_weight": jax.random.normal(ks[2], (16, f, d)) * 0.2,
-         "experts_down_weight": jax.random.normal(ks[3], (16, d, f)) * 0.2}
-    cot = jax.random.normal(ks[4], (n_tok, d))
-    assert moe._widened(w["experts_up_weight"][:4],
-                        w["experts_down_weight"][:4])[0].shape == (4, 1024, d)
+         "ups": tuple(jax.random.normal(ks[2 + i], (16, f, d)) * 0.2
+                      for i in range(n_ups)),
+         "experts_down_weight": jax.random.normal(ks[4], (16, d, f)) * 0.2}
+    cot = jax.random.normal(ks[5], (n_tok, d))
+    *wide, wide_d = moe._widened(tuple(u[:4] for u in w["ups"])
+                                 + (w["experts_down_weight"][:4],))
+    assert [u.shape for u in wide] == [(4, 1024, d)] * n_ups
+    assert wide_d.shape == (4, d, 1024)
+    # a width of whole tiles (Laguna's 1024), or under one, is left alone
+    for width in (1024, 32):
+        leaves = (jnp.zeros((4, width, d)),) * n_ups + (
+            jnp.zeros((4, d, width)),)
+        assert moe._widened(leaves) is leaves
 
     def share(w):
         with jax.default_matmul_precision("highest"):
             tw, ti = moe.route(w["x"] @ w["router_weight"].T, 3, 2.5)
-            return moe.moe_experts(w["x"], tw, ti, None,
-                                   w["experts_up_weight"][4:8],
+            ups = tuple(u[4:8] for u in w["ups"])
+            return moe.moe_experts(w["x"], tw, ti,
+                                   ups[0] if n_ups == 2 else None, ups[-1],
                                    w["experts_down_weight"][4:8], 16, 4)
 
     def dense(w):
         with jax.default_matmul_precision("highest"):
             tw, ti = moe.route(w["x"] @ w["router_weight"].T, 3, 2.5)
-            out = 0.0
-            for e in range(4, 8):
-                mine = jnp.sum(jnp.where(ti == e, tw, 0.0), axis=-1)
-                h = jnp.square(jax.nn.relu(
-                    w["x"] @ w["experts_up_weight"][e].T))
-                out = out + mine[:, None] * (h @ w["experts_down_weight"][e].T)
-        return out
+            return _dense(w["x"], tw, ti, tuple(u[4:8] for u in w["ups"]),
+                          w["experts_down_weight"][4:8], 4, act)
 
-    got, loads = share(w)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(dense(w)),
+    (_, (got, loads)), g_got = jax.jit(jax.value_and_grad(
+        lambda w: (jnp.sum(share(w)[0] * cot), share(w)), has_aux=True))(w)
+    want, g_want = jax.jit(lambda w: (dense(w), jax.grad(
+        lambda w: jnp.sum(dense(w) * cot))(w)))(w)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=2e-4, atol=2e-4)
-    g_got = jax.grad(lambda w: jnp.sum(share(w)[0] * cot))(w)
-    g_want = jax.grad(lambda w: jnp.sum(dense(w) * cot))(w)
-    for name in w:
-        np.testing.assert_allclose(np.asarray(g_got[name]),
-                                   np.asarray(g_want[name]),
-                                   rtol=5e-4, atol=5e-4, err_msg=name)
+    for got_leaf, want_leaf in zip(jax.tree_util.tree_leaves(g_got),
+                                   jax.tree_util.tree_leaves(g_want)):
+        assert float(jnp.abs(want_leaf).sum()) > 0
+        np.testing.assert_allclose(np.asarray(got_leaf),
+                                   np.asarray(want_leaf),
+                                   rtol=5e-4, atol=5e-4)
     # the plan: every expert's rows start at a multiple of the tile, every
-    # pair routed here has one row, no row holds a pair routed elsewhere
+    # pair routed here has one row, no row holds a pair routed elsewhere,
+    # none past the last expert's tiles holds any
     _, ti = moe.route(w["x"] @ w["router_weight"].T, 3, 2.5)
-    slots, ends, planned = moe._plan_tiled(ti, 4, 4, 128)
-    slots, ends = np.asarray(slots), np.asarray(ends)
+    order, ends, planned = moe._plan_tiled(ti, 4, 4, 128)
+    slots = np.asarray(moe._slots(order, ends, planned,
+                                  jnp.arange(n_tok * 3 + 4 * 128)))
+    ends = np.asarray(ends)
     assert planned.tolist() == np.asarray(loads).tolist()
-    assert (ends % 128 == 0).all() and slots.shape == (n_tok * 3 + 4 * 128,)
+    assert (ends % 128 == 0).all() and ends[-1] <= slots.shape[0]
     held = slots[slots < n_tok * 3]
     assert sorted(held) == sorted(np.flatnonzero(
         (np.asarray(ti).reshape(-1) >= 4) & (np.asarray(ti).reshape(-1) < 8)))
@@ -408,3 +540,4 @@ def test_relu2_experts_on_tiles_of_their_own_and_at_a_padded_width():
         assert (np.asarray(ti).reshape(-1)[rows] == 4 + e).all()
         assert len(rows) == planned[e]
         start = end
+    assert (slots[start:] == n_tok * 3).all()
