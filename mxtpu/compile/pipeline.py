@@ -218,7 +218,7 @@ def program_name(fn):
 
 
 def record_program_build(kind, owner, fn, precision=None, transforms=None,
-                         cert=None):
+                         cert=None, scopes=None):
     """Public build-seam entry for program tables outside the Executor
     (the fused train step, metric accumulators): bump the build
     counters, notify the listeners, and wrap ``fn`` for first-call
@@ -227,10 +227,11 @@ def record_program_build(kind, owner, fn, precision=None, transforms=None,
     process reports through one seam. ``precision``/``transforms``/
     ``cert`` tag the program's cost record (``program_table``'s
     prec/xforms/cert columns) when the compile pipeline rewrote the
-    graph."""
+    graph; ``scopes`` is ``instrument_program``'s."""
     notify_build(kind, owner)
     return instrument_program(kind, fn, owner=owner, precision=precision,
-                              transforms=transforms, cert=cert)
+                              transforms=transforms, cert=cert,
+                              scopes=scopes)
 
 
 _AOT_MISS = object()     # sentinel: "the AOT capture path produced nothing"
@@ -240,7 +241,7 @@ _DEMOTE_MISS_TOTAL = 64  # lifetime misses → demote even if hits interleave
 
 def instrument_program(kind, fn, owner=None, matmul_env=False,
                        precision=None, transforms=None, calib_heads=None,
-                       cert=None):
+                       cert=None, scopes=None):
     """Wrap a freshly built jit program with the build-seam diagnostics.
 
     First invocation — the one that pays tracing + XLA compilation —
@@ -266,6 +267,12 @@ def instrument_program(kind, fn, owner=None, matmul_env=False,
     label from the captured argument dtypes. ``transforms`` stamps the
     record with the applied transform-pass names (the per-transform
     ProgramRecord tag — a rejected pass never appears).
+
+    ``scopes`` (``diagnostics.opscopes.symbol_scopes`` of the Symbol the
+    program was traced from) marks a step program: its record keeps the
+    executable's host-side HLO so that ``ProgramRecord.op_scopes()`` can
+    map every instruction back to its graph node. The imperative and
+    initialiser programs pass none and keep nothing.
 
     ``calib_heads`` (int8 calibration capture): names, in order, of the
     OBSERVATION heads the builder appended to the program's primary
@@ -320,6 +327,8 @@ def instrument_program(kind, fn, owner=None, matmul_env=False,
                 _diag.summarize_shardings(state["rec"], args)
                 _diag.summarize_precision(state["rec"], args,
                                           tag=precision)
+                if scopes is not None:
+                    _diag.keep_scopes(state["rec"], exe, scopes)
             except Exception:
                 exe = None
                 state["compiled"] = None

@@ -36,8 +36,9 @@ from . import ledger as ledger_mod  # module alias BEFORE the function
 # that need the module's flag/globals use ledger_mod
 from .ledger import (DeviceMemoryLedger, alloc_origin, current_origin,
                      device_label, ledger, mem_enabled, set_mem_enabled)
-from .programs import (ProgramRecord, cost_enabled, latest_record,
-                       owner_name, program_table, programs, record_program,
+from . import opscopes
+from .programs import (ProgramRecord, cost_enabled, keep_scopes,
+                       latest_record, owner_name, program_table, programs, record_program,
                        set_cost_enabled, summarize_precision,
                        summarize_shardings)
 from .flight import (FlightRecorder, flight_enabled, record, recorder,
@@ -54,7 +55,8 @@ __all__ = [
     "DeviceMemoryLedger", "ledger", "alloc_origin", "current_origin",
     "device_label", "mem_enabled", "set_mem_enabled", "reconcile",
     "ProgramRecord", "programs", "program_table", "record_program",
-    "latest_record", "cost_enabled", "set_cost_enabled",
+    "latest_record", "keep_scopes", "opscopes", "cost_enabled",
+    "set_cost_enabled",
     "summarize_shardings", "summarize_precision",
     "FlightRecorder", "recorder", "record", "flight_enabled",
     "set_flight_enabled",

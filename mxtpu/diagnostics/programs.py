@@ -28,7 +28,7 @@ from .. import telemetry as _tel
 from ..analysis import concurrency as _conc
 
 __all__ = ["ProgramRecord", "record_program", "programs", "program_table",
-           "latest_record", "cost_enabled", "set_cost_enabled", "clear",
+           "latest_record", "keep_scopes", "cost_enabled", "set_cost_enabled", "clear",
            "summarize_shardings", "summarize_precision"]
 
 _ENABLED = os.environ.get("MXTPU_DIAG_COST", "1") != "0"
@@ -70,7 +70,8 @@ class ProgramRecord:
                  "bytes_accessed", "argument_bytes", "output_bytes",
                  "temp_bytes", "generated_code_bytes", "calls",
                  "n_devices", "sharded_args", "replicated_args",
-                 "precision", "transforms", "cert", "_exe")
+                 "precision", "transforms", "cert", "_exe", "_hlo",
+                 "_scopes", "_table")
 
     def __init__(self, kind, owner, compile_ms):
         self.id = next(_ids)
@@ -103,6 +104,12 @@ class ProgramRecord:
         # disarmed, "-" for untransformed programs
         self.cert = "-"
         self._exe = None  # weakref to the compiled executable (HLO source)
+        # the scope table's raw material (``keep_scopes``): the program's
+        # host-side HloModules and what the Symbol says of its nodes, until
+        # ``op_scopes`` has made the table of them
+        self._hlo = None
+        self._scopes = None
+        self._table = None
 
     def hlo_text(self):
         """The compiled program's HLO text, while the executable is still
@@ -115,6 +122,33 @@ class ProgramRecord:
             return exe.as_text()
         except Exception:
             return None
+
+    def op_scopes(self):
+        """``{instruction name: OpScope(node, operator, block, phase,
+        mixed)}`` for every instruction of the compiled program
+        (``diagnostics.opscopes``), or None for a program that was built
+        without scopes. Made on the first request from what the build seam
+        kept on the host; it outlives the executable and holds nothing of
+        the device."""
+        with _LOCK:
+            hlo, scopes = self._hlo, self._scopes
+        if self._table is None and hlo is not None:
+            from . import opscopes
+            table = {}
+            try:
+                for module in hlo:
+                    table.update(opscopes.build_table(module.to_string(),
+                                                      scopes))
+            except Exception:
+                # introspection must not take down what it describes: a
+                # module this reader cannot make a table of has none
+                import logging
+                logging.getLogger("mxtpu.diagnostics").warning(
+                    "no scope table for %s", self.name, exc_info=True)
+                table = None
+            with _LOCK:
+                self._table, self._hlo = table, None
+        return self._table
 
     def to_dict(self):
         return {
@@ -266,6 +300,27 @@ def record_program(kind, owner, compiled, compile_ms, transforms=None,
     return rec
 
 
+#: records that still hold their raw HLO, oldest first: a process that
+#: rebinds for ever keeps the newest few programs' modules, not all 1024
+MAX_RAW_HLO = 8
+_RAW = deque()
+
+
+def keep_scopes(rec, compiled, scopes):
+    """Keep, with ``rec``, what ``op_scopes`` makes its table of: the
+    executable's host-side HloModules (copies: they pin no device program)
+    and ``scopes`` (``opscopes.symbol_scopes``). Never raises."""
+    try:
+        hlo = list(compiled.runtime_executable().hlo_modules())
+    except Exception:
+        return
+    with _LOCK:
+        rec._hlo, rec._scopes = hlo, scopes
+        _RAW.append(rec)
+        while len(_RAW) > MAX_RAW_HLO:
+            _RAW.popleft()._hlo = None
+
+
 def programs(kind=None):
     """Snapshot of captured records (list of dicts, oldest first)."""
     with _LOCK:
@@ -273,13 +328,16 @@ def programs(kind=None):
     return [r.to_dict() for r in recs if kind is None or r.kind == kind]
 
 
-def latest_record(kind=None):
-    """The most recent live ProgramRecord (optionally of one kind) —
-    tooling reads its captured numbers and ``hlo_text()`` instead of
-    re-lowering the program (tools/hlo_analyze.py)."""
+def latest_record(kind=None, name=None):
+    """The most recent live ProgramRecord (optionally of one kind, or of
+    one XLA module name, ``jit_mxtpu_fused_step``: what a trace calls the
+    program) — tooling reads its captured numbers, ``hlo_text()`` and
+    ``op_scopes()`` instead of re-lowering the program
+    (tools/hlo_analyze.py)."""
     with _LOCK:
         for r in reversed(_RECORDS):
-            if kind is None or r.kind == kind:
+            if (kind is None or r.kind == kind) \
+                    and (name is None or r.name == name):
                 return r
     return None
 
@@ -315,3 +373,4 @@ def clear():
     """Drop captured records (tests)."""
     with _LOCK:
         _RECORDS.clear()
+        _RAW.clear()

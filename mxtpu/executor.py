@@ -149,6 +149,7 @@ def _trace_graph(symbol, is_train, placements=None, remat_tags=None,
     Without a filter the return stays the historical 2-tuple."""
     topo = symbol._topo()
     node_index = {id(n): i for i, n in enumerate(topo)}
+    scopes = _diag.opscopes.node_scopes(topo)
     aux_nodes = symbol._aux_node_set()
     out_entries = [(id(n), i) for n, i in symbol._outputs]
     tap_prog = None
@@ -178,8 +179,10 @@ def _trace_graph(symbol, is_train, placements=None, remat_tags=None,
             # named_scope stamps the layer name into HLO op metadata, so
             # XLA/xprof traces attribute device time per layer — the
             # TPU-native form of the engine's per-op OprExecStat stamps
-            # (src/engine/threaded_engine.h:314-325)
-            with jax.named_scope(node.name or node.op.name):
+            # (src/engine/threaded_engine.h:314-325). The scope is unique
+            # to the node (a nameless or namesake node's carries its place
+            # in the order): ``ProgramRecord.op_scopes`` reads it back
+            with jax.named_scope(scopes[id(node)]):
                 outs = node.op.trace(attrs, ins, rng=key)
             if placements:
                 grp = node._extra_attrs.get("__ctx_group__")
@@ -548,7 +551,8 @@ class Executor:
                                  precision=self._precision_tag(),
                                  transforms=self._transform_tags(),
                                  calib_heads=calib_heads,
-                                 cert=self._cert_tag())
+                                 cert=self._cert_tag(),
+                                 scopes=_diag.opscopes.symbol_scopes(symbol))
         self._fns[kind] = fn
         return fn
 

@@ -93,11 +93,34 @@ take no gradient and which ``fit`` fetches with the metric.
 
 Layout discipline as transformer.py's: tokens (B, T) -> (B, T, D); both
 mixers in (B, H, T, dh); every matmul a FullyConnected(flatten=False).
+
+Every node carries the model block it belongs to as a ``__block__``
+attribute (``mx.AttrScope(block=...)``): ``embed``, ``attention``,
+``delta_rule``, ``mamba2``, ``ffn`` (the dense FFNs and an expert layer's
+shared expert), ``experts`` (router, top-k, dispatch, products, combine) and
+``head`` (final norm, head projection, loss); a sublayer's norm and residual
+add belong to the block they feed. No operator reads it: a profiler's device
+table and the benchmark's per-block shares do (docs/observability.md,
+"Device time by graph node").
 """
+import functools
+
 from .. import symbol as sym
+from ..attribute import AttrScope
 
 FULL, LINEAR = "full_attention", "linear_attention"
 SLIDING, MAMBA2, NONE = "sliding_attention", "mamba2", "none"
+
+
+def _block(block):
+    """The builder's nodes (and the variables it makes) are of `block`."""
+    def wrap(build):
+        @functools.wraps(build)
+        def scoped(*args, **kwargs):
+            with AttrScope(block=block):
+                return build(*args, **kwargs)
+        return scoped
+    return wrap
 
 
 def _fc(x, num_hidden, name, no_bias=False, flatten=False):
@@ -115,6 +138,7 @@ def _heads(x, seq_len, num_heads, dh):
     return sym.transpose(x, axes=(0, 2, 1, 3))
 
 
+@_block("attention")
 def attention_mix(x, seq_len, num_heads, d_model, prefix, no_bias=False,
                   qk_norm_eps=None):
     """Proj(Attn(x)): causal softmax attention over `num_heads` heads of
@@ -137,6 +161,7 @@ def attention_mix(x, seq_len, num_heads, d_model, prefix, no_bias=False,
     return _fc(att, d_model, "%s_proj" % prefix, no_bias)
 
 
+@_block("attention")
 def grouped_attention_mix(x, seq_len, num_heads, num_kv_heads, head_dim,
                           d_model, prefix, window=0, rope=None, gate=None,
                           norm_eps=1e-6, qk_norm=True):
@@ -178,6 +203,7 @@ def grouped_attention_mix(x, seq_len, num_heads, num_kv_heads, head_dim,
     return _fc(att, d_model, "%s_proj" % prefix, True)
 
 
+@_block("delta_rule")
 def delta_rule_mix(x, seq_len, num_heads, d_model, prefix, key_dim, value_dim,
                    conv_kernel=4, neg_eigval=True, norm_eps=1e-6):
     """The Gated DeltaNet mixer (arXiv:2412.06464): q, k, v through a causal
@@ -225,6 +251,7 @@ def delta_rule_mix(x, seq_len, num_heads, d_model, prefix, key_dim, value_dim,
     return _fc(o, d_model, "%s_proj" % prefix, True)
 
 
+@_block("mamba2")
 def mamba2_mix(x, seq_len, d_model, prefix, num_heads, head_dim, n_groups,
                state_size, conv_kernel=4, chunk=128, norm_eps=1e-5):
     """The Mamba-2 mixer (arXiv:2405.21060): [z, xBC, dt] = x W_in; xBC
@@ -266,12 +293,14 @@ def mamba2_mix(x, seq_len, d_model, prefix, num_heads, head_dim, n_groups,
     return _fc(y, d_model, "%s_proj" % prefix, True)
 
 
+@_block("ffn")
 def relu_ffn(x, d_model, d_ff, prefix):
     f = _fc(x, d_ff, "%s_ff1" % prefix)
     f = sym.Activation(f, act_type="relu")
     return _fc(f, d_model, "%s_ff2" % prefix)
 
 
+@_block("ffn")
 def silu_gated_ffn(x, d_model, d_ff, prefix):
     gate = _fc(x, d_ff, "%s_ff_gate" % prefix, True)
     up = _fc(x, d_ff, "%s_ff_up" % prefix, True)
@@ -279,6 +308,7 @@ def silu_gated_ffn(x, d_model, d_ff, prefix):
     return _fc(f, d_model, "%s_ff_down" % prefix, True)
 
 
+@_block("ffn")
 def relu2_ffn(x, d_model, d_ff, prefix):
     up = _fc(x, d_ff, "%s_ff_up" % prefix, True)
     f = sym.square(sym.Activation(up, act_type="relu"))
@@ -289,6 +319,7 @@ _DENSE_FFN = {"relu": relu_ffn, "relu2": relu2_ffn,
               "silu_gated": silu_gated_ffn}
 
 
+@_block("experts")
 def moe_ffn(x, d_model, prefix, num_experts, top_k, experts_held, hidden,
             shared_hidden=0, expert_offset=0, scale=1.0,
             score_func="sigmoid", norm_topk=True, activation="silu_gated"):
@@ -316,18 +347,24 @@ def moe_ffn(x, d_model, prefix, num_experts, top_k, experts_held, hidden,
     return y, routed[1]
 
 
-def _sublayer(h, fn, norm, name, eps, dropout):
+def _sublayer(h, fn, norm, name, eps, dropout, block):
     """h + fn(LN(h)) (`layer_pre`), h + fn(RMSNorm(h)) (`rms_pre`) or
-    h + RMSNorm(fn(h)) (`rms_post`)."""
-    if norm == "layer_pre":
-        y = fn(sym.LayerNorm(h, name=name))
-    elif norm == "rms_pre":
-        y = fn(sym.RMSNorm(h, eps=eps, name=name))
-    else:
-        y = sym.RMSNorm(fn(h), eps=eps, name=name)
-    if dropout > 0:
-        y = sym.Dropout(y, p=dropout)
-    return h + y
+    h + RMSNorm(fn(h)) (`rms_post`); the norm and the add are of `block`,
+    the one `fn` builds."""
+    with AttrScope(block=block):
+        if norm == "layer_pre":
+            y = fn(sym.LayerNorm(h, name=name))
+        elif norm == "rms_pre":
+            y = fn(sym.RMSNorm(h, eps=eps, name=name))
+        else:
+            y = sym.RMSNorm(fn(h), eps=eps, name=name)
+        if dropout > 0:
+            y = sym.Dropout(y, p=dropout)
+        return h + y
+
+
+_MIXER_BLOCK = {FULL: "attention", SLIDING: "attention",
+                LINEAR: "delta_rule", MAMBA2: "mamba2"}
 
 
 def build(vocab_size, seq_len, layer_types, num_heads, d_model, d_ff,
@@ -365,19 +402,20 @@ def build(vocab_size, seq_len, layer_types, num_heads, d_model, d_ff,
         assert d_model % num_heads == 0, "d_model must divide into heads"
     pre = norm == "layer_pre"
     data = sym.Variable("data")
-    h = sym.Embedding(data, input_dim=vocab_size, output_dim=d_model,
-                      name="tok_emb")
-    if dtype is not None:
-        h = sym.Cast(h, dtype=dtype)
-    if positions == "learned":
-        max_len = max_len or seq_len
-        assert max_len >= seq_len, "max_len must cover seq_len"
-        pos = sym.Variable("pos_emb", shape=(1, max_len, d_model))
+    with AttrScope(block="embed"):
+        h = sym.Embedding(data, input_dim=vocab_size, output_dim=d_model,
+                          name="tok_emb")
         if dtype is not None:
-            pos = sym.Cast(pos, dtype=dtype)
-        if max_len != seq_len:
-            pos = sym.slice_axis(pos, axis=1, begin=0, end=seq_len)
-        h = sym.broadcast_add(h, pos)
+            h = sym.Cast(h, dtype=dtype)
+        if positions == "learned":
+            max_len = max_len or seq_len
+            assert max_len >= seq_len, "max_len must cover seq_len"
+            pos = sym.Variable("pos_emb", shape=(1, max_len, d_model))
+            if dtype is not None:
+                pos = sym.Cast(pos, dtype=dtype)
+            if max_len != seq_len:
+                pos = sym.slice_axis(pos, axis=1, begin=0, end=seq_len)
+            h = sym.broadcast_add(h, pos)
     lin = dict(linear or {})
     lin_heads = lin.pop("num_heads", num_heads)
     loads = []
@@ -409,7 +447,7 @@ def build(vocab_size, seq_len, layer_types, num_heads, d_model, d_ff,
             raise ValueError("layer %d: unknown layer type %r" % (i, kind))
         if kind != NONE:
             h = _sublayer(h, mix, norm, p + ("_ln1" if pre else "_mix_norm"),
-                          norm_eps, dropout)
+                          norm_eps, dropout, _MIXER_BLOCK[kind])
         if ffn_of[i] == NONE:
             continue
         if ffn_of[i] == "moe":
@@ -421,16 +459,18 @@ def build(vocab_size, seq_len, layer_types, num_heads, d_model, d_ff,
             def feed(x, p=p, ffn=_DENSE_FFN[ffn_of[i]]):
                 return ffn(x, d_model, d_ff, p)
         h = _sublayer(h, feed, norm, p + ("_ln2" if pre else "_ffn_norm"),
-                      norm_eps, dropout)
-    if pre:
-        h = sym.LayerNorm(h, name="ln_f")
-    else:
-        h = sym.RMSNorm(h, eps=norm_eps, name="norm_f")
-    h = sym.reshape(h, shape=(-1, d_model))
-    logits = _fc(h, vocab_size, "lm_head", no_bias=not pre, flatten=True)
-    if dtype is not None:
-        logits = sym.Cast(logits, dtype="float32")
-    out = sym.SoftmaxOutput(logits, name="softmax")
+                      norm_eps, dropout,
+                      "experts" if ffn_of[i] == "moe" else "ffn")
+    with AttrScope(block="head"):
+        if pre:
+            h = sym.LayerNorm(h, name="ln_f")
+        else:
+            h = sym.RMSNorm(h, eps=norm_eps, name="norm_f")
+        h = sym.reshape(h, shape=(-1, d_model))
+        logits = _fc(h, vocab_size, "lm_head", no_bias=not pre, flatten=True)
+        if dtype is not None:
+            logits = sym.Cast(logits, dtype="float32")
+        out = sym.SoftmaxOutput(logits, name="softmax")
     return sym.Group([out] + loads) if loads else out
 
 

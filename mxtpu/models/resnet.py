@@ -1,8 +1,12 @@
 """ResNet symbol (parity: example/image-classification/symbols/resnet.py —
 the train_imagenet.py ResNet-50 of baseline config 2; pre-activation v2 style
 residual units). TPU notes: all convs are NCHW, BN stats in f32, residual adds
-fuse into the conv epilogues under XLA."""
+fuse into the conv epilogues under XLA. Every node carries its block
+(``stem``, ``stage1``..``stage4``, ``head``) as a ``__block__`` attribute
+(``mx.AttrScope``), which a profiler's device table reads and no operator
+does."""
 from .. import symbol as sym
+from ..attribute import AttrScope
 
 
 def residual_unit(data, num_filter, stride, dim_match, name,
@@ -58,36 +62,39 @@ def resnet(units, num_stages, filter_list, num_classes, image_shape,
            bottle_neck=True, bn_mom=0.9):
     data = sym.Variable("data")
     (nchannel, height, width) = image_shape
-    if height <= 32:
-        body = sym.Convolution(data, num_filter=filter_list[0], kernel=(3, 3),
-                               stride=(1, 1), pad=(1, 1), no_bias=True,
-                               name="conv0")
-    else:
-        body = sym.Convolution(data, num_filter=filter_list[0], kernel=(7, 7),
-                               stride=(2, 2), pad=(3, 3), no_bias=True,
-                               name="conv0")
-        body = sym.BatchNorm(body, fix_gamma=False, eps=2e-5, momentum=bn_mom,
-                             name="bn0")
-        body = sym.Activation(body, act_type="relu", name="relu0")
-        body = sym.Pooling(body, kernel=(3, 3), stride=(2, 2), pad=(1, 1),
-                           pool_type="max")
+    with AttrScope(block="stem"):
+        if height <= 32:
+            body = sym.Convolution(data, num_filter=filter_list[0],
+                                   kernel=(3, 3), stride=(1, 1), pad=(1, 1),
+                                   no_bias=True, name="conv0")
+        else:
+            body = sym.Convolution(data, num_filter=filter_list[0],
+                                   kernel=(7, 7), stride=(2, 2), pad=(3, 3),
+                                   no_bias=True, name="conv0")
+            body = sym.BatchNorm(body, fix_gamma=False, eps=2e-5,
+                                 momentum=bn_mom, name="bn0")
+            body = sym.Activation(body, act_type="relu", name="relu0")
+            body = sym.Pooling(body, kernel=(3, 3), stride=(2, 2), pad=(1, 1),
+                               pool_type="max")
     for i in range(num_stages):
-        body = residual_unit(body, filter_list[i + 1],
-                             (1 if i == 0 else 2, 1 if i == 0 else 2),
-                             False, name="stage%d_unit%d" % (i + 1, 1),
-                             bottle_neck=bottle_neck, bn_mom=bn_mom)
-        for j in range(units[i] - 1):
-            body = residual_unit(body, filter_list[i + 1], (1, 1), True,
-                                 name="stage%d_unit%d" % (i + 1, j + 2),
+        with AttrScope(block="stage%d" % (i + 1)):
+            body = residual_unit(body, filter_list[i + 1],
+                                 (1 if i == 0 else 2, 1 if i == 0 else 2),
+                                 False, name="stage%d_unit%d" % (i + 1, 1),
                                  bottle_neck=bottle_neck, bn_mom=bn_mom)
-    bn1 = sym.BatchNorm(body, fix_gamma=False, eps=2e-5, momentum=bn_mom,
-                        name="bn1")
-    relu1 = sym.Activation(bn1, act_type="relu", name="relu1")
-    pool1 = sym.Pooling(relu1, global_pool=True, kernel=(7, 7),
-                        pool_type="avg", name="pool1")
-    flat = sym.Flatten(pool1)
-    fc1 = sym.FullyConnected(flat, num_hidden=num_classes, name="fc1")
-    return sym.SoftmaxOutput(fc1, name="softmax")
+            for j in range(units[i] - 1):
+                body = residual_unit(body, filter_list[i + 1], (1, 1), True,
+                                     name="stage%d_unit%d" % (i + 1, j + 2),
+                                     bottle_neck=bottle_neck, bn_mom=bn_mom)
+    with AttrScope(block="head"):
+        bn1 = sym.BatchNorm(body, fix_gamma=False, eps=2e-5, momentum=bn_mom,
+                            name="bn1")
+        relu1 = sym.Activation(bn1, act_type="relu", name="relu1")
+        pool1 = sym.Pooling(relu1, global_pool=True, kernel=(7, 7),
+                            pool_type="avg", name="pool1")
+        flat = sym.Flatten(pool1)
+        fc1 = sym.FullyConnected(flat, num_hidden=num_classes, name="fc1")
+        return sym.SoftmaxOutput(fc1, name="softmax")
 
 
 def get_symbol(num_classes=1000, num_layers=50, image_shape=(3, 224, 224),

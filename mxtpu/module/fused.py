@@ -32,6 +32,7 @@ from .. import diagnostics as _diag
 from .. import random as _rnd
 from ..base import NumericsError
 from ..compile import pipeline as _pipeline
+from ..diagnostics import opscopes as _opscopes
 from ..executor import _trace_graph, head_cotangent
 from ..ops import optimizer_ops as _ops
 
@@ -668,8 +669,9 @@ class FusedTrainStep:
                                                   has_aux=True)
             else:
                 (outs, auxu), vjp = jax.vjp(f, train_p)
-            cts = ([head_cotangent(o) for o in outs],
-                   {k: jnp.zeros_like(v) for k, v in auxu.items()})
+            with jax.named_scope(_opscopes.HEAD_GRAD):
+                cts = ([head_cotangent(o) for o in outs],
+                       {k: jnp.zeros_like(v) for k, v in auxu.items()})
             (grads,) = vjp(cts)
             new_params = dict(fixed)
             new_opt = {}
@@ -678,31 +680,37 @@ class FusedTrainStep:
             # over stacked members — per-parameter lr/wd enter as a
             # leading-axis column, so the arithmetic is identical to
             # the per-parameter chains below, element for element
+            # every update runs under ``mxtpu.update/<parameter>`` (a
+            # batched region under its first member's name), so a trace's
+            # operations are told from the graph's (diagnostics.opscopes)
             for names in update_groups:
-                p_stk = jnp.stack([params[n] for n in names])
-                g_stk = jnp.stack([grads[n] for n in names])
-                s_stk = jax.tree.map(lambda *ls: jnp.stack(ls),
-                                     *[opt_state[n] for n in names])
-                col = (len(names),) + (1,) * (p_stk.ndim - 1)
-                lr_col = jnp.reshape(
-                    jnp.stack([lrs[tindex[n]] for n in names]), col)
-                wd_col = jnp.reshape(
-                    jnp.stack([wds[tindex[n]] for n in names]), col)
-                p2, s2 = apply_update(p_stk, g_stk, s_stk, lr_col, wd_col)
-                for j, n in enumerate(names):
-                    new_params[n] = p2[j].astype(params[n].dtype)
-                    new_opt[n] = jax.tree.map(lambda t, _j=j: t[_j], s2)
+                with jax.named_scope("%s/%s" % (_opscopes.UPDATE, names[0])):
+                    p_stk = jnp.stack([params[n] for n in names])
+                    g_stk = jnp.stack([grads[n] for n in names])
+                    s_stk = jax.tree.map(lambda *ls: jnp.stack(ls),
+                                         *[opt_state[n] for n in names])
+                    col = (len(names),) + (1,) * (p_stk.ndim - 1)
+                    lr_col = jnp.reshape(
+                        jnp.stack([lrs[tindex[n]] for n in names]), col)
+                    wd_col = jnp.reshape(
+                        jnp.stack([wds[tindex[n]] for n in names]), col)
+                    p2, s2 = apply_update(p_stk, g_stk, s_stk, lr_col,
+                                          wd_col)
+                    for j, n in enumerate(names):
+                        new_params[n] = p2[j].astype(params[n].dtype)
+                        new_opt[n] = jax.tree.map(lambda t, _j=j: t[_j], s2)
             for i, n in enumerate(trainable):
                 if n in grouped_names:
                     continue
-                g = grads[n]
-                if grad_shardings is not None and n in grad_shardings:
-                    g = jax.lax.with_sharding_constraint(g,
-                                                         grad_shardings[n])
-                p2, s2 = apply_update(params[n], g, opt_state[n],
-                                      lrs[i], wds[i])
-                new_params[n] = p2.astype(params[n].dtype)
-                new_opt[n] = s2
+                with jax.named_scope("%s/%s" % (_opscopes.UPDATE, n)):
+                    g = grads[n]
+                    if grad_shardings is not None and n in grad_shardings:
+                        g = jax.lax.with_sharding_constraint(
+                            g, grad_shardings[n])
+                    p2, s2 = apply_update(params[n], g, opt_state[n],
+                                          lrs[i], wds[i])
+                    new_params[n] = p2.astype(params[n].dtype)
+                    new_opt[n] = s2
             new_aux = dict(aux)
             new_aux.update(auxu)
             if not health_classes:
@@ -714,30 +722,31 @@ class FusedTrainStep:
             # nonfinite count covers grads AND the fresh weights, so
             # an LR bomb is visible at the cadence of the step that
             # fired it, before the next step consumes the wreckage.
-            f32 = jnp.float32
-            sum_rows, max_rows = [], []
-            for _label, names in health_classes:
-                g2 = w2 = u2 = nf = None
-                gm = None
-                for n in names:
-                    g = grads[n].astype(f32)
-                    p_new = new_params[n].astype(f32)
-                    d = p_new - params[n].astype(f32)
-                    bad = (jnp.sum(~jnp.isfinite(g))
-                           + jnp.sum(~jnp.isfinite(p_new))).astype(f32)
-                    parts = (jnp.sum(g * g), jnp.sum(p_new * p_new),
-                             jnp.sum(d * d), bad)
-                    if g2 is None:
-                        g2, w2, u2, nf = parts
-                        gm = jnp.max(jnp.abs(g))
-                    else:
-                        g2, w2, u2, nf = (g2 + parts[0], w2 + parts[1],
-                                          u2 + parts[2], nf + parts[3])
-                        gm = jnp.maximum(gm, jnp.max(jnp.abs(g)))
-                sum_rows.append(jnp.stack([g2, w2, u2, nf]))
-                max_rows.append(gm)
-            hstats = {"sums": jnp.stack(sum_rows),
-                      "max": jnp.stack(max_rows)}
+            with jax.named_scope(_opscopes.HEALTH):
+                f32 = jnp.float32
+                sum_rows, max_rows = [], []
+                for _label, names in health_classes:
+                    g2 = w2 = u2 = nf = None
+                    gm = None
+                    for n in names:
+                        g = grads[n].astype(f32)
+                        p_new = new_params[n].astype(f32)
+                        d = p_new - params[n].astype(f32)
+                        bad = (jnp.sum(~jnp.isfinite(g))
+                               + jnp.sum(~jnp.isfinite(p_new))).astype(f32)
+                        parts = (jnp.sum(g * g), jnp.sum(p_new * p_new),
+                                 jnp.sum(d * d), bad)
+                        if g2 is None:
+                            g2, w2, u2, nf = parts
+                            gm = jnp.max(jnp.abs(g))
+                        else:
+                            g2, w2, u2, nf = (g2 + parts[0], w2 + parts[1],
+                                              u2 + parts[2], nf + parts[3])
+                            gm = jnp.maximum(gm, jnp.max(jnp.abs(g)))
+                    sum_rows.append(jnp.stack([g2, w2, u2, nf]))
+                    max_rows.append(gm)
+                hstats = {"sums": jnp.stack(sum_rows),
+                          "max": jnp.stack(max_rows)}
             if taps is not None:
                 hstats["taps"] = taps
             return new_params, new_aux, new_opt, outs, hstats
@@ -830,7 +839,8 @@ class FusedTrainStep:
                 "fused_step", self, self._step_fn,
                 precision=rep.precision if rep is not None else None,
                 transforms=rep.transforms if rep is not None else None,
-                cert=rep.cert if rep is not None else None)
+                cert=rep.cert if rep is not None else None,
+                scopes=_opscopes.symbol_scopes(self._graph_symbol))
         try:
             res = self._step_fn(
                 self.params, self.aux, self.opt_state, batch,

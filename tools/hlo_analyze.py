@@ -1,7 +1,9 @@
 #!/usr/bin/env python
 """Analyze the compiled HLO of the fused ResNet-50 train step: per-opcode
 materialized bytes (fusion bodies excluded) and the largest single
-materializations. Compile-only (abstract inputs), so it never allocates on
+materializations, each with the graph node and phase it came from (the
+instructions are read by mxtpu.diagnostics.opscopes, the repository's one
+reader of HLO text). Compile-only (abstract inputs), so it never allocates on
 the device and can run alongside a benchmark.
 
 The cost/memory numbers and the HLO text come from the diagnostics
@@ -23,8 +25,11 @@ import sys
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 
-def shape_bytes(s, _DT={'bf16': 2, 'f32': 4, 's32': 4, 'u32': 4, 'f16': 2,
-                        'pred': 1, 's8': 1, 'u8': 1, 's64': 8, 'f64': 8}):
+_DT = {'bf16': 2, 'f32': 4, 's32': 4, 'u32': 4, 'f16': 2, 'pred': 1, 's8': 1,
+       'u8': 1, 's64': 8, 'f64': 8}
+
+
+def shape_bytes(s):
     tot = 0
     for m in re.finditer(r'(\w+)\[([\d,]*)\]', s):
         dt, dims = m.group(1), m.group(2)
@@ -38,41 +43,41 @@ def shape_bytes(s, _DT={'bf16': 2, 'f32': 4, 's32': 4, 'u32': 4, 'f16': 2,
     return tot
 
 
-def analyze(txt, top=25):
-    """Tally output bytes of materializing ops (outside fusion bodies)."""
+def analyze(txt, top=25, scopes=None):
+    """Tally output bytes of materializing ops (outside fusion bodies and
+    reducers), read through the repository's one reader of HLO text
+    (mxtpu.diagnostics.opscopes); the largest name the graph node they
+    came from."""
+    from mxtpu.diagnostics import opscopes
+    module = opscopes.parse(txt)
+    table = opscopes.build_table(module, scopes)
     stats = collections.Counter()
     counts = collections.Counter()
     biggest = []
-    cur = None
-    for line in txt.splitlines():
-        ls = line.strip()
-        # computation header: `%name (args) -> type {` or `ENTRY ...`
-        if ls.endswith('{') and ('(' in ls) and ('=' not in ls.split('(')[0]):
-            m = re.match(r'(?:ENTRY\s+)?%?([\w.$-]+)', ls)
-            cur = m.group(1) if m else None
+    for comp, ins in module.instructions():
+        if 'fused' in comp or 'region' in comp:
             continue
-        if cur and ('fused' in cur or 'region' in cur):
+        if ins.opcode in ('parameter', 'constant', 'get-tuple-element',
+                          'tuple', 'bitcast'):
             continue
-        m = re.match(r'%?[\w.$-]+ = (\S+?) ([\w-]+)\(', ls)
-        if not m:
-            continue
-        outshape, opk = m.group(1), m.group(2)
-        if opk in ('parameter', 'constant', 'get-tuple-element', 'tuple',
-                   'bitcast'):
-            continue
-        b = shape_bytes(outshape)
-        stats[opk] += b
-        counts[opk] += 1
+        b = shape_bytes(ins.shape)
+        stats[ins.opcode] += b
+        counts[ins.opcode] += 1
         if b > 50e6:
-            biggest.append((b, opk, cur, ls[:140]))
+            biggest.append((b, ins.opcode, comp, ins.name, ins.shape,
+                            table[ins.name]))
     print('total materialized output bytes: %.1f GB' %
           (sum(stats.values()) / 1e9))
     for k, v in stats.most_common(20):
         print('%-22s %8.2f GB  x%d' % (k, v / 1e9, counts[k]))
     biggest.sort(reverse=True)
     print('--- largest materializations ---')
-    for b, opk, comp, l in biggest[:top]:
-        print('%9.0f MB %-12s [%s] %s' % (b / 1e6, opk, comp, l[:120]))
+    print('%12s %-12s %-28s %-9s %s' % ('', 'opcode', 'node', 'phase',
+                                        'instruction'))
+    for b, opk, comp, name, shape, sc in biggest[:top]:
+        print('%9.0f MB %-12s %-28s %-9s [%s] %s = %s' % (
+            b / 1e6, opk, (sc.node or '-')[:28], sc.phase, comp, name,
+            shape[:80]))
 
 
 def table_from_dump(path):
@@ -157,7 +162,9 @@ def main():
           'temp %.1f GB)' % (rec.flops / 1e12, rec.bytes_accessed / 1e9,
                              rec.compile_ms, rec.temp_bytes / 1e9))
     print(diag.program_table('hlo_analyze'))
-    analyze(rec.hlo_text() or c.as_text())
+    from mxtpu.diagnostics import opscopes
+    analyze(rec.hlo_text() or c.as_text(),
+            scopes=opscopes.symbol_scopes(sym))
     return 0
 
 
