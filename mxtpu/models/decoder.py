@@ -207,16 +207,19 @@ def grouped_attention_mix(x, seq_len, num_heads, num_kv_heads, head_dim,
 def delta_rule_mix(x, seq_len, num_heads, d_model, prefix, key_dim, value_dim,
                    conv_kernel=4, neg_eigval=True, norm_eps=1e-6):
     """The Gated DeltaNet mixer (arXiv:2412.06464): q, k, v through a causal
-    short convolution and SiLU, q and k L2-normalised per head, the gated
-    delta rule, a per-head RMSNorm gated by silu(x W_g), then W_o. The decay
-    (A_log, dt_bias, softplus, exp) is float32 whatever x is."""
+    short convolution with SiLU (one ``_contrib_CausalConv1D`` each, the
+    activation its attribute), q and k L2-normalised per head, the gated
+    delta rule, a per-head RMSNorm gated by silu(x W_g) (one gated
+    ``RMSNorm``), then W_o. Both operators bring their own gradients
+    (ops/mixers.py): nothing float32 of a q, k, v or output's size lies
+    between the projections and the kernel. The decay (A_log, dt_bias,
+    softplus, exp) is float32 whatever x is."""
     h = num_heads
 
     def short(tag, dh):
         p = _fc(x, h * dh, "%s_%s" % (prefix, tag), True)
-        p = sym.contrib.CausalConv1D(p, kernel=conv_kernel,
+        p = sym.contrib.CausalConv1D(p, kernel=conv_kernel, act_type="silu",
                                      name="%s_%s_conv" % (prefix, tag))
-        p = sym.Activation(p, act_type="silu")
         p = sym.reshape(p, shape=(-1, seq_len, h, dh))
         if tag != "v":
             p = sym.L2Normalization(p, mode="last", eps=1e-6)
@@ -255,11 +258,14 @@ def delta_rule_mix(x, seq_len, num_heads, d_model, prefix, key_dim, value_dim,
 def mamba2_mix(x, seq_len, d_model, prefix, num_heads, head_dim, n_groups,
                state_size, conv_kernel=4, chunk=128, norm_eps=1e-5):
     """The Mamba-2 mixer (arXiv:2405.21060): [z, xBC, dt] = x W_in; xBC
-    through a causal short convolution with bias and SiLU, split into x
+    through a causal short convolution with bias and SiLU (one
+    ``_contrib_CausalConv1D``, the activation its attribute), split into x
     (H heads of P), B and C (G groups of N); dt = softplus(dt + dt_bias);
     the state-space scan with A = -exp(A_log) and the skip D; y * silu(z)
-    through an RMSNorm over each of the G groups of channels; W_out. dt,
-    A_log, dt_bias and D are float32 whatever x is."""
+    through an RMSNorm over each of the G groups of channels (one gated
+    ``RMSNorm`` with ``gate_first``: z is its gate and multiplies before the
+    mean square); W_out. Both operators bring their own gradients
+    (ops/mixers.py). dt, A_log, dt_bias and D are float32 whatever x is."""
     h, p, g, n = num_heads, head_dim, n_groups, state_size
     inner, conv_dim = h * p, h * p + 2 * g * n
 
@@ -270,8 +276,7 @@ def mamba2_mix(x, seq_len, d_model, prefix, num_heads, head_dim, n_groups,
     z = cut(zxbcdt, 0, inner)
     xbc = sym.contrib.CausalConv1D(cut(zxbcdt, inner, inner + conv_dim),
                                    kernel=conv_kernel, bias=True,
-                                   name="%s_conv" % prefix)
-    xbc = sym.Activation(xbc, act_type="silu")
+                                   act_type="silu", name="%s_conv" % prefix)
     xs = sym.reshape(cut(xbc, 0, inner), shape=(-1, seq_len, h, p))
     bm = sym.reshape(cut(xbc, inner, inner + g * n),
                      shape=(-1, seq_len, g, n))
@@ -288,7 +293,7 @@ def mamba2_mix(x, seq_len, d_model, prefix, num_heads, head_dim, n_groups,
     y = sym.contrib.SSDScan(xs, dt, a_log, bm, cm, skip, chunk=chunk,
                             name="%s_ssd" % prefix)
     y = sym.reshape(y, shape=(-1, seq_len, inner))
-    y = sym.RMSNorm(y * sym.Activation(z, act_type="silu"), eps=norm_eps,
+    y = sym.RMSNorm(y, gate=z, gated=True, gate_first=True, eps=norm_eps,
                     groups=g, name="%s_o_norm" % prefix)
     return _fc(y, d_model, "%s_proj" % prefix, True)
 

@@ -18,6 +18,7 @@ import jax.numpy as jnp
 import numpy as _np
 from jax import lax
 
+from . import mixers
 from .registry import Required, register
 
 # ---------------------------------------------------------------- FullyConnected
@@ -92,27 +93,24 @@ register("Convolution", _convolution,
 
 def _causal_conv1d(a, data, weight, bias=None):
     """Causal depthwise convolution over time, channels last: data
-    (B, T, C), weight (C, K), y[t] = sum_i weight[:, i] * x[t - (K-1) + i],
-    nothing before the row's start; with ``bias`` a third input (C,) is
-    added. The short convolution of the linear-attention and state-space
-    mixers (K = 4): K shifted multiply-adds that XLA fuses into one pass,
-    where a grouped Convolution with one channel a group would go through
-    the convolution emitter. Accumulates in float32."""
-    k = int(a.kernel)
-    t = data.shape[1]
-    x = jnp.pad(data, ((0, 0), (k - 1, 0), (0, 0)))
-    w = weight.astype(jnp.float32)
-    out = sum(x[:, i:i + t, :].astype(jnp.float32) * w[:, i]
-              for i in range(k))
-    if bias is not None:
-        out = out + bias.astype(jnp.float32)
-    return out.astype(data.dtype)
+    (B, T, C), weight (C, K), y[t] = act(sum_i weight[:, i] * x[t - (K-1) + i]
+    + bias), nothing before the row's start; with ``bias`` a third input
+    (C,) is added; ``act_type`` is ``"none"`` or ``"silu"``. The short
+    convolution of the linear-attention and state-space mixers (K = 4): K
+    shifted multiply-adds that XLA fuses into one pass, where a grouped
+    Convolution with one channel a group would go through the convolution
+    emitter. Accumulates in float32, rounds once after the activation. The
+    gradient is the operator's own (mixers.py `short_conv`): from dY and the
+    data alone, one pass, nothing float32 of the data's size kept or
+    written."""
+    return mixers.short_conv(data, weight, bias, a.kernel,
+                             a.get("act_type", "none"))
 
 
 register("_contrib_CausalConv1D", _causal_conv1d,
          arg_names=lambda a: ["data", "weight", "bias"] if a.get("bias")
          else ["data", "weight"],
-         attrs={"kernel": Required(int), "bias": False},
+         attrs={"kernel": Required(int), "bias": False, "act_type": "none"},
          aliases=("causal_conv1d",))
 
 
@@ -308,13 +306,22 @@ def _rms_norm(a, data, gamma, gate=None):
     arXiv:1910.07467), no mean and no shift. The reduction and the scaling
     run in float32 whatever the input's dtype; one fused pass, as
     _layer_norm. With ``gated`` a third input scales the result by
-    silu(gate), the output gate of the linear-attention mixers. With
-    ``groups`` > 1 the mean square is taken over each of that many equal
-    groups of the axis (the last), gamma still one number a channel: the
-    output norm of the state-space mixers."""
+    silu(gate), the output gate of the linear-attention mixers; with
+    ``gate_first`` as well, silu(gate) multiplies x before the mean square
+    is taken, as the state-space mixers gate theirs. With ``groups`` > 1 the
+    mean square is taken over each of that many equal groups of the axis
+    (the last), gamma still one number a channel: the output norm of the
+    state-space mixers. A gated norm over the last axis is the operator
+    with its own gradient (mixers.py `gated_norm`): one pass forward, one
+    backward from dOut, the data and the gate."""
     ax = int(a.axis) % data.ndim
-    x = data.astype(jnp.float32)
     groups = int(a.get("groups", 1))
+    gate_first = bool(a.get("gate_first"))
+    if gate is not None and ax == data.ndim - 1:
+        return mixers.gated_norm(data, gamma, gate, a.eps, groups, gate_first)
+    x = data.astype(jnp.float32)
+    if gate is not None and gate_first:
+        x = x * jax.nn.silu(gate.astype(jnp.float32))
     if groups > 1:
         if ax != data.ndim - 1 or data.shape[ax] % groups:
             raise ValueError("RMSNorm: %d groups of axis %d of %r" % (
@@ -327,7 +334,7 @@ def _rms_norm(a, data, gamma, gate=None):
         ms = jnp.mean(jnp.square(x), axis=ax, keepdims=True)
     bshape = tuple(data.shape[ax] if i == ax else 1 for i in range(data.ndim))
     out = x * lax.rsqrt(ms + a.eps) * gamma.astype(jnp.float32).reshape(bshape)
-    if gate is not None:
+    if gate is not None and not gate_first:
         out = out * jax.nn.silu(gate.astype(jnp.float32))
     return out.astype(data.dtype)
 
@@ -335,7 +342,8 @@ def _rms_norm(a, data, gamma, gate=None):
 register("RMSNorm", _rms_norm,
          arg_names=lambda a: ["data", "gamma", "gate"] if a.get("gated") else
          ["data", "gamma"],
-         attrs={"eps": 1e-6, "axis": -1, "gated": False, "groups": 1})
+         attrs={"eps": 1e-6, "axis": -1, "gated": False, "groups": 1,
+                "gate_first": False})
 
 # ---------------------------------------------------------------- activations
 

@@ -408,3 +408,96 @@ def test_expert_layer_takes_its_first_trip_outside_the_loop(
     made = re.findall(r"= (f32\[8,\d+,\d+\])\S* (\w[\w-]*)\(", text)
     assert {shape for shape, _ in made} == set(leaves)
     assert "broadcast" not in {op for _, op in made}, made
+
+
+def _block_gradient_text(mix, data_shape, one_chip):
+    """The compiled gradient (every argument's) of sum(mix(data)) in
+    bfloat16, the float32 decay parameters as the graph declares them."""
+    import jax
+    import jax.numpy as jnp
+    import mxtpu as mx
+    from mxtpu.executor import _trace_graph
+    with mx.name.NameManager():
+        net = mix(mx.sym.Variable("data"))
+    names = net.list_arguments()
+    shapes, _, _ = net.infer_shape(data=data_shape)
+    types, _, _ = net.infer_type(
+        **{n: "float32" if n.endswith(("A_log", "dt_bias", "_D"))
+           else "bfloat16" for n in names})
+    args = {n: jax.ShapeDtypeStruct(s, jnp.dtype(t), sharding=one_chip)
+            for n, s, t in zip(names, shapes, types)}
+    run = _trace_graph(net, is_train=True)
+
+    def loss(args):
+        (out,), _ = run(args, {}, None)
+        return jnp.sum(out.astype(jnp.float32))
+
+    return jax.jit(jax.grad(loss)).lower(args).compile().as_text()
+
+
+def _entry(text):
+    return text[text.index("\nENTRY "):]
+
+
+def _builds(name):
+    from mxtpu import telemetry
+    return {m.labels.get("path"): m.value
+            for m in telemetry.registry().series() if m.name == name}
+
+
+def test_mamba2_block_keeps_no_float32_pass_at_the_nemotron_cells_size(
+        one_chip, no_cache):
+    """nemotron-twotower-30b-fit-s4096's mixer, differentiated: batch 2,
+    4096 tokens, d 2688, 64 heads of 64 in 8 groups of state 128, bf16. The
+    short convolution's backward and the gated group norm's two passes are
+    Mosaic calls beside the scan's two; the entry computation holds no
+    float32 buffer of an xBC (6144 channels) or of a y or z (4096)."""
+    from mxtpu.models import decoder
+    from mxtpu.ops import mixers, ssd
+    before = _builds("mixer_conv_builds"), _builds("mixer_norm_builds")
+    text = _block_gradient_text(
+        lambda x: decoder.mamba2_mix(x, 4096, 2688, "l0", num_heads=64,
+                                     head_dim=64, n_groups=8, state_size=128,
+                                     norm_eps=1e-5),
+        (2, 4096, 2688), one_chip)
+    for name in (mixers.CONV_BWD_KERNEL_NAME, mixers.NORM_FWD_KERNEL_NAME,
+                 mixers.NORM_BWD_KERNEL_NAME, ssd.FWD_KERNEL_NAME,
+                 ssd.BWD_KERNEL_NAME):
+        assert text.count("%%%s" % name) >= 1, name
+    assert text.count("custom_call_target=\"tpu_custom_call\"") == 5
+    entry = _entry(text)
+    assert "f32[2,4096,6144]" not in entry and "f32[2,4096,4096]" not in entry
+    assert "f32[8192,4096]" not in entry
+    after = _builds("mixer_conv_builds"), _builds("mixer_norm_builds")
+    assert after[0].get("fused", 0) == before[0].get("fused", 0) + 1
+    assert after[1].get("fused", 0) == before[1].get("fused", 0) + 1
+
+
+def test_delta_rule_block_keeps_no_float32_pass_at_the_hybrid_cells_size(
+        one_chip, no_cache):
+    """olmo-hybrid-7b-fit-s2048's linear-attention mixer, differentiated:
+    batch 4, 2048 tokens, d 3840, 30 heads of 96 / 192, bf16. All three
+    short convolutions take the backward kernel (v's 5760 channels in blocks
+    of three lane tiles, q's and k's 2880, 22.5 tiles, as whole rows); the
+    gated norm's two passes take pairs of heads; the entry computation holds
+    no float32 buffer of a v, an output or a gate (5760 channels)."""
+    import re
+    from mxtpu.models import decoder
+    from mxtpu.ops import delta_rule, mixers
+    before = _builds("mixer_conv_builds"), _builds("mixer_norm_builds")
+    text = _block_gradient_text(
+        lambda x: decoder.delta_rule_mix(x, 2048, 30, 3840, "l0", key_dim=96,
+                                         value_dim=192),
+        (4, 2048, 3840), one_chip)
+    for name in (mixers.CONV_BWD_KERNEL_NAME, mixers.NORM_FWD_KERNEL_NAME,
+                 mixers.NORM_BWD_KERNEL_NAME, delta_rule.FWD_KERNEL_NAME,
+                 delta_rule.BWD_KERNEL_NAME):
+        assert text.count("%%%s" % name) >= 1, name
+    assert text.count("custom_call_target=\"tpu_custom_call\"") == 7
+    # (q's and k's L2 norms, no part of this, still hold theirs in float32)
+    assert not re.findall(r"f32\[(?:4,2048,5760|4,2048,30,192|8192,5760)\]",
+                          _entry(text))
+    after = _builds("mixer_conv_builds"), _builds("mixer_norm_builds")
+    assert after[0].get("fused", 0) == before[0].get("fused", 0) + 3
+    assert after[0].get("composed", 0) == before[0].get("composed", 0)
+    assert after[1].get("fused", 0) == before[1].get("fused", 0) + 1

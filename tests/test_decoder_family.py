@@ -231,10 +231,12 @@ PARENT_JSON = {
         "cec68f99864419071f9aefa595857a3098342ba96825a1f53ff208981f3b56a0",
     ("opt-1.3b-fit-s1024", True):
         "fd4823d640e2cf6ef7a6823814d0c38229dc258cf7b78f2c55450cab0ee16fcb",
+    # the hybrid member since its short convolutions carry their SiLU as
+    # an attribute (PR 37: nine `Activation` nodes fewer, no name moved)
     ("olmo-hybrid-7b-fit-s2048", False):
-        "d5954aa2a101034f0fe8234456255df52812ff742aa435e9f16f451c5220e3fc",
+        "9c05a955222d03d2c8f40ad4a045a1874b2334e2e4308173fc5c9d392439ae1a",
     ("olmo-hybrid-7b-fit-s2048", True):
-        "2b0b14a1e2b563066ce77df58c41b6dec4fd1967ce11cdb932efbae89e84130e",
+        "420832ded325d722974cf3e972fc8b51288296eae7a3eaf5c5f24960c5cee7fd",
     # the Laguna member at the commit before the one-sublayer member
     # (23a39d9): the gated expert layer's graph has no attribute it lacked
     ("laguna-s-2.1-fit-s4096", False):

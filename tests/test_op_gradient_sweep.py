@@ -218,6 +218,13 @@ SPECS = {
     "_contrib_CausalConv1D_bias": dict(op="_contrib_CausalConv1D",
                                        primary={"data": (2, 5, 3)},
                                        attrs={"kernel": 3, "bias": True}),
+    "_contrib_CausalConv1D_silu": dict(op="_contrib_CausalConv1D",
+                                       primary={"data": (2, 5, 3)},
+                                       attrs={"kernel": 3,
+                                              "act_type": "silu"}),
+    "_contrib_CausalConv1D_silu_bias": dict(
+        op="_contrib_CausalConv1D", primary={"data": (2, 5, 3)},
+        attrs={"kernel": 4, "bias": True, "act_type": "silu"}),
     "_contrib_RotaryEmbedding": dict(
         primary={"data": (1, 2, 4, 6)},
         attrs={"rotary_dims": 4, "theta": 100.0, "scale": 1.3}),
@@ -232,6 +239,11 @@ SPECS = {
                           attrs={"gated": True}),
     "RMSNorm_groups": dict(op="RMSNorm", primary={"data": (3, 4)},
                            attrs={"groups": 2}),
+    "RMSNorm_gate_first": dict(op="RMSNorm", primary={"data": S},
+                               attrs={"gated": True, "gate_first": True}),
+    "RMSNorm_gated_groups": dict(op="RMSNorm", primary={"data": (2, 3, 4)},
+                                 attrs={"gated": True, "gate_first": True,
+                                        "groups": 2}),
     "_slice_assign": dict(primary={"lhs": S, "rhs": (2, 2)},
                           attrs={"begin": (0, 0), "end": (2, 2)}),
     "_slice_assign_scalar": dict(primary={"data": S},

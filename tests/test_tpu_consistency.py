@@ -264,12 +264,13 @@ def _rel(got, want):
 
 
 def _with_grads(f):
-    """(f(a, b), its gradients under the weights w) as one program."""
+    """(f(*operands), its gradients under the weights w, the last argument)
+    as one program."""
     import jax
 
-    def run(a, b, w):
-        out, vjp = jax.vjp(f, a, b)
-        return (out,) + vjp(w.astype(out.dtype))
+    def run(*args):
+        out, vjp = jax.vjp(f, *args[:-1])
+        return (out,) + vjp(args[-1].astype(out.dtype))
     return jax.jit(run)
 
 
@@ -346,6 +347,91 @@ def test_head_gate_kernels_on_chip(heads):
         assert new_.dtype == jnp.bfloat16
         assert _rel(new_, want_) < 6e-3
         assert _rel(new_, want_) <= 1.05 * _rel(old_, want_)
+
+
+def _as_the_chain_does(got, old, want, dtypes):
+    """Each result of the operator at a bfloat16 rounding or two from the
+    float32 chain, and no further from it than the bfloat16 chain is."""
+    for new_, old_, want_, dtype in zip(got, old, want, dtypes):
+        assert new_.dtype == dtype
+        assert _rel(new_, want_) < 6e-3
+        assert _rel(new_, want_) <= 1.05 * _rel(old_, want_)
+
+
+@pytest.mark.parametrize("b,t,c,bias,calls", [
+    (2, 4096, 6144, True, 1),    # nemotron-twotower-30b-fit-s4096's xBC
+    (4, 2048, 5760, False, 1),   # olmo-hybrid-7b-fit-s2048's v
+    (4, 2048, 2880, False, 1),   # its q and k: 22.5 lane tiles, whole rows
+    (2, 1000, 256, True, 0),     # T does not tile: the plain body
+])
+def test_short_conv_kernel_on_chip(b, t, c, bias, calls):
+    """The short convolution with its SiLU at the cells' sizes: values and
+    the data's, the filter's and the bias's gradients against the chain it
+    replaces (the convolution rounded, then `Activation`) taken in float32;
+    the backward one Mosaic call where the shape tiles."""
+    import jax
+    import jax.numpy as jnp
+    from mxtpu.ops import mixers
+    _require_accel()
+    rng = np.random.RandomState(2)
+    x = jnp.asarray(rng.randn(b, t, c), "bfloat16")
+    args = [x, jnp.asarray(0.29 * rng.randn(c, 4), "bfloat16")]
+    if bias:
+        args.append(jnp.asarray(0.1 * rng.randn(c), "bfloat16"))
+    args.append(jnp.asarray(rng.randn(b, t, c), "bfloat16"))
+
+    def fused(x, w, bias=None):
+        return mixers.short_conv(x, w, bias, 4, "silu")
+
+    def chain(x, w, bias=None):
+        return jax.nn.silu(mixers._conv_plain(x, w, bias, 4, "none"))
+
+    assert _with_grads(fused).lower(*args).as_text().count(
+        "tpu_custom_call") == calls
+    got = _with_grads(fused)(*args)
+    old = _with_grads(chain)(*args)
+    want = _with_grads(chain)(*(a.astype("float32") for a in args))
+    _as_the_chain_does(got, old, want, [jnp.bfloat16] * len(got))
+
+
+@pytest.mark.parametrize("shape,groups,gate_first", [
+    ((2, 4096, 4096), 8, True),      # nemotron-twotower-30b-fit-s4096
+    ((4, 2048, 30, 192), 1, False),  # olmo-hybrid-7b-fit-s2048: pairs of heads
+])
+def test_gated_norm_kernels_on_chip(shape, groups, gate_first):
+    """The gated output norm at the cells' sizes, the Mosaic kernels: values
+    and the data's, gamma's and the gate's gradients against the chain it
+    replaces (Nemotron: `y * Activation(z)` rounded, then the grouped
+    `RMSNorm`; hybrid: the norm, then the gate, in one float32 body) taken
+    in float32."""
+    import jax
+    import jax.numpy as jnp
+    from mxtpu.ops import mixers
+    from mxtpu.ops.nn import _rms_norm
+    from mxtpu.ops.registry import AttrDict
+    _require_accel()
+    rng = np.random.RandomState(3)
+    x = jnp.asarray(rng.randn(*shape), "bfloat16")
+    gamma = jnp.asarray(1.0 + 0.2 * rng.randn(shape[-1]), "bfloat16")
+    gate, w = (jnp.asarray(rng.randn(*shape), "bfloat16") for _ in range(2))
+    plain = AttrDict(axis=-1, eps=1e-5, groups=groups)
+
+    def fused(x, gamma, gate):
+        return mixers.gated_norm(x, gamma, gate, 1e-5, groups, gate_first)
+
+    def chain(x, gamma, gate):
+        if gate_first:
+            return _rms_norm(plain, x * jax.nn.silu(gate), gamma)
+        out = _rms_norm(plain, x.astype("float32"), gamma.astype("float32"))
+        return (out * jax.nn.silu(gate.astype("float32"))).astype(x.dtype)
+
+    assert _with_grads(fused).lower(x, gamma, gate, w).as_text().count(
+        "tpu_custom_call") == 2
+    got = _with_grads(fused)(x, gamma, gate, w)
+    old = _with_grads(chain)(x, gamma, gate, w)
+    want = _with_grads(chain)(
+        *(a.astype("float32") for a in (x, gamma, gate, w)))
+    _as_the_chain_does(got, old, want, [jnp.bfloat16] * 4)
 
 
 def test_ssd_kernels_on_chip():
