@@ -32,18 +32,32 @@ padded with zeros inside the operator (`_widened`). The walk (`_walk_tiled`)
 takes the rows `chunk` at a time for as many trips as the pairs held here
 need: gather the rows' tokens, a grouped product over the chunk's ragged
 groups (`jax.lax.ragged_dot_general`, which XLA lowers to a Mosaic grouped
-matmul on the TPU) for gate, up and down, weight, scatter-add into the
-tokens' rows. Nothing has a capacity, so nothing overflows; the static bound
-is the pairs' array itself (tokens x top_k int32, and a tile an expert) and
-one chunk of activations, and **time follows the pairs routed here**, not
-tokens x experts held. The trip count depends on the data, so the layer
-brings its own gradient (``jax.custom_vjp``, one pair for both forms, the
-activation a function it is told): the same walk again, each thing once a
-trip. Gate and up are recomputed (the up product alone in the ungated form)
+matmul on the TPU) for gate, up and down, and the combine. Nothing has a
+capacity, so nothing overflows; the static bound is the pairs' array itself
+(tokens x top_k int32, and a tile an expert) and one chunk of activations,
+and **time follows the pairs routed here**, not tokens x experts held.
+
+On the TPU the combine is owner-computes (`_combined`), the Pallas kernel
+`mxtpu_moe_combine` where the shape takes it (`_combine_blocks`): each
+token's row is written once, the float32 sum of the trip's rows that hold
+its pairs, each read once, times the pair's weight in the forward, in the
+data's dtype; no row is scattered. The plan says where each block of
+tokens' pairs lie (`_bounds`): an expert's pairs lie in its tiles in token
+order, so a block's run of rows in an expert's tiles starts at the
+expert's first row plus its pairs among the blocks before. Elsewhere, and
+on the CPU, the combine is the scatter-add of the trip's rows into the
+tokens' rows. With one trip, the common case, the first trip's combine
+writes the result; behind it each trip's combine adds into a float32 sum
+the loop carries.
+
+The trip count depends on the data, so the layer brings its own gradient
+(``jax.custom_vjp``, one pair for both forms, the activation a function it
+is told): the same walk again, each thing once a trip, and the same combine
+for dx. Gate and up are recomputed (the up product alone in the ungated form)
 and the activation's own derivative is taken from them; the down product is
 not: the router weight's gradient comes from the product that carries the
 cotangent to the experts' width. Forward and backward take their first trip
-as straight-line code (`_peeled`): the backward's grouped products over the
+as straight-line code (`_combined`): the backward's grouped products over the
 ragged contraction ARE the float32 weight gradients, and a loop behind the
 first trip adds to them only where the pairs need a second one, which
 `moe_trips_after_first` over `moe_layer_steps_seen` counts.
@@ -55,13 +69,21 @@ import functools
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import lax
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from .. import telemetry
+from .heads import _lowered, _vmem
 from .registry import Required, register
+
+COMBINE_KERNEL_NAME = "mxtpu_moe_combine"
 
 _F32 = jnp.float32
 CHUNK = 4096        # rows a trip of the dispatch loop, at most
 TILE = 512          # rows and columns a tile of XLA's grouped matmul (TPU)
+_COMBINE_TOKENS = 256               # tokens a grid step of the combine
+_COMBINE_RESIDENT = 64 << 20        # a trip's float32 rows, in VMEM at most
 
 # (tokens, tile, rows a trip) of the last expert layer traced: what the
 # fetched loads are counted against (`observe_loads`)
@@ -230,56 +252,203 @@ def _plus(acc, new):
     return new if acc is None else acc + new
 
 
-def _peeled(trips, trip, start):
-    """The dispatch, `trip(c, carry)` for c < trips, with trip 0 outside
-    the loop as straight-line code. It is always taken: with no pair held
-    every group is empty and every row dead, which any trip has to get
-    right for an expert with no rows. What it returns is what the loop
-    behind it carries on, so a sum may start from its first term (`_plus`)
-    and XLA schedules the trip nearly every layer-step stops at with the
-    code around it."""
-    return jax.lax.fori_loop(1, trips, trip, trip(0, start))
+# ------------------------------------------------------------- the combine
+def _bounds(index, held, offset, ends, tokens):
+    """Where the pairs of each block of `tokens` tokens lie among the plan's
+    rows: flat ((N / tokens + 1) x held,) int32, at b x held + e the first
+    row of held expert e's pairs among block b's tokens and, last, past each
+    expert's last pair. An expert's pairs lie in its tiles in token order
+    (the plan's sort is stable), so that is its first row plus its pairs
+    among the blocks before."""
+    k = index.shape[-1]
+    local = index.reshape(-1, tokens * k) - offset
+    mine = jnp.sum(local[:, :, None] == jnp.arange(held, dtype=local.dtype),
+                   axis=1, dtype=jnp.int32)
+    before = jnp.concatenate([jnp.zeros((1,), ends.dtype), ends[:-1]])
+    runs = jnp.cumsum(jnp.concatenate(
+        [jnp.zeros((1, held), jnp.int32), mine]), axis=0)
+    return (before + runs).astype(jnp.int32).reshape(-1)
 
 
+def _combine_blocks(n, d, chunk):
+    """Tokens a grid step of the combine kernel, or None where the shape
+    does not take it: tokens a multiple of `_COMBINE_TOKENS`, d of 128
+    lanes, and a trip's float32 rows within `_COMBINE_RESIDENT` of VMEM."""
+    if n % _COMBINE_TOKENS or d % 128 or chunk * d * 4 > _COMBINE_RESIDENT:
+        return None
+    return _COMBINE_TOKENS
+
+
+def _combine_kernel(edges_ref, tok_ref, *refs, held, weighted, summed):
+    """A block of tokens: for each held expert the run of the trip's rows
+    its pairs among the block's tokens hold (`edges_ref`, scalar-prefetched:
+    block b, expert e at b x held + e), each row read once from the trip's
+    rows resident in VMEM, times its pair's weight where there are weights,
+    and added in float32 to its token's row (`tok_ref`: each row's token)
+    of the sum carried so far or of 0; the block written once, in the
+    output's dtype."""
+    w_ref = refs[0] if weighted else None
+    rows_ref, *refs = refs[1:] if weighted else refs
+    prior_ref, out_ref, acc_ref = refs if summed else (None,) + tuple(refs)
+    b = pl.program_id(0)
+    first = b * out_ref.shape[0]
+    acc_ref[...] = (prior_ref[...] if summed
+                    else jnp.zeros(acc_ref.shape, _F32))
+
+    def row(r, carry):
+        y = rows_ref[pl.ds(r, 1), :]
+        at = pl.ds(tok_ref[r] - first, 1)
+        acc_ref[at, :] += y * w_ref[r] if weighted else y
+        return carry
+
+    def expert(e, carry):
+        return lax.fori_loop(edges_ref[b * held + e],
+                             edges_ref[(b + 1) * held + e], row, carry)
+
+    lax.fori_loop(0, held, expert, 0)
+    out_ref[...] = acc_ref[...].astype(out_ref.dtype)
+
+
+def _combine_call(rows, tok, w, edges, prior, n, dtype, tokens,
+                  interpret=False):
+    """The kernel over blocks of `tokens` of the n tokens: rows (chunk, d)
+    float32 resident in VMEM, each row's token (and weight) in SMEM,
+    `edges` the runs' ends in the trip's rows, `prior` (n, d) float32
+    aliased to the output."""
+    d = rows.shape[1]
+    blocks = n // tokens
+    block = pl.BlockSpec((tokens, d), lambda b, e: (b, 0))
+    lists = [tok] + ([] if w is None else [w])
+    args = lists + [rows] + ([] if prior is None else [prior])
+    resident = (rows.size + tokens * d) * 4 \
+        + 2 * tokens * d * (jnp.dtype(dtype).itemsize
+                            + (0 if prior is None else 4))
+    return pl.pallas_call(
+        functools.partial(_combine_kernel,
+                          held=edges.shape[0] // (blocks + 1),
+                          weighted=w is not None, summed=prior is not None),
+        out_shape=jax.ShapeDtypeStruct((n, d), dtype),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=(blocks,),
+            in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM)] * len(lists)
+            + [pl.BlockSpec(memory_space=pltpu.VMEM)]
+            + ([] if prior is None else [block]),
+            out_specs=block,
+            scratch_shapes=[pltpu.VMEM((tokens, d), _F32)]),
+        input_output_aliases={} if prior is None else {len(args): 0},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",),
+            vmem_limit_bytes=_vmem(resident)),
+        interpret=interpret,
+        name=COMBINE_KERNEL_NAME,
+    )(edges, *args)
+
+
+def _combine(got, bounds, base, prior, n, dtype, tokens):
+    """sum over each token's pairs among a trip's rows, the plan's rows
+    base .. base + chunk - 1, times the pair's weight where there are
+    weights, added to `prior` (n, d) float32 or to 0: (n, d) in `dtype`.
+    `got`: (the trip's rows (chunk, d) float32, each row's token, each row's
+    weight or None, which rows hold a pair). The kernel reads only the rows
+    `bounds` says hold a pair; the plain body is the scatter-add of the rows
+    into the tokens' rows, a row that holds no pair masked."""
+    def kernel(rows, tok, w, live, bounds, prior):
+        edges = jnp.clip(bounds - base, 0, rows.shape[0])
+        return _combine_call(rows, tok, w, edges, prior, n, dtype, tokens)
+
+    def plain(rows, tok, w, live, bounds, prior):
+        y = rows if w is None else rows * w[:, None]
+        start = jnp.zeros((n, rows.shape[1]), _F32) if prior is None \
+            else prior
+        return start.at[tok].add(jnp.where(live[:, None], y, 0.0)).astype(
+            dtype)
+
+    return _lowered(tokens, kernel, plain, *got, bounds, prior)
+
+
+def _combined(trips, trip, start, bounds, chunk, n, dtype, tokens):
+    """The dispatch, `trip(c, carry)` -> (what `_combine` reads of trip c,
+    carry) for c < trips, and its combine into n tokens' rows. Trip 0 is
+    straight-line code: it is always taken (with no pair held every group
+    is empty and every row dead, which any trip has to get right for an
+    expert with no rows), what it returns is what the loop behind it carries
+    on, so a sum may start from its first term (`_plus`), and XLA schedules
+    the trip nearly every layer-step stops at with the code around it. With
+    one trip one combine writes the result in `dtype`; behind a first trip
+    the sum is carried in float32, a combine a trip, and rounded once."""
+    first, carry = trip(0, start)
+
+    def alone(first, carry):
+        return _combine(first, bounds, 0, None, n, dtype, tokens), carry
+
+    def more(first, carry):
+        def step(c, state):
+            got, carry = trip(c, state[1])
+            return (_combine(got, bounds, c * chunk, state[0], n, _F32,
+                             tokens), carry)
+
+        out, carry = lax.fori_loop(1, trips, step, (
+            _combine(first, bounds, 0, None, n, _F32, tokens), carry))
+        return out.astype(dtype), carry
+
+    return lax.cond(trips > 1, more, alone, first, carry)
+
+
+def _count_combine(tokens):
+    telemetry.counter(
+        "moe_combine_builds",
+        labels={"path": "plain" if tokens is None else "fused"},
+        help="combines of differentiated expert layers traced, forward and "
+             "backward, by whether their shape takes the combine kernel on "
+             "the chip").inc()
+
+
+# ----------------------------------------------------------------- the walk
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6))
 def _experts(x, w, plan, leaves, act, k, chunk):
-    """sum over the pairs held here of w E(x): `plan` is `_plan_tiled`'s,
+    """sum over the pairs held here of w E(x): `plan` is `_plan_tiled`'s
+    and `_bounds`' (None where the shape does not take the combine kernel),
     `leaves` the stacked leaves an expert's `act` reads x through (gate and
     up, or up alone) and, last, the down leaf that takes its result back."""
-    trips, rows = _walk_tiled(*plan, k, chunk)
+    trips, rows = _walk_tiled(*plan[:3], k, chunk)
     w_flat = w.reshape(-1)
     *ups_t, wd_t = _turned(*_widened(leaves))
 
-    def trip(c, out):
+    def trip(c, carry):
         pairs, tok, live, sizes = rows(c)
         h = act(*_up(x[tok], sizes, live, *ups_t)).astype(x.dtype)
-        y = _grouped(h, wd_t, sizes, "nn") * w_flat[pairs][:, None]
-        return out.at[tok].add(jnp.where(live[:, None], y, 0.0))
+        return (_grouped(h, wd_t, sizes, "nn"), tok, w_flat[pairs],
+                live), carry
 
-    return _peeled(trips, trip, jnp.zeros(x.shape, _F32)).astype(x.dtype)
+    out, _ = _combined(trips, trip, None, plan[3], chunk, x.shape[0],
+                       x.dtype, _combine_blocks(*x.shape, chunk))
+    return out
 
 
 def _experts_fwd(x, w, plan, leaves, act, k, chunk):
+    _count_combine(_combine_blocks(*x.shape, chunk))
     return _experts(x, w, plan, leaves, act, k, chunk), (x, w, plan, leaves)
 
 
 def _experts_bwd(act, k, chunk, res, g):
     """The same walk again. The first trip's grouped products ARE the
-    float32 weight gradients (`_peeled`, `_plus`), and the loop behind it
+    float32 weight gradients (`_combined`, `_plus`), and the loop behind it
     adds to them only where there is a second trip. The router weight's
     gradient sum(g * (h W_down^T)) is taken as sum((g W_down) * h) from the
     product the backward needs anyway, the pair's weight applied in float32
-    after it."""
+    after it. dx is the combine of the rows' input gradients."""
     x, w, plan, leaves = res
+    tokens = _combine_blocks(*x.shape, chunk)
+    _count_combine(tokens)
     with telemetry.span("moe.build", category="compile",
                         tags={"pass": "bwd"}):
-        trips, rows = _walk_tiled(*plan, k, chunk)
+        trips, rows = _walk_tiled(*plan[:3], k, chunk)
         w_flat = w.reshape(-1)
         *wide, wide_d = _widened(leaves)
         ups_t = _turned(*wide)
 
         def trip(c, carry):
-            dx, dw, dleaves = carry
+            dw, dleaves = carry
             pairs, tok, live, sizes = rows(c)
             xs, gs, wp = x[tok], g[tok], w_flat[pairs][:, None]
             h, pull = jax.vjp(act, *_up(xs, sizes, live, *ups_t))
@@ -291,15 +460,16 @@ def _experts_bwd(act, k, chunk, res, g):
                 _grouped(gs, (h * wp).astype(x.dtype), sizes, "tn")]
             dxs = sum(_grouped(da, u, sizes, "nn")
                       for da, u in zip(das, wide))
-            dx = dx.at[tok].add(jnp.where(live[:, None], dxs, 0.0))
-            return dx, dw, tuple(map(_plus, dleaves, new))
+            return (dxs, tok, None, live), (dw,
+                                            tuple(map(_plus, dleaves, new)))
 
-        dx, dw, dleaves = _peeled(trips, trip, (
-            jnp.zeros(x.shape, _F32), jnp.zeros(w_flat.shape, _F32),
-            (None,) * len(leaves)))
+        dx, (dw, dleaves) = _combined(
+            trips, trip, (jnp.zeros(w_flat.shape, _F32),
+                          (None,) * len(leaves)),
+            plan[3], chunk, x.shape[0], x.dtype, tokens)
     f = leaves[-1].shape[2]      # the published width, under the padding
     dleaves = [d[:, :f] for d in dleaves[:-1]] + [dleaves[-1][:, :, :f]]
-    return (dx.astype(x.dtype), dw.reshape(w.shape).astype(w.dtype),
+    return (dx, dw.reshape(w.shape).astype(w.dtype),
             jax.tree.map(lambda v: np.zeros(v.shape, jax.dtypes.float0),
                          plan),
             tuple(d.astype(v.dtype) for d, v in zip(dleaves, leaves)))
@@ -337,6 +507,9 @@ def moe_experts(x, topk_weight, topk_index, gate_weight, up_weight,
                               "chunk": chunk}):
         w = topk_weight.reshape(n, k).astype(_F32)
         plan = _plan_tiled(topk_index, held, int(expert_offset), tile)
+        tokens = _combine_blocks(n, d, chunk)
+        plan += (None if tokens is None else _bounds(
+            topk_index, held, int(expert_offset), plan[1], tokens),)
         leaves, act = (((up_weight, down_weight), _relu2)
                        if gate_weight is None else
                        ((gate_weight, up_weight, down_weight), _silu_gated))

@@ -357,15 +357,61 @@ def test_ssd_forward_alone_keeps_no_states(one_chip, no_cache):
     assert "f32[2,8,32,128,512]" not in text
 
 
+_EXPERT_CELLS = {
+    "laguna-s-2.1-fit-s4096": (3072, 1024, 10, 256, True),
+    "nemotron-twotower-30b-fit-s4096": (2688, 1856, 6, 128, False)}
+_expert_texts = {}
+
+
+def _expert_layer_text(cell, one_chip):
+    """The compiled value and gradient of the expert layer at a cell's
+    sizes: 8,192 tokens, 8 experts held, bf16, trips of 4,096 rows (one
+    compile a cell for the tests below), and the combines it counted."""
+    if cell not in _expert_texts:
+        import jax
+        import jax.numpy as jnp
+        from mxtpu.ops import moe
+        d, f, k, experts, gated = _EXPERT_CELLS[cell]
+
+        def s(shape, dtype=jnp.bfloat16):
+            return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+        ups = [s((8, f, d))] * (2 if gated else 1)
+
+        def loss(x, tw, ups, wd, ti, cot):
+            out, _ = moe.moe_experts(x, tw, ti, ups[0] if gated else None,
+                                     ups[-1], wd, experts, 0)
+            return jnp.sum(out.astype(jnp.float32) * cot)
+
+        before = _builds("moe_combine_builds")
+        text = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2, 3))).lower(
+            s((8192, d)), s((8192, k), jnp.float32), ups, s((8, d, f)),
+            s((8192, k), jnp.int32), s((8192, d), jnp.float32)
+        ).compile().as_text()
+        after = _builds("moe_combine_builds")
+        _expert_texts[cell] = text, {
+            p: after[p] - before.get(p, 0) for p in after}
+    return _expert_texts[cell]
+
+
+def _computations(text):
+    """{computation name ("entry" for the entry): its body}."""
+    import re
+    return {("entry" if head.startswith("ENTRY") else head): body
+            for head, body in re.findall(
+                r"^((?:ENTRY )?%[\w.-]+) \(.*?\n(.*?)^\}", text,
+                re.M | re.S)}
+
+
 @pytest.mark.parametrize(
-    "d,f,k,experts,gated,leaves,fwd,bwd",
-    [(3072, 1024, 10, 256, True, ("f32[8,1024,3072]", "f32[8,3072,1024]"),
+    "cell,leaves,fwd,bwd",
+    [("laguna-s-2.1-fit-s4096", ("f32[8,1024,3072]", "f32[8,3072,1024]"),
       3, 8),
-     (2688, 1856, 6, 128, False, ("f32[8,2048,2688]", "f32[8,2688,2048]"),
-      2, 5)],
+     ("nemotron-twotower-30b-fit-s4096",
+      ("f32[8,2048,2688]", "f32[8,2688,2048]"), 2, 5)],
     ids=["laguna-s-2.1-fit-s4096", "nemotron-twotower-30b-fit-s4096"])
 def test_expert_layer_takes_its_first_trip_outside_the_loop(
-        one_chip, no_cache, d, f, k, experts, gated, leaves, fwd, bwd):
+        one_chip, no_cache, cell, leaves, fwd, bwd):
     """The expert layer and its gradient at the two cells' sizes: 8,192
     tokens, 8 experts held, bf16, trips of 4,096 rows. The grouped products
     of the forward and of the backward stand in the entry computation (the
@@ -376,30 +422,12 @@ def test_expert_layer_takes_its_first_trip_outside_the_loop(
     products themselves, so no float32 zeros of a stacked leaf's shape are
     made for the loop to add to."""
     import re
-    import jax
-    import jax.numpy as jnp
-    from mxtpu.ops import moe
-
-    def s(shape, dtype=jnp.bfloat16):
-        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
-
-    ups = [s((8, f, d))] * (2 if gated else 1)
-
-    def loss(x, tw, ups, wd, ti, cot):
-        out, _ = moe.moe_experts(x, tw, ti, ups[0] if gated else None,
-                                 ups[-1], wd, experts, 0)
-        return jnp.sum(out.astype(jnp.float32) * cot)
-
-    text = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2, 3))).lower(
-        s((8192, d)), s((8192, k), jnp.float32), ups, s((8, d, f)),
-        s((8192, k), jnp.int32), s((8192, d), jnp.float32)
-    ).compile().as_text()
+    text, _ = _expert_layer_text(cell, one_chip)
     products = {}
-    for head, body in re.findall(
-            r"^((?:ENTRY )?%[\w.-]+) \(.*?\n(.*?)^\}", text, re.M | re.S):
+    for head, body in _computations(text).items():
         calls = len(re.findall(r"^\s*%ragged-dot-none[\w.]* = ", body, re.M))
         if calls:
-            products["entry" if head.startswith("ENTRY") else head] = calls
+            products[head] = calls
     # both first trips; XLA may share the forward's gate and up products
     # with the backward's recomputed ones, which then stand there once
     assert bwd < products.pop("entry") <= fwd + bwd, products
@@ -408,6 +436,38 @@ def test_expert_layer_takes_its_first_trip_outside_the_loop(
     made = re.findall(r"= (f32\[8,\d+,\d+\])\S* (\w[\w-]*)\(", text)
     assert {shape for shape, _ in made} == set(leaves)
     assert "broadcast" not in {op for _, op in made}, made
+
+
+@pytest.mark.parametrize("cell", list(_EXPERT_CELLS))
+def test_expert_layer_combines_without_a_scatter(one_chip, no_cache, cell):
+    """The combine of the expert layer at the two cells' sizes writes each
+    token's row once: no scatter of the trip's rows into an f32[8192,d] of
+    the tokens' rows, forward or backward. Each direction's conditional in
+    the entry computation (one trip, or more) has a branch of one combine
+    kernel call and no loop, the call writing the layer's bf16[8192,d]
+    (the cast rides in the call), and a branch with one call before its
+    loop and one in the loop's body, in float32; the grouped products are
+    the ones the test above counts, and the traced gradient counts its two
+    combines as fused."""
+    import re
+    from mxtpu.ops import moe
+    text, built = _expert_layer_text(cell, one_chip)
+    d = _EXPERT_CELLS[cell][0]
+    assert not re.search(r"= f32\[8192,%d\]\S* scatter\(" % d, text)
+    assert built == {"fused": 2}, built
+    comps = _computations(text)
+    call = r"^\s*%%%s[\w.]* = (\w+)\[" % moe.COMBINE_KERNEL_NAME
+    conds = re.findall(r" conditional\(.*?branch_computations=\{([^}]*)\}",
+                       comps["entry"])
+    assert len(conds) == 2, conds
+    for branches in conds:
+        alone, more = [b.strip() for b in branches.split(",")]
+        assert re.findall(call, comps[alone], re.M) == ["bf16"]
+        assert " while(" not in comps[alone]
+        assert re.findall(call, comps[more], re.M) == ["f32"]
+        body = re.search(r" while\(.*?body=(%[\w.-]+)", comps[more]).group(1)
+        assert re.findall(call, comps[body], re.M) == ["f32"]
+    assert len(re.findall(call, text, re.M)) == 6
 
 
 def _block_gradient_text(mix, data_shape, one_chip):

@@ -328,6 +328,88 @@ def test_every_gradient_is_the_dense_loops(form, case):
         assert float(jnp.abs(g_want["tw"][7, 2])) > 1e-3
 
 
+# ------------------------------------------------------------- the combine
+# case: (the experts its 64 tokens choose among, 3 each, of which 4..7 are
+# held; rows a trip, if not all of the plan's rows)
+COMBINES = {
+    "several_pairs_a_token": (range(3, 9), 0),
+    "dead_rows_in_a_tile": (range(16), 0),
+    "an_expert_with_no_pairs": ([0, 1, 4, 6, 7, 9], 0),
+    "no_pair_held": (range(8, 16), 0),
+    "a_second_trip": (range(2, 10), 128),
+}
+
+
+@pytest.mark.parametrize("case", list(COMBINES))
+@pytest.mark.parametrize("path", ["plain", "kernel"])
+def test_the_combine_is_the_scatter_add(path, case, monkeypatch):
+    """The combine of every trip's float32 rows, the kernel (in interpret
+    mode) and the plain body, against `.at[tok].add` of the same rows into
+    the tokens' rows, with the pairs' weights (the forward) and without (the
+    backward's dx): each token's pairs summed in float32 and nothing else; a
+    row that holds no pair (NaN here) adds nothing; one trip is written in
+    the output's dtype, several summed in float32 first."""
+    among, chunk = COMBINES[case]
+    n, k, held, offset, d, tile = 64, 3, 4, 4, 128, 128
+    ks = jax.random.split(jax.random.PRNGKey(7), 3)
+    ti = jnp.asarray(np.asarray(list(among)))[jnp.argsort(jax.random.uniform(
+        ks[0], (n, len(among))), axis=-1)[:, :k]].astype(jnp.int32)
+    tw = jax.random.uniform(ks[1], (n, k), minval=0.2, maxval=1.0)
+    order, ends, loads = moe._plan_tiled(ti, held, offset, tile)
+    bounds = moe._bounds(ti, held, offset, ends, 32)
+    chunk = chunk or int(max(ends[-1], tile))
+    trips, rows = moe._walk_tiled(order, ends, loads, k, chunk)
+    trips = max(int(trips), 1)      # the first trip is always taken
+    assert (trips > 1) == (case == "a_second_trip"), trips
+    held_pairs = ((ti >= offset) & (ti < offset + held)).sum(axis=1)
+    if case == "several_pairs_a_token":
+        assert int(held_pairs.max()) == k
+    if case == "an_expert_with_no_pairs":
+        assert 0 in np.asarray(loads).tolist()
+    if case == "no_pair_held":
+        assert int(held_pairs.max()) == 0
+    walked = [rows(c) for c in range(trips)]
+    assert any(not bool(live.all()) for _, _, live, _ in walked)  # dead rows
+    got_rows = jnp.stack([jnp.where(live[:, None], jax.random.normal(
+        jax.random.fold_in(ks[2], c), (chunk, d)), jnp.nan)
+        for c, (_, _, live, _) in enumerate(walked)])
+    tokens = None
+    if path == "kernel":
+        call, tokens = moe._combine_call, 32
+        monkeypatch.setattr(moe, "_combine_call", lambda *a: call(
+            *a, interpret=True))
+        monkeypatch.setattr(moe, "_lowered",
+                            lambda tiles, kernel, plain, *a: kernel(*a))
+    for weighted in (True, False):
+        want = jnp.zeros((n, d), jnp.float32)
+        for c, (pairs, tok, live, _) in enumerate(walked):
+            y = got_rows[c] * (tw.reshape(-1)[pairs][:, None]
+                               if weighted else 1.0)
+            want = want.at[tok].add(jnp.where(live[:, None], y, 0.0))
+        pairs_of = jnp.stack([p for p, _, _, _ in walked])
+        toks = jnp.stack([t for _, t, _, _ in walked])
+        lives = jnp.stack([v for _, _, v, _ in walked])
+
+        def trip(c, carry):
+            w = tw.reshape(-1)[pairs_of[c]] if weighted else None
+            return (got_rows[c], toks[c], w, lives[c]), carry + 1
+
+        out, carry = moe._combined(trips, trip, 0, bounds, chunk, n,
+                                   jnp.bfloat16, tokens)
+        assert int(carry) == trips and out.dtype == jnp.bfloat16
+        np.testing.assert_allclose(
+            np.asarray(out, np.float32),
+            np.asarray(want.astype(jnp.bfloat16), np.float32),
+            rtol=1e-2, atol=1e-2)
+        # in float32, to the rounding of a sum of at most k rows
+        out32, _ = moe._combined(trips, trip, 0, bounds, chunk, n,
+                                 jnp.float32, tokens)
+        np.testing.assert_allclose(np.asarray(out32), np.asarray(want),
+                                   rtol=1e-6, atol=1e-6)
+        if case == "no_pair_held":
+            assert float(jnp.abs(out32).max()) == 0.0
+
+
 # ------------------------------------------ the ungated squared-ReLU form
 # the Nemotron-H rehearsal's expert layer: 16 experts of width 32, 3 a
 # token, a shared one of 64; 16 shares of ONE expert each add up below

@@ -488,3 +488,55 @@ def test_ssd_kernels_on_chip():
     for name, a, b_ in zip(("y", "dx", "ddt", "dA_log", "dB", "dC", "dD"),
                            got, want):
         assert _rel(a, b_) < 8e-3, (name, _rel(a, b_))
+
+
+@pytest.mark.parametrize("d,f,k,experts,gated", [
+    (3072, 1024, 10, 256, True),    # laguna-s-2.1-fit-s4096
+    (2688, 1856, 6, 128, False),    # nemotron-twotower-30b-fit-s4096
+    (2688, 1856, 6, 8, False),      # a deployment: 6 of the 8 held a token
+], ids=["laguna", "nemotron", "thirteen_trips"])
+def test_moe_combine_kernel_on_chip(d, f, k, experts, gated):
+    """The expert layer (8,192 tokens, 8 experts held, bfloat16) at the two
+    cells' sizes, one trip, and with every token routed to 6 of the 8 held,
+    6,144 rows an expert, thirteen trips of 4,096 rows: with the combine
+    kernel, one call a direction and, behind a first trip, one a trip into
+    the float32 sum the loop carries, against the same layer with the plain
+    combine, the scatter-add; the output and the data's, the routing
+    weights' and every stacked leaf's gradients at a bfloat16 rounding of
+    each other, since the two sum the same float32 rows in another order."""
+    import jax
+    import jax.numpy as jnp
+    from mxtpu.ops import moe
+    _require_accel()
+    ks = jax.random.split(jax.random.PRNGKey(42), 6)
+    x = jax.random.normal(ks[0], (8192, d), jnp.bfloat16)
+    index = jnp.argsort(jax.random.uniform(ks[1], (8192, experts)),
+                        axis=-1)[:, :k].astype(jnp.int32)
+    weight = jax.random.uniform(ks[2], (8192, k), jnp.float32, 0.1, 1.0)
+    ups = [(jax.random.normal(ks[3 + i], (8, f, d)) * 0.02).astype("bfloat16")
+           for i in range(2 if gated else 1)]
+    down = (jax.random.normal(ks[5], (8, d, f)) * 0.02).astype("bfloat16")
+    w = jax.random.normal(ks[0], (8192, d))
+    trips = -(-int(moe._plan_tiled(index, 8, 0, moe.TILE)[1][-1])
+              // moe.CHUNK)
+    assert trips == (13 if experts == 8 else 1), trips
+
+    def layer(x, weight, *leaves):
+        return moe.moe_experts(x, weight, index, leaves[0] if gated else None,
+                               leaves[-2], leaves[-1], experts, 0)[0]
+
+    args = (x, weight, *ups, down, w)
+    assert moe.COMBINE_KERNEL_NAME in \
+        _with_grads(layer).lower(*args).as_text()
+    got = _with_grads(layer)(*args)
+    blocks, moe._combine_blocks = moe._combine_blocks, lambda *a: None
+    try:
+        assert moe.COMBINE_KERNEL_NAME not in \
+            _with_grads(layer).lower(*args).as_text()
+        plain = _with_grads(layer)(*args)
+    finally:
+        moe._combine_blocks = blocks
+    assert got[0].dtype == jnp.bfloat16
+    for a, b_ in zip(got, plain):
+        assert a.dtype == b_.dtype
+        assert _rel(a, b_) < 6e-3
